@@ -60,33 +60,28 @@ def main():
                 for k in ("text.tokenize.calls",
                           "data.wire.bytes_shipped")}
 
-    try:
-        loss = lm.loss_fn()
-        opt = optax.adam(3e-3)
-        opt_state = opt.init(params)
+    loss = lm.loss_fn()
+    opt = optax.adam(3e-3)
+    opt_state = opt.init(params)
 
-        @jax.jit
-        def step(p, o, wire):
-            tokens = wire.astype(jnp.int32)  # the TokenCodec prologue
-            l, g = jax.value_and_grad(loss)(p, tokens)
-            updates, o = opt.update(g, o)
-            return optax.apply_updates(p, updates), o, l
+    @jax.jit
+    def step(p, o, wire):
+        tokens = wire.astype(jnp.int32)  # the TokenCodec prologue
+        l, g = jax.value_and_grad(loss)(p, tokens)
+        updates, o = opt.update(g, o)
+        return optax.apply_updates(p, updates), o, l
 
-        for epoch in range(2):
-            c0 = counters()
-            for (wire,) in ds.iter_epoch(epoch):
-                params, opt_state, l = step(params, opt_state, wire)
-            c1 = counters()
-            print(f"epoch {epoch}: loss {float(l):.3f}, "
-                  f"{c1['text.tokenize.calls'] - c0['text.tokenize.calls']}"
-                  f" tokenize calls, "
-                  f"{c1['data.wire.bytes_shipped'] - c0['data.wire.bytes_shipped']}"
-                  " wire bytes"
-                  + ("  <- warm replay: both zero" if epoch else ""))
-    except ImportError as e:
-        # jax builds without top-level shard_map cannot run the full
-        # forward; generation below uses the decode path regardless
-        print(f"skipping fine-tune ({e}); generating from init weights")
+    for epoch in range(2):
+        c0 = counters()
+        for (wire,) in ds.iter_epoch(epoch):
+            params, opt_state, l = step(params, opt_state, wire)
+        c1 = counters()
+        print(f"epoch {epoch}: loss {float(l):.3f}, "
+              f"{c1['text.tokenize.calls'] - c0['text.tokenize.calls']}"
+              f" tokenize calls, "
+              f"{c1['data.wire.bytes_shipped'] - c0['data.wire.bytes_shipped']}"
+              " wire bytes"
+              + ("  <- warm replay: both zero" if epoch else ""))
 
     # -- 3. ragged prompts -> completions, bucketed programs ----------
     gen = LMGenerator(inputCol="prompt", outputCol="story", model=lm,

@@ -1,88 +1,55 @@
 #!/usr/bin/env bash
-# One-command gate (ref: python/run-tests.sh — SURVEY.md §2.5): the full
-# suite on the simulated 8-device CPU mesh, then the driver's multi-chip
-# dry run, then a single-chip compile check of the flagship entry point.
+# One-command gate (ref: python/run-tests.sh — SURVEY.md §2.5): the
+# linter, the acceptance subsets one by one (each on its own line so a
+# regression there fails loudly, not inside the full-suite noise), the
+# full suite on the simulated 8-device CPU mesh, then the multi-chip dry
+# run and the entry-point compile check. All of it is a CPU run
+# (JAX_PLATFORMS=cpu below); the on-chip check is `python chip_smoke.py`
+# through the chip tool.
 set -euo pipefail
 cd "$(dirname "$0")"
+export JAX_PLATFORMS=cpu
 
 echo "== tpudl-check (AST invariant linter, ANALYSIS.md + CONCURRENCY.md) =="
 python -m tools.tpudl_check tpudl tools bench.py
+python -m tools.tpudl_check --registry-audit tpudl tools bench.py
 
 echo "== tsan pass (lock sanitizer armed over the concurrency subset) =="
-# exit reports go to a scratch dir, not the checkout. Target the
-# concurrency module DIRECTLY: collecting all of tests/ drags in
-# modules whose imports fail on older jax (collection errors make
-# pytest exit 1 even with --continue-on-collection-errors, and set -e
-# would kill the whole gate before the main suite runs). User args go
+# exit reports go to a scratch dir, not the checkout. User args go
 # FIRST: pytest keeps the last -m, so a caller's -m (e.g. 'not slow')
 # must not replace the concurrency marker and run everything armed.
 TPUDL_TSAN=1 TPUDL_FLIGHT_DIR="$(mktemp -d)" \
     python -m pytest tests/test_concurrency.py -q "$@" -m concurrency
 
 echo "== traceguard subset (jit-boundary rules + traceck sentinel) =="
-# Target the traceguard module DIRECTLY (same rationale as the armed
-# concurrency subset above: an unrelated jax-version collection error
-# exits pytest 1 under set -e). The armed-sentinel cases run in
-# subprocesses the tests spawn themselves, so no env is set here.
+# The armed-sentinel cases run in subprocesses the tests spawn
+# themselves, so no env is set here.
 python -m pytest tests/test_traceguard.py -q "$@"
 
 echo "== chaos subset (fault-containment matrix, ISSUE 14 acceptance) =="
-# Target the supervisor module DIRECTLY (same rationale as the armed
-# concurrency subset above: an unrelated jax-version collection error
-# exits pytest 1 under set -e). User args go FIRST so a caller's -m
-# cannot replace the chaos marker and skip the matrix.
+# User args go FIRST so a caller's -m cannot replace the chaos marker
+# and skip the matrix.
 python -m pytest tests/test_supervisor.py -q "$@" -m chaos
 
 echo "== compile subset (ISSUE 15: buckets + AOT store acceptance) =="
-# Target the compile module DIRECTLY (same rationale as the armed
-# concurrency subset above): the zero-retrace traceck sweep and the
-# kill-mid-precompile case run in subprocesses the tests spawn
-# themselves, and an unrelated jax-version collection error must not
-# mask a compile-subsystem regression under set -e.
 python -m pytest tests/test_compile.py -q "$@"
 
 echo "== virtual-mesh executor subset (ISSUE 11 acceptance) =="
-# Target the mesh-executor module DIRECTLY (same rationale as the
-# armed concurrency subset above): a jax-version collection error in
-# an unrelated module exits pytest 1 even with
-# --continue-on-collection-errors, and set -e would otherwise let that
-# mask a mesh regression inside the full-suite noise.
 python -m pytest tests/test_mesh_executor.py -q "$@"
 
 echo "== 2-D mesh tensor parallelism subset (ISSUE 16 acceptance) =="
-# Target the mesh2d module DIRECTLY (same rationale as the armed
-# concurrency subset above): the TP parity matrix, the HLO collective
-# pin and the 2-D warm-restore subprocess must fail loudly on their
-# own line, not inside the full-suite noise.
 python -m pytest tests/test_mesh2d.py -q "$@"
 
 echo "== serve subset (ISSUE 17: continuous batching acceptance) =="
-# Target the serve module DIRECTLY (same rationale as the armed
-# concurrency subset above): the zero-retrace serve-loop sweep and
-# the overload-chaos burst run in subprocesses the tests spawn
-# themselves, and must fail loudly on their own line.
 python -m pytest tests/test_serve.py -q "$@"
 
 echo "== serve telemetry subset (ISSUE 18: traces + SLO acceptance) =="
-# Target the telemetry module DIRECTLY (same rationale as the armed
-# concurrency subset above): the segment-sum contract, the windowed-
-# vs-loadgen percentile agreement and the slo_burn doctor fixtures
-# must fail loudly on their own line.
 python -m pytest tests/test_serve_telemetry.py -q "$@"
 
 echo "== text subset (ISSUE 19: tokenizer codec + tokens/s acceptance) =="
-# Target the text module DIRECTLY (same rationale as the armed
-# concurrency subset above): the traceck-armed ragged prompt sweep
-# runs in a subprocess the test spawns itself, and the epoch-2
-# zero-tokenize/zero-wire warm-replay pin must fail loudly on its
-# own line.
 python -m pytest tests/test_text.py -q "$@"
 
 echo "== attribution subset (ISSUE 20: scoped ledgers acceptance) =="
-# Target the attribution module DIRECTLY (same rationale as the armed
-# concurrency subset above): the two-tenant acceptance (serve loop +
-# concurrent fit reconciling exactly), the cross-pool scope carries
-# and the TSAN-armed ledger pass must fail loudly on their own line.
 python -m pytest tests/test_obs_attribution.py -q "$@"
 
 echo "== pytest (simulated 8-device CPU mesh) =="
@@ -91,19 +58,15 @@ python -m pytest tests/ -q "$@"
 echo "== multi-chip dryrun (8-device virtual mesh) =="
 python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
-echo "== single-chip entry compile check =="
-python - <<'EOF'
-import os
-os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
-    " --xla_force_host_platform_device_count=8"
+echo "== single-chip entry compile check (CPU; the chip's is chip_smoke.py) =="
+XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=8" python - <<'EOF2'
 import jax
-jax.config.update("jax_platforms", "cpu")  # CI-safe; TPU hosts: remove
 import numpy as np
 import __graft_entry__ as g
 fn, args = g.entry()
 out = np.asarray(jax.jit(fn)(*args))
 assert np.isfinite(out).all()
 print(f"entry() ok: {out.shape}")
-EOF
+EOF2
 
 echo "ALL GATES GREEN"

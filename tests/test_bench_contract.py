@@ -3,8 +3,8 @@
 The driver stores only a ~2,000-char stdout TAIL of ``bench.py`` and
 parses its last line as the judged record. Round 4 emitted one large
 JSON line with the headline keys FIRST, so the tail held the cut-off
-END of the record and the driver parsed nothing (BENCH_r04.json:
-``parsed: null``). These tests pin the fixed contract against the REAL
+END of the record and the driver parsed nothing (the round-4 driver
+record: ``parsed: null``). These tests pin the fixed contract against the REAL
 round-4 rehearsal record (committed at
 ``bench_records/bench_r04_rehearsal.json``): the compact summary must
 carry the judged keys, fit comfortably inside the tail window, and be
@@ -163,8 +163,8 @@ def test_quick_run_under_tight_budget_emits_summary_last(tmp_path):
     """The round-6 budget contract: a QUICK run whose TPUDL_BENCH_BUDGET_S
     is already spent must SKIP every sub-bench, exit 0 fast, and still
     print a parseable compact summary (flagged partial) as the LAST
-    stdout line — the failure mode this kills is BENCH_r05.json's
-    rc=124/parsed=null driver timeout."""
+    stdout line — the failure mode this kills is the round-5 driver
+    record's rc=124/parsed=null timeout."""
     import subprocess
 
     env = dict(os.environ)
@@ -259,3 +259,14 @@ def test_emit_summary_survives_unserializable_record(bench, monkeypatch,
         if os.path.exists(rec_path):
             os.remove(rec_path)
         bench._EMITTED.clear()
+
+
+def test_peak_flops_is_keyed_by_device_kind_and_unknown_is_an_error(bench):
+    """A utilisation divided by another chip's peak is a wrong number:
+    the table knows the v5e by jax's device_kind and raises for any
+    kind it does not list (the CPU included)."""
+    assert bench.peak_flops("TPU v5 lite") == 197e12
+    with pytest.raises(RuntimeError, match="no published peak"):
+        bench.peak_flops("TPU v7x")
+    with pytest.raises(RuntimeError, match="no published peak"):
+        bench.peak_flops()  # the live backend here is the CPU

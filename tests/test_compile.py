@@ -1,7 +1,8 @@
 """tpudl.compile — shape-bucketed AOT program store (ISSUE 15).
 
-Covers the grown compilation-cache module (env precedence, "0" kill
-switch, loud failure), the bucket ladder, the program store (manifest
+Covers the compilation-cache module (placement by
+JAX_COMPILATION_CACHE_DIR, fixed in-checkout default, loud failure),
+the bucket ladder, the program store (manifest
 round trip, serialized-executable restore, corruption recovery), the
 executor wiring (bucketed-vs-exact bitwise parity across
 depth×donate×fuse×mesh8, AOT hit/miss accounting), the traceck-armed
@@ -59,43 +60,50 @@ def _metric(name):
 
 
 # ---------------------------------------------------------------------------
-# satellite: enable_compilation_cache — precedence, kill switch, loudness
+# satellite: enable_compilation_cache — placement, loudness
 # ---------------------------------------------------------------------------
 
 class TestCompilationCache:
-    def _restore(self):
-        import jax as j
+    """Placement is decided outside the code: JAX_COMPILATION_CACHE_DIR
+    when set (jax reads it itself — nothing is set here), else ONE
+    fixed in-checkout path. The program store's default follows."""
 
-        return j.config.jax_compilation_cache_dir
+    def test_jax_env_wins_and_config_is_untouched(self, tmp_path,
+                                                  monkeypatch):
+        placed = str(tmp_path / "placed")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+        monkeypatch.delenv("TPUDL_COMPILE_AOT", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        assert ccache.enable_compilation_cache() == placed
+        # an explicit path does not override the operator's placement
+        assert ccache.enable_compilation_cache(
+            str(tmp_path / "arg")) == placed
+        assert jax.config.jax_compilation_cache_dir == before
+        assert not (tmp_path / "arg").exists()
+        assert C.store_dir() == os.path.join(placed, "programs")
 
-    def test_explicit_path_beats_env(self, tmp_path, monkeypatch):
-        prev = self._restore()
+    def test_unset_env_uses_fixed_in_checkout_path(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.delenv("TPUDL_COMPILE_AOT", raising=False)
+        fixed = os.path.join(REPO, ".jax_cache")
+        assert ccache.DEFAULT_CACHE_DIR == fixed
+        prev = jax.config.jax_compilation_cache_dir
         try:
-            monkeypatch.setenv("TPUDL_COMPILE_CACHE_DIR",
-                               str(tmp_path / "envdir"))
+            assert ccache.enable_compilation_cache() == fixed
+            assert jax.config.jax_compilation_cache_dir == fixed
+        finally:
+            jax.config.update("jax_compilation_cache_dir", prev)
+        assert C.store_dir() == os.path.join(fixed, "programs")
+
+    def test_explicit_path_when_env_unset(self, tmp_path, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        prev = jax.config.jax_compilation_cache_dir
+        try:
             got = ccache.enable_compilation_cache(str(tmp_path / "arg"))
             assert got == str(tmp_path / "arg")
             assert os.path.isdir(got)
         finally:
             jax.config.update("jax_compilation_cache_dir", prev)
-
-    def test_env_beats_default(self, tmp_path, monkeypatch):
-        prev = self._restore()
-        try:
-            monkeypatch.setenv("TPUDL_COMPILE_CACHE_DIR",
-                               str(tmp_path / "envdir"))
-            assert ccache.enable_compilation_cache() == \
-                str(tmp_path / "envdir")
-        finally:
-            jax.config.update("jax_compilation_cache_dir", prev)
-
-    def test_zero_kill_switch_beats_explicit_path(self, tmp_path,
-                                                  monkeypatch):
-        monkeypatch.setenv("TPUDL_COMPILE_CACHE_DIR", "0")
-        assert ccache.enable_compilation_cache(str(tmp_path)) is None
-        assert ccache.enable_compilation_cache() is None
-        # the deliberate kill switch is silent: no breadcrumb, no warn
-        assert _metric("compile.cache_disabled") is None
 
     def test_failure_is_loud_warn_once_plus_counter(self, tmp_path,
                                                     monkeypatch):
@@ -106,7 +114,7 @@ class TestCompilationCache:
         blocker.write_text("a file where the cache dir path needs a "
                            "directory")
         bad = str(blocker / "sub")  # makedirs → NotADirectoryError
-        monkeypatch.delenv("TPUDL_COMPILE_CACHE_DIR", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         ccache._reset_warned_for_tests()
         with warnings.catch_warnings(record=True) as rec:
             warnings.simplefilter("always")

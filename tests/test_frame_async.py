@@ -2,8 +2,8 @@
 
 The acceptance surface, all tier-1 fast:
 
-1. OVERLAP — with a fault-harness-injected per-dispatch latency (the
-   deterministic tunnel), the depth-D executor sustains ≥ 1.8× the
+1. OVERLAP — with a fault-harness-injected per-dispatch latency (a
+   deterministic slow round-trip), the depth-D executor sustains ≥ 1.8× the
    blocking executor's throughput, and batch N+1 provably dispatches
    while batch N's d2h drain is still in progress;
 2. BOUND — the in-flight window never exceeds D (gauge max AND a live

@@ -1,17 +1,18 @@
 """Driver-gate regression tests for __graft_entry__.
 
-Round-1 post-mortem: MULTICHIP_r01 went red because dryrun_multichip
-assumed the live backend already had n devices (the driver host has ONE
-real TPU chip). These tests pin both halves of the contract:
+Round-1 post-mortem: the first multi-chip dry run went red because
+dryrun_multichip assumed the live backend already had n devices. These
+tests pin the contract:
 
-- the inline path on the simulated 8-device CPU mesh (what the driver's
-  virtual-mesh run exercises), and
-- the self-provisioning subprocess path taken when fewer devices are
-  live than requested.
+- the inline path on the simulated 8-device CPU mesh,
+- the self-provisioning subprocess path taken when fewer CPU devices
+  are live than requested, and
+- the refusal to leave an accelerator backend for the CPU.
 """
 
 import jax
 import numpy as np
+import pytest
 
 
 def test_entry_compiles_and_runs():
@@ -34,9 +35,25 @@ def test_dryrun_multichip_inline_8():
 def test_dryrun_multichip_self_provisions():
     """With fewer visible devices than requested the dryrun must re-exec
     itself onto a virtual CPU mesh instead of dying with
-    'needs N devices, have 1' (the MULTICHIP_r01 failure)."""
+    'needs N devices, have 1'."""
     import __graft_entry__ as g
 
     # We can't shrink the live backend in-process, so drive the subprocess
     # branch by asking for more devices than the suite's simulated 8.
     g.dryrun_multichip(16)
+
+
+def test_dryrun_multichip_never_leaves_a_tpu_for_the_cpu(monkeypatch):
+    """On a live accelerator a short device count raises: a process
+    that holds a chip must not re-execute on a virtual CPU mesh and
+    report that as the chip's result."""
+    import subprocess
+
+    import __graft_entry__ as g
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        subprocess, "run",
+        lambda *a, **k: pytest.fail("re-executed from a tpu backend"))
+    with pytest.raises(RuntimeError, match="refusing to re-execute"):
+        g.dryrun_multichip(jax.device_count() + 1)

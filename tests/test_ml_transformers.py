@@ -191,6 +191,27 @@ class TestTFImageTransformer:
 
 # -- named models ----------------------------------------------------------
 class TestNamedImage:
+    def test_ragged_slice_is_resized_to_model_geometry(self):
+        """A named model knows its input geometry, so its pack resizes
+        a mixed-size slice (any real image directory) on the host; a
+        uniform slice is stacked untouched for the on-device resize."""
+        from tpudl.ml.named_image import _model_geometry_pack
+
+        rng = np.random.default_rng(0)
+
+        def struct(h, w):
+            return imageIO.imageArrayToStruct(
+                rng.integers(0, 255, size=(h, w, 3), dtype=np.uint8))
+
+        pack = _model_geometry_pack(24, 32)
+        ragged = pack([struct(16, 16), struct(40, 20), struct(24, 32)])
+        assert ragged.shape == (3, 24, 32, 3) and ragged.dtype == np.uint8
+        uniform = [struct(16, 20), struct(16, 20)]
+        out = pack(uniform)
+        assert out.shape == (2, 16, 20, 3)
+        np.testing.assert_array_equal(
+            out[1], imageIO.imageStructToArray(uniform[1]))
+
     def test_featurizer_matches_zoo_oracle(self):
         from tpudl.ml import DeepImageFeaturizer
         from tpudl.ml.named_image import load_named_params
@@ -232,8 +253,7 @@ class TestNamedImage:
         np.testing.assert_allclose(got, want, rtol=1e-6)
 
     def test_warmup_no_fetch_then_transform_matches(self):
-        """``warmup`` compiles+executes WITHOUT any device→host read (the
-        streaming-mode-preserving warm path, BASELINE.md two-mode model)
+        """``warmup`` compiles+executes WITHOUT any device→host read
         and a subsequent transform reuses the warmed program and matches
         the unwarmed transformer's output."""
         from tpudl.ml import DeepImageFeaturizer

@@ -56,7 +56,7 @@ def test_profile_writes_trace(tmp_path):
 
 
 def test_summarize_device_trace():
-    """The trace-viewer aggregation behind PROFILE.md and the bench's
+    """The trace-viewer aggregation behind tools/profile_featurize.py and the bench's
     device_profile record: XLA-Modules lane sums to program time,
     XLA-Ops lane aggregates per-op with category/bytes; host lanes and
     non-TPU processes are ignored."""
@@ -126,6 +126,7 @@ def test_persistent_compilation_cache_round_trip(tmp_path, monkeypatch):
         except Exception:  # private API drift: best effort
             pass
 
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_min_time = jax.config.jax_persistent_cache_min_compile_time_secs
     prev_min_size = jax.config.jax_persistent_cache_min_entry_size_bytes
@@ -158,8 +159,3 @@ def test_persistent_compilation_cache_round_trip(tmp_path, monkeypatch):
         _reset_persistent_cache()
 
 
-def test_compilation_cache_env_disable(monkeypatch):
-    from tpudl.compilation_cache import enable_compilation_cache
-
-    monkeypatch.setenv("TPUDL_COMPILE_CACHE_DIR", "0")
-    assert enable_compilation_cache() is None

@@ -156,6 +156,31 @@ def _run_child(tmp_path, body, env_extra=None, sig=None, timeout=60):
     return proc.returncode, out, err
 
 
+def test_dump_never_brings_a_backend_up():
+    """``import tpudl`` loads jax; a flight dump from a process that
+    meant to stay off the device (a bench parent before its trial
+    children, a dying interpreter) must not initialise a backend — on a
+    TPU host the first ``jax.device_count()`` TAKES the chip."""
+    import subprocess
+    import sys
+
+    code = (
+        "import tpudl\n"
+        "from tpudl.obs import flight\n"
+        "from jax._src import xla_bridge as xb\n"
+        "snap = flight.get_recorder().snapshot('probe')\n"
+        "assert snap['backend'] == {'jax_loaded': True, "
+        "'backend_up': False}, snap['backend']\n"
+        "assert not xb.backends_are_initialized()\n"
+        "import jax; jax.devices()\n"
+        "assert flight._jax_info()['device_count'] >= 1\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120,
+                       cwd=os.path.dirname(os.path.dirname(
+                           os.path.abspath(__file__))))
+    assert r.returncode == 0, r.stderr[-1500:]
+
+
 class TestDumpTriggers:
     def test_unhandled_exception_dumps(self, forensics, tmp_path):
         rc, _out, err = _run_child(tmp_path, (
@@ -663,10 +688,7 @@ class TestRestartForensics:
                 raise RuntimeError("nan loss at step 7")
             return "ok"
 
-        try:
-            result = HorovodRunner(np=1, max_restarts=1).run(main)
-        except AttributeError as e:  # pre-existing jax-version mesh gap
-            pytest.skip(f"mesh API unavailable in this jax: {e}")
+        result = HorovodRunner(np=1, max_restarts=1).run(main)
         assert result == "ok"
         restarts = forensics.snapshot()["restarts"]
         assert len(restarts) == 1

@@ -57,6 +57,9 @@ class TestStatusFile:
                                   dtype=np.float32).reshape(-1, 4)})
         f.map_batches(lambda a: a.sum(axis=1), ["x"], ["y"],
                       batch_size=32)
+        # the run's heartbeat armed the daemon writer; stop it so a
+        # mid-run frame of its cannot land over the explicit write
+        live.stop_status_writer()
         path = live.write_status(str(status_env))
         assert path and os.path.exists(path)
         assert os.path.basename(path) == \
@@ -85,10 +88,14 @@ class TestStatusFile:
             hb.beat(step=1)
             deadline = time.time() + 5.0
             path = live.status_path(str(status_env))
-            while not os.path.exists(path) and time.time() < deadline:
+            # the writer's first frame may be collected before the
+            # heartbeat is registered; the next one (0.1 s) holds it
+            payload = {"heartbeats": {}}
+            while ("test.work" not in payload["heartbeats"]
+                   and time.time() < deadline):
                 time.sleep(0.02)
-            assert os.path.exists(path)
-            payload = json.load(open(path))
+                if os.path.exists(path):
+                    payload = json.load(open(path))
             assert "test.work" in payload["heartbeats"]
         live.stop_status_writer()
 

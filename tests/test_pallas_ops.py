@@ -76,14 +76,27 @@ class TestFlashKernel:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-4)
 
-    def test_indivisible_block_falls_back(self, qkv):
-        # block sizes are advisory: non-dividing requests shrink to the
-        # largest divisor (gcd) instead of erroring (round-3 ADVICE)
-        q, k, v = (jnp.asarray(a) for a in qkv)
-        want = np.asarray(attention_reference(q, k, v))
-        got = np.asarray(flash_attention(q, k, v, block_q=24, block_k=24,
-                                         interpret=True))
-        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_indivisible_length_pads_and_masks(self, qkv, causal):
+        """A length that is no block multiple (50 over blocks of 16/24)
+        is padded up to one — pad keys masked in-kernel, pad query rows
+        dropped — in all three kernels: values AND grads match dense."""
+        q, k, v = (jnp.asarray(a[:, :50]) for a in qkv)
+
+        def flash(a, b, c):
+            return jnp.sum(flash_attention(
+                a, b, c, causal=causal, block_q=16, block_k=24,
+                interpret=True) ** 2)
+
+        def dense(a, b, c):
+            return jnp.sum(attention_reference(a, b, c, causal=causal) ** 2)
+
+        got, g_got = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
+        want, g_want = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+        for a, b in zip(g_got, g_want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4)
 
 
 class TestRingWithPallas:
@@ -130,8 +143,8 @@ class TestReviewRegressions:
         assert np.all(np.asarray(lse) < -1e29)
 
     def test_ring_pallas_accepts_non_multiple_shards(self, rng):
-        """s_loc=24 (not a multiple of 128) must work via the gcd block,
-        matching the plain ring path."""
+        """s_loc=24 (not a multiple of 128) must work as one clipped
+        block, matching the plain ring path."""
         mesh = M.build_mesh()
         q, k, v = (rng.normal(size=(1, 24 * 8, 2, 16)).astype(np.float32)
                    for _ in range(3))

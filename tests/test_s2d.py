@@ -1,7 +1,8 @@
 """Space-to-depth stem: exact-equivalence oracle tests.
 
 The transform (tpudl/zoo/s2d.py) re-expresses the InceptionV3 stem in
-block-2 s2d form for MXU lane occupancy (PROFILE.md ranks 1/2/10).
+block-2 s2d form for MXU lane occupancy (the July 2026 profile's
+stem convs).
 It must be numerically a REFORMULATION, not an approximation: every
 test here checks against the canonical stem/model at fp32 noise
 tolerance, including the edge machinery (garbage-slot masking where

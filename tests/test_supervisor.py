@@ -120,7 +120,7 @@ class TestTaxonomy:
             RuntimeError("sharding failed"), stage="h2d") == "transfer"
         assert sup.classify_exception(OSError("flaky NFS")) == "transfer"
         assert sup.classify_exception(
-            TimeoutError("tunnel")) == "transfer"
+            TimeoutError("link")) == "transfer"
 
     def test_fatal_never_retried(self):
         assert sup.classify_exception(TypeError("bug")) == "fatal"

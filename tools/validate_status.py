@@ -6,7 +6,7 @@ validate_shards.py, validate_dump.py): a ``tpudl-status-<pid>.json``
 written by :mod:`tpudl.obs.live` must
 
 - parse as ONE complete JSON object — the atomic tmp+rename write
-  contract means a torn/partial file is a bug, not weather;
+  contract means a torn/partial file is a bug, not bad luck;
 - carry every schema key with the right type, with the filename's pid
   matching the payload's;
 - stay SMALL (< 1 MB): the status file is a heads-up display, not a

@@ -176,10 +176,6 @@ KNOBS: tuple[Knob, ...] = (
          "1 enables the space-to-depth conv stem (defaults OFF: slower "
          "on this backend, see zoo/s2d.py)"),
     # -- compile subsystem (COMPILE.md) --------------------------------
-    Knob("TPUDL_COMPILE_CACHE_DIR", "path",
-         "~/.cache/tpudl/xla_cache", "compile",
-         "persistent XLA compilation cache directory (0 disables, "
-         "loudly: warn-once + compile.cache_disabled)"),
     Knob("TPUDL_COMPILE_AOT", "str", "", "compile",
          "arms the AOT program store: 1 = on at "
          "<compile cache dir>/programs, a path = on at that "

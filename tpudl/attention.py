@@ -23,13 +23,11 @@ test mesh to a pod slice.
 
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from jax import shard_map  # top-level since jax 0.6 (pyproject floor)
+from jax import shard_map
 
 from tpudl import mesh as M
 
@@ -90,8 +88,8 @@ def ring_attention(q, k, v, mesh, *, axis: str = M.DATA_AXIS,
     materializes an (S/n)² matrix per device, and strictly-future
     hops/tiles are skipped under causal masking. Partials merge exactly
     via their log-sum-exps (the standard ring/flash-decoding merge).
-    ``pallas_interpret`` defaults to auto (interpret off TPU, compiled
-    on TPU).
+    ``pallas_interpret`` is :func:`flash_attention`'s ``interpret``:
+    None compiles the kernel on TPU and interprets it elsewhere.
     """
     n = mesh.shape[axis]
     if q.shape[1] % n:
@@ -169,15 +167,9 @@ def _ring_attention_pallas(q, k, v, mesh, axis, n, seq_spec, causal,
     softmax, applied across blocks)."""
     from tpudl.pallas_ops import _NEG_INF, flash_attention
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
     def local(qb, kb, vb):
         idx = jax.lax.axis_index(axis)
         s_loc = qb.shape[1]
-        # largest block that divides the shard (min() alone would reject
-        # shard lengths like 192 that the plain ring path accepts)
-        blk = math.gcd(s_loc, block)
         q_off = idx * s_loc
         o0 = jnp.zeros(qb.shape, jnp.float32)
         lse0 = jnp.full((qb.shape[0], s_loc, qb.shape[2]), _NEG_INF,
@@ -194,7 +186,7 @@ def _ring_attention_pallas(q, k, v, mesh, axis, n, seq_spec, causal,
                 kc, vc = args
                 return flash_attention(
                     qb, kc, vc, causal=causal, q_offset=q_off,
-                    k_offset=src * s_loc, block_q=blk, block_k=blk,
+                    k_offset=src * s_loc, block_q=block, block_k=block,
                     interpret=interpret, return_lse=True)
 
             def future(args):
@@ -246,10 +238,6 @@ def _rotate_unless_last(kc, vc, s, n, axis, perm):
 
 def _mark_varying(t, axes):
     """Mark ``t`` device-varying over ``axes`` (a name or tuple of
-    names) under shard_map's varying-axis type tracking (``lax.pcast``
-    on current jax; ``pvary`` is the 0.6–0.7 spelling within the
-    supported floor)."""
+    names) under shard_map's varying-axis type tracking."""
     axes = (axes,) if isinstance(axes, str) else tuple(axes)
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(t, axes, to="varying")
-    return jax.lax.pvary(t, axes)  # pragma: no cover - jax 0.6/0.7
+    return jax.lax.pcast(t, axes, to="varying")

@@ -85,18 +85,15 @@ def aot_enabled(value=None) -> bool:
 def store_dir() -> str:
     """The program store directory: a path-valued ``TPUDL_COMPILE_AOT``
     names it directly; otherwise ``<compilation cache dir>/programs``
-    (the two caches travel together — one operator knob to relocate
-    both)."""
+    (the two caches travel together — ``JAX_COMPILATION_CACHE_DIR``
+    relocates both, :func:`tpudl.compile.cache.cache_dir`)."""
     env = os.environ.get("TPUDL_COMPILE_AOT", "").strip()
     if env and env.lower() not in _TRUTHY \
             and env.lower() not in ("0", "off", "false", "none"):
         return os.path.expanduser(env)
-    from tpudl.compile.cache import DEFAULT_CACHE_DIR
+    from tpudl.compile.cache import cache_dir
 
-    base = os.environ.get("TPUDL_COMPILE_CACHE_DIR")
-    if not base or base == "0":
-        base = DEFAULT_CACHE_DIR
-    return os.path.join(os.path.expanduser(base), "programs")
+    return os.path.join(cache_dir(), "programs")
 
 
 def backend_token() -> dict:

@@ -1,8 +1,8 @@
 """Wire codecs: shrink the host→device representation of prepared batches.
 
-The round-5 diagnosis (BASELINE.md, PROFILE.md) is that the featurize
-executor sits ON the measured H2D wire: every byte a batch does not ship
-is throughput. A :class:`WireCodec` is the two-sided contract that makes
+Where the featurize executor sits ON the H2D wire (the July 2026
+diagnosis; not re-measured on the current machine), every byte a batch
+does not ship is throughput. A :class:`WireCodec` is the two-sided contract that makes
 shipping fewer bytes safe:
 
 - ``encode(arr)`` runs HOST-side in the executor's prepare stage and
@@ -315,8 +315,7 @@ def _auto_pick(arr: np.ndarray) -> WireCodec:
     exact. uint8 columns ship as u8 (every batch of a uint8 column is
     uint8 — lossless by construction); float32 columns ship bf16 when
     the measured wire is slower than ``TPUDL_DATA_BF16_WIRE_MBPS``
-    (default 1000 MB/s — any tunneled link qualifies, a local
-    PCIe/host link does not), identity when the wire is fast or
+    (default 1000 MB/s), identity when the wire is fast or
     unknown (never trade accuracy for a link that was not measured to
     need it). Exact-u8 float encoding is the explicit ``'u8'`` /
     ``U8Codec(scale=...)`` contract, which documents its strictness."""

@@ -1,10 +1,11 @@
 """HBM-tier device-resident batch cache: epoch ≥ 2 ships zero wire bytes.
 
 The top of the cache hierarchy (DATA.md "Cache hierarchy"): disk shards
-(PR 4) killed the re-DECODE, this module kills the re-SHIP. BENCH_r05's
-own decomposition says why it matters: the chip does ~5,144 img/s when
-input is already device-resident vs 89.6 img/s end-to-end, because every
-epoch re-crosses an 8–22 MB/s H2D wire with the same bytes. The
+(PR 4) killed the re-DECODE, this module kills the re-SHIP. The July
+2026 record's decomposition says when it matters: the chip did ~5,144
+img/s with the input already device-resident vs 89.6 img/s end-to-end,
+because every epoch re-crossed an 8–22 MB/s H2D wire with the same
+bytes (the v5e host's own link measures 590–670 MB/s, PERF.md). The
 paper-shaped workloads — featurize-then-fit, multi-epoch estimator
 fitting, repeat batch inference over one table — re-ship *identical*
 bytes every pass, so a :class:`DeviceBatchCache` pins the prepared,

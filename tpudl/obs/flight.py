@@ -1,6 +1,6 @@
 """Flight recorder: the always-on black box that explains a dead run.
 
-BENCH_r05.json ended rc=124 with nothing but an stderr tail — a hung
+A July 2026 driver run ended rc=124 with nothing but an stderr tail — a hung
 infeed and a decode-error storm were indistinguishable from a slow run.
 This module is the post-mortem layer of :mod:`tpudl.obs`
 (OBSERVABILITY.md "Failure forensics"): a process-wide
@@ -87,13 +87,26 @@ def _env_int(name: str, default: int) -> int:
 
 
 def _jax_info() -> dict:
-    """Backend/process facts WITHOUT importing jax: a dump from a
-    host-only pipeline (or a dying interpreter) must not trigger a
-    backend bring-up. Every probe is best-effort — a wedged runtime
-    may fail any of these calls."""
+    """Backend/process facts WITHOUT importing jax or bringing a
+    backend up: ``import tpudl`` alone loads jax, and on a TPU host
+    the first ``jax.device_count()`` TAKES the chip — a dump from a
+    parent that meant to stay off the device (or from a dying
+    interpreter) must not take it from the child that needs it. Every
+    probe is best-effort — a wedged runtime may fail any of these
+    calls."""
     jax = sys.modules.get("jax")
     info: dict = {"jax_loaded": jax is not None}
     if jax is None:
+        return info
+    try:
+        from jax._src import xla_bridge
+
+        info["backend_up"] = bool(xla_bridge.backends_are_initialized())
+    # tpudl: ignore[swallowed-except] — private-API drift reads as "not
+    # up": the dump loses the device facts, never takes a chip
+    except Exception:
+        info["backend_up"] = False
+    if not info["backend_up"]:
         return info
     try:
         info["version"] = getattr(jax, "__version__", None)

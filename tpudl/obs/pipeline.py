@@ -50,7 +50,7 @@ class PipelineReport:
       path (``mesh.transfer_batch`` is async since ISSUE 11 — the
       copies themselves ride under later dispatches, so this stage
       measures the enqueue/pad cost, not the wire; on the mesh=None
-      tunnel path the transfer rides the dispatch, see map_batches);
+      path the transfer rides the dispatch, see map_batches);
     - ``dispatch``: seconds in ``fn(...)`` — on the serial path these
       are consumer-thread seconds (enqueue only for async device fns,
       enqueue+compute for host fns); under the D-deep async dispatch

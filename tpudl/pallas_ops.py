@@ -19,14 +19,16 @@ causal masking stays correct when the caller holds only a shard of the
 sequence (the ring case).
 
 CPU/tests run the same kernel with ``interpret=True`` (pure jax
-semantics, no tiling constraints); on TPU use block sizes that are
-multiples of the (8, 128) f32 tile — the defaults are.
+semantics, no tiling constraints). Compiled, every block is a multiple
+of the 128-lane tile: a sequence that is not is PADDED up to the next
+block multiple and the padded keys are masked in-kernel, so an awkward
+length (S=2047) costs one partial tile, never a whole-sequence block
+that outgrows VMEM.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -48,21 +50,25 @@ def _tile_live(causal, qoff_ref, koff_ref, iq, ik, block_q, block_k):
 
 
 def _masked_scores(q_ref, k_ref, qoff_ref, koff_ref, iq, ik, *, causal,
-                   scale, block_q, block_k, precision):
+                   scale, block_q, block_k, kv_len, precision):
     """QKᵀ·scale with the global-position causal mask applied — the ONE
-    definition of the score tile shared by forward, dq and dkv kernels."""
+    definition of the score tile shared by forward, dq and dkv kernels.
+    ``kv_len`` (static; None when the keys were not padded) masks the
+    pad keys past the real sequence end by their LOCAL index."""
     q = q_ref[0].astype(jnp.float32)
     k = k_ref[0].astype(jnp.float32)
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
                 precision=precision) * scale
+    if causal or kv_len is not None:
+        k_idx = ik * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
     if causal:
         q_pos = (qoff_ref[0] + iq * block_q
                  + jax.lax.broadcasted_iota(jnp.int32,
                                             (block_q, block_k), 0))
-        k_pos = (koff_ref[0] + ik * block_k
-                 + jax.lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 1))
-        s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+        s = jnp.where(q_pos >= koff_ref[0] + k_idx, s, _NEG_INF)
+    if kv_len is not None:
+        s = jnp.where(k_idx < kv_len, s, _NEG_INF)
     return q, k, s
 
 
@@ -76,7 +82,7 @@ def _bwd_p(s, lse):
 
 def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                   m_scr, l_scr, acc_scr, *, causal: bool, scale: float,
-                  block_q: int, block_k: int, precision):
+                  block_q: int, block_k: int, kv_len, precision):
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -96,7 +102,7 @@ def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         _q, _k, s = _masked_scores(
             q_ref, k_ref, qoff_ref, koff_ref, iq, ik, causal=causal,
             scale=scale, block_q=block_q, block_k=block_k,
-            precision=precision)
+            kv_len=kv_len, precision=precision)
 
         m_prev = m_scr[:, 0]                          # [TQ]
         m_new = jnp.maximum(m_prev, s.max(axis=1))
@@ -128,7 +134,8 @@ def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, dlt_ref, dq_ref, dq_scr, *, causal: bool,
-                   scale: float, block_q: int, block_k: int, precision):
+                   scale: float, block_q: int, block_k: int, kv_len,
+                   precision):
     """dq = Σ_k  p ⊙ (dOVᵀ − δ + dlse) · scale @ K, accumulated over the
     innermost K-tile grid dim — same tiling discipline as the forward,
     no S² materialization. δ = rowsum(dO ⊙ O), and ``p = exp(s − lse)``
@@ -146,7 +153,7 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         q, k, s = _masked_scores(
             q_ref, k_ref, qoff_ref, koff_ref, iq, ik, causal=causal,
             scale=scale, block_q=block_q, block_k=block_k,
-            precision=precision)
+            kv_len=kv_len, precision=precision)
         p = _bwd_p(s, lse_ref[0, 0])
         do = do_ref[0].astype(jnp.float32)
         dp = jnp.dot(do, v_ref[0].astype(jnp.float32).T,
@@ -164,7 +171,7 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, dlt_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                     causal: bool, scale: float, block_q: int,
-                    block_k: int, precision):
+                    block_k: int, kv_len, precision):
     """dk = Σ_q (p ⊙ (dOVᵀ − δ + dlse) · scale)ᵀ @ Q ; dv = Σ_q pᵀ @ dO —
     grid over K tiles with the Q-tile dim innermost."""
     ik, iq, nq = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
@@ -181,7 +188,7 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
         q, k, s = _masked_scores(
             q_ref, k_ref, qoff_ref, koff_ref, iq, ik, causal=causal,
             scale=scale, block_q=block_q, block_k=block_k,
-            precision=precision)
+            kv_len=kv_len, precision=precision)
         p = _bwd_p(s, lse_ref[0, 0])
         do = do_ref[0].astype(jnp.float32)
         dv_scr[:] += jnp.dot(p.T, do, preferred_element_type=jnp.float32,
@@ -200,7 +207,8 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
 
 def _pallas_flash_bwd(qh, kh, vh, out, lse, qoff, koff, do, dlse, *,
-                      causal, block_q, block_k, interpret, precision):
+                      causal, block_q, block_k, kv_len, interpret,
+                      precision):
     """Tiled flash backward: (dq, dk, dv) without any S² tensor.
 
     The lse cotangent folds in analytically: ∂lse_i/∂s_ij = p_ij, so the
@@ -218,7 +226,7 @@ def _pallas_flash_bwd(qh, kh, vh, out, lse, qoff, koff, do, dlse, *,
     lse8 = jnp.broadcast_to(lse[:, None, :], (bh_n, 8, s_q))
     dlt8 = jnp.broadcast_to(dlt[:, None, :], (bh_n, 8, s_q))
     kernel_kw = dict(causal=causal, scale=scale, block_q=block_q,
-                     block_k=block_k, precision=precision)
+                     block_k=block_k, kv_len=kv_len, precision=precision)
 
     # dq: grid (BH, Sq/TQ, Sk/TK) — q tile fixed per row, K innermost
     def qi_q(bh, iq, ik):
@@ -284,8 +292,8 @@ def _pallas_flash_bwd(qh, kh, vh, out, lse, qoff, koff, do, dlse, *,
 
 
 @functools.lru_cache(maxsize=32)
-def _flash_fn(causal: bool, block_q: int, block_k: int, interpret: bool,
-              precision):
+def _flash_fn(causal: bool, block_q: int, block_k: int, kv_len,
+              interpret: bool, precision):
     """One custom-VJP'd head-major flash fn per static config: forward
     AND backward are Pallas kernels (pallas_call has no generic
     autodiff), so neither direction materializes an S² tensor."""
@@ -293,7 +301,8 @@ def _flash_fn(causal: bool, block_q: int, block_k: int, interpret: bool,
     def fwd_impl(qh, kh, vh, qoff, koff):
         return _pallas_flash_bh(qh, kh, vh, qoff, koff, causal=causal,
                                 block_q=block_q, block_k=block_k,
-                                interpret=interpret, precision=precision)
+                                kv_len=kv_len, interpret=interpret,
+                                precision=precision)
 
     f = jax.custom_vjp(fwd_impl)
 
@@ -306,12 +315,20 @@ def _flash_fn(causal: bool, block_q: int, block_k: int, interpret: bool,
         do, dlse = cots
         dq, dk, dv = _pallas_flash_bwd(
             qh, kh, vh, out, lse, qoff, koff, do, dlse, causal=causal,
-            block_q=block_q, block_k=block_k, interpret=interpret,
-            precision=precision)
+            block_q=block_q, block_k=block_k, kv_len=kv_len,
+            interpret=interpret, precision=precision)
         return dq, dk, dv, None, None
 
     f.defvjp(fwd, bwd)
     return f
+
+
+def _fit_block(block: int, s: int, align: int) -> tuple[int, int]:
+    """``(block, padded_s)`` for a requested block over a length-``s``
+    axis: the request clipped to the (aligned) sequence and rounded
+    down to ``align``, and ``s`` rounded up to a multiple of it."""
+    block = max(align, min(block, s + (-s % align)) // align * align)
+    return block, s + (-s % block)
 
 
 @functools.partial(
@@ -319,69 +336,68 @@ def _flash_fn(causal: bool, block_q: int, block_k: int, interpret: bool,
                               "return_lse", "precision"))
 def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
                     k_offset=0, block_q: int = 128, block_k: int = 128,
-                    interpret: bool = False, return_lse: bool = False,
-                    precision=None):
+                    interpret: bool | None = None,
+                    return_lse: bool = False, precision=None):
     """Tiled flash attention. q: [B, Sq, H, D], k/v: [B, Sk, H, D] →
     out [B, Sq, H, D] (and, with ``return_lse``, lse [B, Sq, H] —
     ``logsumexp(scores)`` per query row, for ring partial merges).
 
     ``q_offset``/``k_offset`` are the blocks' GLOBAL sequence positions
     for causal masking; they may be traced values (each ring device
-    passes its rotating source position). Block sizes are advisory:
-    non-dividing or Mosaic-unaligned requests shrink to the largest
-    legal divisor (full-dim at worst), so any sequence length works."""
+    passes its rotating source position). Block sizes are advisory: a
+    compiled block is a multiple of the 128-lane tile, and a sequence
+    that is not a block multiple is zero-padded up to one (pad keys
+    masked in-kernel, pad query rows dropped), so any length works
+    with a bounded VMEM footprint.
+
+    ``interpret=None`` picks from the process's default backend: the
+    compiled Mosaic kernel on TPU, the Pallas interpreter anywhere else
+    (Mosaic has no other target)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
-    # non-dividing block requests shrink to the largest divisor (e.g.
-    # S=192, block=128 → 64) instead of erroring — same gcd discipline
-    # as the ring path, so standalone callers get it too
-    block_q = math.gcd(min(block_q, s_q), s_q)
-    block_k = math.gcd(min(block_k, s_k), s_k)
-    if not interpret:
-        # Mosaic tiling: a block's trailing dims must be (8, 128)-aligned
-        # OR equal the full array dim. block_q is the lse lane dim and the
-        # q sublane dim; block_k is the k sublane dim. An unaligned
-        # result falls back to the always-legal full-dim block.
-        if block_q % 128 and block_q != s_q:
-            block_q = s_q
-        if block_k % 8 and block_k != s_k:
-            block_k = s_k
-    assert s_q % block_q == 0 and s_k % block_k == 0
+    align = 1 if interpret else 128
+    block_q, pad_q = _fit_block(block_q, s_q, align)
+    block_k, pad_k = _fit_block(block_k, s_k, align)
 
     # head-major [B*H, S, D]: each grid row owns one (batch, head) pair
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
+    def to_bh(x, padded):
+        x = jnp.pad(x, ((0, 0), (0, padded - x.shape[1]), (0, 0), (0, 0)))
+        return x.transpose(0, 2, 1, 3).reshape(b * h, padded, d)
 
-    qh, kh, vh = to_bh(q), to_bh(k), to_bh(v)
+    qh, kh, vh = to_bh(q, pad_q), to_bh(k, pad_k), to_bh(v, pad_k)
     qoff = jnp.asarray(q_offset, jnp.int32).reshape(1)
     koff = jnp.asarray(k_offset, jnp.int32).reshape(1)
-    out, lse = _flash_fn(causal, block_q, block_k, interpret, precision)(
-        qh, kh, vh, qoff, koff)
-    out = out.reshape(b, h, s_q, d).transpose(0, 2, 1, 3)
+    out, lse = _flash_fn(causal, block_q, block_k,
+                         s_k if pad_k != s_k else None, interpret,
+                         precision)(qh, kh, vh, qoff, koff)
+    out = out.reshape(b, h, pad_q, d).transpose(0, 2, 1, 3)[:, :s_q]
     if not return_lse:
         return out
-    lse = lse.reshape(b, h, s_q).transpose(0, 2, 1)
+    lse = lse.reshape(b, h, pad_q).transpose(0, 2, 1)[:, :s_q]
     return out, lse
 
 
 def _pallas_flash_bh(qh, kh, vh, qoff, koff, *, causal, block_q, block_k,
-                     interpret, precision=None):
+                     kv_len, interpret, precision=None):
     """The raw kernel launch, head-major [BH, S, D] → (out, lse[BH, S])."""
     bh_n, s_q, d = qh.shape
     s_k = kh.shape[1]
     grid = (bh_n, s_q // block_q, s_k // block_k)
     out, lse8 = _launch(qh, kh, vh, qoff, koff, grid=grid, causal=causal,
-                        block_q=block_q, block_k=block_k,
+                        block_q=block_q, block_k=block_k, kv_len=kv_len,
                         interpret=interpret, precision=precision)
     return out, lse8[:, 0, :]
 
 
 def _launch(qh, kh, vh, qoff, koff, *, grid, causal, block_q, block_k,
-            interpret, precision=None):
+            kv_len, interpret, precision=None):
     bh_n, s_q, d = qh.shape
     kernel = functools.partial(
         _flash_kernel, causal=causal, scale=1.0 / (d ** 0.5),
-        block_q=block_q, block_k=block_k, precision=precision)
+        block_q=block_q, block_k=block_k, kv_len=kv_len,
+        precision=precision)
     return pl.pallas_call(
         kernel,
         grid=grid,

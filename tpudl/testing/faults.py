@@ -30,7 +30,7 @@ watch it recover. This module is that demand side:
   - ``delay`` — sleep ``seconds`` on the firing thread: the
     deterministic stand-in for a high-latency dispatch round-trip
     (the async-executor overlap acceptance tests inject a per-dispatch
-    tunnel this way and measure how much of it the D-deep window
+    latency this way and measure how much of it the D-deep window
     hides). ``slow_client`` wraps it for the serve plane's
     ``serve.client`` point: a stalling client whose requests age in
     the queue exercises the deadline-shed path;
@@ -211,7 +211,7 @@ class FaultPlan:
     def delay(cls, point: str, seconds: float,
               first_calls: int | None = None) -> "FaultPlan":
         """Sleep ``seconds`` at every firing of ``point`` (or only its
-        first K) — the deterministic per-dispatch tunnel latency the
+        first K) — the deterministic per-dispatch latency the
         overlap acceptance tests inject (``frame.dispatch``): a D-deep
         window must hide all but ~1/D of it, a blocking executor pays
         it per batch."""
@@ -307,7 +307,7 @@ class FaultPlan:
         if matched.action == "delay":
             # on the FIRING thread deliberately: a delayed dispatch
             # stage blocks its dispatch-window thread exactly like a
-            # slow tunnel round-trip would, so overlap tests measure
+            # slow dispatch round-trip would, so overlap tests measure
             # the executor, not the harness
             time.sleep(matched.seconds)
             return None

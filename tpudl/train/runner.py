@@ -424,7 +424,7 @@ class Trainer:
         # Single host, multi-device: plain shard_batch. A 1-wide data
         # axis needs no explicit sharding: host arrays go straight into
         # the jitted step, whose own arg transfer pipelines (an explicit
-        # per-step device_put serializes on tunneled backends).
+        # per-step device_put is one more blocking call per step).
         multi_host = self.mesh is not None and D.process_count() > 1
         shard_inputs = (self.mesh is not None
                         and self.mesh.shape[M.DATA_AXIS] > 1)

@@ -39,7 +39,7 @@ def _use_s2d_stem(s: Store, x) -> bool:
     transform is derived for.
 
     Default OFF: measured 40.83 ms/step vs the canonical stem's
-    34.26 ms on the real v5e chip (PROFILE.md "space-to-depth" section
+    34.26 ms on the real v5e chip (July 2026 profile, ROADMAP.md A8
     — the s2d reshuffles cost ~4.4 ms of HBM copies and XLA's conv
     already contracts over kh·kw·ci, so 3×3×32 = 288 taps was never
     lane-starved). Kept because the transform is exact and tested; a
@@ -55,7 +55,7 @@ def _use_s2d_stem(s: Store, x) -> bool:
 def _stem_s2d(s: Store, x):
     """The three stem conv+BN+ReLU layers in space-to-depth form
     (tpudl.zoo.s2d — measured SLOWER than the canonical stem on v5e;
-    see _use_s2d_stem above and PROFILE.md). Reads the SAME
+    see _use_s2d_stem above). Reads the SAME
     canonically-named params the plain stem uses, advancing the Namer
     identically, so checkpoints/conversion are unaffected."""
     from tpudl.zoo.s2d import inception_stem_s2d

@@ -6,7 +6,7 @@ space-to-depth form so every 2×2 spatial patch becomes 4× the
 channels, trading 1.78× FLOPs (2×2 windows over 4c channels replace
 3×3 windows over c) for fatter MXU-lane contractions.
 
-**Measured outcome (PROFILE.md "space-to-depth" section): a 19%
+**Measured outcome (July 2026 v5e profile, ROADMAP.md A8): a 19%
 REGRESSION on the real chip — 40.83 ms/step vs the canonical stem's
 34.26 ms — so ``TPUDL_S2D_STEM`` defaults OFF.** Two reasons: XLA's
 TPU convolutions contract over kh·kw·ci, so the canonical 3×3×32 stem

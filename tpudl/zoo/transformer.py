@@ -27,6 +27,13 @@ import numpy as np
 __all__ = ["TinyCausalLM"]
 
 
+def _embed(params, ids):
+    """Token-embedding lookup. ``init`` returns host NumPy arrays, and
+    NumPy cannot be indexed by a traced ``ids`` — lift the table first
+    (a no-op for device arrays and tracers)."""
+    return jnp.asarray(params["embed"]["table"])[ids]
+
+
 def _layer_norm(x, p, eps=1e-5):
     mu = x.mean(-1, keepdims=True)
     var = ((x - mu) ** 2).mean(-1, keepdims=True)
@@ -268,7 +275,7 @@ class TinyCausalLM:
                 f"sequence length {s} exceeds max_len {self.max_len}")
         tp_constrain, head_axis = self._tp_hooks(mesh, tp)
 
-        x = params["embed"]["table"][tokens]              # [B, S, D]
+        x = _embed(params, tokens)                         # [B, S, D]
 
         # rotary-free: learned-position-less (relative order comes from
         # the causal mask; adequate for the convergence tests this
@@ -281,9 +288,7 @@ class TinyCausalLM:
             if use_pallas:
                 from tpudl.pallas_ops import flash_attention
 
-                return flash_attention(
-                    q, k, v, causal=True,
-                    interpret=jax.default_backend() != "tpu")
+                return flash_attention(q, k, v, causal=True)
             return attention_reference(q, k, v, causal=True)
 
         def block(x, p):
@@ -329,7 +334,7 @@ class TinyCausalLM:
                 x, p, lambda q, k, v: attention_reference(q, k, v,
                                                           causal=True))
 
-        x = params["embed"]["table"][tokens]              # [B, S, D]
+        x = _embed(params, tokens)                         # [B, S, D]
         xm = x.reshape(n_micro, b // n_micro, s, self.dim)
         stacked = jax.tree.map(
             lambda *xs: jnp.stack(xs),
@@ -470,7 +475,7 @@ class TinyCausalLM:
                     "clamp onto the last slot")
         except TypeError:
             pass  # traced pos: generate() bounds it via max_len
-        x = params["embed"]["table"][tok][:, None]         # [B, 1, D]
+        x = _embed(params, tok)[:, None]                   # [B, 1, D]
         new_cache = []
 
         def cached_attn(layer):
@@ -533,7 +538,7 @@ class TinyCausalLM:
             raise NotImplementedError(
                 "KV-cache decode for MoE blocks not supported")
         tp_constrain, head_axis = self._tp_hooks(mesh, tp)
-        x = params["embed"]["table"][tok][:, None]         # [S, 1, D]
+        x = _embed(params, tok)[:, None]                   # [S, 1, D]
         new_cache = []
 
         def cached_attn(layer):
