@@ -1,0 +1,9 @@
+"""Share of the traced window in which no program ran on the device: 1 -
+union of the ``XLA Modules`` intervals over the window, mean over chips."""
+
+
+def read(facts):
+    trace = facts.get("trace")
+    if not trace or not trace.get("window_s"):
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
