@@ -41,18 +41,30 @@ def test_named_scope_composes_with_jit():
 
 
 def test_profile_writes_trace(tmp_path):
-    d = str(tmp_path / "trace")
-    with profile(d):
-        jax.block_until_ready(jax.jit(lambda x: x + 1)(np.zeros(4)))
     import os
+    import time
 
-    files = [os.path.join(r, f) for r, _d, fs in os.walk(d) for f in fs]
-    assert files, "profiler produced no trace files"
-    # the capture window is recorded for window="profile" host exports
     from tpudl import obs
 
-    w = obs.get_tracer().last_profile_window
-    assert w is not None and w[1] >= w[0]
+    d = str(tmp_path / "trace")
+    before = time.time_ns()
+    with profile(d):
+        with obs.span("test.profiled"):
+            jax.block_until_ready(jax.jit(lambda x: x + 1)(np.zeros(4)))
+    after = time.time_ns()
+    files = [os.path.join(r, f) for r, _d, fs in os.walk(d) for f in fs]
+    assert files, "profiler produced no trace files"
+    # the trace carries its own window, on the clock spans start on:
+    # nothing is written on the tracer for window="profile" exports
+    start, stop = obs.profile_window(d)
+    assert before <= start <= stop <= after
+    assert not hasattr(obs.get_tracer(), "last_profile_window")
+    path = obs.export_chrome_trace(os.path.join(d, "run.host.trace.json"),
+                                   window="profile")
+    inside = obs.align(obs.load_host_spans(path), start)
+    assert "test.profiled" in [s.name for s in inside]
+    assert all(s.start_ns + s.dur_ns >= 0 and s.start_ns <= stop - start
+               for s in inside)
 
 
 def test_summarize_device_trace():

@@ -1,9 +1,12 @@
-"""Trace parsing + host/device merge + the ``python -m tpudl.obs trace``
-CLI (ISSUE 3 tentpole pillar 1 merge path + satellite 3).
+"""Trace parsing, the host spans beside the device planes on one clock,
+and the ``python -m tpudl.obs trace`` CLI (ISSUE 3 pillar 1; ISSUE 25:
+the shared clock, idle attribution, queue lead, the spans of ``fit``).
 
-Fixtures are synthetic trace-viewer dumps: gzipped JSON with TPU
-process/lane metadata exactly as the jax.profiler writes them, plus a
-CPU-only variant that must summarize to empty rather than crash.
+Fixtures: synthetic trace-viewer dumps (gzipped JSON as the jax.profiler
+writes them) for the device-lane aggregation, and hand-written
+``xplane.pb`` files (a text proto serialized by jax's own ProfileData)
+with a ``/device:TPU:0`` plane and the ``Task Environment`` plane that
+carries the session's start and stop.
 """
 
 import gzip
@@ -12,10 +15,13 @@ import os
 import subprocess
 import sys
 
+import statistics
+import time
+
 import pytest
 
 from tpudl.obs import trace as T
-from tpudl.obs.tracer import Tracer
+from tpudl.obs.tracer import Span, Tracer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -83,6 +89,80 @@ def _write_host_json(trace_dir, events, name="y.host.trace.json"):
     return path
 
 
+START_NS = 1_790_000_000_000_000_000   # the fixture session's start
+STOP_NS = START_NS + 1_000_000         # ... and its stop, 1 ms later
+
+# the device side of the fixtures, ns since the session's start: two
+# runs of jit_step, [100k, 150k] and [220k, 280k], and their operations
+MODULES = [("jit_step(123)", 100_000, 50_000),
+           ("jit_step(123)", 220_000, 60_000)]
+FUSION = "%fusion.1 = (bf16[256]{0:T(256)}) fusion(bf16[256]{0} %copy.2)"
+OPS = [(FUSION, 100_000, 30_000), (FUSION, 220_000, 30_000),
+       ("copy.2", 250_000, 10_000)]
+
+
+def _write_xplane(trace_dir, modules=MODULES, ops=OPS, start=START_NS,
+                  stop=STOP_NS, name="host.xplane.pb", task_plane=True):
+    """A hand-written ``xplane.pb``: one ``/device:TPU:0`` plane (when
+    ``modules`` is not None), a host plane that must be ignored, and the
+    ``Task Environment`` plane with the session's start and stop."""
+    import jax
+
+    def line(title, events, ids):
+        body = "".join(
+            f"events {{ metadata_id: {ids[n]} offset_ps: {s * 1000} "
+            f"duration_ps: {d * 1000} }}\n" for n, s, d in events)
+        return f'lines {{ name: "{title}" timestamp_ns: 0\n{body}}}\n'
+
+    text = ""
+    if modules is not None:
+        ids = {n: i + 1 for i, n in enumerate(
+            dict.fromkeys(n for n, _, _ in list(modules) + list(ops)))}
+        meta = "".join(
+            f'event_metadata {{ key: {i} value {{ id: {i} name: "{n}" }} }}\n'
+            for n, i in ids.items())
+        text += ('planes { name: "/device:TPU:0"\n'
+                 + line("XLA Modules", modules, ids)
+                 + line("XLA Ops", ops, ids)
+                 + line("Steps", modules, ids)  # a line nobody asked for
+                 + meta + "}\n")
+    text += 'planes { name: "/host:CPU" }\n'
+    if task_plane:
+        text += ('planes { name: "Task Environment"\n'
+                 f"stats {{ metadata_id: 1 uint64_value: {start} }}\n"
+                 f"stats {{ metadata_id: 2 uint64_value: {stop} }}\n"
+                 'stat_metadata { key: 1 value { id: 1 '
+                 'name: "profile_start_time" } }\n'
+                 'stat_metadata { key: 2 value { id: 2 '
+                 'name: "profile_stop_time" } }\n}\n')
+    os.makedirs(trace_dir, exist_ok=True)
+    path = os.path.join(trace_dir, name)
+    with open(path, "wb") as f:
+        f.write(jax.profiler.ProfileData.text_proto_to_serialized_xspace(
+            text))
+    return path
+
+
+def _host_tracer(base=START_NS):
+    """Host spans on the epoch clock: frame.prepare [0, 100k] and
+    frame.d2h [150k, 200k] ns after ``base``."""
+    tr = Tracer(ring=16)
+    tr.record("frame.prepare", base, 100_000)
+    tr.record("frame.d2h", base + 150_000, 50_000)
+    return tr
+
+
+def _write_host_export(trace_dir, tracer=None, name="run.host.trace.json"):
+    os.makedirs(trace_dir, exist_ok=True)
+    return (tracer or _host_tracer()).export_chrome_trace(
+        os.path.join(trace_dir, name))
+
+
+def sp(name, start, dur, id, parent=None, tid=1, **attrs):
+    return Span(name, start, dur, id=id, parent=parent, root=1, tid=tid,
+                attrs=attrs or None)
+
+
 class TestTraceParsing:
     def test_load_trace_events_reads_gzipped_fixture(self, tmp_path):
         d = str(tmp_path)
@@ -118,82 +198,321 @@ class TestTraceParsing:
     def test_find_trace_files(self, tmp_path):
         d = str(tmp_path)
         assert T.find_trace_files(d) == {"host": None, "device": None}
-        dev = _write_device_gz(os.path.join(d, "plugins"),
-                               _device_events())
-        host = _write_host_json(d, _host_events())
-        found = T.find_trace_files(d)
-        assert found == {"host": host, "device": dev}
+        # the trace-viewer JSON beside it is not the device trace
+        _write_device_gz(os.path.join(d, "plugins"), _device_events())
+        dev = _write_xplane(os.path.join(d, "plugins"))
+        host = _write_host_export(d)
+        assert T.find_trace_files(d) == {"host": host, "device": dev}
+
+    def test_load_device_planes_and_profile_window(self, tmp_path):
+        d = str(tmp_path)
+        old = _write_xplane(d, modules=MODULES[:1], ops=(),
+                            name="old.xplane.pb", start=1, stop=2)
+        os.utime(old, (1, 1))
+        _write_xplane(os.path.join(d, "plugins", "profile"))
+        planes = T.load_device_planes(d)  # the newest file, TPU planes only
+        assert list(planes) == ["/device:TPU:0"]
+        assert planes["/device:TPU:0"] == {"XLA Modules": MODULES,
+                                           "XLA Ops": OPS}
+        assert T.profile_window(d) == (START_NS, STOP_NS)
+
+    def test_no_device_plane_and_no_task_plane(self, tmp_path):
+        d = str(tmp_path / "cpu")
+        _write_xplane(d, modules=None)
+        assert T.load_device_planes(d) == {}
+        assert T.profile_window(d) == (START_NS, STOP_NS)
+        bare = str(tmp_path / "bare")
+        _write_xplane(bare, task_plane=False)
+        with pytest.raises(ValueError, match="Task Environment"):
+            T.profile_window(bare)
+        with pytest.raises(FileNotFoundError, match="xplane.pb"):
+            T.profile_window(str(tmp_path / "nothing"))
+
+    def test_profile_window_of_a_real_profile_is_bracketed(self, tmp_path):
+        """The clock the whole merge rests on: a real CPU profile taken
+        with the host and Python tracers off carries its own start, and
+        that start lies between two reads of time.time_ns() around
+        start_trace."""
+        import jax
+        import numpy as np
+
+        d = str(tmp_path / "real")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 0
+        before = time.time_ns()
+        jax.profiler.start_trace(d, profiler_options=opts)
+        started = time.time_ns()
+        try:
+            jax.block_until_ready(jax.jit(lambda x: x + 1)(np.zeros(4)))
+        finally:
+            stopping = time.time_ns()
+            jax.profiler.stop_trace()
+        stopped = time.time_ns()
+        start, stop = T.profile_window(d)
+        assert before <= start <= started
+        assert stopping <= stop <= stopped
+
+    def test_export_window_profile_reads_the_trace_beside_it(self, tmp_path):
+        d = str(tmp_path)
+        _write_xplane(os.path.join(d, "plugins"))
+        tr = Tracer(ring=8)
+        tr.record("before", START_NS - 5_000, 1_000)
+        tr.record("inside", START_NS + 5_000, 1_000)
+        tr.record("straddles", STOP_NS - 500, 1_000)
+        tr.record("after", STOP_NS + 5_000, 1_000)
+        path = tr.export_chrome_trace(
+            os.path.join(d, "run.host.trace.json"), window="profile")
+        assert [s.name for s in T.load_host_spans(path)] == [
+            "inside", "straddles"]
+
+    def test_host_spans_survive_the_export_exactly(self, tmp_path):
+        tr = Tracer(ring=8)
+        with tr.span("train.step", step=3) as step:
+            with tr.span("train.step.dispatch"):
+                pass
+        path = _write_host_export(str(tmp_path), tr)
+        dispatch, loaded = T.load_host_spans(path)
+        assert (loaded.id, loaded.parent, loaded.root) == (
+            step.id, None, step.id)
+        assert (loaded.start_ns, loaded.dur_ns) == (step.start_ns,
+                                                    step.dur_ns)
+        assert loaded.attrs == {"step": 3} and loaded.tid == step.tid
+        assert dispatch.parent == step.id and dispatch.attrs is None
+
+
+    def test_export_without_identity_still_loads(self, tmp_path):
+        """An export written before spans had ids (no args): fresh ids,
+        no parents, nanoseconds from the float microseconds."""
+        path = _write_host_json(str(tmp_path), _host_events(base=5000.0))
+        prepare, d2h = T.load_host_spans(path)
+        assert (prepare.name, prepare.start_ns, prepare.dur_ns) == (
+            "frame.prepare", 5_000_000, 100_000)
+        assert (d2h.start_ns, d2h.dur_ns, d2h.tid) == (5_150_000, 50_000, 1)
+        assert prepare.parent is None and prepare.id != d2h.id
+        assert prepare.attrs is None
+
+
+class TestSharedClock:
+    def test_align_is_one_subtraction(self):
+        spans = [sp("a", START_NS + 40, 10, 1), sp("b", START_NS - 5, 7, 2,
+                                                   parent=1, step=4)]
+        a, b = T.align(spans, START_NS)
+        assert (a.start_ns, a.dur_ns, b.start_ns, b.dur_ns) == (40, 10, -5, 7)
+        assert (b.id, b.parent, b.attrs) == (2, 1, {"step": 4})
+        assert spans[0].start_ns == START_NS + 40  # the ring's are untouched
+
+    def test_attribute_idle_names_the_innermost_covering_span(self):
+        """Gaps: [150k,220k] between the runs; with the session's window
+        also [0,100k] before the first and [280k,400k] after the last."""
+        spans = [
+            sp("train.fit", 60_000, 300_000, 1),
+            sp("train.step", 140_000, 100_000, 2, parent=1),
+            sp("train.step.data", 150_000, 10_000, 3, parent=2),
+            sp("train.step.place", 160_000, 55_000, 4, parent=2),
+            sp("train.step.dispatch", 215_000, 20_000, 5, parent=2),
+            # another thread's span covers everything and answers nothing
+            sp("frame.prepare", 0, 400_000, 6, tid=2),
+        ]
+        got = T.attribute_idle(MODULES, spans)
+        assert got["gaps"] == [{"start_ns": 150_000, "dur_ns": 70_000,
+                                "span": "train.step.place", "id": 4}]
+        assert got["by_span"] == {"train.step.place": 70_000}
+        assert got["idle_ns"] == 70_000
+        whole = T.attribute_idle(MODULES, spans, window=(0, 400_000))
+        assert [(g["start_ns"], g["dur_ns"], g["span"]) for g in
+                whole["gaps"]] == [
+            (0, 100_000, "(no span)"),          # [0,60k] bare, [60k,100k] fit
+            (150_000, 70_000, "train.step.place"),
+            (280_000, 120_000, "train.fit")]    # [280k,360k] fit, 40k bare
+        assert whole["by_span"] == {"(no span)": 100_000,
+                                    "train.step.place": 70_000,
+                                    "train.fit": 120_000}
+        assert whole["idle_ns"] == 290_000
+
+    def test_attribute_idle_without_spans_or_without_programs(self):
+        bare = T.attribute_idle(MODULES, [])
+        assert bare["by_span"] == {"(no span)": 70_000}
+        assert T.attribute_idle([], [sp("x", 0, 10, 1)]) == {
+            "gaps": [], "by_span": {}, "idle_ns": 0}
+
+    def test_queue_lead_pairs_runs_with_dispatches(self):
+        spans = [
+            sp("train.step.dispatch", 10_000, 20_000, 2),    # ends 30k
+            sp("train.step.dispatch", 40_000, 200_000, 3),   # ends 240k
+            sp("train.step.data", 35_000, 1_000, 4),
+        ]
+        mods = MODULES + [("jit_eval(9)", 300_000, 5_000)]
+        # run 0 starts 70k after dispatch 0 ended: the host ran ahead;
+        # run 1 starts 20k BEFORE dispatch 1 returned: the host waited
+        assert T.queue_lead(mods, spans, "jit_step") == [70_000, -20_000]
+        with pytest.raises(ValueError, match="1 runs of 'jit_eval'.*2 train"):
+            T.queue_lead(mods, spans, "jit_eval")
+        with pytest.raises(ValueError, match="not paired"):
+            T.queue_lead(mods, spans[:1], "jit_step")
+
+    def test_traced_fit_medians_and_selection(self):
+        spans = [sp("train.fit", 0, 900, 1)]
+        for i, (dur, dispatch) in enumerate([(100, 70), (120, 80), (90, 75)]):
+            spans.append(sp("train.step", 200 + 150 * i, dur, 10 + i,
+                            parent=1, step=i))
+            spans.append(sp("train.step.dispatch", 210 + 150 * i, dispatch,
+                            20 + i, parent=10 + i))
+        spans.append(sp("train.fit", 5_000, 50, 2))  # newer, no steps
+        got = T.traced_fit(spans)
+        assert got["fit"].id == 1 and [s.id for s in got["steps"]] == [
+            10, 11, 12]
+        assert got["step_host_ns"] == statistics.median([30, 40, 15])
+        assert got["dispatch_ns"] == 75 and got["start_ns"] == 200
+        assert T.traced_fit(spans, 3)["fit"].id == 1
+        assert T.traced_fit(spans, 4) is None
+        assert T.traced_fit([]) is None
+
+
+def _fit_spans():
+    """Two steps of a fit beside MODULES, ns since the session's start:
+    dispatch 0 [60k,90k] -> run 0 [100k,150k]; dispatch 1 [160k,215k]
+    -> run 1 [220k,280k]; a compilation inside dispatch 1."""
+    return [
+        sp("train.fit", 20_000, 300_000, 1, steps=2),
+        sp("train.fit.place", 21_000, 30_000, 2, parent=1),
+        sp("train.step", 55_000, 40_000, 3, parent=1, step=0),
+        sp("train.step.data", 56_000, 2_000, 4, parent=3),
+        sp("train.step.dispatch", 60_000, 30_000, 5, parent=3),
+        sp("train.step", 155_000, 62_000, 6, parent=1, step=1),
+        sp("train.step.data", 156_000, 2_000, 7, parent=6),
+        sp("train.step.dispatch", 160_000, 55_000, 8, parent=6),
+        sp("compile.program", 170_000, 40_000, 9, parent=8, cache_hit=False),
+        sp("train.fit.drain", 290_000, 25_000, 10, parent=1),
+    ]
 
 
 class TestMerge:
-    def test_merge_separates_pids_and_normalizes(self):
-        merged = T.merge_trace_events(_host_events(base=5000.0),
-                                      _device_events(base=77000.0))
+    def test_merge_places_both_streams_on_the_session_clock(self):
+        """Neither stream is zeroed on its own first event: a host span
+        that started 40 us into the session sits at ts 40, and the first
+        program run at ts 100, where the trace put it."""
+        spans = T.align(_host_tracer(base=START_NS + 40_000).spans(),
+                        START_NS)
+        merged = T.merge_trace_events(
+            spans, {"/device:TPU:0": {"XLA Modules": MODULES,
+                                      "XLA Ops": OPS}})
         host_x = [e for e in merged
                   if e.get("ph") == "X" and e["pid"] == T.HOST_PID]
-        assert {e["name"] for e in host_x} == {"frame.prepare",
-                                               "frame.d2h"}
-        # each stream re-zeroed on its own start despite wild bases
-        assert min(e["ts"] for e in host_x) == 0.0
+        assert [(e["name"], e["ts"], e["dur"]) for e in host_x] == [
+            ("frame.prepare", 40.0, 100.0), ("frame.d2h", 190.0, 50.0)]
         dev_x = [e for e in merged
                  if e.get("ph") == "X" and e["pid"] != T.HOST_PID]
-        assert min(e["ts"] for e in dev_x) == 0.0
-        # device pids renumbered 1.. — never colliding with the host lane
-        assert T.HOST_PID not in {e["pid"] for e in dev_x}
+        assert min(e["ts"] for e in dev_x) == 100.0
+        # an operation goes by its name, not by its whole HLO text
+        assert {e["name"] for e in dev_x} == {"jit_step(123)", "%fusion.1",
+                                              "copy.2"}
+        # device planes count up from 1 — never colliding with the host
+        assert {e["pid"] for e in dev_x} == {1}
+        lanes = {(e["pid"], e["args"]["name"]) for e in merged
+                 if e.get("ph") == "M" and e["name"] == "thread_name"}
+        assert lanes == {(1, "XLA Modules"), (1, "XLA Ops")}
+        procs = {e["pid"]: e["args"]["name"] for e in merged
+                 if e.get("ph") == "M" and e["name"] == "process_name"}
+        assert procs == {0: "tpudl host", 1: "/device:TPU:0"}
 
     def test_summarize_merged_overlap_math(self):
-        # on the common normalized clock: host busy [0,100]+[150,200],
-        # device modules [0,50]+[120,180] -> overlap [0,50]+[150,180]
-        s = T.summarize_merged(_host_events(), _device_events())
-        assert s["host_busy_us"] == 150.0
-        assert s["host_stage_us"] == {"frame.d2h": 50.0,
-                                      "frame.prepare": 100.0}
-        assert s["host_stage_calls"] == {"frame.d2h": 1,
-                                         "frame.prepare": 1}
-        assert s["device_busy_us"] == 110.0
-        assert s["overlap_us"] == 80.0
-        assert s["host_overlap_frac"] == pytest.approx(80.0 / 150.0,
-                                                       abs=1e-4)
-        assert s["device_busy_frac"] == pytest.approx(110.0 / 180.0,
-                                                      abs=1e-4)
-        assert s["wall_us"] == 200.0
-        assert s["device"]["module_count"] == 2
-        assert s["top_ops"][0]["name"] == "fusion.1"
+        # on the session's clock: host busy [0,100k]+[150k,200k], device
+        # programs [100k,150k]+[220k,280k] -> they never overlap; shifted
+        # 100 us later the host covers [100k,200k]+[250k,300k] -> overlap
+        # [100k,150k]+[250k,280k]
+        planes = {"/device:TPU:0": {"XLA Modules": MODULES, "XLA Ops": OPS}}
+        s = T.summarize_merged(T.align(_host_tracer().spans(), START_NS),
+                               planes, (0, 1_000_000))
+        assert s["host_busy_ns"] == 150_000
+        assert s["host_stage_ns"] == {"frame.d2h": 50_000,
+                                      "frame.prepare": 100_000}
+        assert s["host_stage_calls"] == {"frame.d2h": 1, "frame.prepare": 1}
+        assert s["device_busy_ns"] == 110_000 and s["module_count"] == 2
+        assert s["overlap_ns"] == 0
+        assert s["device_busy_frac"] == pytest.approx(110 / 180, abs=1e-4)
+        assert s["wall_ns"] == 1_000_000
+        assert s["top_ops"][0] == {"name": "%fusion.1", "ns": 60_000,
+                                   "count": 2}
+        later = T.summarize_merged(
+            T.align(_host_tracer(START_NS + 100_000).spans(), START_NS),
+            planes, (0, 1_000_000))
+        assert later["overlap_ns"] == 80_000
+        assert later["host_overlap_frac"] == pytest.approx(80 / 150,
+                                                           abs=1e-4)
+        # one thread, two spans: it is the dispatching thread, and the
+        # gap [150k,220k] has frame.d2h [250k,300k] nowhere near it
+        assert later["idle_by_span"]["frame.prepare"] == 70_000
 
     def test_summarize_merged_host_only_and_device_only(self):
-        s = T.summarize_merged(_host_events(), [])
-        assert s["device_busy_us"] == 0.0
-        assert s["device_busy_frac"] is None
-        assert s["host_busy_us"] == 150.0
-        assert s["overlap_us"] == 0.0
-        s2 = T.summarize_merged([], _device_events())
-        assert s2["host_busy_us"] == 0.0
-        assert s2["host_overlap_frac"] is None
-        assert s2["device_busy_us"] == 110.0
+        s = T.summarize_merged(_host_tracer().spans(), {})
+        assert s["device_busy_ns"] == 0 and s["device_busy_frac"] is None
+        assert s["host_busy_ns"] == 150_000 and s["overlap_ns"] == 0
+        assert s["wall_ns"] == 200_000 and "idle_by_span" not in s
+        s2 = T.summarize_merged(
+            [], {"/device:TPU:0": {"XLA Modules": MODULES}})
+        assert s2["host_busy_ns"] == 0 and s2["host_overlap_frac"] is None
+        assert s2["device_busy_ns"] == 110_000 and s2["fits"] == []
+
+    def test_summary_of_a_fit(self):
+        planes = {"/device:TPU:0": {"XLA Modules": MODULES, "XLA Ops": OPS}}
+        s = T.summarize_merged(_fit_spans(), planes, (0, 400_000))
+        lead = s["queue_lead"]
+        assert (lead["program"], lead["pairs"]) == ("jit_step", 2)
+        assert lead["median_ns"] == 7_500 and lead["min_ns"] == 5_000
+        assert lead["after_dispatch_start"] == {"median_ns": 50_000,
+                                                "min_ns": 40_000}
+        # gaps: [0,100k] (place covers most), [150k,220k] (inside dispatch
+        # 1 the compilation is innermost for 40k), [280k,400k] (past the
+        # fit's end: 80k under no span, 25k drain, 15k fit)
+        assert s["idle_by_span"] == {"(no span)": 120_000,
+                                     "train.fit.place": 100_000,
+                                     "compile.program": 70_000}
+        (fit,) = s["fits"]
+        assert fit["steps"] == 2 and fit["dur_ns"] == 300_000
+        assert fit["device_idle_ns"] == 300_000 - 110_000
+        assert (fit["compilations"], fit["compiled_in_steps"]) == (1, [1])
+
+    def test_queue_lead_refused_when_no_program_matches(self):
+        spans = [s for s in _fit_spans() if s.id != 8]  # one dispatch lost
+        s = T.summarize_merged(
+            spans, {"/device:TPU:0": {"XLA Modules": MODULES}}, (0, 400_000))
+        assert "1 train.step.dispatch" in s["queue_lead"]["refused"]
+        assert "'jit_step': 2" in s["queue_lead"]["refused"]
 
     def test_tracer_export_feeds_merge(self, tmp_path):
         """The real producer path: Tracer.export_chrome_trace output is
-        loadable and mergeable with a device fixture."""
+        loadable and summarizes beside a device fixture."""
         tr = Tracer(ring=16)
         with tr.span("frame.prepare"):
             pass
-        path = os.path.join(str(tmp_path), "run.host.trace.json")
-        tr.export_chrome_trace(path)
-        host_events = T.load_host_trace_events(path)
-        s = T.summarize_merged(host_events, _device_events())
-        assert "frame.prepare" in s["host_stage_us"]
-        assert s["device"]["module_count"] == 2
+        path = _write_host_export(str(tmp_path), tr)
+        (span,) = tr.spans()
+        spans = T.align(T.load_host_spans(path), span.start_ns - 1_000)
+        assert spans[0].start_ns == 1_000
+        s = T.summarize_merged(
+            spans, {"/device:TPU:0": {"XLA Modules": MODULES}})
+        assert "frame.prepare" in s["host_stage_ns"]
+        assert s["module_count"] == 2
 
 
 class TestCLI:
     def test_trace_cli_end_to_end_on_fixtures(self, tmp_path):
-        """ISSUE 3 acceptance: ``python -m tpudl.obs trace <dir>`` on a
-        dir holding a host-span export AND a device trace prints a
-        merged summary (device busy, host stage totals, overlap) and
+        """``python -m tpudl.obs trace <dir>`` on a dir holding a
+        host-span export AND an xplane prints the merged summary on the
+        trace's clock (device busy, host stage totals, overlap, idle by
+        span, queue lead, idle and compilations inside train.fit) and
         writes the merged Chrome trace."""
         d = str(tmp_path)
-        _write_device_gz(d, _device_events())
-        _write_host_json(d, _host_events())
+        _write_xplane(os.path.join(d, "plugins", "profile", "x"))
+        tr = Tracer(ring=32)
+        for s in _fit_spans():
+            tr.record(s.name, START_NS + s.start_ns, s.dur_ns,
+                      parent=next((p for p in tr.spans()
+                                   if p.attrs and p.attrs.get("was") ==
+                                   s.parent), None),
+                      was=s.id, **(s.attrs or {}))
+        _write_host_export(d, tr)
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         proc = subprocess.run(
             [sys.executable, "-m", "tpudl.obs", "trace", d],
@@ -201,39 +520,52 @@ class TestCLI:
             cwd=REPO)
         assert proc.returncode == 0, proc.stderr[-2000:]
         out = proc.stdout
-        assert "device busy:" in out and "110" in out
-        assert "host stages:" in out and "frame.prepare" in out
+        assert f"profile_start_time {START_NS}" in out
+        assert "device busy:" in out and "110 us" in out
+        assert "across 2 module executions" in out
+        assert "host stages:" in out and "train.step.dispatch" in out
         assert "host/device overlap:" in out
+        assert "device idle by host span (890 us idle" in out
+        assert "queue lead:         median 8 us, min 5 us" in out
+        assert "2 runs of jit_step" in out
+        assert "device idle inside train.fit: 190 us" in out
+        assert "compilations inside train.fit: 1 (steps 1)" in out
         assert "top device ops:" in out and "fusion.1" in out
         merged_path = os.path.join(d, "merged.trace.json")
-        assert os.path.exists(merged_path)
         with open(merged_path) as f:
             doc = json.load(f)
-        names = {e.get("name") for e in doc["traceEvents"]
-                 if e.get("ph") == "X"}
-        assert {"frame.prepare", "jit_step"} <= names
+        xs = {e["name"]: e for e in doc["traceEvents"]
+              if e.get("ph") == "X"}
+        assert {"train.fit", "jit_step(123)"} <= set(xs)
+        # one clock: the fit began 20 us into the session, the first run
+        # at 100 us
+        assert xs["train.fit"]["ts"] == 20.0
 
-    def test_trace_cli_newer_gzipped_host_export_not_mistaken_for_device(
-            self, tmp_path, capsys):
-        """A gzipped HOST export written after the device trace must not
-        shadow it: the CLI loads the exact device file find_trace_files
-        selected, not the newest *.trace.json.gz."""
+    def test_trace_cli_newest_xplane_and_gz_host_export(self, tmp_path,
+                                                        capsys):
+        """The device stream is the newest xplane.pb, whatever else the
+        profiler wrote beside it (its trace.json.gz, an older xplane);
+        a gzipped host export is read too."""
         import gzip as _gzip
-        import time as _time
 
         from tpudl.obs.__main__ import main
 
         d = str(tmp_path)
-        dev = _write_device_gz(d, _device_events())
-        _time.sleep(0.05)
-        host_gz = os.path.join(d, "run.host.trace.json.gz")
-        with _gzip.open(host_gz, "wt") as f:
-            json.dump({"traceEvents": _host_events()}, f)
-        assert os.path.getmtime(host_gz) >= os.path.getmtime(dev)
+        old = _write_xplane(d, modules=MODULES[:1], ops=(),
+                            name="old.xplane.pb")
+        os.utime(old, (1, 1))
+        _write_xplane(d)
+        _write_device_gz(d, _device_events())
+        plain = _write_host_export(d)
+        with open(plain) as f, _gzip.open(
+                os.path.join(d, "later.host.trace.json.gz"), "wt") as g:
+            g.write(f.read())
+        os.utime(plain, (1, 1))
         assert main(["trace", d]) == 0
         out = capsys.readouterr().out
-        assert "2 module executions" in out  # device stream is the real one
-        assert "frame.prepare" in out       # host stream still merged
+        assert "2 module executions" in out
+        assert "later.host.trace.json.gz" in out
+        assert "frame.prepare" in out
 
     def test_trace_cli_empty_dir_fails_cleanly(self, tmp_path):
         from tpudl.obs.__main__ import main
@@ -244,10 +576,11 @@ class TestCLI:
         from tpudl.obs.__main__ import main
 
         d = str(tmp_path)
-        _write_host_json(d, _host_events())
+        _write_host_export(d)
         assert main(["trace", d]) == 0
         out = capsys.readouterr().out
         assert "host stages:" in out and "frame.d2h" in out
+        assert "queue lead" not in out and "device idle by" not in out
 
     def test_metrics_cli_validates_file(self, tmp_path, capsys):
         from tpudl.obs.__main__ import main
@@ -264,3 +597,145 @@ class TestCLI:
         with open(path, "a") as f:
             f.write("garbage\n")
         assert main(["metrics", path]) == 1
+
+
+class TestFitSpans:
+    """The spans of ``Trainer.fit`` (ISSUE 25): names, tree, and what
+    reads them."""
+
+    @staticmethod
+    def _fit(steps, scale=0.1, **trainer_kw):
+        import jax.numpy as jnp
+        import numpy as np
+        import optax
+
+        from tpudl import obs
+        from tpudl.train.runner import Trainer
+
+        def loss(p, x, y):
+            return jnp.mean((x @ p["w"] * scale - y) ** 2)
+
+        x = np.ones((8, 4), np.float32)
+        y = np.ones((8, 1), np.float32)
+        trainer = Trainer(loss, optax.sgd(0.1), **trainer_kw)
+        hist = obs.histogram("train.step_seconds")
+        count0 = hist.count
+        mark = obs.get_tracer().record("test.mark", 0, 0).id
+        trainer.fit({"w": np.zeros((4, 1), np.float32)},
+                    lambda step: (x, y), steps=steps)
+        spans = [s for s in obs.get_tracer().spans() if s.id > mark]
+        return spans, hist.count - count0
+
+    def test_four_step_fit_yields_the_span_tree(self):
+        spans, observed = self._fit(4)
+        by_name = {}
+        for s in spans:
+            by_name.setdefault(s.name, []).append(s)
+        (fit,) = by_name["train.fit"]
+        assert fit.attrs == {"steps": 4, "start": 0, "devices": 1}
+        assert fit.parent is None
+        steps = by_name["train.step"]
+        assert [s.attrs["step"] for s in steps] == [0, 1, 2, 3]
+        assert {s.parent for s in steps} == {fit.id}
+        for step in steps:
+            kids = [c.name for c in spans if c.parent == step.id]
+            assert kids == ["train.step.data", "train.step.dispatch"]
+        assert len(by_name["train.fit.place"]) == 1
+        assert len(by_name["train.fit.drain"]) == 1
+        assert {s.parent for s in by_name["train.fit.place"]
+                + by_name["train.fit.drain"]} == {fit.id}
+        # no data axis to shard over, no checkpoint directory
+        assert "train.step.place" not in by_name
+        assert "train.fit.restore" not in by_name
+        assert "train.step.checkpoint" not in by_name
+        assert {s.root for s in spans
+                if s.name.startswith("train.")} == {fit.id}
+        # one histogram sample per step, and it IS the span's duration
+        assert observed == 4
+        got = T.traced_fit(spans, 4)
+        assert got["fit"] is fit and got["steps"] == steps
+        assert 0 < got["start_ns"] < fit.dur_ns
+        assert got["step_host_ns"] > 0 and got["dispatch_ns"] > 0
+        assert sum(s.dur_ns for s in steps) <= fit.dur_ns
+
+    def test_step_seconds_is_fed_from_the_step_span(self):
+        from tpudl import obs
+
+        spans, _ = self._fit(3, scale=0.2)
+        longest = max(s.dur_ns for s in spans if s.name == "train.step")
+        # the histogram keeps its maximum over all samples ever seen
+        assert obs.histogram("train.step_seconds").max >= longest / 1e9
+
+    def test_mesh_fit_has_place_spans_with_bytes(self):
+        from tpudl import mesh as M
+
+        spans, _ = self._fit(2, scale=0.3, mesh=M.build_mesh())
+        place = [s for s in spans if s.name == "train.step.place"]
+        assert len(place) == 2
+        assert all(s.attrs == {"bytes": 8 * 4 * 4 + 8 * 4} for s in place)
+        steps = [s for s in spans if s.name == "train.step"]
+        assert [s.parent for s in place] == [s.id for s in steps]
+        (fit,) = [s for s in spans if s.name == "train.fit"]
+        assert fit.attrs["devices"] == 8
+
+    def test_checkpoint_and_restore_spans(self, tmp_path):
+        from tpudl import obs
+
+        saves = obs.histogram("train.checkpoint_save_seconds")
+        restores = obs.histogram("train.checkpoint_restore_seconds")
+        s0, r0 = saves.count, restores.count
+        spans, _ = self._fit(4, scale=0.4, checkpoint_dir=str(tmp_path),
+                             save_every=2)
+        by_id = {s.id: s for s in spans}
+        ck = [s for s in spans if s.name == "train.step.checkpoint"]
+        # one at step 2 (inside the loop), one forced in the drain; step
+        # 4 is the last and is the drain's
+        assert [by_id[s.parent].name for s in ck] == ["train.step",
+                                                      "train.fit.drain"]
+        assert by_id[ck[0].parent].attrs == {"step": 1}
+        (restore,) = [s for s in spans if s.name == "train.fit.restore"]
+        assert restore.attrs is None  # nothing to resume from
+        assert saves.count - s0 == 2
+        assert restores.count - r0 == 0
+        again, observed = self._fit(6, scale=0.4,
+                                    checkpoint_dir=str(tmp_path),
+                                    save_every=2)
+        (restore,) = [s for s in again if s.name == "train.fit.restore"]
+        assert restore.attrs == {"resumed_at": 4}
+        (fit,) = [s for s in again if s.name == "train.fit"]
+        assert fit.attrs["start"] == 4 and observed == 2
+        assert restores.count - r0 == 1
+        assert restores.max >= restore.dur_ns / 1e9
+
+    def test_compilation_is_a_child_of_the_dispatch_that_paid(
+            self, tmp_path, monkeypatch):
+        import jax
+
+        from tpudl.compile import cache as ccache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        prev = jax.config.jax_compilation_cache_dir
+        try:
+            assert ccache.enable_compilation_cache(str(tmp_path / "jc"))
+            ccache.enable_compilation_cache(str(tmp_path / "jc"))  # once
+            spans, _ = self._fit(3, scale=0.5)
+        finally:
+            jax.config.update("jax_compilation_cache_dir", prev)
+        by_id = {s.id: s for s in spans}
+        compiled = [s for s in spans if s.name == "compile.program"]
+        in_dispatch = [s for s in compiled
+                       if by_id[s.parent].name == "train.step.dispatch"]
+        # the step program compiles inside the FIRST step's dispatch,
+        # once (a second listener would record it twice)
+        assert len(in_dispatch) == 1
+        (paid,) = in_dispatch
+        step = by_id[by_id[paid.parent].parent]
+        assert step.name == "train.step" and step.attrs == {"step": 0}
+        assert paid.attrs["cache_hit"] in (False, None)
+        dispatch = by_id[paid.parent]
+        assert dispatch.start_ns <= paid.start_ns + 2_000_000
+        assert paid.dur_ns <= dispatch.dur_ns
+        # the summary hangs it under its fit and names the step
+        (fit,) = T.summarize_merged(spans, {})["fits"]
+        assert fit["compiled_in_steps"].count(0) >= 1
+        assert all(s == 0 or s is None for s in fit["compiled_in_steps"])

@@ -176,7 +176,9 @@ METRICS: tuple[Metric, ...] = (
     # -- train ---------------------------------------------------------
     Metric("train.steps", "counter", "optimizer steps taken"),
     Metric("train.examples", "counter", "examples consumed"),
-    Metric("train.step_seconds", "histogram", "wall time per step"),
+    Metric("train.step_seconds", "histogram",
+           "host loop's time per step (the train.step span): the device's "
+           "only while the device throttles the host"),
     Metric("train.last_step", "gauge",
            "last completed step (live progress)"),
     Metric("train.restarts", "counter", "gang restarts"),

@@ -26,11 +26,20 @@ log. Now the first failure warns once per process, counts
 ``compile.cache_disabled``, and files a flight-recorder breadcrumb, so
 ``python -m tpudl.obs doctor`` and the metrics sink both show WHY the
 fleet is cold.
+
+Every compilation is a span: enabling the cache registers ONE
+``jax.monitoring`` listener, and each ``backend_compile_duration`` event
+(a compile or a load from the cache) becomes a ``compile.program`` span
+in the host-span ring, a child of whatever span the compiling thread had
+open, so a recompilation inside a fit hangs under the
+``train.step.dispatch`` that paid for it (OBSERVABILITY.md).
 """
 
 from __future__ import annotations
 
 import os
+import threading
+import time
 import warnings
 
 __all__ = ["enable_compilation_cache", "cache_dir", "DEFAULT_CACHE_DIR"]
@@ -42,6 +51,13 @@ DEFAULT_CACHE_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 _warned_disabled = False
+
+# jax.monitoring event names (jax/_src/dispatch.py, compilation_cache.py)
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_listening = False
+_last_lookup = threading.local()  # .hit: this thread's last cache lookup
 
 
 def cache_dir() -> str:
@@ -81,6 +97,40 @@ def _note_disabled(path: str, exc: BaseException) -> None:
             RuntimeWarning, stacklevel=3)
 
 
+def _on_cache_event(event, **_kw) -> None:
+    if event == _CACHE_HIT:
+        _last_lookup.hit = True
+    elif event == _CACHE_MISS:
+        _last_lookup.hit = False
+
+
+def _on_compile_duration(event, duration, **_kw) -> None:
+    """The event arrives when the compile (or cache load) has ended, on
+    the thread that asked for it: start = now - duration."""
+    if event != _BACKEND_COMPILE:
+        return
+    from tpudl.obs import tracer as _tracer
+
+    dur_ns = int(duration * 1e9)
+    _tracer.get_tracer().record(
+        "compile.program", time.time_ns() - dur_ns, dur_ns,
+        cache_hit=getattr(_last_lookup, "hit", None))
+    _last_lookup.hit = None
+
+
+def _listen_for_compiles() -> None:
+    """Once per process: jax.monitoring has no public way to take a
+    listener back."""
+    global _listening
+    if _listening:
+        return
+    import jax.monitoring as mon
+
+    mon.register_event_listener(_on_cache_event)
+    mon.register_event_duration_secs_listener(_on_compile_duration)
+    _listening = True
+
+
 def enable_compilation_cache(path: str | None = None) -> str | None:
     """Enable JAX's persistent compilation cache and return its
     directory (None when it could not be enabled).
@@ -104,6 +154,7 @@ def enable_compilation_cache(path: str | None = None) -> str | None:
         # the small entries are nothing next to one CNN executable
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        _listen_for_compiles()
         return target
     except OSError as e:  # read-only / not-a-directory: loud, never fatal
         _note_disabled(str(target), e)

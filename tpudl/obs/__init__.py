@@ -6,15 +6,16 @@ run-wide subsystem that replaces it (OBSERVABILITY.md is the operator
 guide), three pillars:
 
 - :mod:`tpudl.obs.tracer` — host-span tracer: ``obs.span("stage")``
-  records thread-aware wall-clock spans into a bounded ring, exportable
-  as Chrome trace-event JSON;
+  records spans with an id, a parent and an epoch-nanosecond start into
+  a bounded ring, exportable as Chrome trace-event JSON;
 - :mod:`tpudl.obs.metrics` — process-wide metrics registry: thread-safe
   counters/gauges/bounded-histograms with ``snapshot()`` and an opt-in
   JSONL sink (``TPUDL_METRICS_FILE``);
-- :mod:`tpudl.obs.trace` — jax.profiler capture + trace-viewer parsing,
-  and the host/device MERGE: ``python -m tpudl.obs trace <dir>`` renders
-  host spans and XLA device lanes on one timeline with a combined
-  summary (device busy %, host stage totals, overlap).
+- :mod:`tpudl.obs.trace` — jax.profiler capture, its device planes, and
+  the host spans beside them on the clock the profiler stamps its
+  session with: ``python -m tpudl.obs trace <dir>`` renders both on one
+  timeline with a combined summary (device busy %, host stage totals,
+  device idle by host span, queue lead).
 
 Per-run executor reports (:class:`PipelineReport`) live in
 :mod:`tpudl.obs.pipeline`, kept in a bounded ring keyed by run id.
@@ -70,10 +71,14 @@ from tpudl.obs.watchdog import heartbeat, start_watchdog
 from tpudl.obs.pipeline import (PipelineReport, get_pipeline_report,
                                 last_pipeline_report, pipeline_reports,
                                 set_last_pipeline)
-from tpudl.obs.trace import (load_host_trace_events, load_trace_events,
-                             merge_trace_events, named_scope, profile,
-                             summarize_device_trace, summarize_merged)
-from tpudl.obs.tracer import export_chrome_trace, get_tracer, span
+from tpudl.obs.trace import (align, attribute_idle, load_device_planes,
+                             load_host_spans, load_host_trace_events,
+                             load_trace_events, merge_trace_events,
+                             named_scope, profile, profile_window,
+                             queue_lead, summarize_device_trace,
+                             summarize_merged)
+from tpudl.obs.tracer import (children, export_chrome_trace, get_tracer,
+                              self_ns, span)
 
 __all__ = [
     # attribution plane (scoped ledgers)
@@ -81,7 +86,7 @@ __all__ = [
     "get_ledger", "reset_ledger", "ledger_snapshot", "ledger_totals",
     "reconcile",
     # tracer
-    "span", "get_tracer", "export_chrome_trace",
+    "span", "get_tracer", "export_chrome_trace", "children", "self_ns",
     # metrics
     "counter", "gauge", "histogram", "snapshot", "flush_metrics",
     "get_registry", "timed", "Meter",
@@ -89,6 +94,8 @@ __all__ = [
     "profile", "named_scope", "load_trace_events",
     "summarize_device_trace", "load_host_trace_events",
     "merge_trace_events", "summarize_merged",
+    "load_device_planes", "profile_window", "load_host_spans", "align",
+    "attribute_idle", "queue_lead",
     # per-run pipeline reports
     "PipelineReport", "last_pipeline_report", "set_last_pipeline",
     "pipeline_reports", "get_pipeline_report",

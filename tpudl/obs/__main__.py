@@ -1,13 +1,16 @@
 """``python -m tpudl.obs`` — the observability CLI.
 
-``trace <dir>`` merges the newest host-span export
+``trace <dir>`` puts the newest host-span export
 (``*.host.trace.json[.gz]``, written by
-``obs.get_tracer().export_chrome_trace``) with the newest jax.profiler
-device trace (``*.trace.json.gz``) under ``<dir>``, writes the combined
-Chrome trace to ``<dir>/merged.trace.json`` (open it in Perfetto /
+``obs.get_tracer().export_chrome_trace``) beside the device planes of
+the newest jax.profiler trace (``*.xplane.pb``) under ``<dir>``, on the
+clock the trace stamps its session with, writes the combined Chrome
+trace to ``<dir>/merged.trace.json`` (open it in Perfetto /
 chrome://tracing) and prints the merged summary: device busy time, host
-stage totals, overlap, top ops. Either stream alone still summarizes —
-a CPU-only run gets host totals, a host-blind capture gets device lanes.
+stage totals, overlap, device idle by host span, queue lead, device idle
+and compilations inside each ``train.fit``, top ops. Either stream alone
+still summarizes — a CPU-only run gets host totals, a span-less capture
+gets device lanes.
 
 ``metrics <file.jsonl>`` schema-checks and tail-summarizes a
 ``TPUDL_METRICS_FILE`` emission (delegates the check to
@@ -44,54 +47,79 @@ import sys
 from tpudl.obs import trace as T
 
 
-def _fmt_us(us: float) -> str:
-    return f"{us / 1e3:.2f} ms" if us >= 1e3 else f"{us:.0f} us"
+def _fmt_ns(ns: float) -> str:
+    return f"{ns / 1e6:.2f} ms" if abs(ns) >= 1e6 else f"{ns / 1e3:.0f} us"
 
 
 def cmd_trace(trace_dir: str, out_path: str | None = None) -> int:
     found = T.find_trace_files(trace_dir)
-    host_events = (T.load_host_trace_events(found["host"])
-                   if found["host"] else [])
-    # load the exact file find_trace_files selected (a re-glob could
-    # pick a newer gzipped HOST export as the device stream);
-    # load_host_trace_events is format-wise just "events from one
-    # [gzipped] trace JSON", which is what's needed here
-    device_events = (T.load_host_trace_events(found["device"])
-                     if found["device"] else [])
-    if not host_events and not device_events:
-        print(f"no host or device traces under {trace_dir}",
+    spans = T.load_host_spans(found["host"]) if found["host"] else []
+    planes, window = {}, None
+    if found["device"]:
+        planes = T.load_device_planes(trace_dir)
+        start, stop = T.profile_window(trace_dir)
+        spans, window = T.align(spans, start), (0, stop - start)
+    if not spans and not planes:
+        print(f"no host spans or device planes under {trace_dir}",
               file=sys.stderr)
         return 2
     print(f"host trace:   {found['host'] or '(none)'}")
     print(f"device trace: {found['device'] or '(none)'}")
-    merged = T.merge_trace_events(host_events, device_events)
+    if window:
+        print(f"clock:        ns since profile_start_time {start} "
+              f"(session {_fmt_ns(window[1])})")
     out_path = out_path or os.path.join(trace_dir, "merged.trace.json")
     with open(out_path, "w") as f:
-        json.dump({"traceEvents": merged, "displayTimeUnit": "ms"}, f)
+        json.dump({"traceEvents": T.merge_trace_events(spans, planes),
+                   "displayTimeUnit": "ms"}, f)
     print(f"merged trace: {out_path} (open in Perfetto / chrome://tracing)")
-    s = T.summarize_merged(host_events, device_events)
+    s = T.summarize_merged(spans, planes, window)
     print("\n== merged timeline summary ==")
-    print(f"wall window:        {_fmt_us(s['wall_us'])}")
+    print(f"wall window:        {_fmt_ns(s['wall_ns'])}")
     busy = s["device_busy_frac"]
-    print(f"device busy:        {_fmt_us(s['device_busy_us'])}"
+    print(f"device busy:        {_fmt_ns(s['device_busy_ns'])}"
           + (f" ({busy:.1%} of device window)" if busy is not None else "")
-          + f" across {s['device']['module_count']} module executions")
-    print(f"host busy:          {_fmt_us(s['host_busy_us'])}")
+          + f" across {s['module_count']} module executions")
+    print(f"host busy:          {_fmt_ns(s['host_busy_ns'])}")
     ov = s["host_overlap_frac"]
-    print(f"host/device overlap: {_fmt_us(s['overlap_us'])}"
-          + (f" ({ov:.1%} of host work hidden under device compute)"
+    print(f"host/device overlap: {_fmt_ns(s['overlap_ns'])}"
+          + (f" ({ov:.1%} of host span time had a program running)"
              if ov is not None else ""))
-    if s["host_stage_us"]:
+    if s["host_stage_ns"]:
         print("host stages:")
-        for name, us in sorted(s["host_stage_us"].items(),
+        for name, ns in sorted(s["host_stage_ns"].items(),
                                key=lambda kv: -kv[1]):
-            print(f"  {name:<28} {_fmt_us(us):>12}"
+            print(f"  {name:<28} {_fmt_ns(ns):>12}"
                   f"  x{s['host_stage_calls'][name]}")
+    if s.get("idle_by_span"):
+        print(f"device idle by host span ({_fmt_ns(s['idle_ns'])} idle "
+              "in the session):")
+        for name, ns in s["idle_by_span"].items():
+            print(f"  {name:<28} {_fmt_ns(ns):>12}")
+    lead = s.get("queue_lead")
+    if lead and "refused" in lead:
+        print(f"queue lead:         not paired: {lead['refused']}")
+    elif lead:
+        after = lead["after_dispatch_start"]
+        print(f"queue lead:         median {_fmt_ns(lead['median_ns'])}, "
+              f"min {_fmt_ns(lead['min_ns'])} ({lead['pairs']} runs of "
+              f"{lead['program']}; device start after dispatch START: "
+              f"median {_fmt_ns(after['median_ns'])}, min "
+              f"{_fmt_ns(after['min_ns'])})")
+    for fit in s["fits"]:
+        print(f"train.fit #{fit['id']} ({fit['steps']} steps, "
+              f"{_fmt_ns(fit['dur_ns'])}):")
+        if planes:
+            print(f"  device idle inside train.fit: "
+                  f"{_fmt_ns(fit['device_idle_ns'])}")
+        steps = ", ".join(str(x) for x in fit["compiled_in_steps"])
+        print(f"  compilations inside train.fit: {fit['compilations']}"
+              + (f" (steps {steps})" if steps else ""))
     if s["top_ops"]:
         print("top device ops:")
         for op in s["top_ops"]:
-            print(f"  {op['name']:<28} {_fmt_us(op['us']):>12}"
-                  f"  x{op['count']}  {op['category']}")
+            print(f"  {op['name']:<28} {_fmt_ns(op['ns']):>12}"
+                  f"  x{op['count']}")
     return 0
 
 

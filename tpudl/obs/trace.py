@@ -1,20 +1,27 @@
-"""Device traces + the host/device merged timeline.
+"""Device traces, and the host spans beside them on one clock.
 
 The device half of the observability subsystem: capture a jax.profiler
-trace (:func:`profile`), parse the trace-viewer JSON it writes
-(:func:`load_trace_events`), aggregate the XLA Modules/Ops lanes
-(:func:`summarize_device_trace`) — and MERGE the host-span tracer's
-export (:mod:`tpudl.obs.tracer`) with the device lanes into one Chrome
-trace (:func:`merge_trace_events`) plus one summary
-(:func:`summarize_merged`): device busy %, host stage totals, and how
-much host work was hidden under device compute. ``python -m tpudl.obs
-trace <dir>`` drives all of this from the command line.
+trace (:func:`profile`), read its device planes
+(:func:`load_device_planes`) and the session's own start and stop
+(:func:`profile_window`), and put the host-span tracer's spans
+(:mod:`tpudl.obs.tracer`) beside them (:func:`align`). On that clock
+every device idle gap has a host span to answer for it
+(:func:`attribute_idle`), every run of a step program has the dispatch
+that enqueued it (:func:`queue_lead`), and one Chrome trace
+(:func:`merge_trace_events`) and one summary (:func:`summarize_merged`)
+hold both sides. ``python -m tpudl.obs trace <dir>`` drives all of this
+from the command line.
 
-Time bases: the profiler's trace-viewer events use an opaque device
-time base; host spans are epoch µs. The merge normalizes EACH stream to
-its own first event, so the combined timeline is stream-relative — the
-right call when both streams cover the same window (the
-``obs.profile`` + tracer pattern), and stated in the summary either way.
+The clock: the profiler stamps every ``xplane.pb`` with
+``profile_start_time`` / ``profile_stop_time`` in epoch nanoseconds (the
+plane ``Task Environment``) and counts device events in nanoseconds
+since that start. A span's ``start_ns`` is ``time.time_ns()``, so
+``start_ns - profile_start_time`` is its place on the device's clock: one
+subtraction, no stream is zeroed on its own first event.
+
+:func:`load_trace_events` / :func:`summarize_device_trace` still read the
+trace-viewer JSON (``*.trace.json.gz``) for the tools that aggregate
+device lanes alone.
 """
 
 from __future__ import annotations
@@ -24,37 +31,44 @@ import glob
 import gzip
 import json
 import os
+import re
+import statistics
+from collections import Counter
+
+from tpudl.obs.tracer import Span, children
 
 __all__ = ["profile", "named_scope", "load_trace_events",
            "summarize_device_trace", "load_host_trace_events",
-           "find_trace_files", "merge_trace_events", "summarize_merged"]
+           "find_trace_files", "load_device_planes", "profile_window",
+           "load_host_spans", "align", "attribute_idle", "queue_lead",
+           "traced_fit", "merge_trace_events", "summarize_merged"]
 
-HOST_PID = 0  # merged-trace pid for the host lane (device pids re-number up)
+HOST_PID = 0  # merged-trace pid for the host lane (device pids count up)
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+MODULES, OPS = "XLA Modules", "XLA Ops"
+TASK_PLANE = "Task Environment"
+NO_SPAN = "(no span)"
 
 
 @contextlib.contextmanager
 def profile(log_dir: str):
-    """Capture a jax.profiler trace for the enclosed block; view with
-    tensorboard-plugin-profile or xprof against ``log_dir``, or parse
-    programmatically with :func:`load_trace_events` +
-    :func:`summarize_device_trace`. The capture window is recorded on
-    the host-span tracer, so ``export_chrome_trace(path,
-    window="profile")`` exports exactly the spans this block covered —
-    the merged-timeline pairing."""
-    import time
-
+    """Capture a jax.profiler trace for the enclosed block, device planes
+    only: the host and Python tracers are off (with them on, the host's
+    runtime threads alone made a 413 MB trace that took 58 s to write),
+    and the program's own spans are the host side. The trace carries its
+    own start and stop (:func:`profile_window`), so
+    ``export_chrome_trace(path_in_log_dir, window="profile")`` exports
+    exactly the spans this block covered."""
     import jax
 
-    from tpudl.obs import tracer as _tracer_mod
-
-    t0_us = time.time() * 1e6
-    jax.profiler.start_trace(log_dir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
-        _tracer_mod.get_tracer().last_profile_window = (t0_us,
-                                                        time.time() * 1e6)
 
 
 def named_scope(name: str):
@@ -87,18 +101,86 @@ def load_host_trace_events(path: str) -> list[dict]:
     return tr["traceEvents"] if isinstance(tr, dict) else tr
 
 
+def _newest(trace_dir: str, *patterns: str) -> str | None:
+    paths = [p for pat in patterns for p in glob.glob(
+        os.path.join(trace_dir, "**", pat), recursive=True)]
+    return max(paths, key=os.path.getmtime) if paths else None
+
+
 def find_trace_files(trace_dir: str) -> dict:
     """Locate the newest host export and device trace under a directory:
     ``{"host": path|None, "device": path|None}``. Host exports are the
-    tracer's ``*.host.trace.json`` (optionally ``.gz``); device traces
-    are the profiler's ``*.trace.json.gz`` (host exports excluded)."""
-    host = [p for pat in ("**/*.host.trace.json", "**/*.host.trace.json.gz")
-            for p in glob.glob(os.path.join(trace_dir, pat), recursive=True)]
-    dev = [p for p in glob.glob(os.path.join(trace_dir, "**/*.trace.json.gz"),
-                                recursive=True)
-           if not p.endswith(".host.trace.json.gz")]
-    newest = lambda ps: max(ps, key=os.path.getmtime) if ps else None  # noqa: E731
-    return {"host": newest(host), "device": newest(dev)}
+    tracer's ``*.host.trace.json`` (optionally ``.gz``); the device trace
+    is the profiler's ``*.xplane.pb``."""
+    return {"host": _newest(trace_dir, "*.host.trace.json",
+                            "*.host.trace.json.gz"),
+            "device": _newest(trace_dir, "*.xplane.pb")}
+
+
+def _xspace(trace_dir: str):
+    import jax
+
+    path = _newest(trace_dir, "*.xplane.pb")
+    if path is None:
+        raise FileNotFoundError(f"no xplane.pb under {trace_dir}")
+    return jax.profiler.ProfileData.from_file(path)
+
+
+def load_device_planes(trace_dir: str) -> dict:
+    """``{plane: {line: [(name, start_ns, dur_ns), ...]}}`` for the
+    ``/device:TPU:<n>`` planes of the newest ``*.xplane.pb`` under
+    ``trace_dir``, lines ``XLA Modules`` (one event per program run) and
+    ``XLA Ops``; times are nanoseconds since the session's start. ``{}``
+    when the trace has no device plane (a CPU backend)."""
+    planes = {}
+    for plane in _xspace(trace_dir).planes:
+        if DEVICE_PLANE.match(plane.name):
+            planes[plane.name] = {
+                line.name: [(e.name, int(e.start_ns), int(e.duration_ns))
+                            for e in line.events]
+                for line in plane.lines if line.name in (MODULES, OPS)}
+    return planes
+
+
+def profile_window(trace_dir: str) -> tuple[int, int]:
+    """``(profile_start_time, profile_stop_time)`` of the newest
+    ``*.xplane.pb`` under ``trace_dir``, epoch nanoseconds, from the
+    stats of its ``Task Environment`` plane."""
+    for plane in _xspace(trace_dir).planes:
+        if plane.name == TASK_PLANE:
+            stats = dict(plane.stats)
+            if "profile_start_time" in stats:
+                return (int(stats["profile_start_time"]),
+                        int(stats["profile_stop_time"]))
+    raise ValueError(
+        f"the trace under {trace_dir} has no {TASK_PLANE!r} plane with "
+        "profile_start_time: host spans cannot be placed on its clock")
+
+
+def load_host_spans(path: str) -> list[Span]:
+    """The spans of a host-span tracer export, on the epoch clock. An
+    export written before spans had an identity gets fresh ids, no
+    parents, and times from its float microseconds."""
+    spans = []
+    for e in load_host_trace_events(path):
+        if e.get("ph") != "X":
+            continue
+        a = dict(e.get("args") or {})
+        start, dur = a.pop("start_ns", None), a.pop("dur_ns", None)
+        spans.append(Span(
+            e["name"],
+            round(e["ts"] * 1e3) if start is None else start,
+            round(e.get("dur", 0) * 1e3) if dur is None else dur,
+            id=a.pop("id", None), parent=a.pop("parent", None),
+            root=a.pop("root", None), tid=e.get("tid", 0),
+            attrs=a or None))
+    return spans
+
+
+def align(spans, start_ns: int) -> list[Span]:
+    """``spans`` in nanoseconds since ``start_ns`` (a session's
+    ``profile_start_time``): the device events' own unit and origin."""
+    return [s.shifted(start_ns) for s in spans]
 
 
 def summarize_device_trace(events: list[dict]) -> dict:
@@ -149,20 +231,10 @@ def _trace_metadata(events):
     return procs, lanes
 
 
-def _durations(events, keep) -> list[tuple[float, float]]:
-    """(start, end) µs intervals of "X" events passing ``keep(e)``."""
-    out = []
-    for e in events:
-        if e.get("ph") == "X" and keep(e):
-            ts = float(e.get("ts", 0.0))
-            out.append((ts, ts + float(e.get("dur", 0.0))))
-    return out
-
-
 def _merged(intervals) -> list[tuple[float, float]]:
     """Coalesce possibly-overlapping intervals — the ONE sweep behind
     both union and intersection (diverging copies would skew
-    device_busy_us vs overlap_us)."""
+    device_busy_ns vs overlap_ns)."""
     out: list = []
     for s, e in sorted(intervals):
         if out and s <= out[-1][1]:
@@ -172,16 +244,16 @@ def _merged(intervals) -> list[tuple[float, float]]:
     return out
 
 
-def _union_us(intervals) -> float:
+def _union(intervals) -> float:
     """Total covered time of possibly-overlapping intervals."""
     return sum(e - s for s, e in _merged(intervals))
 
 
-def _intersection_us(a, b) -> float:
+def _intersection(a, b) -> float:
     """Covered time where union(a) and union(b) overlap."""
     am, bm = _merged(a), _merged(b)
     i = j = 0
-    total = 0.0
+    total = 0
     while i < len(am) and j < len(bm):
         s = max(am[i][0], bm[j][0])
         e = min(am[i][1], bm[j][1])
@@ -194,104 +266,291 @@ def _intersection_us(a, b) -> float:
     return total
 
 
-def _normalize(events) -> list[dict]:
-    """Shift a stream's "X" timestamps so its first event starts at 0
-    (metadata events pass through untouched)."""
-    xs = [float(e["ts"]) for e in events
-          if e.get("ph") == "X" and "ts" in e]
-    if not xs:
-        return list(events)
-    base = min(xs)
-    out = []
-    for e in events:
-        if e.get("ph") == "X" and "ts" in e:
-            e = dict(e)
-            e["ts"] = float(e["ts"]) - base
-        out.append(e)
-    return out
+def _interval(span) -> tuple[int, int]:
+    return span.start_ns, span.start_ns + span.dur_ns
 
 
-def merge_trace_events(host_events: list[dict],
-                       device_events: list[dict]) -> list[dict]:
+def _program(event_name: str) -> str:
+    """``jit_step(2287243686015180859)`` -> ``jit_step``."""
+    return event_name.split("(", 1)[0]
+
+
+def _op(event_name: str) -> str:
+    """On the TPU an ``XLA Ops`` event is named by its whole HLO
+    instruction: ``%fusion.7 = (bf16[256]{...}) fusion(...)`` ->
+    ``%fusion.7``."""
+    return event_name.split(" = ", 1)[0]
+
+
+def _gaps(busy, lo, hi) -> list[tuple[int, int]]:
+    """The parts of ``[lo, hi]`` that the coalesced ``busy`` leaves."""
+    out, end = [], lo
+    for s, e in busy:
+        if s > end:
+            out.append((end, min(s, hi)))
+        end = max(end, e)
+        if end >= hi:
+            break
+    if end < hi:
+        out.append((end, hi))
+    return [(a, b) for a, b in out if b > a]
+
+
+def _dispatching_tid(spans):
+    """The thread that enqueues device work: the one with the most
+    ``*.dispatch`` spans, or with the most spans when there is none."""
+    tids = ([s.tid for s in spans if s.name.endswith(".dispatch")]
+            or [s.tid for s in spans])
+    return Counter(tids).most_common(1)[0][0] if tids else None
+
+
+def attribute_idle(modules, spans, window=None) -> dict:
+    """Every device idle gap with the host span that answers for it.
+
+    ``modules`` are one plane's ``XLA Modules`` events and ``spans`` are
+    :func:`align`-ed, so both count nanoseconds from the session's start.
+    A gap is a part of ``window`` (default: the first program's start to
+    the last one's end) in which no program ran. At each instant of a gap
+    the dispatching thread has one innermost open span, or none; the gap
+    goes to the span that is innermost for most of it, or to ``"(no
+    span)"``. Returns ``{"gaps": [{"start_ns", "dur_ns", "span", "id"}],
+    "by_span": {name: idle_ns}, "idle_ns": total}``.
+    """
+    busy = _merged((s, s + d) for _, s, d in modules)
+    if window is None:
+        window = (busy[0][0], busy[-1][1]) if busy else (0, 0)
+    tid = _dispatching_tid(spans)
+    thread = sorted((s for s in spans if s.tid == tid),
+                    key=lambda s: s.dur_ns)  # a child before its parent
+    names = {s.id: s.name for s in thread}
+    gaps, by_span = [], {}
+    for a, b in _gaps(busy, *window):
+        open_, claims = [(a, b)], {}
+        for s in thread:
+            lo, hi = _interval(s)
+            if hi <= a or lo >= b or not open_:
+                continue
+            rest = []
+            for x, y in open_:
+                cut = min(y, hi) - max(x, lo)
+                if cut <= 0:
+                    rest.append((x, y))
+                    continue
+                claims[s.id] = claims.get(s.id, 0) + cut
+                if x < lo:
+                    rest.append((x, lo))
+                if hi < y:
+                    rest.append((hi, y))
+            open_ = rest
+        best = max(claims, key=claims.get, default=None)
+        if best is None or sum(y - x for x, y in open_) > claims[best]:
+            name, best = NO_SPAN, None
+        else:
+            name = names[best]
+        gaps.append({"start_ns": a, "dur_ns": b - a, "span": name,
+                     "id": best})
+        by_span[name] = by_span.get(name, 0) + b - a
+    return {"gaps": gaps, "by_span": by_span,
+            "idle_ns": sum(g["dur_ns"] for g in gaps)}
+
+
+def _paired(modules, spans, program):
+    runs = sorted((s, d) for name, s, d in modules
+                  if _program(name) == program)
+    dispatches = sorted((s for s in spans
+                         if s.name == "train.step.dispatch"),
+                        key=lambda s: s.start_ns)
+    if len(runs) != len(dispatches):
+        raise ValueError(
+            f"{len(runs)} runs of {program!r} on the device against "
+            f"{len(dispatches)} train.step.dispatch spans: not paired")
+    return list(zip(dispatches, runs))
+
+
+def queue_lead(modules, spans, program: str) -> list[int]:
+    """How far the host runs ahead of the device, per step: the start of
+    the i-th run of ``program`` minus the end of the i-th
+    ``train.step.dispatch`` span, nanoseconds (both on the session's
+    clock, ``spans`` being one fit's). Near 0 when the device starves;
+    as many step times as the runtime's queue holds programs when the
+    host only fills it. Unequal counts raise ``ValueError``: a pairing by position
+    means nothing then."""
+    return [run[0] - _interval(d)[1]
+            for d, run in _paired(modules, spans, program)]
+
+
+def traced_fit(spans, n_steps: int | None = None) -> dict | None:
+    """The newest ``train.fit`` span of ``spans`` (with exactly
+    ``n_steps`` ``train.step`` children, when given) in numbers:
+    ``fit`` and its ``steps`` in order, ``step_host_ns`` (median over the
+    steps of the step's duration less its ``train.step.dispatch`` child:
+    data, placement and bookkeeping), ``dispatch_ns`` (median dispatch
+    duration) and ``start_ns`` (first step's start minus the fit's: what
+    ``fit`` does before its first step). None when there is no such
+    fit."""
+    by_parent: dict = {}
+    for s in spans:
+        by_parent.setdefault(s.parent, []).append(s)
+    for fit in sorted((s for s in spans if s.name == "train.fit"),
+                      key=lambda s: -s.start_ns):
+        steps = sorted((s for s in by_parent.get(fit.id, ())
+                        if s.name == "train.step"),
+                       key=lambda s: s.start_ns)
+        if not steps or n_steps not in (None, len(steps)):
+            continue
+        dispatch = [sum(c.dur_ns for c in by_parent.get(step.id, ())
+                        if c.name == "train.step.dispatch")
+                    for step in steps]
+        return {"fit": fit, "steps": steps,
+                "step_host_ns": statistics.median(
+                    step.dur_ns - d for step, d in zip(steps, dispatch)),
+                "dispatch_ns": statistics.median(dispatch),
+                "start_ns": steps[0].start_ns - fit.start_ns}
+    return None
+
+
+def merge_trace_events(spans, planes: dict) -> list[dict]:
     """One Chrome trace with the host-span lane alongside the device
-    lanes. Each stream is normalized to its own start (time bases are
-    incompatible: host = epoch µs, device = profiler-internal); host
-    events take ``pid=HOST_PID`` and device pids are renumbered from 1
-    upward so the lanes can never collide."""
-    host = _normalize(host_events)
-    dev = _normalize(device_events)
-    merged = []
-    for e in host:
-        e = dict(e)
-        e["pid"] = HOST_PID
-        merged.append(e)
-    pid_map: dict = {}
-    for e in device_events:
-        if "pid" in e and e["pid"] not in pid_map:
-            pid_map[e["pid"]] = len(pid_map) + 1
-    for e in dev:
-        e = dict(e)
-        if "pid" in e:
-            e["pid"] = pid_map[e["pid"]]
-        merged.append(e)
+    lanes, both in microseconds since the session's start (``spans``
+    :func:`align`-ed, ``planes`` from :func:`load_device_planes`). Host
+    events take ``pid=HOST_PID``; device planes count up from 1."""
+    merged = [{"ph": "M", "pid": HOST_PID, "name": "process_name",
+               "args": {"name": "tpudl host"}}]
+    merged.extend(s.to_event(HOST_PID) for s in spans)
+    for pid, plane in enumerate(sorted(planes), start=1):
+        merged.append({"ph": "M", "pid": pid, "name": "process_name",
+                       "args": {"name": plane}})
+        for tid, line in enumerate(sorted(planes[plane]), start=1):
+            merged.append({"ph": "M", "pid": pid, "tid": tid,
+                           "name": "thread_name", "args": {"name": line}})
+            merged.extend(
+                {"ph": "X", "pid": pid, "tid": tid, "name": _op(name),
+                 "ts": s / 1e3, "dur": d / 1e3}
+                for name, s, d in planes[plane][line])
     return merged
 
 
-def summarize_merged(host_events: list[dict],
-                     device_events: list[dict]) -> dict:
+def _fit_facts(fit, spans, busy, window) -> dict:
+    """One ``train.fit`` span against the device: idle inside it (its
+    duration, cut to the session's window, minus the union of program
+    runs inside it) and the steps whose dispatch paid for a
+    compilation."""
+    lo, hi = _interval(fit)
+    if window:
+        lo, hi = max(lo, window[0]), min(hi, window[1])
+    inside = sum(min(e, hi) - max(s, lo) for s, e in busy
+                 if e > lo and s < hi)
+    by_id = {s.id: s for s in spans}
+    compiled = []
+    for s in spans:
+        if s.name != "compile.program":
+            continue
+        up, step = s, None
+        while up is not None and up.id != fit.id:
+            if up.name == "train.step":
+                step = (up.attrs or {}).get("step")
+            up = by_id.get(up.parent)
+        if up is not None:
+            compiled.append(step)
+    return {"id": fit.id, "start_ns": fit.start_ns, "dur_ns": fit.dur_ns,
+            "steps": sum(1 for s in children(fit, spans)
+                         if s.name == "train.step"),
+            "device_idle_ns": max(hi - lo, 0) - inside,
+            "compilations": len(compiled), "compiled_in_steps": compiled}
+
+
+def summarize_merged(spans, planes: dict, window=None) -> dict:
     """The merged-timeline summary behind ``python -m tpudl.obs trace``.
 
-    - ``device``: :func:`summarize_device_trace` of the device stream;
-    - ``device_busy_us`` / ``device_busy_frac``: union of XLA-Modules
-      intervals over the stream's wall window — the chip's duty cycle;
-    - ``host_stage_us``: per-span-name host totals (the run-wide
-      generalization of PipelineReport's stage_seconds);
-    - ``host_busy_us``: union of all host spans;
-    - ``overlap_us`` / ``host_overlap_frac``: host-busy time that
-      coincides with device-busy time, on each stream's own normalized
-      clock — the run-level overlap-efficiency twin. Both streams must
-      cover the same window for this to mean overlap (the
-      ``obs.profile`` + tracer capture pattern does).
+    ``spans`` and ``planes`` are on one clock (:func:`align`,
+    :func:`load_device_planes`); ``window`` is the session's ``(0,
+    stop - start)`` when a trace gave one. Device numbers are of the
+    first chip's plane. All times in nanoseconds.
+
+    - ``device_busy_ns`` / ``device_busy_frac``: union of ``XLA Modules``
+      intervals, over the first program's start to the last one's end;
+    - ``host_stage_ns`` / ``host_stage_calls``: totals per span name;
+      ``host_busy_ns``: union of all host spans; ``overlap_ns``: time in
+      which a host span and a program run were both open;
+    - ``idle_by_span``: :func:`attribute_idle` over the window;
+    - ``queue_lead``: :func:`queue_lead` of the newest fit whose
+      dispatch count some program's run count equals, as ``median_ns`` /
+      ``min_ns``, with ``after_dispatch_start`` (device start minus
+      dispatch START, which causality keeps above 0), or ``refused``;
+    - ``fits``: per ``train.fit`` span, device idle inside it and the
+      compilations its steps paid for;
+    - ``top_ops``: the five operations with the most device time.
     """
-    procs, lanes = _trace_metadata(device_events)
-    device_pids = {p for p, n in procs.items() if "TPU" in (n or "")}
-    dev_norm = _normalize(device_events)
-    host_norm = _normalize(host_events)
-    mod_iv = _durations(
-        dev_norm, lambda e: e.get("pid") in device_pids
-        and lanes.get((e["pid"], e.get("tid")), "") == "XLA Modules")
-    host_iv = _durations(host_norm, lambda e: True)
-    host_stage_us: dict[str, float] = {}
-    host_stage_calls: dict[str, int] = {}
-    for e in host_norm:
-        if e.get("ph") == "X":
-            host_stage_us[e["name"]] = (host_stage_us.get(e["name"], 0.0)
-                                        + float(e.get("dur", 0.0)))
-            host_stage_calls[e["name"]] = host_stage_calls.get(e["name"],
-                                                               0) + 1
-    xs = [x for s, e in mod_iv + host_iv for x in (s, e)]
-    wall_us = (max(xs) - min(xs)) if xs else 0.0
-    dev_xs = [x for s, e in mod_iv for x in (s, e)]
-    dev_wall = (max(dev_xs) - min(dev_xs)) if dev_xs else 0.0
-    device_busy = _union_us(mod_iv)
-    host_busy = _union_us(host_iv)
-    overlap = _intersection_us(host_iv, mod_iv)
-    summary = summarize_device_trace(device_events)
-    top = sorted(summary["ops"].items(), key=lambda kv: -kv[1]["us"])[:5]
-    return {
-        "device": summary,
-        "device_busy_us": round(device_busy, 1),
+    first = planes[min(planes)] if planes else {}
+    modules = first.get(MODULES, [])
+    busy = _merged((s, s + d) for _, s, d in modules)
+    host_iv = [_interval(s) for s in spans]
+    stage_ns: dict = {}
+    stage_calls: dict = {}
+    for s in spans:
+        stage_ns[s.name] = stage_ns.get(s.name, 0) + s.dur_ns
+        stage_calls[s.name] = stage_calls.get(s.name, 0) + 1
+    device_busy = _union(busy)
+    dev_wall = busy[-1][1] - busy[0][0] if busy else 0
+    host_busy = _union(host_iv)
+    overlap = _intersection(host_iv, busy)
+    edges = [x for iv in host_iv + busy for x in iv]
+    ops: dict = {}
+    for name, _, d in first.get(OPS, []):
+        rec = ops.setdefault(_op(name), {"ns": 0, "count": 0})
+        rec["ns"] += d
+        rec["count"] += 1
+    out = {
+        "planes": len(planes),
+        "module_count": len(modules),
+        "device_busy_ns": device_busy,
         "device_busy_frac": (round(device_busy / dev_wall, 4)
                              if dev_wall > 0 else None),
-        "host_stage_us": {k: round(v, 1)
-                          for k, v in sorted(host_stage_us.items())},
-        "host_stage_calls": dict(sorted(host_stage_calls.items())),
-        "host_busy_us": round(host_busy, 1),
-        "overlap_us": round(overlap, 1),
+        "host_stage_ns": dict(sorted(stage_ns.items())),
+        "host_stage_calls": dict(sorted(stage_calls.items())),
+        "host_busy_ns": host_busy,
+        "overlap_ns": overlap,
         "host_overlap_frac": (round(overlap / host_busy, 4)
                               if host_busy > 0 else None),
-        "wall_us": round(wall_us, 1),
-        "top_ops": [{"name": k, "us": round(v["us"], 1),
-                     "count": v["count"], "category": v["category"]}
-                    for k, v in top],
+        "wall_ns": (window[1] - window[0] if window
+                    else max(edges) - min(edges) if edges else 0),
+        "top_ops": [{"name": k, **v} for k, v in sorted(
+            ops.items(), key=lambda kv: -kv[1]["ns"])[:5]],
+        "fits": [_fit_facts(s, spans, busy, window) for s in spans
+                 if s.name == "train.fit"],
     }
+    if modules and spans:
+        idle = attribute_idle(modules, spans, window)
+        out["idle_ns"] = idle["idle_ns"]
+        out["idle_by_span"] = dict(sorted(idle["by_span"].items(),
+                                          key=lambda kv: -kv[1]))
+        out["queue_lead"] = _lead_facts(modules, spans)
+    return out
+
+
+def _lead_facts(modules, spans) -> dict | None:
+    fit = traced_fit(spans)
+    if fit is None:
+        return None
+    steps = {s.id for s in fit["steps"]}
+    own = [s for s in spans if s.parent in steps]
+    n = sum(1 for s in own if s.name == "train.step.dispatch")
+    runs: dict = {}
+    for name, _, d in modules:
+        rec = runs.setdefault(_program(name), [0, 0])
+        rec[0] += 1
+        rec[1] += d
+    programs = [p for p, (count, _) in runs.items() if count == n]
+    if not programs:
+        return {"refused": f"{n} train.step.dispatch spans in the newest "
+                f"fit; runs by program: "
+                f"{ {p: c for p, (c, _) in sorted(runs.items())} }"}
+    program = max(programs, key=lambda p: runs[p][1])
+    pairs = _paired(modules, own, program)
+    lead = [run[0] - _interval(d)[1] for d, run in pairs]
+    after = [run[0] - d.start_ns for d, run in pairs]
+    return {"program": program, "pairs": len(pairs),
+            "median_ns": statistics.median(lead), "min_ns": min(lead),
+            "after_dispatch_start": {
+                "median_ns": statistics.median(after),
+                "min_ns": min(after)}}

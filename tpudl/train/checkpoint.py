@@ -147,13 +147,16 @@ class CheckpointManager:
             raise
 
     # -- write -------------------------------------------------------------
+    def due(self, step: int) -> bool:
+        """Whether ``step`` hits the save cadence."""
+        return self.save_every > 0 and step % self.save_every == 0
+
     def save(self, step: int, state: dict, *, force: bool = False) -> bool:
         """Save if ``step`` hits the cadence (or ``force``). Blocking
         and durable-before-return is deliberate: resume-equivalence
         (and the job runtime's bounded-rework contract) require the
         write to be on disk before the step counter advances."""
-        if not force and (self.save_every <= 0
-                          or step % self.save_every != 0):
+        if not force and not self.due(step):
             return False
         leaves = jax.tree_util.tree_flatten_with_path(state)[0]
         meta = {"version": PAYLOAD_VERSION, "step": int(step),

@@ -90,6 +90,10 @@ def _restart_backoff_base_s() -> float:
         return 0.1
 
 
+def _nbytes(batch) -> int:
+    return sum(getattr(b, "nbytes", 0) for b in batch)
+
+
 @functools.lru_cache(maxsize=1)
 def _owning_identity():
     """The ONE cached jitted identity program ``Trainer.fit``'s
@@ -268,7 +272,7 @@ class Trainer:
         self._step_fn = make_train_step(loss_fn, optimizer, mesh,
                                         param_shardings=param_shardings)
 
-    def fit(self, params, data_fn, steps: int, *,  # tpudl: hot-path
+    def fit(self, params, data_fn, steps: int, *,
             opt_state=None, stop=None):
         """Train for ``steps`` total steps (resuming included). Returns
         (params, opt_state, history).
@@ -282,7 +286,14 @@ class Trainer:
         at zero steps on the graceful path (≤ ``save_every`` when the
         save itself is lost)."""
         self.history = []  # per-fit; stale entries would misreport results
+        tracer = _obs_tracer.get_tracer()
+        with tracer.span("train.fit", steps=steps) as fit_span:
+            return self._loop(tracer.span, fit_span, params, opt_state,
+                              data_fn, steps, stop)
 
+    def _place(self, params, opt_state):
+        """``params`` and ``opt_state`` (built when None) as buffers this
+        fit owns, placed on the mesh."""
         # own the buffers: the step donates params/opt_state, and device_put
         # may alias the caller's arrays — donating an alias would delete the
         # caller's data out from under them. Host arrays are copied
@@ -391,29 +402,44 @@ class Trainer:
 
             opt_state = jax.tree.map(_place_like, opt_state, template)
             del template
+        return params, opt_state
 
+    def _loop(self, span, fit_span, params, opt_state,  # tpudl: hot-path
+              data_fn, steps, stop):
+        """Placement, restore, the step loop and the drain, inside
+        ``fit``'s ``train.fit`` span. Every span here times the host:
+        nothing synchronises with the device for a span's sake, and what
+        the host waits for inside ``train.step.dispatch`` (argument
+        transfer on a 1-wide data axis, a free slot in the device's
+        queue) is part of what it shows."""
+        with span("train.fit.place"):
+            params, opt_state = self._place(params, opt_state)
         start = 0
         mgr = None
         if self.checkpoint_dir is not None:
-            mgr = CheckpointManager(self.checkpoint_dir,
-                                    save_every=self.save_every)
-            # `like` is built AFTER placement, so restored arrays come
-            # back with the same (possibly TP-sharded) shardings
-            like = {"params": params, "opt_state": opt_state,
-                    "step": np.asarray(0, np.int64)}
-            t_ck = time.perf_counter()
-            restored = mgr.restore(like=like)
+            with span("train.fit.restore") as restore_span:
+                mgr = CheckpointManager(self.checkpoint_dir,
+                                        save_every=self.save_every)
+                # `like` is built AFTER placement, so restored arrays come
+                # back with the same (possibly TP-sharded) shardings
+                restored = mgr.restore(
+                    like={"params": params, "opt_state": opt_state,
+                          "step": np.asarray(0, np.int64)})
+                if restored is not None:
+                    # rebinding drops the pre-restore placed buffers,
+                    # which would otherwise pin ~2x params+opt HBM for
+                    # the whole fit
+                    params = restored["params"]
+                    opt_state = restored["opt_state"]
+                    start = int(restored["step"])
+                    restore_span.set(resumed_at=start)
             if restored is not None:
                 _obs_metrics.histogram(
                     "train.checkpoint_restore_seconds").observe(
-                        time.perf_counter() - t_ck)
-                params = restored["params"]
-                opt_state = restored["opt_state"]
-                start = int(restored["step"])
+                        restore_span.dur_ns / 1e9)
                 log.info("resumed from checkpoint at step %d", start)
-            # the pre-restore placed buffers (still referenced by `like`)
-            # would otherwise pin ~2x params+opt HBM for the whole fit
-            del like, restored
+        fit_span.set(start=start, devices=(
+            self.mesh.devices.size if self.mesh is not None else 1))
 
         step_fn = self._step_fn
 
@@ -428,13 +454,12 @@ class Trainer:
         multi_host = self.mesh is not None and D.process_count() > 1
         shard_inputs = (self.mesh is not None
                         and self.mesh.shape[M.DATA_AXIS] > 1)
-        t0 = time.perf_counter()
         examples = 0
         executed = 0  # steps actually run (a failed run must not
         loss = None   # report the PLANNED count to the registry)
-        # per-step loop time (dispatch cadence: async device dispatch
-        # returns early, so this is the host loop's view — the honest
-        # wall denominator is examples_per_sec in history) and
+        # the host loop's time per step (the `train.step` span: equal to
+        # the device's only while the device throttles the host — the
+        # honest wall denominator is examples_per_sec in history) and
         # checkpoint save durations, published run-wide
         step_hist = _obs_metrics.histogram("train.step_seconds")
         ckpt_hist = _obs_metrics.histogram("train.checkpoint_save_seconds")
@@ -444,86 +469,94 @@ class Trainer:
         step_gauge = _obs_metrics.gauge("train.last_step")
         hb = _obs_watchdog.heartbeat("train.fit", steps=steps,
                                      start=start)
+
+        def _state(at):
+            return {"params": params, "opt_state": opt_state,
+                    "step": np.asarray(at, np.int64)}
+
+        t0 = time.perf_counter()
         try:
             for step in range(start, steps):
-                if stop is not None and stop():
-                    # checkpoint-then-exit: the state BEFORE this step
-                    # is saved at `step` (steps 0..step-1 completed), so
-                    # an identical relaunch resumes with zero re-work
-                    if mgr is not None:
-                        t_ck = time.perf_counter()
-                        mgr.save(step, {"params": params,
-                                        "opt_state": opt_state,
-                                        "step": np.asarray(step, np.int64)},
-                                 force=True)
-                        ckpt_hist.observe(time.perf_counter() - t_ck)
-                    raise Preempted(step, saved=mgr is not None)
-                # step + examples ride the beat: the live status plane
-                # (obs top) shows training progress from the heartbeat
-                # info without a second instrumentation channel
-                hb.beat(step=step, examples=examples)
-                # fault point for the preemption suite: a FaultPlan can
-                # SIGTERM-to-self or raise at an exact step (unarmed:
-                # one global None-check)
-                _faults.fire("train.step", step=step)
-                t_step = time.perf_counter()
-                batch = data_fn(step)
-                if not isinstance(batch, tuple):
-                    batch = (batch,)
-                if multi_host:
-                    batch = tuple(
-                        # tpudl: ignore[hot-sync] — data_fn yields HOST
-                        # arrays; this asarray is the H2D staging copy
-                        # of the local shard, not a device round-trip
-                        D.global_batch(np.asarray(b), self.mesh)
-                        for b in batch)
-                elif shard_inputs:
-                    # ONE batched async transfer for the whole step
-                    # tuple (mesh.transfer_batch underneath — the same
-                    # edge the frame executor and the estimator use)
-                    batch = M.shard_batch(batch, self.mesh)
-                params, opt_state, loss = step_fn(params, opt_state, *batch)
-                step_hist.observe(time.perf_counter() - t_step)
-                step_gauge.set(step + 1)
-                executed += 1
-                examples += int(np.shape(batch[0])[0])
-                # attribution: training rows consumed under the
-                # caller's scope — fit publishes on the calling thread,
-                # so the contextvar needs no explicit carry here
-                _attr.charge("rows_in", int(np.shape(batch[0])[0]))
-                done = step + 1
-                if mgr is not None and done < steps:
-                    t_ck = time.perf_counter()
-                    if mgr.maybe_save(done, {"params": params,
-                                             "opt_state": opt_state,
-                                             "step": np.asarray(done, np.int64)}):
-                        ckpt_hist.observe(time.perf_counter() - t_ck)
+                with span("train.step", step=step) as step_span:
+                    if stop is not None and stop():
+                        # checkpoint-then-exit: the state BEFORE this step
+                        # is saved at `step` (steps 0..step-1 completed),
+                        # so an identical relaunch resumes with zero
+                        # re-work
+                        if mgr is not None:
+                            with span("train.step.checkpoint") as ck:
+                                mgr.save(step, _state(step), force=True)
+                            ckpt_hist.observe(ck.dur_ns / 1e9)
+                        raise Preempted(step, saved=mgr is not None)
+                    # step + examples ride the beat: the live status plane
+                    # (obs top) shows training progress from the heartbeat
+                    # info without a second instrumentation channel
+                    hb.beat(step=step, examples=examples)
+                    # fault point for the preemption suite: a FaultPlan
+                    # can SIGTERM-to-self or raise at an exact step
+                    # (unarmed: one global None-check)
+                    _faults.fire("train.step", step=step)
+                    with span("train.step.data"):
+                        batch = data_fn(step)
+                    if not isinstance(batch, tuple):
+                        batch = (batch,)
+                    if multi_host:
+                        with span("train.step.place", bytes=_nbytes(batch)):
+                            batch = tuple(
+                                # tpudl: ignore[hot-sync] — data_fn yields
+                                # HOST arrays; this asarray is the H2D
+                                # staging copy of the local shard, not a
+                                # device round-trip
+                                D.global_batch(np.asarray(b), self.mesh)
+                                for b in batch)
+                    elif shard_inputs:
+                        # ONE batched async transfer for the whole step
+                        # tuple (mesh.transfer_batch underneath — the same
+                        # edge the frame executor and the estimator use)
+                        with span("train.step.place", bytes=_nbytes(batch)):
+                            batch = M.shard_batch(batch, self.mesh)
+                    with span("train.step.dispatch"):
+                        params, opt_state, loss = step_fn(params, opt_state,
+                                                          *batch)
+                    step_gauge.set(step + 1)
+                    executed += 1
+                    examples += int(np.shape(batch[0])[0])
+                    # attribution: training rows consumed under the
+                    # caller's scope — fit publishes on the calling
+                    # thread, so the contextvar needs no explicit carry
+                    _attr.charge("rows_in", int(np.shape(batch[0])[0]))
+                    done = step + 1
+                    if mgr is not None and done < steps and mgr.due(done):
+                        with span("train.step.checkpoint") as ck:
+                            mgr.save(done, _state(done))
+                        ckpt_hist.observe(ck.dur_ns / 1e9)
                         log.debug("checkpoint at step %d", done)
-                if self.log_every and done % self.log_every == 0:
+                    if self.log_every and done % self.log_every == 0:
+                        dt = time.perf_counter() - t0
+                        # tpudl: ignore[hot-sync] — opt-in loss logging:
+                        # the fetch is the feature, paid once per
+                        # log_every steps and off by default
+                        l = float(jax.device_get(loss))
+                        self.history.append(
+                            {"step": done, "loss": l,
+                             "examples_per_sec": examples / max(dt, 1e-9)})
+                        log.info("step %d loss %.5f (%.1f ex/s)", done, l,
+                                 examples / max(dt, 1e-9))
+                step_hist.observe(step_span.dur_ns / 1e9)
+            with span("train.fit.drain"):
+                if loss is not None and (not self.history
+                                         or self.history[-1]["step"] != steps):
                     dt = time.perf_counter() - t0
-                    # tpudl: ignore[hot-sync] — opt-in loss logging:
-                    # the fetch is the feature, paid once per
-                    # log_every steps and off by default
-                    l = float(jax.device_get(loss))
                     self.history.append(
-                        {"step": done, "loss": l,
+                        {"step": steps,
+                         # tpudl: ignore[hot-sync] — after the last step:
+                         # the run's final loss fetch, no pipeline behind
+                         "loss": float(jax.device_get(loss)),
                          "examples_per_sec": examples / max(dt, 1e-9)})
-                    log.info("step %d loss %.5f (%.1f ex/s)", done, l,
-                             examples / max(dt, 1e-9))
-            if loss is not None and (not self.history
-                                     or self.history[-1]["step"] != steps):
-                dt = time.perf_counter() - t0
-                self.history.append(
-                    {"step": steps,
-                     # tpudl: ignore[hot-sync] — after the last step:
-                     # the run's final loss fetch, no pipeline behind it
-                     "loss": float(jax.device_get(loss)),
-                     "examples_per_sec": examples / max(dt, 1e-9)})
-            if mgr is not None and steps > start:
-                t_ck = time.perf_counter()
-                mgr.save(steps, {"params": params, "opt_state": opt_state,
-                                 "step": np.asarray(steps, np.int64)}, force=True)
-                ckpt_hist.observe(time.perf_counter() - t_ck)
+                if mgr is not None and steps > start:
+                    with span("train.step.checkpoint") as ck:
+                        mgr.save(steps, _state(steps), force=True)
+                    ckpt_hist.observe(ck.dur_ns / 1e9)
         finally:
             hb.__exit__(None, None, None)
             if mgr is not None:
