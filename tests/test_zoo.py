@@ -11,6 +11,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from tpudl import obs
 from tpudl.zoo import (
     SUPPORTED_MODELS,
     getKerasApplicationModel,
@@ -18,6 +19,7 @@ from tpudl.zoo import (
     preprocess_input,
     decode_predictions,
 )
+from tpudl.zoo.core import Store
 
 keras = pytest.importorskip("keras")
 
@@ -189,3 +191,158 @@ def test_normalization_rescaling_fold(rng):
         build(True, intervene_weightless=True))
     np.testing.assert_allclose(weightless["normalization"]["variance"],
                                [4.0, 4.0, 4.0])
+
+
+# -- Store.conv_bn: moving-statistics batch norm folded into its conv ------
+
+_RESNET_PAIRS = {"ResNet50": 53, "ResNet101": 104, "ResNet152": 155}
+
+
+class _UnfoldedStore(Store):
+    """The pair as it was written before the fold: ``nn.conv2d`` then
+    ``nn.batch_norm`` on the same parameters (the reference the folded
+    path is held to)."""
+
+    def conv_bn(self, x, filters, kernel_size, *, strides=(1, 1),
+                padding="SAME", epsilon=1e-3, conv_name=None, bn_name=None):
+        x = self.conv(x, filters, kernel_size, strides=strides,
+                      padding=padding, name=conv_name)
+        return self.bn(x, epsilon=epsilon, name=bn_name)
+
+
+def _busy_params(m, hw, seed=4):
+    """``init`` leaves batch norm at the identity and biases at zero, where
+    a wrong fold would still pass: draw every such leaf away from it. The
+    head's kernel is scaled down so that the softmax does not saturate and
+    a cross-entropy loss has a gradient to compare."""
+    gen = np.random.default_rng(seed)
+    params = m.init(seed, image_size=(hw, hw))
+    draw = {"gamma": lambda s: gen.uniform(0.5, 1.5, s),
+            "moving_var": lambda s: gen.uniform(0.5, 1.5, s),
+            "beta": lambda s: gen.normal(0, 0.1, s),
+            "moving_mean": lambda s: gen.normal(0, 0.1, s),
+            "bias": lambda s: gen.normal(0, 0.1, s)}
+    params = {lname: {k: (draw[k](v.shape).astype(np.float32) if k in draw
+                          else v) for k, v in p.items()}
+              for lname, p in params.items()}
+    params["predictions"]["kernel"] = params["predictions"]["kernel"] * 0.01
+    return params, jnp.asarray(_rand(gen, hw))
+
+
+def _conv_bn_counts():
+    snap = obs.snapshot("zoo.conv_bn.")
+    return tuple(snap.get(f"zoo.conv_bn.{k}", {}).get("value", 0)
+                 for k in ("folded", "unfolded"))
+
+
+@pytest.mark.parametrize("name", sorted(_RESNET_PAIRS))
+def test_resnet_folded_forward_matches_unfolded_pair(name):
+    """Class probabilities to rtol 1e-5 (measured: 3e-7 to 1.8e-6), and the
+    pooled features, everything the fold touches, to 1e-5 of their scale."""
+    m = getKerasApplicationModel(name)
+    params, x = _busy_params(m, _SMALL[name])
+    folded0, unfolded0 = _conv_bn_counts()
+    probs = np.asarray(m.predict(params, x))
+    assert _conv_bn_counts() == (folded0 + _RESNET_PAIRS[name], unfolded0)
+    unfolded = _UnfoldedStore(params=params)
+    ref = np.asarray(m.build_fn(unfolded, x, include_top=True,
+                                classes=m.classes))
+    np.testing.assert_allclose(probs, ref, rtol=1e-5, atol=0)
+    ref = np.asarray(m.build_fn(unfolded, x, include_top=False,
+                                pooling="avg"))
+    np.testing.assert_allclose(np.asarray(m.featurize(params, x)), ref,
+                               rtol=1e-5, atol=1e-5 * np.abs(ref).max())
+
+
+@pytest.mark.parametrize("name", sorted(_RESNET_PAIRS))
+def test_resnet_folded_gradients_match_unfolded_pair(name):
+    """Plain autodiff through the fold gives every leaf its gradient:
+    kernel, bias, gamma, beta, moving_mean, moving_var and the head, each
+    to rel-l2 1e-5 (measured: worst leaf 1.9e-6). The seed is fixed: where
+    a ReLU's input rounds across zero (seed 3 has one in
+    ``conv2_block2_1``) a small layer's gradient moves by per cents, which
+    is the activation's doing and not the fold's (it is gone in
+    float64)."""
+    import jax
+
+    m = getKerasApplicationModel(name)
+    params, x = _busy_params(m, _SMALL[name])
+    y = np.eye(m.classes, dtype=np.float32)[[3, 7]]
+
+    def loss(store):
+        def f(p):
+            probs = m.build_fn(store(params=p), x, include_top=True,
+                               classes=m.classes)
+            return -jnp.mean(jnp.sum(y * jnp.log(jnp.clip(probs, 1e-7, 1.0)),
+                                     axis=-1))
+        return f
+
+    got = jax.jit(jax.grad(loss(Store)))(params)
+    ref = jax.jit(jax.grad(loss(_UnfoldedStore)))(params)
+    assert jax.tree.structure(got) == jax.tree.structure(ref)
+    worst = {}
+    for lname, leaves in ref.items():
+        for k, r in leaves.items():
+            r, g = np.asarray(r), np.asarray(got[lname][k])
+            assert np.linalg.norm(r) > 0, (lname, k)
+            worst[lname, k] = np.linalg.norm(g - r) / np.linalg.norm(r)
+    bad = {k: v for k, v in worst.items() if not v <= 1e-5}
+    assert not bad, bad
+
+
+@pytest.mark.parametrize("name", sorted(_RESNET_PAIRS))
+def test_resnet_init_is_bitwise_the_unfolded_builders(name):
+    import jax
+
+    m = getKerasApplicationModel(name)
+    hw = _SMALL[name]
+    s = _UnfoldedStore(rng=np.random.default_rng(11))
+    jax.eval_shape(lambda x: m.build_fn(s, x, include_top=True,
+                                        classes=m.classes),
+                   jax.ShapeDtypeStruct((1, hw, hw, 3), jnp.float32))
+    params = m.init(11, image_size=(hw, hw))
+    assert list(params) == list(s.params)
+    for lname, p in s.params.items():
+        assert list(params[lname]) == list(p), lname
+        for k, v in p.items():
+            assert params[lname][k].dtype == v.dtype, (lname, k)
+            assert np.array_equal(params[lname][k], v), (lname, k)
+
+
+@pytest.mark.parametrize("name", sorted(_RESNET_PAIRS))
+def test_resnet_batch_statistics_stay_unfolded(name, rng):
+    m = getKerasApplicationModel(name)
+    hw = _SMALL[name]
+    params = m.init(0, image_size=(hw, hw))
+    folded0, unfolded0 = _conv_bn_counts()
+    _, updates = m.apply(params, jnp.asarray(_rand(rng, hw)), train=True)
+    assert len(updates) == _RESNET_PAIRS[name]
+    assert _conv_bn_counts() == (folded0, unfolded0 + _RESNET_PAIRS[name])
+
+
+def test_folded_block_backward_keeps_no_raw_conv_output():
+    """The mechanism itself, as a count: the backward of one shortcut block
+    in bf16 keeps four activation-sized residuals fewer than the unfolded
+    pair, one raw convolution output per pair. An edit that brings them
+    back fails here and not on the chip."""
+    import jax
+
+    from tpudl.zoo import resnet
+
+    n, side, filters = 5, 8, 8
+    x = jnp.ones((n, side, side, 2 * filters), jnp.bfloat16)
+    s = Store(rng=np.random.default_rng(0))
+    resnet._block(s, x, filters, name="b")
+    params = jax.tree.map(lambda a: jnp.asarray(a, jnp.bfloat16), s.params)
+
+    def activation_residuals(store):
+        def f(p, x):
+            return jnp.sum(resnet._block(store(params=p), x, filters,
+                                         name="b").astype(jnp.float32))
+        _, vjp = jax.eval_shape(lambda p, x: jax.vjp(f, p, x), params, x)
+        return [r for r in jax.tree.leaves(vjp)
+                if r.ndim == 4 and r.shape[:3] == (n, side, side)]
+
+    folded = activation_residuals(Store)
+    unfolded = activation_residuals(_UnfoldedStore)
+    assert len(unfolded) - len(folded) == 4, (len(unfolded), len(folded))

@@ -190,6 +190,14 @@ METRICS: tuple[Metric, ...] = (
            "wall time per checkpoint restore"),
     Metric("train.checkpoint.corrupt", "counter",
            "checkpoints failing checksum on restore (fell back)"),
+    # -- zoo (counted while a program is traced, not per step) ---------
+    Metric("zoo.conv_bn.folded", "counter",
+           "conv + batch-norm pairs traced with the norm's moving "
+           "statistics folded into the kernel and bias (53 per trace of "
+           "ResNet50 with train=False)"),
+    Metric("zoo.conv_bn.unfolded", "counter",
+           "conv + batch-norm pairs traced as written, on batch "
+           "statistics (Store(train=True)): nothing can be folded"),
     # -- jobs / retries ------------------------------------------------
     Metric("retry.attempts", "counter",
            "retry attempts across all RetryPolicy call sites"),

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpudl.obs import metrics as _metrics
 from tpudl.zoo import nn
 
 __all__ = ["Namer", "Store", "glorot_uniform"]
@@ -190,6 +191,33 @@ class Store:
             self.bn_updates[lname] = new_stats
             return y
         return nn.batch_norm(x, p, train=False, epsilon=epsilon)
+
+    def conv_bn(self, x, filters, kernel_size, *, strides=(1, 1),
+                padding="SAME", epsilon=1e-3, conv_name=None, bn_name=None):
+        """A biased convolution followed directly by batch norm: the same
+        two parameter dictionaries, in the same order of draws, as ``conv``
+        then ``bn``. On moving statistics the norm is a constant
+        per-channel scale and shift, so it is folded into the kernel and
+        the bias inside the traced program (``nn.conv2d_bn_folded``): all
+        six leaves keep their gradients, and the backward pass no longer
+        needs the raw convolution output. On batch statistics
+        (``train=True``) the scale depends on the data and the pair runs
+        as it is written. The store's mode decides; callers set nothing.
+
+        The two counters are bumped while a program is TRACED, once per
+        pair, on purpose: they say whether the fold engaged in it."""
+        if self.initializing or self.train:
+            if self.train:
+                _metrics.counter("zoo.conv_bn.unfolded").inc()
+            x = self.conv(x, filters, kernel_size, strides=strides,
+                          padding=padding, name=conv_name)
+            return self.bn(x, epsilon=epsilon, name=bn_name)
+        _metrics.counter("zoo.conv_bn.folded").inc()
+        pc = self._get(self.name("conv2d", conv_name), None)
+        pb = self._get(self.name("batch_normalization", bn_name), None)
+        return nn.conv2d_bn_folded(x, pc["kernel"], pc["bias"], pb,
+                                   epsilon=epsilon, strides=strides,
+                                   padding=padding)
 
     def norm_stats(self, x, *, name=None):
         """Keras ``Normalization`` layer: (x - mean) / sqrt(variance)

@@ -28,6 +28,8 @@ __all__ = [
     "separable_conv2d",
     "dense",
     "batch_norm",
+    "moving_scale_shift",
+    "conv2d_bn_folded",
     "max_pool",
     "avg_pool",
     "global_avg_pool",
@@ -85,6 +87,32 @@ def dense(x, kernel, bias=None):
     return y
 
 
+def moving_scale_shift(p: dict, epsilon: float):
+    """Batch norm on its moving statistics as one per-channel float32
+    ``(scale, shift)``: ``y = x * scale + shift``."""
+    inv = lax.rsqrt(p["moving_var"].astype(jnp.float32) + epsilon)
+    if p.get("gamma") is not None:
+        inv = inv * p["gamma"].astype(jnp.float32)
+    shift = -p["moving_mean"].astype(jnp.float32) * inv
+    if p.get("beta") is not None:
+        shift = shift + p["beta"].astype(jnp.float32)
+    return inv, shift
+
+
+def conv2d_bn_folded(x, kernel, bias, p: dict, *, epsilon: float,
+                     strides=(1, 1), padding="SAME"):
+    """``batch_norm(conv2d(x, kernel, bias), p)`` on moving statistics as
+    ONE convolution: the scale is per output channel, so it folds into
+    the kernel and the bias exactly. Autodiff then takes the scale's
+    gradient from a kernel-sized product and never needs the raw
+    convolution output: the backward pass stores and reads one
+    activation-sized tensor fewer per pair."""
+    inv, shift = moving_scale_shift(p, epsilon)
+    kernel = (kernel.astype(jnp.float32) * inv).astype(x.dtype)
+    bias = (bias.astype(jnp.float32) * inv + shift).astype(x.dtype)
+    return conv2d(x, kernel, bias, strides=strides, padding=padding)
+
+
 def batch_norm(x, p: dict, *, train: bool = False, epsilon: float = 1e-3,
                momentum: float = 0.99):
     """Keras BatchNormalization over the channel (last) axis.
@@ -97,12 +125,7 @@ def batch_norm(x, p: dict, *, train: bool = False, epsilon: float = 1e-3,
     gamma = p.get("gamma")
     beta = p.get("beta")
     if not train:
-        inv = lax.rsqrt(p["moving_var"].astype(jnp.float32) + epsilon)
-        if gamma is not None:
-            inv = inv * gamma.astype(jnp.float32)
-        shift = -p["moving_mean"].astype(jnp.float32) * inv
-        if beta is not None:
-            shift = shift + beta.astype(jnp.float32)
+        inv, shift = moving_scale_shift(p, epsilon)
         return x * inv.astype(x.dtype) + shift.astype(x.dtype)
     axes = tuple(range(x.ndim - 1))
     xf = x.astype(jnp.float32)

@@ -11,6 +11,18 @@ Conv/BN details from the Keras source: conv1 is 7×7 s2 VALID after a
 (3,3) zero-pad, all convs use bias, BN epsilon 1.001e-5; stacks
 conv2(64×3, s1), conv3(128×4, s2), conv4(256×6, s2), conv5(512×3, s2);
 block shortcut is a 1×1 VALID conv at stride s.
+
+Every convolution here is followed directly by batch norm, so all five
+sites go through ``Store.conv_bn``. On moving statistics (``apply`` with
+``train=False``: inference, and every training path that calls
+``predict``) the norm's per-channel scale and shift are folded into the
+convolution's kernel and bias inside the traced program; the parameter
+tree keeps its six leaves per pair and autodiff through the fold gives
+all six their gradients, without the backward pass storing and re-reading
+the raw convolution output. The fold does not apply on batch statistics
+(``train=True``: the scale depends on the data) and not where the norm
+comes before its convolution or something sits between them (DenseNet,
+the other zoo models keep ``conv`` + ``bn``).
 """
 
 from __future__ import annotations
@@ -29,21 +41,21 @@ _EPS = 1.001e-5
 
 
 def _block(s: Store, x, filters, *, stride=1, conv_shortcut=True, name=""):
+    def conv_bn(x, filters, kernel_size, i, **kw):
+        return s.conv_bn(x, filters, kernel_size, epsilon=_EPS,
+                         conv_name=f"{name}_{i}_conv", bn_name=f"{name}_{i}_bn",
+                         **kw)
+
     if conv_shortcut:
-        shortcut = s.conv(x, 4 * filters, 1, strides=(stride, stride),
-                          padding="VALID", name=f"{name}_0_conv")
-        shortcut = s.bn(shortcut, epsilon=_EPS, name=f"{name}_0_bn")
+        shortcut = conv_bn(x, 4 * filters, 1, 0, strides=(stride, stride),
+                           padding="VALID")
     else:
         shortcut = x
-    x = s.conv(x, filters, 1, strides=(stride, stride), padding="VALID",
-               name=f"{name}_1_conv")
-    x = s.bn(x, epsilon=_EPS, name=f"{name}_1_bn")
+    x = conv_bn(x, filters, 1, 1, strides=(stride, stride), padding="VALID")
     x = nn.relu(x)
-    x = s.conv(x, filters, 3, padding="SAME", name=f"{name}_2_conv")
-    x = s.bn(x, epsilon=_EPS, name=f"{name}_2_bn")
+    x = conv_bn(x, filters, 3, 2, padding="SAME")
     x = nn.relu(x)
-    x = s.conv(x, 4 * filters, 1, padding="VALID", name=f"{name}_3_conv")
-    x = s.bn(x, epsilon=_EPS, name=f"{name}_3_bn")
+    x = conv_bn(x, 4 * filters, 1, 3, padding="VALID")
     return nn.relu(shortcut + x)
 
 
@@ -60,8 +72,8 @@ def _build_resnet(s: Store, x, stacks, *, include_top=True, pooling=None,
     conv2..conv5 stage (keras.applications.resnet: ResNet50 (3,4,6,3),
     ResNet101 (3,4,23,3), ResNet152 (3,8,36,3))."""
     x = nn.zero_pad(x, ((3, 3), (3, 3)))
-    x = s.conv(x, 64, 7, strides=(2, 2), padding="VALID", name="conv1_conv")
-    x = s.bn(x, epsilon=_EPS, name="conv1_bn")
+    x = s.conv_bn(x, 64, 7, strides=(2, 2), padding="VALID", epsilon=_EPS,
+                  conv_name="conv1_conv", bn_name="conv1_bn")
     x = nn.relu(x)
     x = nn.zero_pad(x, ((1, 1), (1, 1)))
     x = nn.max_pool(x, (3, 3), strides=(2, 2))
