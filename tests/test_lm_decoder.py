@@ -1,0 +1,615 @@
+"""The config-driven decoder (tpudl.zoo.decoder) and its routed experts
+against the plain float32 reference, at toy widths on the CPU.
+
+Seeded weights; float32 comparisons at 1e-5 under highest matmul
+precision; bfloat16 (the precision the cell trains in) at the stated
+tolerances, on the program's own routes."""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from tpudl.train import Trainer, with_compute_dtype
+from tpudl.zoo import lm_blocks, moe
+from tpudl.zoo.decoder import Decoder
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load(path, name):
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+# the plain reference lives with the benchmark's configuration
+R = _load(os.path.join(REPO, "benchmark", "configs", "lfm2-8b-a1b-ep4.py"),
+          "lfm2_reference")
+
+
+def rel(a, b):
+    a = np.asarray(a, np.float32).ravel()
+    b = np.asarray(b, np.float32).ravel()
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+BASE = dict(hidden_size=64, intermediate_size=96, moe_intermediate_size=48,
+            num_attention_heads=4, num_key_value_heads=2, num_experts=8,
+            num_experts_per_tok=2, experts_held=(2, 4), vocab_size=512,
+            vocab_slice=(0, 128), norm_eps=1e-5, rope_theta=1e6,
+            conv_L_cache=3, attention_block_q=8, attention_block_k=8)
+REF = dict(top_k=2, held_first=2, attention_rows=8)
+STACKS = {
+    "conv+dense": (["conv"], 1),
+    "attention+dense": (["full_attention"], 1),
+    "conv+routed": (["conv"], 0),
+    "attention+routed": (["full_attention"], 0),
+    "whole": (["conv", "full_attention", "conv"], 1),
+    # runs of one kind, which the decoder scans
+    "conv+routed x3": (["conv"] * 3, 0),
+    "one period": (["conv", "full_attention", "conv", "conv", "conv"], 1),
+}
+
+
+def build(stack, seed=3, **over):
+    layers, dense = STACKS[stack]
+    lm = Decoder({**BASE, "layer_types": layers, "num_dense_layers": dense,
+                  **over})
+    p = lm.init(seed)
+    # init leaves the selection bias at zero; the tests want it to matter
+    # (the same for every share of a layer: it is the router's)
+    for name in p:
+        if name.endswith("expert_bias"):
+            assert not np.any(p[name])
+            p[name] = (0.02 * np.random.default_rng(seed).standard_normal(
+                p[name].shape)).astype(np.float32)
+    return lm, p
+
+
+def tokens(seed=0, shape=(2, 16)):
+    return np.random.default_rng(seed).integers(
+        0, 128, shape).astype(np.int32)
+
+
+@pytest.mark.parametrize("stack", list(STACKS))
+def test_logits_and_gradients_match_the_reference_in_float32(stack):
+    lm, p = build(stack)
+    ids = tokens()
+    with jax.default_matmul_precision("highest"):
+        assert rel(jax.jit(lm.logits)(p, ids),
+                   jax.jit(lambda q: R.forward(q, ids, **REF))(p)) < 1e-5
+        got_l, got = jax.jit(jax.value_and_grad(lm.loss_fn()))(p, ids)
+        want_l, want = jax.jit(jax.value_and_grad(
+            lambda q: R.loss(q, ids, **REF)))(p)
+    assert abs(float(got_l) - float(want_l)) < 1e-5 * float(want_l)
+    assert set(got) == set(want)
+    for name in want:
+        if name.endswith("expert_bias"):   # a buffer: no gradient reaches it
+            assert not np.any(got[name]) and not np.any(want[name])
+        else:
+            assert rel(got[name], want[name]) < 1e-5, name
+
+
+@pytest.mark.parametrize("stack", ["attention+dense", "conv+routed", "whole"])
+def test_bfloat16_gradients_on_the_programs_routes(stack):
+    """bf16 compute on float32 masters, as the cell trains: every leaf
+    within 0.08 of the float32 gradient taken on the routes the bf16
+    program chose (measured 0.02-0.05 at these widths: a product's
+    operands round to 8 bits, and a 64-wide contraction averages little;
+    an fp8 product or a missing term is several times that,
+    test_the_check_fails_on_each_fault)."""
+    lm, p = build(stack)
+    ids = tokens()
+    loss = with_compute_dtype(lm.loss_fn(), jnp.bfloat16)
+    routes = jax.jit(with_compute_dtype(lm.routes, jnp.bfloat16))(p, ids)
+    got = jax.jit(jax.grad(loss))(p, ids)
+    want = jax.jit(jax.grad(lambda q: R.loss(q, ids, routes, **REF)))(p)
+    for name in want:
+        if not name.endswith("expert_bias"):
+            assert got[name].dtype == jnp.float32
+            assert rel(got[name], want[name]) < 0.08, name
+
+
+def test_a_run_of_identical_layers_is_one_scanned_body():
+    """LFM2's conv, conv, conv between attentions: the program holds the
+    block once (one scan, one set of grouped products), and the leaves
+    stay one flat dict of single layers."""
+    lm, p = build("one period")
+    assert lm.runs() == [(0, 1), (1, 1), (2, 3)]
+    assert Decoder({**BASE, "layer_types": ["conv", "conv", "full_attention"],
+                    "num_dense_layers": 1}).runs() == [(0, 1), (1, 1),
+                                                        (2, 1)]
+    text = str(jax.make_jaxpr(lm.loss_fn(remat=True))(p, tokens()))
+    assert text.count("scan[") == 2          # the run, and the chunked head
+    # layer 1 alone, and once for layers 2-4
+    assert text.count("ragged_dot_general[") == 2 * 3
+    assert all(v.ndim <= 3 and ".moe.w" in k or v.ndim <= 2
+               for k, v in p.items())
+
+
+def test_remat_and_chunked_loss_change_no_value():
+    lm, p = build("whole")
+    ids = tokens(shape=(2, 32))
+    with jax.default_matmul_precision("highest"):
+        a = jax.jit(jax.value_and_grad(
+            lm.loss_fn(remat=True, loss_chunk=16)))(p, ids)
+        b = jax.jit(jax.value_and_grad(
+            lm.loss_fn(remat=False, loss_chunk=64)))(p, ids)
+        c = jax.jit(lm.loss_fn(loss_chunk=48))(p, ids)   # no divisor
+    assert abs(float(a[0]) - float(b[0])) < 1e-6
+    assert abs(float(c) - float(b[0])) < 1e-6
+    for name in a[1]:
+        assert rel(a[1][name], b[1][name]) < 1e-5 or not np.any(b[1][name])
+
+
+# ---- the chip's share ----------------------------------------------------
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    """Partial results of the shares (0,2) (2,2) (4,2) (6,2) of an
+    8-expert layer add up to the uncut reference's layer output; the
+    router, which every share computes alike, is the same in all."""
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (2, 16, 64)), jnp.float32)
+    whole, pw = build("conv+routed", experts_held=(0, 8))
+    name = "layers.0.moe"
+    with jax.default_matmul_precision("highest"):
+        want, routes = R.routed_ff(pw, name, x, 2, 1.0, 0)
+        total = 0.0
+        for first in (0, 2, 4, 6):
+            lm, p = build("conv+routed", experts_held=(first, 2))
+            np.testing.assert_array_equal(p[name + ".router"],
+                                          pw[name + ".router"])
+            np.testing.assert_array_equal(p[name + ".w1"],
+                                          pw[name + ".w1"][first:first + 2])
+            part, chosen = moe.routed_ff(p, name, x, top_k=2,
+                                         held=(first, 2))
+            np.testing.assert_array_equal(chosen, routes)
+            ref_part, _ = R.routed_ff(p, name, x, 2, 1.0, first)
+            assert rel(part, ref_part) < 1e-5
+            total = total + part
+    assert rel(total, want) < 1e-5
+    assert rel(part, want) > 0.1    # one share alone is not the layer
+
+
+@pytest.mark.parametrize("skew", ["all_held", "one_expert", "none_held"])
+def test_nothing_is_dropped_at_any_skew(skew):
+    """A bias that sends every token to held experts (four times the
+    balanced load on 2 of 8), to ONE held expert plus one absent, or to
+    absent experts only: the layer still matches the reference, which
+    has no buffer to overflow."""
+    lm, p = build("conv+routed", experts_held=(2, 2))
+    name = "layers.0.moe"
+    bias = np.full(8, -10.0, np.float32)
+    bias[{"all_held": [2, 3], "one_expert": [3, 6],
+          "none_held": [0, 7]}[skew]] = 10.0
+    p[name + ".expert_bias"] = bias
+    x = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (2, 16, 64)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got, chosen = moe.routed_ff(p, name, x, top_k=2, held=(2, 2))
+        want, _ = R.routed_ff(p, name, x, 2, 1.0, 2)
+        g_got = jax.jit(jax.grad(lambda q: moe.routed_ff(
+            q, name, x, top_k=2, held=(2, 2))[0].sum()))(p)
+        g_want = jax.jit(jax.grad(lambda q: R.routed_ff(
+            q, name, x, 2, 1.0, 2)[0].sum()))(p)
+    held = int(((np.asarray(chosen) >= 2) & (np.asarray(chosen) < 4)).sum())
+    assert held == {"all_held": 64, "one_expert": 32, "none_held": 0}[skew]
+    if skew == "none_held":
+        assert not np.any(got) and not np.any(want)
+    else:
+        assert rel(got, want) < 1e-5
+    for leaf in ("w1", "w2", "w3", "router"):
+        key = f"{name}.{leaf}"
+        if np.any(g_want[key]):
+            assert rel(g_got[key], g_want[key]) < 1e-5, key
+        else:
+            assert not np.any(g_got[key]), key
+
+
+def test_grouped_products_backward_against_the_masked_form():
+    """d/dx and d/dW of the sorted, grouped products against jax.grad of
+    every-expert-on-every-token masked by the weights."""
+    lm, p = build("conv+routed")
+    name = "layers.0.moe"
+    x = jnp.asarray(np.random.default_rng(3).standard_normal(
+        (1, 24, 64)), jnp.float32)
+    w = jnp.asarray(np.random.default_rng(4).standard_normal(
+        (1, 24, 64)), jnp.float32)
+
+    def masked(q, x):
+        experts, weights = moe.route(q, name, x, top_k=2)
+        dense = (jax.nn.one_hot(experts, 8) * weights[..., None]).sum(-2)
+        y = 0.0
+        for e in range(4):
+            h = jax.nn.silu(x @ q[name + ".w1"][e]) * (x @ q[name + ".w3"][e])
+            y = y + dense[..., 2 + e, None] * (h @ q[name + ".w2"][e])
+        return (y * w).sum()
+
+    def grouped(q, x):
+        return (moe.routed_ff(q, name, x, top_k=2, held=(2, 4))[0] * w).sum()
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.grad(grouped, (0, 1)))(p, x)
+        want = jax.jit(jax.grad(masked, (0, 1)))(p, x)
+    assert rel(got[1], want[1]) < 1e-5
+    for leaf in ("w1", "w2", "w3", "router"):
+        assert rel(got[0][f"{name}.{leaf}"], want[0][f"{name}.{leaf}"]) < 1e-5
+
+
+def test_pair_order_sorts_held_pairs_first_and_counts_them():
+    experts = jnp.asarray([5, 2, 9, 3, 2, 0, 3, 3], jnp.int32)
+    order, place, sizes = moe.pair_order(experts, (2, 2))
+    assert sizes.tolist() == [2, 3]
+    assert np.asarray(experts)[np.asarray(order)].tolist()[:5] == [
+        2, 2, 3, 3, 3]
+    assert np.asarray(order)[np.asarray(place)].tolist() == list(range(8))
+
+
+def test_short_convolution_against_a_loop_over_time():
+    rng = np.random.default_rng(5)
+    p = lm_blocks.init_conv(rng, "c", 8, 3)
+    x = rng.standard_normal((2, 10, 8)).astype(np.float32)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(lm_blocks.conv_op(p, "c", jnp.asarray(x)))
+    b, c, u = np.split(x @ p["c.in_proj"], 3, axis=-1)
+    y = b * u
+    v = np.zeros_like(y)
+    for t in range(10):
+        for j in range(3):
+            if t - 2 + j >= 0:
+                v[:, t] += p["c.kernel"][j] * y[:, t - 2 + j]
+    want = (c * v) @ p["c.out_proj"]
+    assert rel(got, want) < 1e-5
+    # causal: a later input moves no earlier output
+    x2 = x.copy()
+    x2[:, 7:] += 1.0
+    again = np.asarray(lm_blocks.conv_op(p, "c", jnp.asarray(x2)))
+    np.testing.assert_array_equal(again[:, :7], got[:, :7])
+
+
+def test_rotary_turns_half_split_pairs_and_keeps_norms():
+    x = jnp.asarray(np.random.default_rng(6).standard_normal(
+        (1, 5, 2, 8)), jnp.float32)
+    y = lm_blocks.rotary(x, 1e6)
+    np.testing.assert_allclose(y[:, 0], x[:, 0], rtol=1e-6)   # position 0
+    np.testing.assert_allclose(jnp.linalg.norm(y, axis=-1),
+                               jnp.linalg.norm(x, axis=-1), rtol=1e-5)
+    # pair (0, 4) of position 1 turns by 1 radian
+    want = x[0, 1, 0, 0] * np.cos(1.0) - x[0, 1, 0, 4] * np.sin(1.0)
+    assert abs(float(y[0, 1, 0, 0]) - float(want)) < 1e-5
+    assert rel(y, R.rotary(x, 1e6)) < 1e-6
+
+
+# ---- the reference -------------------------------------------------------
+def test_the_reference_takes_what_run_py_hands_it():
+    """benchmark/run.py traces ``forward`` on float32 ids of shape
+    (1, S); the tier-1 tests and the cell read one file."""
+    lm, p = build("whole")
+    ids = tokens()
+    forward = jax.jit(lambda q, x: R.forward(q, x, **REF))
+    np.testing.assert_array_equal(forward(p, ids[:1].astype(np.float32)),
+                                  forward(p, ids[:1]))
+    assert not os.path.exists(os.path.join(REPO, "tpudl", "testing",
+                                           "lfm2_reference.py"))
+
+
+def test_reference_routes_argument_replaces_only_the_choice():
+    lm, p = build("conv+routed")
+    ids = tokens()
+    own = R.routes_of(p, ids, **REF)
+    assert len(own) == 1 and own[0].shape == (2, 16, 2)
+    assert float(R.loss(p, ids, own, **REF)) == float(R.loss(p, ids, **REF))
+    other = [(np.asarray(own[0]) + 1) % 8]
+    assert float(R.loss(p, ids, other, **REF)) != float(R.loss(p, ids, **REF))
+
+
+# ---- through the trainer --------------------------------------------------
+def test_one_adamw_step_through_trainer_fit_recovers_the_gradient():
+    """After one AdamW step from zero moments mu = (1 - b1) g: what the
+    cell's check() reads back from the timed path."""
+    lm, p = build("whole")
+    ids = tokens(shape=(2, 32))
+    loss = lm.loss_fn()
+    trainer = Trainer(loss, optax.adamw(3e-4, b1=0.9, b2=0.95,
+                                        weight_decay=0.1,
+                                        mask=lm.decay_mask))
+    p1, opt, history = trainer.fit(p, lambda step: (ids,), steps=1)
+    mu = [s for s in jax.tree.leaves(opt, is_leaf=lambda s: hasattr(s, "mu"))
+          if hasattr(s, "mu")][0].mu
+    want_loss, want = jax.jit(jax.value_and_grad(loss))(p, ids)
+    assert abs(history[-1]["loss"] - float(want_loss)) < 1e-5
+    for name in want:
+        if np.any(want[name]):
+            assert rel(np.asarray(mu[name]) / 0.1, want[name]) < 1e-5, name
+    # the buffer is constant: no gradient, no decay
+    np.testing.assert_array_equal(p1["layers.1.moe.expert_bias"],
+                                  p["layers.1.moe.expert_bias"])
+    assert np.any(np.asarray(p1["layers.1.moe.router"])
+                  != p["layers.1.moe.router"])
+
+
+def test_fit_consume_hands_the_state_over_without_a_copy():
+    from tpudl.train import HorovodRunner
+
+    lm, p = build("conv+dense")
+    ids = tokens()
+
+    def data(step):
+        return (ids,)
+
+    def main(ctx):
+        trainer = ctx.trainer(lm.loss_fn(), optax.sgd(0.1))
+        a, opt, _ = trainer.fit(p, data, steps=1)
+        kept, _, _ = trainer.fit(a, data, steps=2, opt_state=opt)
+        assert not a["embed"].is_deleted()      # the default owns a copy
+        b, _, _ = trainer.fit(a, data, steps=2, opt_state=opt, consume=True)
+        assert a["embed"].is_deleted()          # handed over and donated
+        for name in b:
+            np.testing.assert_array_equal(b[name], kept[name])
+        return True
+
+    assert HorovodRunner(np=1).run(main)
+
+
+# ---- what the routing did, and what was traced ----------------------------
+def test_route_stats_counts_pairs_and_publishes_counters():
+    from tpudl import obs
+
+    lm, p = build("whole")
+    ids = tokens()
+    before = obs.snapshot()
+    stats = lm.route_stats(p, ids)
+    after = obs.snapshot()
+    routes = [np.asarray(r) for r in lm.routes(p, ids)]
+    assert len(stats["layers"]) == 2
+    assert stats["pairs_total"] == 2 * ids.size * 2
+    held = sum(int(((r >= 2) & (r < 6)).sum()) for r in routes)
+    assert stats["pairs_held"] == held
+    assert stats["expert_tokens_max"] == max(
+        max(rec["expert_tokens"]) for rec in stats["layers"])
+    assert sum(stats["layers"][0]["expert_tokens"]) == stats["layers"][0][
+        "pairs_held"]
+
+    def moved(name):
+        return (after[name]["value"]
+                - before.get(name, {"value": 0})["value"])
+
+    assert moved("moe.pairs_held") == held
+    assert moved("moe.pairs_total") == stats["pairs_total"]
+    assert after["moe.expert_tokens_max"]["value"] == stats[
+        "expert_tokens_max"]
+    # bf16 arithmetic may flip a near-tie, never the totals
+    assert lm.route_stats(p, ids, jnp.bfloat16)["pairs_total"] == stats[
+        "pairs_total"]
+
+
+def test_layers_are_counted_by_kind_while_a_program_is_traced():
+    from tpudl import obs
+
+    lm, p = build("whole")
+    before = obs.snapshot()
+    jax.jit(lm.logits).lower(p, tokens())
+    after = obs.snapshot()
+    for kind, n in {"conv": 2, "attention": 1, "dense": 1,
+                    "routed": 2}.items():
+        name = f"zoo.lm.layers.{kind}"
+        assert (after[name]["value"]
+                - before.get(name, {"value": 0})["value"]) == n
+    assert lm.kinds() == {"conv": 2, "attention": 1, "dense": 1, "routed": 2}
+
+
+def test_config_errors_name_what_is_wrong():
+    with pytest.raises(ValueError, match="layer_types"):
+        Decoder({**BASE, "layer_types": ["mamba"]})
+    with pytest.raises(ValueError, match="experts_held"):
+        Decoder({**BASE, "layer_types": ["conv"], "num_dense_layers": 0,
+                 "experts_held": (6, 4)})
+    with pytest.raises(ValueError, match="num_hidden_layers"):
+        Decoder({**BASE, "layer_types": ["conv"], "num_hidden_layers": 2})
+    with pytest.raises(ValueError, match="conv_bias"):
+        Decoder({**BASE, "layer_types": ["conv"], "conv_bias": True})
+
+
+def test_published_parameter_count_of_the_chips_share():
+    """507.8 M at the published widths, counted from shapes alone."""
+    cfg = dict(hidden_size=2048, intermediate_size=7168,
+               moe_intermediate_size=1792, num_attention_heads=32,
+               num_key_value_heads=8, num_experts=32, num_experts_per_tok=4,
+               layer_types=["conv", "full_attention", "conv", "conv", "conv"],
+               num_dense_layers=1, experts_held=(0, 8), vocab_size=65536,
+               vocab_slice=(0, 16384))
+    small = Decoder({**cfg, "hidden_size": 32, "intermediate_size": 112,
+                     "moe_intermediate_size": 28, "num_attention_heads": 4,
+                     "num_key_value_heads": 1, "head_dim": 8,
+                     "vocab_slice": (0, 256)}).init(0)
+    scale = {32: 2048, 96: 6144, 112: 7168, 28: 1792, 256: 16384, 8: 64}
+    total = 0
+    for name, leaf in small.items():
+        shape = list(leaf.shape)
+        if ".moe.w" in name:
+            shape = [shape[0]] + [scale[d] for d in shape[1:]]
+        elif name.endswith(".router"):
+            shape = [scale[shape[0]], shape[1]]
+        elif name.endswith(("q_norm", "k_norm")):
+            shape = [64]
+        elif name.endswith(".kernel"):
+            shape = [3, scale[shape[1]]]
+        elif name.endswith("expert_bias"):
+            pass
+        elif name.endswith(".k_proj") or name.endswith(".v_proj"):
+            shape = [2048, 512]
+        else:
+            shape = [scale[d] for d in shape]
+        total += int(np.prod(shape))
+    assert abs(total - 507.82e6) < 0.01e6
+    assert Decoder(cfg).kinds() == {"conv": 4, "attention": 1, "dense": 1,
+                                    "routed": 4}
+
+
+# ---- the cell's check, on deliberate faults -------------------------------
+@pytest.fixture(scope="module")
+def lm_train():
+    import sys
+
+    sys.path.insert(0, REPO)
+    return _load(os.path.join(REPO, "benchmark", "adapters", "lm_train.py"),
+                 "benchmark_adapter_lm_train_for_tests")
+
+
+LIMITS = {"grad_rel_l2": {g: 0.08 for g in (
+    "experts", "routers", "conv", "attention", "dense_ff", "table",
+    "norms")}, "loss_rel": 0.002, "route_agreement_min": 0.9,
+    "update_rel_l2": 3e-4, "moment2_rel_l2": 1e-3}
+ADAMW = {"learning_rate": 3e-4, "b1": 0.9, "b2": 0.95, "weight_decay": 0.1}
+
+
+def _first_step(lm_train, lm, p, ids, optimizer=None):
+    """One AdamW step of the bf16 program with the routes tap through
+    Trainer.fit, as the cell's warm() takes it: the step's gradient
+    (mu / (1 - b1)), the routes read from the tap, the step's loss, and
+    the parameters and second moments after it."""
+    p = {**p, lm_train.TAP: np.zeros((lm.kinds()["routed"], *ids.shape,
+                                      lm.top_k), np.float32)}
+    trainer = Trainer(
+        with_compute_dtype(lm_train.tapped(lm.loss_fn(with_routes=True)),
+                           jnp.bfloat16),
+        optimizer or optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.1,
+                                 mask=lm.decay_mask))
+    p1, opt, history = trainer.fit(p, lambda step: (ids,), steps=1)
+    adam = lm_train.adam_state(opt)
+    got = {k: np.asarray(v) / np.float32(0.1) for k, v in adam.mu.items()}
+    routes = np.rint(got.pop(lm_train.TAP)).astype(np.int32)
+    update = lm_train.worst_leaf(jax.jit(
+        lambda *state: lm_train.update_errors(*state, ADAMW))(
+            p, p1, adam.mu, adam.nu))
+    return got, list(routes), history[-1]["loss"], update
+
+
+def test_the_routes_tap_reads_the_steps_own_choices(lm_train):
+    """The tap adds exactly 0 to the loss, whatever it holds, and its
+    gradient is the experts the same program selected."""
+    lm, p = build("whole")
+    ids = tokens(seed=7, shape=(2, 32))
+    plain = jax.jit(lm.loss_fn())(p, ids)
+    loss = jax.jit(lm_train.tapped(lm.loss_fn(with_routes=True)))
+    tap = np.random.default_rng(0).standard_normal(
+        (2, 2, 32, 2)).astype(np.float32)
+    assert float(loss({**p, lm_train.TAP: tap}, ids)) == float(plain)
+    grads = jax.jit(jax.grad(lm_train.tapped(lm.loss_fn(with_routes=True))))(
+        {**p, lm_train.TAP: tap}, ids)
+    want = jax.jit(lm.routes)(p, ids)
+    np.testing.assert_array_equal(grads[lm_train.TAP], np.stack(want))
+    # through the trainer, in bf16, out of AdamW's first moment
+    _, routes, _, _ = _first_step(lm_train, lm, p, ids)
+    bf16 = jax.jit(with_compute_dtype(lm.routes, jnp.bfloat16))(p, ids)
+    assert len(routes) == 2
+    for mine, theirs in zip(routes, bf16):
+        assert mine.shape == theirs.shape == (2, 32, 2)
+        assert (mine == np.asarray(theirs)).mean() > 0.95
+
+
+def _fp8_control():
+    return _load(os.path.join(REPO, "benchmark", "controls",
+                              "lm_fp8_experts.py"), "lm_fp8_experts_control")
+
+
+FAULTS = {   # fault -> what has to be over its limit
+    "none": set(),
+    "dropped_pair": {"experts", "routers"},
+    "no_renorm": {"experts", "routers"},
+    "fp8_product": {"experts"},
+    "lowest_k": {"route_agreement"},
+    "warm_up_schedule": {"update_rel_l2"},
+    "no_decay": {"update_rel_l2"},
+    "wrong_b2": {"moment2_rel_l2"},
+}
+
+
+@pytest.mark.parametrize("fault", list(FAULTS))
+def test_the_check_fails_on_each_fault(fault, lm_train, monkeypatch):
+    """compare_groups, update_errors and verdict, as the cell uses them, on the step program's own first step: clean bf16 passes;
+    a single dropped pair, a skipped renormalisation, a scaled fp8 product
+    in the experts, a selection the reference would not make, and an
+    optimizer other than the one named each put a reading outside its
+    limit."""
+    lm, p = build("whole")
+    ids = tokens(seed=7, shape=(2, 32))
+    optimizer = None
+    if fault == "dropped_pair":
+        real = moe.pair_order
+
+        def dropping(experts, held):
+            # the first held pair of the batch goes to no expert
+            first = jnp.argmax((experts >= held[0])
+                               & (experts < held[0] + held[1]))
+            return real(experts.at[first].set(held[0] + held[1]), held)
+
+        monkeypatch.setattr(moe, "pair_order", dropping)
+    elif fault == "no_renorm":
+        real = moe.route
+
+        def unnormalised(q, name, x, **kw):
+            experts, weights = real(q, name, x, **kw)
+            scores = jax.nn.sigmoid(jnp.dot(
+                x, q[name + ".router"], preferred_element_type=jnp.float32))
+            return experts, jnp.take_along_axis(scores, experts, axis=-1)
+
+        monkeypatch.setattr(moe, "route", unnormalised)
+    elif fault == "fp8_product":
+        fp8 = _fp8_control().fp8_ragged_dot
+        monkeypatch.setattr(
+            jax.lax, "ragged_dot",
+            lambda lhs, rhs, group_sizes, **kw: fp8(lhs, rhs, group_sizes))
+    elif fault == "lowest_k":
+        real = jax.lax.top_k
+        monkeypatch.setattr(jax.lax, "top_k",
+                            lambda x, k: real(-x, k))
+    elif fault == "warm_up_schedule":
+        optimizer = optax.adamw(optax.linear_schedule(0.0, 3e-4, 2000),
+                                b1=0.9, b2=0.95, weight_decay=0.1,
+                                mask=lm.decay_mask)
+    elif fault == "no_decay":
+        optimizer = optax.adamw(3e-4, b1=0.9, b2=0.95, weight_decay=0.0)
+    elif fault == "wrong_b2":
+        optimizer = optax.adamw(3e-4, b1=0.9, b2=0.999, weight_decay=0.1,
+                                mask=lm.decay_mask)
+    got, routes, loss, update = _first_step(lm_train, lm, p, ids, optimizer)
+    monkeypatch.undo()
+    want_loss, want = jax.jit(jax.value_and_grad(
+        lambda q: R.loss(q, ids, routes, **REF)))(p)
+    own = jax.jit(lambda q: R.routes_of(q, ids, **REF))(p)
+    agreement = float(np.mean([
+        (mine[..., :, None] == np.asarray(theirs)[..., None, :]).any(-1)
+        for mine, theirs in zip(routes, own)]))
+    readings = {"grad_rel_l2": lm_train.compare_groups(got, want),
+                "loss_rel": abs(loss - float(want_loss)) / float(want_loss),
+                "route_agreement": agreement,
+                **update,
+                "loss_first": loss, "loss_again": loss - 1.0}
+    assert set(readings["grad_rel_l2"]) == set(LIMITS["grad_rel_l2"])
+    over = lm_train.verdict(readings, LIMITS)
+    print(fault, {k: v for k, v in readings.items() if k != "grad_rel_l2"},
+          {k: round(v, 4) for k, v in readings["grad_rel_l2"].items()})
+    if fault == "none":
+        assert over == {}, readings
+        assert "loss_again" in lm_train.verdict(
+            {**readings, "loss_again": loss}, LIMITS)   # an unchanged state
+    else:
+        assert set(over) & FAULTS[fault], (fault, over)
+
+
+def test_gradient_groups_cover_every_leaf(lm_train):
+    lm, p = build("whole")
+    kinds = {lm_train.group_of(name) for name in p}
+    assert kinds == set(LIMITS["grad_rel_l2"])
+    with pytest.raises(KeyError):
+        lm_train.group_of("layers.0.unknown.leaf")
+    assert lm_train.decoder_config(
+        {"num_experts": 8, "vocab_size": 16, "hidden_size": 4,
+         "published": {"num_experts": 32, "vocab_size": 64}}) == {
+             "num_experts": 32, "vocab_size": 64, "hidden_size": 4}

@@ -739,3 +739,159 @@ class TestFitSpans:
         (fit,) = T.summarize_merged(spans, {})["fits"]
         assert fit["compiled_in_steps"].count(0) >= 1
         assert all(s == 0 or s is None for s in fit["compiled_in_steps"])
+
+
+# ---- device time by named scope (PR 28) -----------------------------------
+def _write_scoped_xplane(trace_dir, name="scoped.xplane.pb"):
+    """One TPU plane whose operations carry their ``op_name`` where the
+    profiler puts it: the ``tf_op`` statistic of the EVENT METADATA, as a
+    string or as a reference into the statistics' own names. Two runs of
+    jit_step; a loop and an operation of its body overlap."""
+    import jax
+
+    ops = {
+        1: ("jit_step(9)", None),
+        2: ("%fusion.1", "jit(step)/jvp(lm.conv_op)/dot_general:"),
+        3: ("%ragged.2", "ragged-dot-none:"),
+        4: ("%fusion.3", "jit(step)/transpose(jvp(jvp()))/checkpoint/"
+                         "rematted_computation/moe.route/gather:"),
+        5: ("%while.4", "jit(step)/transpose(jvp(lm.head))/while"),
+        6: ("%fusion.5", "jit(step)/transpose(jvp(lm.head))/while/body/"
+                         "dot_general:"),
+        7: ("%add.6", "jit(step)/add:"),
+        8: ("%copy.7", None),
+    }
+    meta = ""
+    for key, (op, tf_op) in ops.items():
+        stat = ""
+        if tf_op is not None and key != 4:
+            stat = f'stats {{ metadata_id: 3 str_value: "{tf_op}" }}'
+        elif tf_op is not None:       # by reference
+            stat = "stats { metadata_id: 3 ref_value: 4 }"
+        meta += (f'event_metadata {{ key: {key} value {{ id: {key} '
+                 f'name: "{op}" {stat} }} }}\n')
+    meta += ('stat_metadata { key: 3 value { id: 3 name: "tf_op" } }\n'
+             f'stat_metadata {{ key: 4 value {{ id: 4 name: "{ops[4][1]}" '
+             '} }\n')
+
+    def line(title, events):
+        body = "".join(
+            f"events {{ metadata_id: {m} offset_ps: {s * 1000} "
+            f"duration_ps: {d * 1000} }}\n" for m, s, d in events)
+        return f'lines {{ name: "{title}" timestamp_ns: 0\n{body}}}\n'
+
+    modules = [(1, 1_000, 1_000), (1, 3_000, 1_000)]
+    # run 1: conv 100 + ragged 200 + route 50 + loop [1500, 1900) whose
+    # body op [1600, 1800) overlaps it + add 20 + an unnamed copy 10
+    run = [(2, 0, 100), (3, 100, 200), (4, 300, 50), (5, 500, 400),
+           (6, 600, 200), (7, 900, 20), (8, 950, 10)]
+    events = [(m, 1_000 + s, d) for m, s, d in run]
+    events += [(m, 3_000 + s, 2 * d if m == 3 else d) for m, s, d in run]
+    events.append((2, 5_000, 999))          # after the last run: dropped
+    text = ('planes { name: "/device:TPU:0"\n' + line("XLA Modules", modules)
+            + line("XLA Ops", events) + meta + "}\n"
+            'planes { name: "/host:CPU" }\n'
+            'planes { name: "Task Environment"\n'
+            f"stats {{ metadata_id: 1 uint64_value: {START_NS} }}\n"
+            f"stats {{ metadata_id: 2 uint64_value: {STOP_NS} }}\n"
+            'stat_metadata { key: 1 value { id: 1 '
+            'name: "profile_start_time" } }\n'
+            'stat_metadata { key: 2 value { id: 2 '
+            'name: "profile_stop_time" } }\n}\n')
+    os.makedirs(trace_dir, exist_ok=True)
+    path = os.path.join(trace_dir, name)
+    with open(path, "wb") as f:
+        f.write(jax.profiler.ProfileData.text_proto_to_serialized_xspace(
+            text))
+    return path
+
+
+SCOPES = ("lm.conv_op", "moe.route", "moe.experts", "lm.head", "lm.attention")
+
+
+class TestDeviceScopes:
+    @pytest.mark.parametrize("op_name, parts", [
+        ("jit(step)/jvp(lm.conv_op)/dot_general:",
+         ["step", "lm.conv_op", "dot_general:"]),
+        ("jit(step)/transpose(jvp(moe.experts))/mul",
+         ["step", "moe.experts", "mul"]),
+        ("jit(step)/transpose(jvp(jvp()))/checkpoint/rematted_computation/"
+         "lm.attention/jit(flash_attention)/pallas_call:",
+         ["step", "", "checkpoint", "rematted_computation", "lm.attention",
+          "flash_attention", "pallas_call:"]),
+        ("dot_general[dims=((1,), (0,))]", ["dot_general[dims=((1,), (0,))]"]),
+        ("", [""])])
+    def test_scope_parts_strip_transformations(self, op_name, parts):
+        assert T._scope_parts(op_name) == parts
+
+    def test_operations_are_filed_by_scope_from_event_metadata(self,
+                                                               tmp_path):
+        _write_scoped_xplane(str(tmp_path))
+        loaded = T.load_device_op_scopes(
+            str(tmp_path), SCOPES, kernels={"ragged-dot": "moe.experts"})
+        assert [m[0] for m in loaded["modules"]] == ["jit_step(9)"] * 2
+        first = [scope for scope, s, _ in loaded["ops"] if s < 3_000]
+        assert first == ["lm.conv_op", "moe.experts", "moe.route",
+                         "lm.head", "lm.head", None, None]
+        # without the kernel's name the grouped product is nobody's
+        bare = T.load_device_op_scopes(str(tmp_path), SCOPES)
+        assert [scope for scope, s, _ in bare["ops"] if s < 3_000][1] is None
+
+    def test_scope_ns_by_run_takes_the_union_inside_each_run(self, tmp_path):
+        _write_scoped_xplane(str(tmp_path))
+        loaded = T.load_device_op_scopes(
+            str(tmp_path), SCOPES, kernels={"ragged-dot": "moe.experts"})
+        runs = T.scope_ns_by_run(loaded["modules"], loaded["ops"],
+                                 "jit_step")
+        assert [(r["start_ns"], r["dur_ns"]) for r in runs] == [
+            (1_000, 1_000), (3_000, 1_000)]
+        # the loop [500, 900) and its body [600, 800) count once
+        assert runs[0]["scopes"] == {"lm.conv_op": 100, "moe.experts": 200,
+                                     "moe.route": 50, "lm.head": 400,
+                                     None: 30}
+        assert runs[1]["scopes"]["moe.experts"] == 400
+        assert T.scope_ns_by_run(loaded["modules"], loaded["ops"],
+                                 "jit_other") == []
+        # an unscoped loop around scoped bodies (a scanned run of layers)
+        # is charged only what its bodies leave uncovered
+        scanned = T.scope_ns_by_run(
+            [("jit_step(1)", 0, 1_000)],
+            [(None, 0, 900), ("moe.experts", 100, 300),
+             ("moe.route", 400, 100), (None, 950, 20)], "jit_step")
+        assert scanned[0]["scopes"] == {"moe.experts": 300, "moe.route": 100,
+                                        None: 520}
+
+    def test_record_device_scopes_writes_one_span_a_run_and_scope(
+            self, tmp_path):
+        _write_scoped_xplane(str(tmp_path))
+        tracer = T_tracer()
+        fit = tracer.record("train.fit", START_NS, 10_000, steps=2)
+        runs = T.record_device_scopes(
+            str(tmp_path), "jit_step", SCOPES, parent=fit,
+            kernels={"ragged-dot": "moe.experts"})
+        assert len(runs) == 2
+        mine = [s for s in tracer.spans() if s.parent == fit.id]
+        assert len(mine) == 2 * len(SCOPES)
+        experts = [s for s in mine if s.name == "device.moe.experts"]
+        assert [s.dur_ns for s in experts] == [200, 400]
+        assert [s.start_ns for s in experts] == [START_NS + 1_000,
+                                                 START_NS + 3_000]
+        assert [s.attrs["run"] for s in experts] == [0, 1]
+        # a scope the trace never saw still gets its span, of no length
+        assert [s.dur_ns for s in mine
+                if s.name == "device.lm.attention"] == [0, 0]
+
+    def test_no_device_plane_records_nothing(self, tmp_path):
+        _write_xplane(str(tmp_path), modules=None)
+        assert T.load_device_op_scopes(str(tmp_path), SCOPES) == {}
+        assert T.record_device_scopes(str(tmp_path), "jit_step",
+                                      SCOPES) == []
+        with pytest.raises(FileNotFoundError):
+            T.load_device_op_scopes(str(tmp_path / "none"), SCOPES)
+
+
+def T_tracer():
+    """The process-wide tracer record_device_scopes writes to."""
+    from tpudl.obs import get_tracer
+
+    return get_tracer()

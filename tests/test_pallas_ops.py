@@ -154,3 +154,75 @@ class TestReviewRegressions:
         got = np.asarray(ring_attention(qs, ks, vs, mesh, causal=True,
                                         use_pallas=True))
         np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+class TestGroupedQueries:
+    """32-over-8-style heads at toy size: K/V heads are shared in place by
+    the kernels' index maps, dk/dv summed over each group in float32."""
+
+    @pytest.fixture(scope="class")
+    def gqa(self, rng):
+        q = rng.normal(size=(2, 48, 8, 16)).astype(np.float32)
+        k, v = (rng.normal(size=(2, 48, 2, 16)).astype(np.float32)
+                for _ in range(2))
+        return q, k, v
+
+    @staticmethod
+    def _oracle(q, k, v, causal):
+        group = q.shape[2] // k.shape[2]
+        return attention_reference(q, jnp.repeat(k, group, axis=2),
+                                   jnp.repeat(v, group, axis=2),
+                                   causal=causal)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_forward_matches_repeated_heads(self, gqa, causal):
+        q, k, v = (jnp.asarray(a) for a in gqa)
+        got = flash_attention(q, k, v, causal=causal, block_q=16,
+                              block_k=16, interpret=True)
+        np.testing.assert_allclose(got, self._oracle(q, k, v, causal),
+                                   rtol=2e-6, atol=2e-6)
+
+    @pytest.mark.parametrize("blocks", [(16, 16), (48, 16), (16, 48)])
+    def test_backward_sums_each_group(self, gqa, blocks):
+        q, k, v = (jnp.asarray(a) for a in gqa)
+        w = jnp.cos(jnp.arange(q.size, dtype=jnp.float32)).reshape(q.shape)
+
+        def loss(fn):
+            return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+        got = jax.grad(loss(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=blocks[0], block_k=blocks[1],
+            interpret=True)), (0, 1, 2))(q, k, v)
+        want = jax.grad(loss(lambda q, k, v: self._oracle(
+            q, k, v, True)), (0, 1, 2))(q, k, v)
+        for g, w_ in zip(got, want):
+            assert g.shape == w_.shape
+            np.testing.assert_allclose(g, w_, rtol=2e-5, atol=2e-5)
+
+    def test_padded_length_and_lse(self, gqa):
+        q, k, v = (jnp.asarray(a[:, :37]) for a in gqa)
+        out, lse = flash_attention(q, k, v, causal=True, block_q=16,
+                                   block_k=16, interpret=True,
+                                   return_lse=True)
+        np.testing.assert_allclose(out, self._oracle(q, k, v, True),
+                                   rtol=2e-6, atol=2e-6)
+        assert lse.shape == (2, 37, 8)
+
+    def test_bfloat16_operands_stay_bfloat16_on_the_mxu(self, gqa):
+        """bf16 inputs are multiplied as bf16 with float32 accumulation
+        (one MXU pass), not upcast: within bf16's rounding of the oracle."""
+        q, k, v = (jnp.asarray(a, jnp.bfloat16) for a in gqa)
+        got = flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
+                              interpret=True)
+        assert got.dtype == jnp.bfloat16
+        want = self._oracle(*(a.astype(jnp.float32) for a in (q, k, v)),
+                            True)
+        err = jnp.linalg.norm(got.astype(jnp.float32) - want)
+        assert float(err / jnp.linalg.norm(want)) < 2e-2
+
+    def test_heads_must_divide(self, gqa):
+        q, k, v = (jnp.asarray(a) for a in gqa)
+        with pytest.raises(ValueError, match="query heads"):
+            flash_attention(q[:, :, :7], k, v, interpret=True)
+        with pytest.raises(ValueError, match="query heads"):
+            flash_attention(q, k, v[:, :, :1], interpret=True)

@@ -1,0 +1,74 @@
+"""The main path's kernels compiled for a described v5e chip, at the
+published widths: what interpret mode cannot show (tile alignment, VMEM,
+Mosaic's own refusals). Nothing runs and no chip is attached; the topology
+is described inside a fixture, so collection is the same in every worker,
+and all such compiles live in this one file (the on-chip-measurement
+guide, section 2)."""
+
+import os
+
+import pytest
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or it is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep it out."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("seq, blocks", [(8192, (512, 512)),
+                                         (2047, (128, 128))])
+def test_flash_attention_with_grouped_queries_compiles_for_the_v5e(
+        one_chip, no_compile_cache, seq, blocks):
+    """LFM2-8B-A1B's attention: 32 query heads over 8 key/value heads of
+    64, bfloat16, forward and both backward kernels, at 8,192 tokens in
+    512 x 512 tiles and at an awkward length (PR 21's S = 2,047)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpudl.pallas_ops import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, block_q=blocks[0],
+                              block_k=blocks[1], interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    q = jax.ShapeDtypeStruct((1, seq, 32, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, seq, 8, 64), jnp.bfloat16,
+                              sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
+        q, kv, kv).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3      # forward, dq, dk/dv
+    grads = compiled.output_shardings  # compiled: shapes came through
+    assert grads is not None

@@ -64,6 +64,7 @@ _LAZY = {
     "shard_sequence": "tpudl.attention",
     "flash_attention": "tpudl.pallas_ops",
     "TinyCausalLM": "tpudl.zoo.transformer",
+    "Decoder": "tpudl.zoo.decoder",
 }
 
 __all__ = ["__version__", *_LAZY]

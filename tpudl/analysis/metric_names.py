@@ -198,6 +198,23 @@ METRICS: tuple[Metric, ...] = (
     Metric("zoo.conv_bn.unfolded", "counter",
            "conv + batch-norm pairs traced as written, on batch "
            "statistics (Store(train=True)): nothing can be folded"),
+    Metric("zoo.lm.layers.*", "counter",
+           "decoder layers traced, by kind (conv / attention operators, "
+           "dense / routed feed-forwards): what a config-driven Decoder "
+           "program is made of (4 / 1 / 1 / 4 per trace of "
+           "lfm2-8b-a1b-ep4)"),
+    # -- routed experts (published by Decoder.route_stats, outside steps)
+    Metric("moe.pairs_held", "counter",
+           "(token, expert) pairs routed to experts this rank holds, "
+           "summed over routed layers: what the grouped products work on"),
+    Metric("moe.pairs_total", "counter",
+           "pairs routed in all (tokens x experts per token x routed "
+           "layers)"),
+    Metric("moe.expert_tokens_max", "gauge",
+           "tokens of the fullest held expert in the last route_stats "
+           "batch (nothing is dropped: the skew, not an overflow)"),
+    Metric("moe.expert_tokens_mean", "gauge",
+           "mean tokens per held expert in the last route_stats batch"),
     # -- jobs / retries ------------------------------------------------
     Metric("retry.attempts", "counter",
            "retry attempts across all RetryPolicy call sites"),

@@ -26,7 +26,9 @@ device lanes alone.
 
 from __future__ import annotations
 
+import bisect
 import contextlib
+import functools
 import glob
 import gzip
 import json
@@ -35,13 +37,14 @@ import re
 import statistics
 from collections import Counter
 
-from tpudl.obs.tracer import Span, children
+from tpudl.obs.tracer import Span, children, get_tracer
 
 __all__ = ["profile", "named_scope", "load_trace_events",
            "summarize_device_trace", "load_host_trace_events",
            "find_trace_files", "load_device_planes", "profile_window",
            "load_host_spans", "align", "attribute_idle", "queue_lead",
-           "traced_fit", "merge_trace_events", "summarize_merged"]
+           "traced_fit", "merge_trace_events", "summarize_merged",
+           "load_device_op_scopes", "scope_ns_by_run", "record_device_scopes"]
 
 HOST_PID = 0  # merged-trace pid for the host lane (device pids count up)
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -407,6 +410,187 @@ def traced_fit(spans, n_steps: int | None = None) -> dict | None:
                 "dispatch_ns": statistics.median(dispatch),
                 "start_ns": steps[0].start_ns - fit.start_ns}
     return None
+
+
+def _scope_parts(op_name: str) -> list[str]:
+    """The ``jax.named_scope`` parts of an operation's ``op_name``
+    (``jit(step)/jit(main)/transpose(jvp(moe.experts))/ragged_dot`` ->
+    ``[..., "moe.experts", ...]``): transformations wrap a scope's name
+    in ``jvp(...)``, ``transpose(...)``, ``checkpoint``/``rematted`` and
+    the like, so every part is stripped to its innermost name."""
+    parts = []
+    for part in op_name.split("/"):
+        while "(" in part and part.endswith(")"):
+            part = part[part.index("(") + 1:-1]
+        parts.append(part)
+    return parts
+
+
+@functools.lru_cache(maxsize=1)
+def _xspace_subset():
+    """A message class for the part of ``xplane.proto`` that carries an
+    operation's ``op_name``: the profiler files it (as ``tf_op``) under
+    the EVENT METADATA's statistics, which ``jax.profiler.ProfileData``
+    does not expose. Fields are declared by the numbers of the published
+    schema; everything else in the file is skipped as unknown."""
+    from google.protobuf import (descriptor_pb2, descriptor_pool,
+                                 message_factory)
+
+    F = descriptor_pb2.FieldDescriptorProto
+    pkg = "tpudl.xplane_subset"
+    file = descriptor_pb2.FileDescriptorProto(
+        name="tpudl_xplane_subset.proto", package=pkg, syntax="proto3")
+
+    def message(name, *fields, parent=None):
+        m = (parent.nested_type if parent is not None
+             else file.message_type).add(name=name)
+        for fname, number, ftype, repeated, type_name in fields:
+            m.field.add(name=fname, number=number, type=ftype,
+                        label=(F.LABEL_REPEATED if repeated
+                               else F.LABEL_OPTIONAL),
+                        type_name=type_name and f".{pkg}.{type_name}")
+        return m
+
+    message("XStat", ("metadata_id", 1, F.TYPE_INT64, False, None),
+            ("str_value", 5, F.TYPE_STRING, False, None),
+            ("ref_value", 7, F.TYPE_UINT64, False, None))
+    message("XEventMetadata", ("name", 2, F.TYPE_STRING, False, None),
+            ("stats", 5, F.TYPE_MESSAGE, True, "XStat"))
+    message("XStatMetadata", ("name", 2, F.TYPE_STRING, False, None))
+    message("XEvent", ("metadata_id", 1, F.TYPE_INT64, False, None),
+            ("offset_ps", 2, F.TYPE_INT64, False, None),
+            ("duration_ps", 3, F.TYPE_INT64, False, None))
+    message("XLine", ("name", 2, F.TYPE_STRING, False, None),
+            ("timestamp_ns", 3, F.TYPE_INT64, False, None),
+            ("events", 4, F.TYPE_MESSAGE, True, "XEvent"))
+    plane = message(
+        "XPlane", ("name", 2, F.TYPE_STRING, False, None),
+        ("lines", 3, F.TYPE_MESSAGE, True, "XLine"),
+        ("event_metadata", 4, F.TYPE_MESSAGE, True,
+         "XPlane.EventMetadataEntry"),
+        ("stat_metadata", 5, F.TYPE_MESSAGE, True,
+         "XPlane.StatMetadataEntry"))
+    for entry, value in (("EventMetadataEntry", "XEventMetadata"),
+                         ("StatMetadataEntry", "XStatMetadata")):
+        message(entry, ("key", 1, F.TYPE_INT64, False, None),
+                ("value", 2, F.TYPE_MESSAGE, False, value),
+                parent=plane).options.map_entry = True
+    message("XSpace", ("planes", 1, F.TYPE_MESSAGE, True, "XPlane"))
+    pool = descriptor_pool.DescriptorPool()
+    pool.Add(file)
+    return message_factory.GetMessageClass(
+        pool.FindMessageTypeByName(f"{pkg}.XSpace"))
+
+
+def load_device_op_scopes(trace_dir: str, scopes, kernels=None) -> dict:
+    """The first chip's plane of the newest trace under ``trace_dir``
+    with every operation filed under one of ``scopes`` (named scopes the
+    program was traced with): ``{"modules": [(name, start_ns, dur_ns)],
+    "ops": [(scope | None, start_ns, dur_ns)]}``, times in nanoseconds
+    since the session's start. An operation's scope is the outermost of
+    ``scopes`` on its ``op_name`` path (the ``tf_op`` statistic of its
+    event metadata). ``kernels`` maps the start of an ``op_name`` to a
+    scope, for kernels the compiler itself puts in and names (XLA's
+    grouped matrix product is ``ragged-dot-…``, under no scope of the
+    program). ``{}`` without a device plane or without protobuf."""
+    path = _newest(trace_dir, "*.xplane.pb")
+    if path is None:
+        raise FileNotFoundError(f"no xplane.pb under {trace_dir}")
+    try:
+        space = _xspace_subset()()
+    except ImportError:
+        return {}
+    with open(path, "rb") as f:
+        space.ParseFromString(f.read())
+    planes = [pl for pl in space.planes if DEVICE_PLANE.match(pl.name)]
+    if not planes:
+        return {}
+    plane = min(planes, key=lambda pl: pl.name)
+    wanted, kernels = set(scopes), dict(kernels or {})
+    stat_names = {k: v.name for k, v in plane.stat_metadata.items()}
+
+    def scope_of(meta):
+        for stat in meta.stats:
+            if stat_names.get(stat.metadata_id) != "tf_op":
+                continue
+            op_name = stat.str_value or stat_names.get(stat.ref_value, "")
+            for part in _scope_parts(op_name):
+                if part in wanted:
+                    return part
+            return next((scope for head, scope in kernels.items()
+                         if op_name.startswith(head)), None)
+        return None
+
+    names = {k: m.name for k, m in plane.event_metadata.items()}
+    by_id = {k: scope_of(m) for k, m in plane.event_metadata.items()}
+    out = {"modules": [], "ops": []}
+    for line in plane.lines:
+        if line.name not in (MODULES, OPS):
+            continue
+        base = line.timestamp_ns * 1000
+        for e in line.events:
+            start, dur = (base + e.offset_ps) // 1000, e.duration_ps // 1000
+            if line.name == MODULES:
+                out["modules"].append((names.get(e.metadata_id, ""),
+                                       start, dur))
+            else:
+                out["ops"].append((by_id.get(e.metadata_id), start, dur))
+    return out
+
+
+def scope_ns_by_run(modules, ops, program: str) -> list[dict]:
+    """Per run of ``program`` (its events on ``XLA Modules``, in order),
+    ``{"start_ns", "dur_ns", "scopes": {scope: device ns}}`` of the
+    operations that started inside the run: per scope the UNION of their
+    intervals, because the ``XLA Ops`` line carries a loop and the
+    operations of its body as events that overlap. ``None`` is the time
+    of operations filed under no scope that no scoped operation covers:
+    a scanned run of layers is one unscoped loop around scoped bodies."""
+    runs = sorted((s, s + d) for name, s, d in modules
+                  if _program(name) == program)
+    starts = [s for s, _ in runs]
+    inside: list[dict] = [{} for _ in runs]
+    for scope, s, d in ops:
+        i = bisect.bisect_right(starts, s) - 1
+        if i >= 0 and s < runs[i][1]:
+            inside[i].setdefault(scope, []).append((s, s + d))
+    out = []
+    for (s, e), found in zip(runs, inside):
+        scopes = {scope: _union(iv) for scope, iv in found.items()}
+        if None in found:
+            scopes[None] -= _intersection(found[None], [
+                iv for scope, ivs in found.items() if scope is not None
+                for iv in ivs])
+        out.append({"start_ns": s, "dur_ns": e - s, "scopes": scopes})
+    return out
+
+
+def record_device_scopes(trace_dir: str, program: str, scopes,
+                         parent: Span | None = None,
+                         kernels=None) -> list[dict]:
+    """Put what a device trace says of the program's named scopes into
+    the span ring: one ``device.<scope>`` span per run of ``program``
+    and scope, its duration the device time of that scope's operations
+    in that run, its start the run's start on the epoch clock
+    (:func:`profile_window`), children of ``parent`` (the traced
+    ``train.fit`` span). Readers then find device time by scope where
+    they find the host loop's. Returns :func:`scope_ns_by_run`'s list;
+    ``[]`` without a device plane."""
+    loaded = load_device_op_scopes(trace_dir, scopes, kernels)
+    if not loaded:
+        return []
+    try:
+        epoch = profile_window(trace_dir)[0]
+    except ValueError:
+        epoch = 0
+    runs = scope_ns_by_run(loaded["modules"], loaded["ops"], program)
+    tracer = get_tracer()
+    for i, run in enumerate(runs):
+        for scope in scopes:
+            tracer.record(f"device.{scope}", epoch + run["start_ns"],
+                          run["scopes"].get(scope, 0), parent=parent,
+                          run=i)
+    return runs
 
 
 def merge_trace_events(spans, planes: dict) -> list[dict]:

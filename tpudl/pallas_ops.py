@@ -55,8 +55,9 @@ def _masked_scores(q_ref, k_ref, qoff_ref, koff_ref, iq, ik, *, causal,
     definition of the score tile shared by forward, dq and dkv kernels.
     ``kv_len`` (static; None when the keys were not padded) masks the
     pad keys past the real sequence end by their LOCAL index."""
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
+    # operands go to the MXU in their own dtype (bf16 stays one pass);
+    # the product is accumulated, scaled and masked in float32
+    q, k = q_ref[0], k_ref[0]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
                 precision=precision) * scale
     if causal or kv_len is not None:
@@ -78,6 +79,13 @@ def _bwd_p(s, lse):
     is unsupported in Mosaic for non-32-bit types)."""
     alive = (lse > _NEG_INF * 0.5).astype(jnp.float32)[:, None]
     return jnp.exp(s - lse[:, None]) * alive
+
+
+def _bwd_ds(p, do, v, dlt, scale, precision):
+    """The shared score gradient ``p ⊙ (dO Vᵀ − δ + dlse) · scale``."""
+    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32,
+                 precision=precision)
+    return p * (dp - dlt[:, None]) * scale
 
 
 def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -113,8 +121,9 @@ def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         # finalize reports the row as fully masked, not mean(V)
         p = jnp.where((m_new <= _NEG_INF * 0.5)[:, None], 0.0, p)
         l_new = l_scr[:, 0] * corr + p.sum(axis=1)
+        v = v_ref[0]
         acc_scr[:] = (acc_scr[:] * corr[:, None]
-                      + jnp.dot(p, v_ref[0].astype(jnp.float32),
+                      + jnp.dot(p.astype(v.dtype), v,
                                 preferred_element_type=jnp.float32,
                                 precision=precision))
         m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
@@ -155,12 +164,10 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             scale=scale, block_q=block_q, block_k=block_k,
             kv_len=kv_len, precision=precision)
         p = _bwd_p(s, lse_ref[0, 0])
-        do = do_ref[0].astype(jnp.float32)
-        dp = jnp.dot(do, v_ref[0].astype(jnp.float32).T,
-                     preferred_element_type=jnp.float32,
-                     precision=precision)
-        ds = p * (dp - dlt_ref[0, 0][:, None]) * scale
-        dq_scr[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32,
+        ds = _bwd_ds(p, do_ref[0], v_ref[0], dlt_ref[0, 0], scale,
+                     precision)
+        dq_scr[:] += jnp.dot(ds.astype(k.dtype), k,
+                             preferred_element_type=jnp.float32,
                              precision=precision)
 
     @pl.when(ik == nk - 1)
@@ -171,12 +178,16 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, dlt_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                     causal: bool, scale: float, block_q: int,
-                    block_k: int, kv_len, precision):
+                    block_k: int, kv_len, precision, q_tiles: int):
     """dk = Σ_q (p ⊙ (dOVᵀ − δ + dlse) · scale)ᵀ @ Q ; dv = Σ_q pᵀ @ dO —
-    grid over K tiles with the Q-tile dim innermost."""
-    ik, iq, nq = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    grid over K tiles with the Q-tile dim innermost. Under grouped
+    queries the innermost dim runs over every query head of this K/V
+    head's group in turn (``q_tiles`` tiles each), so the group's sum
+    is accumulated in float32 in the same scratch."""
+    ik, j, nj = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    iq = j % q_tiles
 
-    @pl.when(iq == 0)
+    @pl.when(j == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -190,17 +201,16 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             scale=scale, block_q=block_q, block_k=block_k,
             kv_len=kv_len, precision=precision)
         p = _bwd_p(s, lse_ref[0, 0])
-        do = do_ref[0].astype(jnp.float32)
-        dv_scr[:] += jnp.dot(p.T, do, preferred_element_type=jnp.float32,
+        do = do_ref[0]
+        dv_scr[:] += jnp.dot(p.T.astype(do.dtype), do,
+                             preferred_element_type=jnp.float32,
                              precision=precision)
-        dp = jnp.dot(do, v_ref[0].astype(jnp.float32).T,
-                     preferred_element_type=jnp.float32,
-                     precision=precision)
-        ds = p * (dp - dlt_ref[0, 0][:, None]) * scale
-        dk_scr[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32,
+        ds = _bwd_ds(p, do, v_ref[0], dlt_ref[0, 0], scale, precision)
+        dk_scr[:] += jnp.dot(ds.T.astype(q.dtype), q,
+                             preferred_element_type=jnp.float32,
                              precision=precision)
 
-    @pl.when(iq == nq - 1)
+    @pl.when(j == nj - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -208,13 +218,14 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _pallas_flash_bwd(qh, kh, vh, out, lse, qoff, koff, do, dlse, *,
                       causal, block_q, block_k, kv_len, interpret,
-                      precision):
+                      precision, group=1):
     """Tiled flash backward: (dq, dk, dv) without any S² tensor.
 
     The lse cotangent folds in analytically: ∂lse_i/∂s_ij = p_ij, so the
     shared score gradient is ds = p ⊙ (dOVᵀ − δ + dlse) with
     δ = rowsum(dO ⊙ O) − the δ and dlse terms combine into one per-row
-    constant fed to both kernels."""
+    constant fed to both kernels. ``group`` query rows share each K/V
+    row (row ``bh`` reads K/V row ``bh // group``)."""
     bh_n, s_q, d = qh.shape
     s_k = kh.shape[1]
     scale = 1.0 / (d ** 0.5)
@@ -233,7 +244,7 @@ def _pallas_flash_bwd(qh, kh, vh, out, lse, qoff, koff, do, dlse, *,
         return (bh, iq, 0)
 
     def qi_k(bh, iq, ik):
-        return (bh, ik, 0)
+        return (bh // group, ik, 0)
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kernel_kw),
@@ -254,16 +265,22 @@ def _pallas_flash_bwd(qh, kh, vh, out, lse, qoff, koff, do, dlse, *,
         interpret=interpret,
     )(qoff, koff, qh, kh, vh, do, lse8, dlt8)
 
-    # dk/dv: grid (BH, Sk/TK, Sq/TQ) — k tile fixed per row, Q innermost
-    def ki_k(bh, ik, iq):
-        return (bh, ik, 0)
+    # dk/dv: grid (B·Hkv, Sk/TK, group · Sq/TQ) — k tile fixed per row,
+    # the group's query heads and their Q tiles innermost
+    q_tiles = s_q // block_q
 
-    def ki_q(bh, ik, iq):
-        return (bh, iq, 0)
+    def ki_k(bkv, ik, j):
+        return (bkv, ik, 0)
+
+    def ki_q(bkv, ik, j):
+        return (bkv * group + j // q_tiles, j % q_tiles, 0)
+
+    def ki_row(bkv, ik, j):
+        return (bkv * group + j // q_tiles, 0, j % q_tiles)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kernel_kw),
-        grid=(bh_n, s_k // block_k, s_q // block_q),
+        functools.partial(_bwd_dkv_kernel, q_tiles=q_tiles, **kernel_kw),
+        grid=(bh_n // group, s_k // block_k, group * q_tiles),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -271,16 +288,16 @@ def _pallas_flash_bwd(qh, kh, vh, out, lse, qoff, koff, do, dlse, *,
             pl.BlockSpec((1, block_k, d), ki_k),
             pl.BlockSpec((1, block_k, d), ki_k),
             pl.BlockSpec((1, block_q, d), ki_q),
-            pl.BlockSpec((1, 8, block_q), lambda bh, ik, iq: (bh, 0, iq)),
-            pl.BlockSpec((1, 8, block_q), lambda bh, ik, iq: (bh, 0, iq)),
+            pl.BlockSpec((1, 8, block_q), ki_row),
+            pl.BlockSpec((1, 8, block_q), ki_row),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), ki_k),
             pl.BlockSpec((1, block_k, d), ki_k),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh_n, s_k, d), kh.dtype),
-            jax.ShapeDtypeStruct((bh_n, s_k, d), vh.dtype),
+            jax.ShapeDtypeStruct(kh.shape, kh.dtype),
+            jax.ShapeDtypeStruct(vh.shape, vh.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -293,7 +310,7 @@ def _pallas_flash_bwd(qh, kh, vh, out, lse, qoff, koff, do, dlse, *,
 
 @functools.lru_cache(maxsize=32)
 def _flash_fn(causal: bool, block_q: int, block_k: int, kv_len,
-              interpret: bool, precision):
+              interpret: bool, precision, group: int = 1):
     """One custom-VJP'd head-major flash fn per static config: forward
     AND backward are Pallas kernels (pallas_call has no generic
     autodiff), so neither direction materializes an S² tensor."""
@@ -302,7 +319,7 @@ def _flash_fn(causal: bool, block_q: int, block_k: int, kv_len,
         return _pallas_flash_bh(qh, kh, vh, qoff, koff, causal=causal,
                                 block_q=block_q, block_k=block_k,
                                 kv_len=kv_len, interpret=interpret,
-                                precision=precision)
+                                precision=precision, group=group)
 
     f = jax.custom_vjp(fwd_impl)
 
@@ -316,7 +333,7 @@ def _flash_fn(causal: bool, block_q: int, block_k: int, kv_len,
         dq, dk, dv = _pallas_flash_bwd(
             qh, kh, vh, out, lse, qoff, koff, do, dlse, causal=causal,
             block_q=block_q, block_k=block_k, kv_len=kv_len,
-            interpret=interpret, precision=precision)
+            interpret=interpret, precision=precision, group=group)
         return dq, dk, dv, None, None
 
     f.defvjp(fwd, bwd)
@@ -338,9 +355,14 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
                     k_offset=0, block_q: int = 128, block_k: int = 128,
                     interpret: bool | None = None,
                     return_lse: bool = False, precision=None):
-    """Tiled flash attention. q: [B, Sq, H, D], k/v: [B, Sk, H, D] →
+    """Tiled flash attention. q: [B, Sq, H, D], k/v: [B, Sk, Hkv, D] →
     out [B, Sq, H, D] (and, with ``return_lse``, lse [B, Sq, H] —
     ``logsumexp(scores)`` per query row, for ring partial merges).
+
+    Grouped queries: ``Hkv`` divides ``H`` and K/V head ``j`` serves the
+    query heads ``j·H/Hkv … (j+1)·H/Hkv − 1``. The kernels read the
+    shared K/V tiles in place (no repeated copy), and dk/dv come back
+    with ``Hkv`` heads, summed over each group in float32.
 
     ``q_offset``/``k_offset`` are the blocks' GLOBAL sequence positions
     for causal masking; they may be traced values (each ring device
@@ -356,7 +378,10 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, s_q, h, d = q.shape
-    s_k = k.shape[1]
+    s_k, h_kv = k.shape[1], k.shape[2]
+    if h % h_kv or v.shape[2] != h_kv:
+        raise ValueError(f"{h} query heads cannot share {h_kv} key / "
+                         f"{v.shape[2]} value heads")
     align = 1 if interpret else 128
     block_q, pad_q = _fit_block(block_q, s_q, align)
     block_k, pad_k = _fit_block(block_k, s_k, align)
@@ -364,14 +389,14 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
     # head-major [B*H, S, D]: each grid row owns one (batch, head) pair
     def to_bh(x, padded):
         x = jnp.pad(x, ((0, 0), (0, padded - x.shape[1]), (0, 0), (0, 0)))
-        return x.transpose(0, 2, 1, 3).reshape(b * h, padded, d)
+        return x.transpose(0, 2, 1, 3).reshape(-1, padded, d)
 
     qh, kh, vh = to_bh(q, pad_q), to_bh(k, pad_k), to_bh(v, pad_k)
     qoff = jnp.asarray(q_offset, jnp.int32).reshape(1)
     koff = jnp.asarray(k_offset, jnp.int32).reshape(1)
     out, lse = _flash_fn(causal, block_q, block_k,
                          s_k if pad_k != s_k else None, interpret,
-                         precision)(qh, kh, vh, qoff, koff)
+                         precision, h // h_kv)(qh, kh, vh, qoff, koff)
     out = out.reshape(b, h, pad_q, d).transpose(0, 2, 1, 3)[:, :s_q]
     if not return_lse:
         return out
@@ -380,19 +405,20 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
 
 
 def _pallas_flash_bh(qh, kh, vh, qoff, koff, *, causal, block_q, block_k,
-                     kv_len, interpret, precision=None):
+                     kv_len, interpret, precision=None, group=1):
     """The raw kernel launch, head-major [BH, S, D] → (out, lse[BH, S])."""
     bh_n, s_q, d = qh.shape
     s_k = kh.shape[1]
     grid = (bh_n, s_q // block_q, s_k // block_k)
     out, lse8 = _launch(qh, kh, vh, qoff, koff, grid=grid, causal=causal,
                         block_q=block_q, block_k=block_k, kv_len=kv_len,
-                        interpret=interpret, precision=precision)
+                        interpret=interpret, precision=precision,
+                        group=group)
     return out, lse8[:, 0, :]
 
 
 def _launch(qh, kh, vh, qoff, koff, *, grid, causal, block_q, block_k,
-            kv_len, interpret, precision=None):
+            kv_len, interpret, precision=None, group=1):
     bh_n, s_q, d = qh.shape
     kernel = functools.partial(
         _flash_kernel, causal=causal, scale=1.0 / (d ** 0.5),
@@ -405,8 +431,10 @@ def _launch(qh, kh, vh, qoff, koff, *, grid, causal, block_q, block_k,
             pl.BlockSpec(memory_space=pltpu.SMEM),  # q global offset
             pl.BlockSpec(memory_space=pltpu.SMEM),  # k global offset
             pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, iq, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, iq, ik: (bh, ik, 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda bh, iq, ik: (bh // group, ik, 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda bh, iq, ik: (bh // group, ik, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),

@@ -273,7 +273,7 @@ class Trainer:
                                         param_shardings=param_shardings)
 
     def fit(self, params, data_fn, steps: int, *,
-            opt_state=None, stop=None):
+            opt_state=None, stop=None, consume: bool = False):
         """Train for ``steps`` total steps (resuming included). Returns
         (params, opt_state, history).
 
@@ -284,16 +284,25 @@ class Trainer:
         :class:`Preempted` — the checkpoint-then-exit half of the job
         runtime's SIGTERM contract (JOBS.md), with resume rework bounded
         at zero steps on the graceful path (≤ ``save_every`` when the
-        save itself is lost)."""
+        save itself is lost).
+
+        ``consume=True`` hands ``params`` and ``opt_state`` over: a tree
+        that already lies on the mesh is not copied, and the first step
+        donates the very buffers that were passed, so the caller's
+        arrays are dead after the call. Without it a fit that continues
+        from device state holds that state twice for its whole length
+        (the owning copy and the caller's original), which a model whose
+        masters and moments fill a third of the chip cannot afford."""
         self.history = []  # per-fit; stale entries would misreport results
         tracer = _obs_tracer.get_tracer()
         with tracer.span("train.fit", steps=steps) as fit_span:
             return self._loop(tracer.span, fit_span, params, opt_state,
-                              data_fn, steps, stop)
+                              data_fn, steps, stop, consume)
 
-    def _place(self, params, opt_state):
+    def _place(self, params, opt_state, consume=False):
         """``params`` and ``opt_state`` (built when None) as buffers this
-        fit owns, placed on the mesh."""
+        fit owns, placed on the mesh. ``consume``: the caller has given
+        them up, so a tree on the mesh is owned as it is."""
         # own the buffers: the step donates params/opt_state, and device_put
         # may alias the caller's arrays — donating an alias would delete the
         # caller's data out from under them. Host arrays are copied
@@ -312,7 +321,7 @@ class Trainer:
 
         def _own(tree):
             if all(_spans_mesh(leaf) for leaf in jax.tree.leaves(tree)):
-                return _owning_identity()(tree)
+                return tree if consume else _owning_identity()(tree)
             return jax.tree.map(np.asarray, tree)
 
         params = _own(params)
@@ -405,7 +414,7 @@ class Trainer:
         return params, opt_state
 
     def _loop(self, span, fit_span, params, opt_state,  # tpudl: hot-path
-              data_fn, steps, stop):
+              data_fn, steps, stop, consume=False):
         """Placement, restore, the step loop and the drain, inside
         ``fit``'s ``train.fit`` span. Every span here times the host:
         nothing synchronises with the device for a span's sake, and what
@@ -413,7 +422,7 @@ class Trainer:
         transfer on a 1-wide data axis, a free slot in the device's
         queue) is part of what it shows."""
         with span("train.fit.place"):
-            params, opt_state = self._place(params, opt_state)
+            params, opt_state = self._place(params, opt_state, consume)
         start = 0
         mgr = None
         if self.checkpoint_dir is not None:

@@ -1,0 +1,168 @@
+"""Routed experts that drop nothing and know which experts they hold.
+
+``routed_ff`` is one expert-parallel rank's part of a top-k mixture of
+gated SiLU experts (sigmoid scores, a selection bias that takes no
+gradient, weights renormalised over the selected): the router scores
+every published expert, and the rank computes the terms of ``Σ_e w_e ·
+FF_e(x)`` whose expert it holds (``held = (first, count)``; its leaves
+stack those ``count`` experts only). What the absent experts would add
+is left out and that partial result goes on: summed over the ranks that
+share the layer it is the whole layer's output
+(tests/test_lm_decoder.py, the share test). On one chip there is no
+exchange, and no code stands in for one.
+
+No capacity factor and no drop. The ``T·k`` (token, expert) pairs are
+ordered by expert with the pairs of absent experts last, so the held
+pairs are one dense prefix ``[0, pairs_held)`` of a buffer that has room
+for every pair that can occur; the three products are grouped matrix
+products over that prefix (``jax.lax.ragged_dot``, whose transpose rules
+give the backward), and their work follows ``group_sizes``, that is the
+pairs really routed here. Ordering and its inverse are gathers in both
+directions (a permutation's transpose is its inverse), not scatters.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+
+from tpudl.obs.trace import named_scope
+from tpudl.zoo.lm_blocks import normal
+
+__all__ = ["route", "routed_ff", "init_routed", "pair_order", "ROUTES"]
+
+ROUTES = "moe.routes"  # checkpoint_name of a routed layer's selection
+
+
+def route(p, name: str, x, *, top_k: int, scaling: float = 1.0,
+          routes=None):
+    """``(experts [.., k] int32, weights [.., k] float32)``: scores
+    ``sigmoid(x W_g)`` in float32, the top ``k`` of ``scores + bias``
+    (the bias steers the choice and takes no gradient; ``routes``
+    replaces the choice), weights ``scores[selected] / (Σ + 1e-6) ·
+    scaling``. The choice carries the ``checkpoint_name`` ``ROUTES``: a
+    rematerialised block saves it, since recomputed scores may round
+    otherwise and a near-tie would send the backward pass through other
+    experts than the forward pass used."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x, p[name + ".router"], preferred_element_type=jnp.float32))
+    if routes is None:
+        bias = jax.lax.stop_gradient(p[name + ".expert_bias"])
+        _, routes = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    routes = checkpoint_name(routes, ROUTES)
+    picked = jnp.take_along_axis(scores, routes, axis=-1)
+    weights = picked / (picked.sum(-1, keepdims=True) + 1e-6) * scaling
+    return routes, weights
+
+
+def pair_order(experts, held):
+    """Order the flat pairs ``experts`` ``[N]`` by held expert, pairs of
+    absent experts last. Returns ``(order, place, group_sizes)``:
+    ``order[i]`` is the pair at sorted row ``i``, ``place`` its inverse,
+    ``group_sizes`` ``[count]`` the rows of each held expert."""
+    first, count = held
+    local = experts - first
+    local = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    n = experts.shape[0]
+    place = jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+    group_sizes = jnp.bincount(local, length=count + 1)[:count]
+    return order, place, group_sizes.astype(jnp.int32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _dispatch(x, order, place, held_rows, k):
+    """Rows of ``x`` ``[T, D]`` in sorted pair order ``[T·k, D]`` (pair
+    ``i`` belongs to token ``i // k``); ``held_rows`` is the length of
+    the held prefix."""
+    return x[order // k]
+
+
+def _dispatch_fwd(x, order, place, held_rows, k):
+    return x[order // k], (place, held_rows, x.shape[0])
+
+
+def _dispatch_bwd(k, res, g):
+    place, held_rows, t = res
+    # the grouped products define no cotangent for rows of no group
+    g = jnp.where(jnp.arange(g.shape[0])[:, None] < held_rows, g, 0)
+    return g[place].reshape(t, k, -1).sum(1), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _collect(y, order, place):
+    """Sorted pair rows back in pair order: the inverse permutation."""
+    return y[place]
+
+
+def _collect_fwd(y, order, place):
+    return y[place], order
+
+
+def _collect_bwd(order, g):
+    return g[order], None, None
+
+
+_collect.defvjp(_collect_fwd, _collect_bwd)
+
+
+def routed_ff(p, name: str, x, *, top_k: int, held, scaling: float = 1.0,
+              routes=None):
+    """The held experts' part of the routed feed-forward on ``x``
+    ``[B, S, D]``, and the experts selected ``[B, S, k]``. ``routes``
+    replaces the selection (the weights stay the router's own)."""
+    bsz, s, dim = x.shape
+    tokens = x.reshape(bsz * s, dim)
+    with named_scope("moe.route"):
+        if routes is not None:
+            routes = routes.reshape(bsz * s, top_k)
+        experts, weights = route(p, name, tokens, top_k=top_k,
+                                 scaling=scaling, routes=routes)
+        first, count = held
+        mine = (experts >= first) & (experts < first + count)
+        order, place, group_sizes = pair_order(experts.reshape(-1), held)
+        rows = _dispatch(tokens, order, place, group_sizes.sum(), top_k)
+    with named_scope("moe.experts"):
+        dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes)
+        gate = jax.nn.silu(dot(rows, p[name + ".w1"])) * dot(
+            rows, p[name + ".w3"])
+        out = dot(gate, p[name + ".w2"])
+    with named_scope("moe.route"):
+        out = _collect(out, order, place).reshape(bsz * s, top_k, dim)
+        # rows past the held prefix belong to no group: whatever the
+        # grouped product left there is masked, never multiplied
+        out = jnp.where(mine[..., None], out.astype(jnp.float32)
+                        * weights[..., None], 0.0).sum(1)
+    return out.astype(x.dtype).reshape(bsz, s, dim), experts.reshape(
+        bsz, s, top_k)
+
+
+def init_routed(seed, name: str, dim: int, width: int, experts: int,
+                held) -> dict:
+    """One rank's leaves: the router over all ``experts``, the selection
+    bias (a buffer, constant under training; zeros, as a balancing
+    scheme starts it: a bias of 0.02 already moves an expert's share of
+    the tokens by a tenth), and the ``held`` experts' stacked weights.
+    ``seed`` is a sequence of ints; the router comes from its own
+    stream and expert ``e`` from the stream ``(*seed, e)``, so every
+    rank that shares the layer draws the same router and its own
+    experts, whichever it holds."""
+    first, count = held
+    rng = np.random.default_rng([*seed, experts])
+    out = {name + ".router": normal(rng, dim, experts),
+           name + ".expert_bias": np.zeros((experts,), np.float32)}
+    streams = [np.random.default_rng([*seed, e])
+               for e in range(first, first + count)]
+    for leaf, shape in (("w1", (dim, width)), ("w3", (dim, width)),
+                        ("w2", (width, dim))):
+        out[f"{name}.{leaf}"] = np.stack(
+            [normal(rng, *shape) for rng in streams])
+    return out
