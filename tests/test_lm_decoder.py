@@ -5,6 +5,7 @@ Seeded weights; float32 comparisons at 1e-5 under highest matmul
 precision; bfloat16 (the precision the cell trains in) at the stated
 tolerances, on the program's own routes."""
 
+import functools
 import importlib.util
 import os
 
@@ -241,6 +242,146 @@ def test_grouped_products_backward_against_the_masked_form():
         assert rel(got[0][f"{name}.{leaf}"], want[0][f"{name}.{leaf}"]) < 1e-5
 
 
+def _plain_combine(out, weights, place, held_rows):
+    """The parent's form: every pair's row back in pair order as a
+    ``[T, k, D]`` array, weighted, masked and summed over ``k``."""
+    t, k = weights.shape
+    mine = place.reshape(t, k) < held_rows
+    rows = out[place].reshape(t, k, -1).astype(jnp.float32)
+    return jnp.where(mine[..., None], rows * weights[..., None], 0.0).sum(1)
+
+
+def _pairs(t, k, seed=5):
+    """A seeded routing of ``t`` tokens to ``k`` of 8 experts, 4 held."""
+    rng = np.random.default_rng(seed)
+    experts = np.argsort(rng.random((t, 8)), axis=1)[:, :k].astype(np.int32)
+    weights = jnp.asarray(rng.random((t, k)), jnp.float32)
+    order, place, sizes = moe.pair_order(jnp.asarray(experts.reshape(-1)),
+                                         (2, 4))
+    return weights, order, place, int(sizes.sum())
+
+
+@pytest.mark.parametrize("top_k", [2, 4])
+@pytest.mark.parametrize("held_rows", ["none", "some", "all"])
+def test_combine_and_its_hand_written_backward(held_rows, top_k):
+    """``moe.combine`` (slot by slot, no ``[T, k, D]`` array) and its
+    backward in sorted order against ``jax.grad`` of the plain form, in
+    float32: the value, ``d out`` and ``d weights``, with no row held, the
+    routed prefix held, and every row held."""
+    t, dim = 24, 16
+    weights, order, place, routed = _pairs(t, top_k)
+    held = {"none": 0, "some": routed, "all": t * top_k}[held_rows]
+    assert 0 < routed < t * top_k
+    rng = np.random.default_rng(6)
+    out = jnp.asarray(rng.standard_normal((t * top_k, dim)), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal((t, dim)), jnp.float32)
+    got, pull = jax.vjp(lambda o, w: moe.combine(o, w, order, place, held),
+                        out, weights)
+    want, plain = jax.vjp(lambda o, w: _plain_combine(o, w, place, held),
+                          out, weights)
+    assert got.dtype == jnp.float32 and got.shape == (t, dim)
+    grads = pull(ct)
+    if held == 0:
+        assert not np.any(got)
+        assert not any(np.any(g) for g in grads)
+    else:
+        assert rel(got, want) < 1e-6
+        for mine, theirs in zip(grads, plain(ct)):
+            assert rel(mine, theirs) < 1e-6
+    # rows of no group get no cotangent
+    assert not np.any(np.asarray(grads[0])[held:])
+
+
+def test_dispatch_backward_sums_a_tokens_rows_slot_by_slot():
+    t, k, dim = 24, 2, 16
+    _, order, place, routed = _pairs(t, k)
+    rng = np.random.default_rng(8)
+    x = jnp.asarray(rng.standard_normal((t, dim)), jnp.float32)
+    ct = jnp.asarray(rng.standard_normal((t * k, dim)), jnp.float32)
+    rows, pull = jax.vjp(
+        lambda x: moe._dispatch(x, order, place, routed, k), x)
+    np.testing.assert_array_equal(rows, np.asarray(x)[np.asarray(order) // k])
+    masked = np.where(np.arange(t * k)[:, None] < routed, ct, 0)
+    want = masked[np.asarray(place)].reshape(t, k, dim).sum(1)
+    assert rel(pull(ct)[0], want) < 1e-6
+
+
+def _route_ops(text):
+    """``{(op, dims): n}`` of the sorts, scatters and gathers that a
+    compiled program's text files under ``moe.route`` (unit dimensions
+    dropped)."""
+    import collections
+    import re
+
+    found = collections.Counter()
+    for line in text.splitlines():
+        m = re.search(r"= \(?[a-z0-9]+\[([0-9,]*)\][^=]*? "
+                      r"(sort|scatter|gather)\(", line)
+        if m and "moe.route" in line:
+            dims = tuple(int(v) for v in m.group(1).split(",")
+                         if v and int(v) != 1)
+            found[m.group(2), dims] += 1
+    return found
+
+
+def test_a_rematerialised_block_orders_once_and_gathers_five_times():
+    """The compiled gradient of two rematerialised routed blocks: one
+    sort and one index scatter a layer (the ordering is saved with the
+    selection, not recomputed; the parent had two of each), and five
+    gathers of ``[T·k, D]`` rows a layer where the parent had six, a
+    slot's ``[T, D]`` gather counted as ``1/k``: dispatch forward and
+    recomputed, the combine's slots, and in the backward the cotangent
+    in sorted order and dispatch's slots."""
+    lm = Decoder({**BASE, "layer_types": ["conv", "full_attention"],
+                  "num_dense_layers": 0})
+    ids = tokens()
+    ops = _route_ops(jax.jit(jax.grad(lm.loss_fn(remat=True))).lower(
+        lm.init(3), ids).compile().as_text())
+    layers, t, k, dim = 2, ids.size, BASE["num_experts_per_tok"], 64
+    assert ops["sort", (t * k,)] == layers
+    assert ops["scatter", (t * k,)] == layers
+    full, slot = ops["gather", (t * k, dim)], ops["gather", (t, dim)]
+    assert full == 3 * layers and slot == 2 * k * layers
+    assert full + slot / k <= 5 * layers
+    assert not any(len(dims) == 3 for _, dims in ops)    # no [T, k, D]
+
+
+def test_bfloat16_forward_is_the_parents_formula():
+    """``routed_ff`` in bfloat16 against ordering, grouped products and
+    the ``[T, k, D]`` weighted sum written as the parent had them: the
+    float32 sum before its one rounding to 1e-6, the rounded layer to a
+    last place of bfloat16 in an element or two (two programs contract
+    multiply-adds apart)."""
+    lm, p = build("conv+routed")
+    name, k, held = "layers.0.moe", 2, (2, 4)
+    p = {key: jnp.asarray(v, jnp.bfloat16) for key, v in p.items()}
+    x = jnp.asarray(np.random.default_rng(9).standard_normal((2, 16, 64)),
+                    jnp.bfloat16)
+
+    def sorted_outputs(p, x):
+        tok = x.reshape(-1, 64)
+        experts, weights = moe.route(p, name, tok, top_k=k)
+        order, place, sizes = moe.pair_order(experts.reshape(-1), held)
+        dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes)
+        rows = tok[order // k]
+        gate = jax.nn.silu(dot(rows, p[name + ".w1"])) * dot(
+            rows, p[name + ".w3"])
+        return dot(gate, p[name + ".w2"]), weights, order, place, sizes.sum()
+
+    def parent(p, x):
+        out, weights, _, place, held_rows = sorted_outputs(p, x)
+        return _plain_combine(out, weights, place, held_rows)
+
+    want = jax.jit(parent)(p, x)
+    assert np.any(np.asarray(want))
+    assert rel(jax.jit(lambda p, x: moe.combine(*sorted_outputs(p, x)))(
+        p, x), want) < 1e-6
+    got, _ = jax.jit(lambda p, x: moe.routed_ff(
+        p, name, x, top_k=k, held=held))(p, x)
+    assert got.dtype == jnp.bfloat16
+    assert rel(got, want.astype(jnp.bfloat16).reshape(x.shape)) < 1e-4
+
+
 def test_pair_order_sorts_held_pairs_first_and_counts_them():
     experts = jnp.asarray([5, 2, 9, 3, 2, 0, 3, 3], jnp.int32)
     order, place, sizes = moe.pair_order(experts, (2, 2))
@@ -401,6 +542,21 @@ def test_layers_are_counted_by_kind_while_a_program_is_traced():
         assert (after[name]["value"]
                 - before.get(name, {"value": 0})["value"]) == n
     assert lm.kinds() == {"conv": 2, "attention": 1, "dense": 1, "routed": 2}
+
+
+@pytest.mark.parametrize("stack, routed", [("whole", 2), ("one period", 4),
+                                           ("conv+dense", 0)])
+def test_fused_combines_are_counted_per_routed_layer(stack, routed):
+    """``moe.combine.fused``: 1 a routed layer a trace, a scanned run of
+    layers counted by its length; a dense decoder counts none."""
+    from tpudl import obs
+
+    lm, p = build(stack)
+    assert lm.kinds()["routed"] == routed
+    before = obs.snapshot().get("moe.combine.fused", {"value": 0})["value"]
+    jax.jit(jax.grad(lm.loss_fn(remat=True))).lower(p, tokens())
+    after = obs.snapshot().get("moe.combine.fused", {"value": 0})["value"]
+    assert after - before == routed
 
 
 def test_config_errors_name_what_is_wrong():

@@ -203,6 +203,11 @@ METRICS: tuple[Metric, ...] = (
            "dense / routed feed-forwards): what a config-driven Decoder "
            "program is made of (4 / 1 / 1 / 4 per trace of "
            "lfm2-8b-a1b-ep4)"),
+    Metric("moe.combine.fused", "counter",
+           "routed layers traced through moe.combine, the hand-written "
+           "forward/backward pair that puts the experts' rows back at "
+           "their tokens slot by slot (4 per trace of lfm2-8b-a1b-ep4; "
+           "none in a dense decoder)"),
     # -- routed experts (published by Decoder.route_stats, outside steps)
     Metric("moe.pairs_held", "counter",
            "(token, expert) pairs routed to experts this rank holds, "

@@ -52,6 +52,7 @@ __all__ = ["Decoder"]
 
 OPERATORS = ("conv", "full_attention")
 # a rematerialised block recomputes everything but its routing decision
+# and the ordering of the pairs that follows from it
 _SAVE_ROUTES = jax.checkpoint_policies.save_only_these_names(moe.ROUTES)
 
 
@@ -193,9 +194,13 @@ class Decoder:
         conv, conv, conv between attentions), for a copy of the run's
         compute-dtype weights a step. Bumps ``zoo.lm.layers.<kind>``
         once per layer while a program is TRACED, as
-        ``zoo.conv_bn.folded`` is."""
-        for kind, n in self.kinds().items():
+        ``zoo.conv_bn.folded`` is, and ``moe.combine.fused`` once per
+        routed layer."""
+        kinds = self.kinds()
+        for kind, n in kinds.items():
             _metrics.counter(f"zoo.lm.layers.{kind}").inc(n)
+        if kinds["routed"]:   # moe.routed_ff has the one path
+            _metrics.counter("moe.combine.fused").inc(kinds["routed"])
         x = params["embed"][ids]
         given = iter(routes) if routes is not None else None
         chosen = []

@@ -17,8 +17,21 @@ pairs are one dense prefix ``[0, pairs_held)`` of a buffer that has room
 for every pair that can occur; the three products are grouped matrix
 products over that prefix (``jax.lax.ragged_dot``, whose transpose rules
 give the backward), and their work follows ``group_sizes``, that is the
-pairs really routed here. Ordering and its inverse are gathers in both
-directions (a permutation's transpose is its inverse), not scatters.
+pairs really routed here.
+
+Rows move five times a layer, each time as a gather, never as a scatter
+and never as a ``[T, k, D]`` array. ``_dispatch`` gathers the tokens'
+rows into sorted order (again when a block is rematerialised);
+``combine`` takes the experts' rows back one slot of ``k`` at a time,
+``[T, D]`` each, weighting and summing them in float32 as they arrive.
+Both backward passes are written by hand in SORTED order: the
+cotangent of the experts' output is one gather of the layer's cotangent
+scaled by the weights, the weights' own gradient is a row sum over that
+same gather (so nothing needs the un-ordered output, and a
+rematerialised block never recomputes ``combine``), and dispatch's
+backward sums a token's ``k`` rows slot by slot. The ordering
+(``order``, ``place``, ``group_sizes``) carries the selection's
+``checkpoint_name``: a rematerialised block sorts once a step.
 """
 
 from __future__ import annotations
@@ -33,9 +46,11 @@ from jax.ad_checkpoint import checkpoint_name
 from tpudl.obs.trace import named_scope
 from tpudl.zoo.lm_blocks import normal
 
-__all__ = ["route", "routed_ff", "init_routed", "pair_order", "ROUTES"]
+__all__ = ["route", "routed_ff", "combine", "init_routed", "pair_order",
+           "ROUTES"]
 
-ROUTES = "moe.routes"  # checkpoint_name of a routed layer's selection
+# checkpoint_name of a routed layer's selection and of the ordering made of it
+ROUTES = "moe.routes"
 
 
 def route(p, name: str, x, *, top_k: int, scaling: float = 1.0,
@@ -75,6 +90,12 @@ def pair_order(experts, held):
     return order, place, group_sizes.astype(jnp.int32)
 
 
+def _slots(place, k):
+    """``place`` ``[T·k]`` as ``[k, T]``: row ``j`` holds the sorted row
+    of every token's ``j``-th pair."""
+    return place.reshape(-1, k).T
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _dispatch(x, order, place, held_rows, k):
     """Rows of ``x`` ``[T, D]`` in sorted pair order ``[T·k, D]`` (pair
@@ -84,41 +105,70 @@ def _dispatch(x, order, place, held_rows, k):
 
 
 def _dispatch_fwd(x, order, place, held_rows, k):
-    return x[order // k], (place, held_rows, x.shape[0])
+    return x[order // k], (place, held_rows)
 
 
 def _dispatch_bwd(k, res, g):
-    place, held_rows, t = res
+    """``Σ_j g[place[:, j]]``, a token's ``k`` rows one slot at a time
+    (no ``[T, k, D]`` array), summed in float32 and rounded once."""
+    place, held_rows = res
     # the grouped products define no cotangent for rows of no group
     g = jnp.where(jnp.arange(g.shape[0])[:, None] < held_rows, g, 0)
-    return g[place].reshape(t, k, -1).sum(1), None, None, None
+    total = 0.0
+    for slot in _slots(place, k):
+        total = total + g[slot].astype(jnp.float32)
+    return total.astype(g.dtype), None, None, None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
 @jax.custom_vjp
-def _collect(y, order, place):
-    """Sorted pair rows back in pair order: the inverse permutation."""
-    return y[place]
+def combine(out_sorted, weights, order, place, held_rows):
+    """``Σ_j weights[:, j] · out_sorted[place[:, j]]`` ``[T, D]`` float32:
+    the sorted rows ``[T·k, D]`` back at their tokens under the router's
+    weights ``[T, k]``, one slot at a time, the product and the sum in
+    float32. Rows past the held prefix belong to no group: whatever the
+    grouped product left there is masked, never multiplied."""
+    total = 0.0
+    for j, slot in enumerate(_slots(place, weights.shape[1])):
+        rows = jnp.where((slot < held_rows)[:, None], out_sorted[slot], 0)
+        total = total + rows.astype(jnp.float32) * weights[:, j, None]
+    return total
 
 
-def _collect_fwd(y, order, place):
-    return y[place], order
+def _combine_fwd(out_sorted, weights, order, place, held_rows):
+    return (combine(out_sorted, weights, order, place, held_rows),
+            (out_sorted, weights, order, place, held_rows))
 
 
-def _collect_bwd(order, g):
-    return g[order], None, None
+def _combine_bwd(res, g):
+    """Both cotangents in SORTED order, from one gather of ``g``: the
+    experts' output gets ``g · weight`` rounded once to its dtype, the
+    weights get ``Σ_D g · out_sorted`` put back by ``place``. Nothing
+    here needs the un-ordered output, so a rematerialised block never
+    recomputes the forward combine."""
+    out_sorted, weights, order, place, held_rows = res
+    k = weights.shape[1]
+    held = (jnp.arange(order.shape[0]) < held_rows)[:, None]
+    g_sorted = g.astype(out_sorted.dtype)[order // k].astype(jnp.float32)
+    d_out = jnp.where(held, g_sorted * weights.reshape(-1)[order][:, None],
+                      0.0).astype(out_sorted.dtype)
+    d_weights = jnp.where(held, g_sorted * out_sorted.astype(jnp.float32),
+                          0.0).sum(-1)[place].reshape(weights.shape)
+    return d_out, d_weights.astype(weights.dtype), None, None, None
 
 
-_collect.defvjp(_collect_fwd, _collect_bwd)
+combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def routed_ff(p, name: str, x, *, top_k: int, held, scaling: float = 1.0,
               routes=None):
     """The held experts' part of the routed feed-forward on ``x``
     ``[B, S, D]``, and the experts selected ``[B, S, k]``. ``routes``
-    replaces the selection (the weights stay the router's own)."""
+    replaces the selection (the weights stay the router's own). The
+    ordering carries the ``checkpoint_name`` that the selection carries:
+    a rematerialised block sorts once a step."""
     bsz, s, dim = x.shape
     tokens = x.reshape(bsz * s, dim)
     with named_scope("moe.route"):
@@ -126,21 +176,18 @@ def routed_ff(p, name: str, x, *, top_k: int, held, scaling: float = 1.0,
             routes = routes.reshape(bsz * s, top_k)
         experts, weights = route(p, name, tokens, top_k=top_k,
                                  scaling=scaling, routes=routes)
-        first, count = held
-        mine = (experts >= first) & (experts < first + count)
-        order, place, group_sizes = pair_order(experts.reshape(-1), held)
-        rows = _dispatch(tokens, order, place, group_sizes.sum(), top_k)
+        order, place, group_sizes = (
+            checkpoint_name(index, ROUTES)
+            for index in pair_order(experts.reshape(-1), held))
+        held_rows = group_sizes.sum()
+        rows = _dispatch(tokens, order, place, held_rows, top_k)
     with named_scope("moe.experts"):
         dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes)
         gate = jax.nn.silu(dot(rows, p[name + ".w1"])) * dot(
             rows, p[name + ".w3"])
         out = dot(gate, p[name + ".w2"])
     with named_scope("moe.route"):
-        out = _collect(out, order, place).reshape(bsz * s, top_k, dim)
-        # rows past the held prefix belong to no group: whatever the
-        # grouped product left there is masked, never multiplied
-        out = jnp.where(mine[..., None], out.astype(jnp.float32)
-                        * weights[..., None], 0.0).sum(1)
+        out = combine(out, weights, order, place, held_rows)
     return out.astype(x.dtype).reshape(bsz, s, dim), experts.reshape(
         bsz, s, top_k)
 
