@@ -11,8 +11,8 @@ cd "$(dirname "$0")"
 export JAX_PLATFORMS=cpu
 
 echo "== tpudl-check (AST invariant linter, ANALYSIS.md + CONCURRENCY.md) =="
-python -m tools.tpudl_check tpudl tools bench.py
-python -m tools.tpudl_check --registry-audit tpudl tools bench.py
+python -m tools.tpudl_check tpudl tools
+python -m tools.tpudl_check --registry-audit tpudl tools
 
 echo "== tsan pass (lock sanitizer armed over the concurrency subset) =="
 # exit reports go to a scratch dir, not the checkout. User args go

@@ -7,14 +7,14 @@ Four layers, mirroring the other validator suites:
    that fires it, kept honest by a negative snippet that doesn't, and
    a suppression snippet that silences it (with the required reason);
 2. the self-lint — the repo's own tree is clean, which is the
-   acceptance criterion (`python -m tools.tpudl_check tpudl tools
-   bench.py` exits 0);
+   acceptance criterion (`python -m tools.tpudl_check tpudl tools`
+   exits 0);
 3. registry round-trips — every declared knob/metric is used, every
    used one is declared (deleting a knob's last read without deleting
    its declaration fails here, and vice versa);
 4. the CLI contract — exit 0 clean / 2 findings / 1 error, importable
-   like the five runtime validators, and under the 20 s budget so it
-   can never eat the bench window.
+   like the five runtime validators, and under the 20 s budget of a
+   gate that runs before every test run.
 """
 
 import importlib.util
@@ -34,8 +34,7 @@ from tpudl.analysis import (RULES, check_paths, check_source,
 from tpudl.analysis.metric_names import matches_pattern_prefix
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CHECK_TARGETS = [os.path.join(REPO, "tpudl"), os.path.join(REPO, "tools"),
-                 os.path.join(REPO, "bench.py")]
+CHECK_TARGETS = [os.path.join(REPO, "tpudl"), os.path.join(REPO, "tools")]
 
 
 def _load_cli():
@@ -689,7 +688,7 @@ class TestSelfLint:
         dt = time.perf_counter() - t0
         assert errors == []
         assert findings == [], "\n".join(f.render() for f in findings)
-        # the CI budget: the checker must never eat the bench window
+        # the CI budget: the gate runs before every test run
         assert dt < 20.0, f"self-lint took {dt:.1f}s (budget 20s)"
 
     def test_registries_round_trip(self):
@@ -728,7 +727,7 @@ class TestRegistries:
                               "path", "json")
             assert k.subsystem in ("frame", "data", "obs", "jobs",
                                    "train", "zoo", "compile", "serve",
-                                   "text", "bench")
+                                   "text")
             assert k.help
         assert len(KNOB_NAMES) == len(KNOBS)  # no duplicate names
 
@@ -781,7 +780,7 @@ class TestCLI:
 
     @pytest.mark.slow
     def test_clean_tree_exits_0(self):
-        p = self._run("tpudl", "tools", "bench.py")
+        p = self._run("tpudl", "tools")
         assert p.returncode == 0, p.stderr + p.stdout
         assert "0 finding(s)" in p.stdout
 

@@ -125,11 +125,6 @@ class TestCompilationCache:
         assert len(loud) == 1  # warn-once
         assert _metric("compile.cache_disabled") == 2  # count-always
 
-    def test_back_compat_shim(self):
-        from tpudl.compilation_cache import enable_compilation_cache
-
-        assert enable_compilation_cache is ccache.enable_compilation_cache
-
 
 # ---------------------------------------------------------------------------
 # bucket ladder

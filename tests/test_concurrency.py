@@ -40,8 +40,7 @@ from tpudl.testing import tsan
 pytestmark = pytest.mark.concurrency
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CHECK_TARGETS = [os.path.join(REPO, "tpudl"), os.path.join(REPO, "tools"),
-                 os.path.join(REPO, "bench.py")]
+CHECK_TARGETS = [os.path.join(REPO, "tpudl"), os.path.join(REPO, "tools")]
 
 
 def rules_of(findings):
@@ -1073,22 +1072,44 @@ class TestUnarmedOverhead:
         assert type(tsan.named_lock("x", kind="rlock")) \
             is type(threading.RLock())
 
-    def test_unarmed_acquisition_within_5pct_of_raw(self, unarmed):
-        named = tsan.named_lock("obs.pipeline.ring")
-        raw = threading.Lock()
+    def test_unarmed_acquisitions_leave_no_record(self, unarmed):
+        # the unarmed lock IS the stdlib type (the test above), so a
+        # timing of one against the other measures the machine. What
+        # the unarmed path must not do is book-keep: nested
+        # acquisitions in both orders and an unguarded-mutation probe,
+        # each of which the armed sanitizer records, leave its report
+        # as it was.
+        def records():
+            rep = tsan.report()
+            return {k: rep[k] for k in ("findings", "edges",
+                                        "locks_seen", "hold_times")}
 
-        def best_of(lk, reps=7, n=30000):
-            best = float("inf")
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                for _ in range(n):
-                    with lk:
-                        pass
-                best = min(best, time.perf_counter() - t0)
-            return best
+        def inversion_and_unguarded_probe():
+            a = tsan.named_lock("obs.pipeline.ring")
+            b = tsan.named_lock("obs.metrics.registry")
+            for _ in range(100):
+                with a, b:
+                    pass
+                with b, a:
+                    pass
+                tsan.check_guarded("obs.pipeline.ring", "ring")
 
-        best_of(raw, reps=1)  # warm
-        assert best_of(named) < best_of(raw) * 1.05
+        before = records()
+        inversion_and_unguarded_probe()
+        assert records() == before
+        tsan.arm()  # the same calls, armed, are all recorded
+        try:
+            tsan.reset()
+            inversion_and_unguarded_probe()
+            armed = records()
+        finally:
+            tsan.disarm()
+            tsan.reset()
+        assert {"obs.pipeline.ring",
+                "obs.metrics.registry"} <= set(armed["locks_seen"])
+        assert armed["edges"] and armed["hold_times"]
+        assert {f["kind"] for f in armed["findings"]} >= {"inversion",
+                                                          "lockset"}
 
     def test_unarmed_check_guarded_is_one_flag_read(self, unarmed):
         t0 = time.perf_counter()

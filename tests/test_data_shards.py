@@ -211,7 +211,7 @@ class TestConcurrency:
 class TestValidateShardsTool:
     """tools/validate_shards.py is the offline audit authority — wired
     into tier-1 here exactly like tools/validate_metrics.py is in
-    test_bench_contract.py."""
+    test_obs_metrics.py."""
 
     def test_clean_cache_validates(self, tmp_path, validator):
         cache = ShardCache(tmp_path, cache_key("m"))

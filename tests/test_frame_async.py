@@ -15,7 +15,7 @@ The acceptance surface, all tier-1 fast:
 5. AUTOTUNE — with no env knobs set, the executor's chosen
    fuse_steps/dispatch_depth match ``obs.analyze_roofline()``'s advice
    over the previous report, and ``TPUDL_FRAME_PREFETCH=0`` still
-   yields the fully serial executor (the bench baseline arm).
+   yields the fully serial executor (the baseline arm of an A/B).
 """
 
 from __future__ import annotations

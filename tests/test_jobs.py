@@ -895,7 +895,7 @@ class TestValidateJob:
 
 # -- the acceptance path: kill-mid-epoch subprocess round-trip -------------
 _JOB_SCRIPT = """
-import os, sys
+import os, signal, sys
 import numpy as np
 import jax.numpy as jnp
 import optax
@@ -905,11 +905,16 @@ from tpudl.train import Trainer
 
 faults.install_from_env()
 workdir, out = sys.argv[1], sys.argv[2]
+sigkill_at = int(sys.argv[3]) if len(sys.argv) > 3 else None
 rng = np.random.default_rng(0)
 X = rng.normal(size=(256, 4)).astype(np.float32)
 y = X @ np.array([[1.0], [-2.0], [0.5], [3.0]], np.float32) + 0.1
 
 def data_fn(step, batch=32):
+    with open(out + ".steps", "a") as f:
+        f.write(f"{step}\\n")
+    if step == sigkill_at:  # no handler runs, no boundary is reached
+        os.kill(os.getpid(), signal.SIGKILL)
     i = (step * batch) % (len(X) - batch + 1)
     return X[i:i + batch], y[i:i + batch]
 
@@ -927,28 +932,35 @@ print("DONE")
 """
 
 
-def _run_job(tmp_path, workdir, out, env_extra=None, timeout=120):
+def _run_job(tmp_path, workdir, out, env_extra=None, timeout=120,
+             sigkill_at=None):
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=REPO + os.pathsep
                + os.environ.get("PYTHONPATH", ""),
                **(env_extra or {}))
     env.pop("TPUDL_FAULT_PLAN", None) if env_extra is None else None
     r = subprocess.run(
-        [sys.executable, "-c", _JOB_SCRIPT, str(workdir), str(out)],
+        [sys.executable, "-c", _JOB_SCRIPT, str(workdir), str(out)]
+        + ([str(sigkill_at)] if sigkill_at is not None else []),
         capture_output=True, text=True, env=env, timeout=timeout)
     return r
 
 
 class TestKillMidEpochAcceptance:
-    def test_sigterm_relaunch_bit_identical(self, tmp_path):
+    @pytest.fixture(scope="class")
+    def ref_params(self, tmp_path_factory):
+        """Final params of the uninterrupted 20-step job."""
+        d = tmp_path_factory.mktemp("ref")
+        ref = _run_job(d, d / "ref_job", d / "ref")
+        assert ref.returncode == 0, ref.stderr[-800:]
+        return dict(np.load(str(d / "ref.npz")))
+
+    def test_sigterm_relaunch_bit_identical(self, tmp_path, ref_params):
         """THE acceptance test: SIGTERM-at-step-13 (injected
         deterministically by the fault plan) → rc 75 → relaunch of the
         identical spec → final params BIT-IDENTICAL to an uninterrupted
         run; the dump in the workdir classifies preempted_resumable and
         the manifest passes the audit."""
-        ref = _run_job(tmp_path, tmp_path / "ref_job", tmp_path / "ref")
-        assert ref.returncode == 0, ref.stderr[-800:]
-
         plan = faults.FaultPlan.kill_at_step(13)
         killed = _run_job(tmp_path, tmp_path / "job", tmp_path / "kill",
                           env_extra={"TPUDL_FAULT_PLAN": plan.to_env()})
@@ -963,10 +975,9 @@ class TestKillMidEpochAcceptance:
 
         resumed = _run_job(tmp_path, tmp_path / "job", tmp_path / "kill")
         assert resumed.returncode == 0, resumed.stderr[-800:]
-        a = np.load(str(tmp_path / "ref.npz"))
         b = np.load(str(tmp_path / "kill.npz"))
         for k in ("w", "b"):
-            assert np.array_equal(a[k], b[k]), (
+            assert np.array_equal(ref_params[k], b[k]), (
                 f"params[{k}] differ after preempt+resume")
 
         res = obs_doctor.diagnose(str(tmp_path / "job"))
@@ -979,6 +990,32 @@ class TestKillMidEpochAcceptance:
         final = load_manifest(str(tmp_path / "job"))
         assert final["status"] == "done"
         assert final["attempt"] == 2
+
+    def test_sigkill_relaunch_reworks_at_most_save_every_steps(
+            self, tmp_path, ref_params):
+        """The hard kill: SIGKILL inside step 13's ``data_fn`` (no
+        handler, no boundary) → the relaunch of the identical spec
+        starts from the last periodic save, re-executes at most
+        ``save_every`` = 5 of the steps the killed run had finished,
+        and ends BIT-IDENTICAL to an uninterrupted run."""
+        def steps_seen():
+            with open(str(tmp_path / "kill.steps")) as f:
+                return [int(x) for x in f.read().split()]
+
+        killed = _run_job(tmp_path, tmp_path / "job", tmp_path / "kill",
+                          sigkill_at=13)
+        assert killed.returncode == -signal.SIGKILL, killed.stderr[-800:]
+        assert steps_seen() == list(range(14))  # steps 0..12 finished
+        resumed = _run_job(tmp_path, tmp_path / "job", tmp_path / "kill")
+        assert resumed.returncode == 0, resumed.stderr[-800:]
+        resumed_steps = steps_seen()[14:]
+        start = resumed_steps[0]
+        assert resumed_steps == list(range(start, 20))
+        assert 0 < 13 - start <= 5, f"resumed at step {start}"
+        b = np.load(str(tmp_path / "kill.npz"))
+        for k in ("w", "b"):
+            assert np.array_equal(ref_params[k], b[k]), (
+                f"params[{k}] differ after hard kill + resume")
 
 
 # -- executor overhead guard (fault hooks must stay free) ------------------

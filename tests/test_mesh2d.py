@@ -650,7 +650,7 @@ class TestObsTopology:
         from tpudl.obs import live
 
         status = {"pid": 1, "alive": True, "ts": 0.0, "interval_s": 1.0,
-                  "argv": ["bench.py"], "host": "h", "runs": [
+                  "argv": ["job.py"], "host": "h", "runs": [
                       live._run_entry(_report())]}
         out = live.render([status], now=1.0)
         assert "mesh=4x2" in out

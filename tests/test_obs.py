@@ -68,8 +68,7 @@ def test_profile_writes_trace(tmp_path):
 
 
 def test_summarize_device_trace():
-    """The trace-viewer aggregation behind tools/profile_featurize.py and the bench's
-    device_profile record: XLA-Modules lane sums to program time,
+    """The trace-viewer-JSON aggregation: XLA-Modules lane sums to program time,
     XLA-Ops lane aggregates per-op with category/bytes; host lanes and
     non-TPU processes are ignored."""
     from tpudl.obs import summarize_device_trace
@@ -128,7 +127,7 @@ def test_persistent_compilation_cache_round_trip(tmp_path, monkeypatch):
     import jax
     import jax.numpy as jnp
 
-    from tpudl.compilation_cache import enable_compilation_cache
+    from tpudl.compile import enable_compilation_cache
 
     def _reset_persistent_cache():
         try:

@@ -288,7 +288,7 @@ class TestPropagation:
         assert ServeRequest(np.array([1], np.int32), 2).scope is None
 
     def test_loadgen_tenant_stamping(self):
-        """The bench's two-tenant sub-bench path: ``tenant=("a", "b")``
+        """Two tenants under the load generator: ``tenant=("a", "b")``
         alternates client scopes, so the closed loop produces exactly
         two ledger rows whose completions sum to the request count."""
         from tpudl.serve import ModelRegistry, Server, run_closed_loop
@@ -518,31 +518,16 @@ class TestValidators:
         assert any("ledger" in e
                    for e in vd.validate_payload(payload))
 
-    def test_bench_record_ledger_block_schema(self):
-        """The serve trial record's ``ledger`` block satisfies the
-        shared section schema, and the judged summary line carries the
-        ISSUE-20 scalars (tenant count + reconciliation verdict)
-        without breaking the flat-line contract."""
-        bench = importlib.util.module_from_spec(
-            importlib.util.spec_from_file_location(
-                "bench", os.path.join(REPO, "bench.py")))
-        bench.__spec__.loader.exec_module(bench)
+    def test_two_tenant_ledger_with_reconcile_satisfies_section_schema(
+            self):
+        """A two-tenant ledger carrying its reconciliation verdict (the
+        block a closed-loop serve run under ``tenant=("a", "b")``
+        leaves) satisfies the shared section schema."""
         vd = _load_tool("validate_dump")
-        vm = _load_tool("validate_metrics")
         led = _ledger_fixture()
         led["scopes"]["tenant=b"] = dict(led["unattributed"])
         led["reconcile"] = {"ok": True, "checks": []}
         assert vd.validate_ledger_section(led) == []
-        record = {"metric": "m", "value": 1.0, "unit": "u",
-                  "vs_baseline": None,
-                  "serve": {"sustained_qps": 3.5, "ledger": led,
-                            "tenants": ["tenant=a", "tenant=b"],
-                            "ledger_ok": True}}
-        s = bench._compact_summary(record)
-        assert s["serve_tenants"] == 2
-        assert s["serve_ledger_ok"] is True
-        assert "ledger" not in s  # too nested for the judged line
-        assert vm.validate_bench_summary_line(json.dumps(s)) == []
 
     def test_metrics_cardinality_breach_is_rc2(self, tmp_path, capsys):
         """Minting per-label names into one family breaches the

@@ -203,7 +203,7 @@ def _fixture_status(tmp_path, pid=4242, alive=True, with_run=True):
     payload = {
         "schema": live.SCHEMA, "version": live.VERSION,
         "ts": time.time(), "pid": pid, "host": "testhost",
-        "argv": ["bench.py"], "interval_s": 1.0, "alive": alive,
+        "argv": ["job.py"], "interval_s": 1.0, "alive": alive,
         "runs": [], "heartbeats": {
             "frame.map_batches": {"age_s": 0.2, "beats": 37,
                                   "info": {"stage": "dispatch"},

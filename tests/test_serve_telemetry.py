@@ -400,7 +400,7 @@ class TestWindowedVsLoadgen:
 def _payload(**over):
     base = {"schema": "tpudl-flight-dump", "version": 1,
             "reason": "manual", "ts": time.time(), "pid": 1000,
-            "process_index": 0, "process_count": 1, "argv": ["bench.py"],
+            "process_index": 0, "process_count": 1, "argv": ["job.py"],
             "python": "3.11.0", "backend": {"jax_loaded": False},
             "env": {}, "error": None, "batches": [], "errors": [],
             "stalls": [], "metric_ticks": [], "restarts": [],
@@ -599,7 +599,7 @@ class TestValidateDumpRequests:
 
 def _status_payload(serve):
     return {"schema": "tpudl-status", "version": 1, "ts": time.time(),
-            "pid": 1234, "host": "h0", "argv": ["bench.py"],
+            "pid": 1234, "host": "h0", "argv": ["job.py"],
             "interval_s": 1.0, "alive": True, "runs": [],
             "heartbeats": {}, "metrics": {}, "roofline": None,
             "serve": serve}

@@ -1,10 +1,10 @@
 #!/usr/bin/env python
-"""tpudl-check: the AST invariant linter over tpudl/, tools/, bench.py.
+"""tpudl-check: the AST invariant linter over tpudl/ and tools/.
 
 The sixth repo gate, same shape as the five runtime validators
 (validate_metrics/shards/dump/status/job): pure stdlib + tpudl.analysis,
 importable (``from tpudl_check import run_check``) and runnable
-(``python -m tools.tpudl_check tpudl tools bench.py``). Where the
+(``python -m tools.tpudl_check tpudl tools``). Where the
 validators check emitted ARTIFACTS, this checks the SOURCE for the
 invariants those artifacts assume — atomic writes, flag-only signal
 handlers, the shared RetryPolicy, no hot-path syncs, no swallowed
@@ -233,14 +233,14 @@ def collect_findings(paths, root: str = ".", rules=None,
     if want_stale:
         # graph-rule suppressions are judged only when the scan covers
         # whole ROOT trees including at least one directory (the
-        # canonical gate shape: `tpudl tools bench.py`).
+        # canonical gate shape: `tpudl tools`).
         # `tpudl_check tpudl/testing` scans a SUB-package (its parent
-        # carries __init__.py — the graph is truncated) and
-        # `tpudl_check bench.py` alone has no package graph at all —
-        # either truncation makes 'absorbed nothing' prove nothing
-        # about rot. Judged off the paths' own package structure, so
-        # absolute paths / foreign cwd behave identically to the
-        # in-repo relative invocation.
+        # carries __init__.py — the graph is truncated) and a lone
+        # file (`tpudl_check chip_smoke.py`) has no package graph at
+        # all — either truncation makes 'absorbed nothing' prove
+        # nothing about rot. Judged off the paths' own package
+        # structure, so absolute paths / foreign cwd behave identically
+        # to the in-repo relative invocation.
         def _sub_scope(p):
             parent = os.path.dirname(os.path.abspath(p))
             return os.path.exists(os.path.join(parent, "__init__.py"))

@@ -1,25 +1,19 @@
 #!/usr/bin/env python
 """Schema checks for tpudl's observability emissions.
 
-Two contracts live here (wired into tier-1 via
-tests/test_bench_contract.py and tests/test_obs_metrics.py, so a
-malformed emission fails CI, not a downstream dashboard):
+One contract is always checked (wired into tier-1 via
+tests/test_obs_metrics.py, so a malformed emission fails CI, not a
+downstream dashboard): the metrics JSONL a ``TPUDL_METRICS_FILE`` sink
+appends (:mod:`tpudl.obs.metrics` — one JSON object per line:
+``{ts, event, pid, metrics: {name: typed-dict}}``).
 
-1. the metrics JSONL a ``TPUDL_METRICS_FILE`` sink appends
-   (:mod:`tpudl.obs.metrics` — one JSON object per line:
-   ``{ts, event, pid, metrics: {name: typed-dict}}``);
-2. the bench's judged LAST-line summary (``bench.py _compact_summary``
-   — flat JSON, required keys, < 1500 chars, nothing nested deeper
-   than one list-of-scalars).
-
-Opt-in third contract (``--check-names``): every metric NAME in the
+Opt-in second contract (``--check-names``): every metric NAME in the
 sink must be declared in the registry
 (:mod:`tpudl.analysis.metric_names`, ANALYSIS.md) — opt-in because a
 sink file may legitimately carry user-defined metrics, but tpudl's own
-emissions must match the schema the dashboards and the bench sentinel
-key on.
+emissions must match the schema the dashboards key on.
 
-Always-on fourth contract (ISSUE 20): the labeled-series bound. The
+Always-on third contract (ISSUE 20): the labeled-series bound. The
 attribution plane keeps per-tenant aggregates in ONE bounded ledger
 precisely so nobody multiplies metric names by scope; a snapshot whose
 name family (first two dot segments) holds more distinct series than
@@ -47,8 +41,6 @@ _METRIC_KEYS = {
                   "mean": (*_NUM, type(None)), "p50": (*_NUM, type(None)),
                   "p95": (*_NUM, type(None)), "p99": (*_NUM, type(None))},
 }
-SUMMARY_REQUIRED_KEYS = ("metric", "value", "unit", "vs_baseline")
-SUMMARY_MAX_CHARS = 1500
 # cardinality bound per name family in one snapshot: generously above
 # any legitimate tpudl prefix (serve.* tops out around a dozen), far
 # below what per-tenant name-minting produces
@@ -118,33 +110,6 @@ def validate_metrics_file(path: str):
     if n == 0:
         errors.append(f"{path}: no JSONL lines")
     return errors, n, last
-
-
-def validate_bench_summary_line(line: str) -> list[str]:
-    """Errors in the bench's judged last-line summary (empty = valid)."""
-    errs = []
-    if len(line) >= SUMMARY_MAX_CHARS:
-        errs.append(f"summary line is {len(line)} chars "
-                    f"(contract: < {SUMMARY_MAX_CHARS})")
-    try:
-        obj = json.loads(line)
-    except json.JSONDecodeError as e:
-        return errs + [f"summary line is not JSON ({e})"]
-    if not isinstance(obj, dict):
-        return errs + ["summary line is not a JSON object"]
-    for key in SUMMARY_REQUIRED_KEYS:
-        if key not in obj:
-            errs.append(f"summary missing required key {key!r}")
-    if "value" in obj and not isinstance(obj["value"], (*_NUM, type(None))):
-        errs.append(f"summary value={obj['value']!r} is not number|null")
-    for k, v in obj.items():
-        if isinstance(v, list):
-            if not all(isinstance(x, _NUM) for x in v):
-                errs.append(f"summary key {k!r}: list holds non-scalars")
-        elif isinstance(v, dict):
-            errs.append(f"summary key {k!r}: nested object "
-                        "(contract: one level, scalars only)")
-    return errs
 
 
 def unknown_sink_names(metrics: dict) -> list[str]:

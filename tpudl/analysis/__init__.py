@@ -27,7 +27,7 @@ Three pieces (ANALYSIS.md):
   :mod:`tpudl.testing.traceck` (``TPUDL_TRACECK=1`` recompile-storm
   sentinel).
 
-CLI: ``python -m tools.tpudl_check tpudl tools bench.py``
+CLI: ``python -m tools.tpudl_check tpudl tools``
 (exit 0 clean / 2 findings / 1 error; ``--rules`` / ``--json`` for
 selective machine-readable runs). Wired into run-tests.sh and tier-1
 via tests/test_analysis.py + tests/test_concurrency.py.

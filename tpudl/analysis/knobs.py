@@ -32,7 +32,7 @@ class Knob:
     kind: str        # int | float | bool | str | enum | path | json
     default: str     # rendered default ("" = unset / derived)
     subsystem: str   # frame | data | obs | jobs | train | zoo |
-                     # compile | serve | text | bench
+                     # compile | serve | text
     help: str        # one line, present tense
 
 
@@ -187,81 +187,6 @@ KNOBS: tuple[Knob, ...] = (
          "explicit comma list of rungs | unset/0 = off. Ragged "
          "dispatch shapes pad to the nearest rung so the workload "
          "runs through O(log n) compiled programs"),
-    # -- bench (bench.py header) ---------------------------------------
-    Knob("TPUDL_BENCH_BUDGET_S", "float", "2400", "bench",
-         "soft wall-clock budget; remaining sub-benches skip past it"),
-    Knob("TPUDL_BENCH_DEADLINE_S", "float", "3300", "bench",
-         "hard watchdog backstop: dump + emit the partial summary"),
-    Knob("TPUDL_BENCH_SUBBENCH_FRAC", "float", "0.5", "bench",
-         "max fraction of the remaining budget one sub-bench may spend"),
-    Knob("TPUDL_BENCH_QUICK", "bool", "0", "bench",
-         "1 runs the headline config only with shrunk trial counts"),
-    Knob("TPUDL_BENCH_DTYPE", "str", "bfloat16", "bench",
-         "compute dtype for the featurize benches"),
-    Knob("TPUDL_BENCH_BATCH", "int", "256", "bench",
-         "featurize batch size"),
-    Knob("TPUDL_BENCH_N", "int", "1024", "bench",
-         "featurize row count"),
-    Knob("TPUDL_BENCH_TRIALS", "int", "2", "bench",
-         "trials per arm (sync-mode phase)"),
-    Knob("TPUDL_BENCH_STREAM_TRIALS", "int", "4", "bench",
-         "streaming-phase subprocess trials per arm (0 disables; 1 "
-         "when quick)"),
-    Knob("TPUDL_BENCH_STREAM_BUDGET_S", "float", "1500", "bench",
-         "streaming phase: stop starting trials past this wall-clock"),
-    Knob("TPUDL_BENCH_TRIAL_TIMEOUT_S", "float", "450", "bench",
-         "per-subprocess trial kill timeout"),
-    Knob("TPUDL_BENCH_SKIP_BASELINE", "bool", "0", "bench",
-         "1 skips the TF-CPU baseline side"),
-    Knob("TPUDL_BENCH_RECORD_NAME", "str", "BENCH_r05_full", "bench",
-         "basename for the full record written to bench_records/"),
-    Knob("TPUDL_BENCH_COMPUTE_ITERS", "int", "8", "bench",
-         "compute-only sub-bench iterations"),
-    Knob("TPUDL_BENCH_COMPUTE_BATCH", "int", "256", "bench",
-         "compute-only sub-bench batch size"),
-    Knob("TPUDL_BENCH_CURVE_STEPS", "int", "120", "bench",
-         "training-curve sub-bench step count"),
-    Knob("TPUDL_BENCH_CURVE_BATCH", "int", "32", "bench",
-         "training-curve sub-bench batch size"),
-    Knob("TPUDL_BENCH_TRAIN_BATCH", "int", "64", "bench",
-         "horovod-train sub-bench batch size"),
-    Knob("TPUDL_BENCH_TRAIN_STEPS", "int", "10", "bench",
-         "horovod-train sub-bench step count"),
-    Knob("TPUDL_BENCH_MLP_ROWS", "int", "65536", "bench",
-         "keras-transformer MLP sub-bench row count"),
-    Knob("TPUDL_BENCH_PRED_N", "int", "512", "bench",
-         "predictor sub-bench image count"),
-    Knob("TPUDL_BENCH_EST_INC_FILES", "int", "96", "bench",
-         "incremental-estimator sub-bench file count"),
-    Knob("TPUDL_BENCH_EST_INC_BATCH", "int", "16", "bench",
-         "incremental-estimator sub-bench batch size"),
-    Knob("TPUDL_BENCH_DECODE_N", "int", "256", "bench",
-         "decode sub-bench image count"),
-    Knob("TPUDL_BENCH_DATA_N", "int", "512", "bench",
-         "data-pipeline sub-bench row count"),
-    Knob("TPUDL_BENCH_HBM_N", "int", "512", "bench",
-         "device-cache sub-bench row count (epoch-1 cold vs epoch-2 "
-         "resident)"),
-    Knob("TPUDL_BENCH_DATA_FILES", "int", "192", "bench",
-         "data-pipeline cache sub-bench file count"),
-    Knob("TPUDL_BENCH_FAULT_N", "int", "512", "bench",
-         "fault-recovery sub-bench row count (clean vs "
-         "injected-fault+recovery arms)"),
-    Knob("TPUDL_BENCH_ASYNC_N", "int", "768", "bench",
-         "async-dispatch A/B sub-bench row count"),
-    Knob("TPUDL_BENCH_ASYNC_DEPTH", "int", "4", "bench",
-         "async-dispatch A/B sub-bench depth-D arm window size"),
-    Knob("TPUDL_BENCH_MESH_N", "int", "1024", "bench",
-         "mesh-scaling sub-bench row count (virtual 8-device child)"),
-    Knob("TPUDL_BENCH_MESH2D_N", "int", "1024", "bench",
-         "2-D mesh sub-bench row count (8x1 vs 4x2 interleaved child)"),
-    Knob("TPUDL_BENCH_FLASH_SEQS", "str", "2048,4096,8192,16384",
-         "bench", "flash-attention sub-bench sequence-length ladder"),
-    Knob("TPUDL_BENCH_PREEMPT_STEPS", "int", "300", "bench",
-         "preemption sub-bench child-job step count"),
-    Knob("TPUDL_BENCH_COLD_N", "int", "256", "bench",
-         "cold-start sub-bench row count (empty- vs warmed-program-"
-         "store first-result subprocess A/B)"),
     # -- serve plane (SERVE.md) ----------------------------------------
     Knob("TPUDL_SERVE_QUEUE_CAP", "int", "64", "serve",
          "request-queue admission cap: past this depth submits get a "
@@ -276,15 +201,6 @@ KNOBS: tuple[Knob, ...] = (
     Knob("TPUDL_SERVE_HBM_MB", "float", "", "serve",
          "admission budget on QUEUED payload bytes (MB): submits past "
          "it get a typed hbm_budget reject (unset = off)"),
-    Knob("TPUDL_BENCH_SERVE_N", "int", "48", "bench",
-         "serve sub-bench total request count driven by the "
-         "closed-loop load generator"),
-    Knob("TPUDL_BENCH_SERVE_CLIENTS", "int", "4", "bench",
-         "serve sub-bench closed-loop client thread count (offered "
-         "concurrency)"),
-    Knob("TPUDL_BENCH_SERVE_P99_MS", "float", "2000", "bench",
-         "serve sub-bench p99 latency target (ms): sustained QPS is "
-         "judged only when the measured p99 meets it"),
     # -- serve telemetry (ISSUE 18: lifecycle traces + SLO engine) -----
     Knob("TPUDL_SERVE_TRACE", "bool", "1", "serve",
          "request lifecycle tracing: 0 disarms ReqTrace entirely "
@@ -311,20 +227,6 @@ KNOBS: tuple[Knob, ...] = (
          "TokenCodec wire dtype: u16|i32 (unset = auto: u16 when the "
          "vocab fits 65536 ids, else i32); an explicit codec arg "
          "always wins over the env"),
-    Knob("TPUDL_BENCH_LM_ROWS", "int", "192", "bench",
-         "lm_train sub-bench corpus row count (rounded down to full "
-         "frame batches for stable packed shapes)"),
-    Knob("TPUDL_BENCH_LM_SEQ", "int", "64", "bench",
-         "lm_train sub-bench packed sequence length (docs are sized "
-         "so each batch packs to exactly [batch, seq])"),
-    Knob("TPUDL_BENCH_LM_BATCH", "int", "32", "bench",
-         "lm_train sub-bench frame batch size (= packed rows per "
-         "train step)"),
-    Knob("TPUDL_BENCH_LM_PROMPTS", "int", "48", "bench",
-         "lm_generate sub-bench ragged prompt count (6 distinct "
-         "lengths cycled)"),
-    Knob("TPUDL_BENCH_LM_MAX_NEW", "int", "8", "bench",
-         "lm_generate sub-bench tokens generated per prompt"),
 )
 
 KNOB_NAMES = frozenset(k.name for k in KNOBS)

@@ -7,14 +7,13 @@ here, exactly once, so the name schema is reviewable in one place
 1. the static checker (rule ``undeclared-metric``): a literal (or
    f-string) name at a ``counter(...)``/``gauge(...)``/
    ``histogram(...)`` call site must match a declaration — dashboards
-   and the bench sentinel key on these strings, so an unreviewed
-   rename is a silent break;
+   key on these strings, so an unreviewed rename is a silent break;
 2. ``tools/validate_metrics.py``: the JSONL-sink validator can
    cross-check emitted names against this registry (opt-in
    ``--check-names`` — sink files may legitimately carry user-defined
    metrics);
 3. the round-trip test (tests/test_analysis.py): declared ⊆ used and
-   used ⊆ declared over ``tpudl/``, ``tools/``, ``bench.py``.
+   used ⊆ declared over ``tpudl/`` and ``tools/``.
 
 Families with a runtime-computed segment (``frame.stage.<name>.seconds``)
 are declared as patterns with exactly one ``*`` segment; the checker
@@ -393,8 +392,7 @@ METRICS: tuple[Metric, ...] = (
     Metric("lm.generate.requests", "counter",
            "prompts completed by LMGenerator transforms"),
     Metric("lm.generate.tokens", "counter",
-           "tokens generated by LMGenerator (post-EOS-trim; the "
-           "lm_generate bench rate numerator)"),
+           "tokens generated by LMGenerator (post-EOS-trim)"),
 )
 
 METRIC_NAMES = frozenset(m.name for m in METRICS if "*" not in m.name)

@@ -7,8 +7,8 @@ again every process start. JAX's persistent compilation cache
 (serialized executables keyed by HLO+flags+topology) removes the
 *compile* for repeat runs; the AOT program store
 (:mod:`tpudl.compile.store`) sits above it and removes the *trace* too.
-This module turns the JAX cache on; ``bench.py`` and ``chip_smoke.py``
-call it, library code never does.
+This module turns the JAX cache on; ``benchmark/run.py`` and
+``chip_smoke.py`` call it, library code never does.
 
 Where the cache lives is decided OUTSIDE the code: when
 ``JAX_COMPILATION_CACHE_DIR`` is set, jax itself reads it and this

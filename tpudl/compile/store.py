@@ -850,7 +850,7 @@ class ProgramStore:
 
     def drain(self, timeout: float | None = None) -> None:
         """Wait for every queued background compile/restore (tests,
-        and the bench child that must persist before exiting)."""
+        and a process that must persist before exiting)."""
         with self._lock:
             futs = list(self._futures)
         for f in futs:
@@ -870,7 +870,7 @@ _STORE_LOCK = _tsan.named_lock("compile.store.singleton")
 
 def get_program_store() -> ProgramStore:
     """The process-wide store at the CURRENT ``store_dir()`` (a changed
-    env — tests, bench children — transparently re-roots)."""
+    env — tests, child processes — transparently re-roots)."""
     global _STORE
     root = store_dir()
     with _STORE_LOCK:

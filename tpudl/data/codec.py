@@ -22,8 +22,8 @@ Selection: pass a :class:`WireCodec`, a name (``"u8"``, ``"bf16"``,
 ``"identity"``), or ``"auto"`` — auto picks from the first packed
 batch's DTYPE, never its values (the pick is pinned for the run):
 uint8 → ``u8``; float32 → ``bf16`` on a slow wire, identity on a fast
-one, using the same bare-``device_put`` probe bench.py's wire
-sub-bench runs (threshold ``TPUDL_DATA_BF16_WIRE_MBPS``).
+one, judged by :func:`probe_wire_mbps`, a bare ``device_put`` of one
+buffer (threshold ``TPUDL_DATA_BF16_WIRE_MBPS``).
 ``"u8"`` by name infers its scale from the first batch and REFUSES
 non-exact batches — strictness by request. ``TPUDL_WIRE_CODEC`` is the
 process-wide default ``Frame.map_batches`` falls back to.
@@ -261,8 +261,8 @@ _WIRE_MBPS_LOCK = _tsan.named_lock("data.codec.wire_probe")
 
 
 def probe_wire_mbps(mb: int = 4) -> float | None:
-    """H2D bandwidth of the default backend in MB/s — the same bare
-    ``device_put`` probe bench.py's ``measure_wire_bandwidth`` runs,
+    """H2D bandwidth of the default backend in MB/s — a bare
+    ``device_put`` of one contiguous buffer, waited for,
     sized small (4 MB) and cached per process so 'auto' codec selection
     costs one probe, ever. ``TPUDL_WIRE_MBPS`` overrides (tests, and
     operators who already know their link). None when probing fails —

@@ -670,7 +670,7 @@ class Frame:
         plain-python wrapper around a jitted call; the executor warns
         once when a "host" fn returns device arrays.
         ``TPUDL_FRAME_PREFETCH=0`` force-disables the whole pipelined
-        executor — prefetch AND fusion — for the bench A/B arm.
+        executor — prefetch AND fusion (the serial arm of an A/B).
 
         The ``tpudl.data`` knobs (DATA.md has the operator guide):
 
@@ -874,7 +874,7 @@ class Frame:
             # (sharded jax arrays are futures too — ISSUE 11); host fns
             # stay serial (their in-place mutations would race on the
             # pool), and the kill switches must yield the serial
-            # executor (bench A/B arms)
+            # executor (the serial arm of an A/B)
             d_depth = 1
         donate_flag = (bool(donate) if donate is not None
                        else os.environ.get("TPUDL_FRAME_DONATE", "1")

@@ -224,7 +224,7 @@ class PipelineReport:
                 inflight.to_dict()["mean"])
         # mesh-path waste accounting (ISSUE 11): rows of SPMD padding
         # this run shipped and computed only to throw away — the
-        # mesh_scaling bench and the roofline read these
+        # roofline reads these
         if self.config.get("mesh"):
             with self._lock:
                 pad = int(self.calls.get("pad_rows", 0))
@@ -234,9 +234,9 @@ class PipelineReport:
                     100.0 * pad / (int(rows) + pad))
             # 2-D grid truth (ISSUE 16): the model-axis size the run
             # actually executed under — 1 on a data-parallel mesh, >1
-            # when tensor-parallel params were resident. obs top and
-            # the mesh_2d bench read this to prove the second axis was
-            # armed, not silently collapsed to 1-D.
+            # when tensor-parallel params were resident. obs top
+            # reads this to prove the second axis was armed, not
+            # silently collapsed to 1-D.
             _metrics.gauge("frame.mesh.model_axis").set(
                 int(self.config["mesh"].get("model") or 1))
         # serve-session truth (ISSUE 17): a serve run's report commits
@@ -298,7 +298,8 @@ _REPORTS_LOCK = _tsan.named_lock("obs.pipeline.ring")
 
 def set_last_pipeline(report: PipelineReport | None):
     """Filed by ``Frame.map_batches`` at the start of every run, so the
-    caller above any transformer stack (bench.py, a notebook) can read
+    caller above any transformer stack (``benchmark/adapters/``, a
+    notebook) can read
     the executor's stage breakdown without threading a handle through
     the transformer APIs. Reports live in a bounded ring keyed by run
     id — concurrent runs no longer clobber each other (each stays

@@ -73,8 +73,8 @@ class RooflineReport:
     - ``d2h_s``              measured outfeed drain;
     - ``collective_s``       model-axis communication on a 2-D mesh
       (the tensor-parallel all-reduce/reduce-scatter share of the
-      dispatch window — supplied per dispatch by a profile or the
-      mesh_2d bench's arm delta; 0 on 1-D grids);
+      dispatch window — supplied per dispatch by a profile or a
+      measured TP-vs-DP arm delta; 0 on 1-D grids);
     - ``other_s``            wall minus all of the above (host glue).
 
     ``gap_attribution`` maps each non-compute component to its fraction
@@ -179,7 +179,7 @@ def analyze(report: dict | None = None, *,
     ``bytes_prepared`` overrides the executor's own byte accounting.
     ``collective_ms_per_dispatch`` is the model-axis communication time
     of ONE dispatch (a profile's ICI all-reduce/reduce-scatter total,
-    or the mesh_2d bench's measured TP-vs-DP arm delta); it carves a
+    or a measured TP-vs-DP arm delta); it carves a
     ``collective`` component out of the dispatch residue — only
     honored when the report ran on a mesh whose ``model`` axis is >1
     (on a 1-D grid there is no model-axis traffic to attribute).

@@ -4,7 +4,7 @@ The demand side of the serving SLOs (SERVE.md): ``clients`` threads
 each keep exactly one request in flight (the closed-loop discipline —
 offered load tracks service rate, so the measured QPS is SUSTAINED
 throughput, not an open-loop fantasy), and the run reports the SLO
-truths the bench judges: sustained QPS, p50/p99 end-to-end latency,
+truths: sustained QPS, p50/p99 end-to-end latency,
 TTFT percentiles, rejects and deadline sheds.
 
 Two chaos points make overload testable under ``TPUDL_FAULT_PLAN``:
@@ -62,9 +62,9 @@ def run_closed_loop(server, make_prompt, *, requests: int,
 
     ``tenant`` stamps the generated requests with an attribution scope
     (tpudl.obs.attribution): a string tags every client with that
-    tenant; a sequence assigns client ``c`` the ``c % len``-th entry —
-    the two-tenant serve sub-bench drives attribution end to end with
-    ``tenant=("a", "b")``. None leaves requests unattributed."""
+    tenant; a sequence assigns client ``c`` the ``c % len``-th entry
+    (``tenant=("a", "b")`` alternates two tenants). None leaves
+    requests unattributed."""
     # one leaf lock for every tally: the critical sections are scalar
     # bumps/list appends and never nest with the server's locks
     lock = _tsan.named_lock("serve.loadgen")

@@ -5,8 +5,8 @@ model's :class:`~tpudl.serve.slots.SlotDecoder` and — when the AOT
 store is armed — restores the persisted program table
 (``ensure_restored(block=True)``) and submits every serve-loop
 signature through ``precompile_serve``. A previously-served model's
-first token is then a DESERIALIZATION away, not a 60-second jit; the
-``bench serve`` warm arm pins the ratio (``serve_warm_ttft_s``).
+first token is then a DESERIALIZATION away, not a 60-second jit (not
+measured on the chip).
 
 One instance lock (``serve.registry``) guards the name→entry map;
 the ``serve.models`` gauge publishes outside it.

@@ -163,10 +163,6 @@ class SlotDecoder:
         fill = self.model._slot_prefill_program(
             rung, self.slots, self.cache_len, self.temperature,
             mesh=self.mesh, tp=self.tp)
-        # tpudl: ignore[daemon-shared-write] — single-consumer engine:
-        # insert and step only ever run on the one thread driving the
-        # serve loop (the server's daemon thread, or the caller's in
-        # synchronous run()); the cache never has two writers
         first, self._cache = self._call(fill, (
             self.params, self._cache, jnp.asarray(padded),
             jnp.asarray(key), jnp.asarray(plen, jnp.int32),

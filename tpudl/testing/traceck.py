@@ -32,8 +32,7 @@ Findings:
   ``dispatch_slowdown``.
 
 Like the lock sanitizer, the armed sentinel taxes the numbers (every
-trace takes the bookkeeping hop), so bench.py refuses judged rounds
-with it armed and stamps ``traceck_armed`` on the summary line.
+trace takes the bookkeeping hop): a measured run keeps it off.
 
 Unarmed — the default — this module is never imported by product code
 and ``jax.jit`` is untouched: the hot path pays literally nothing.
@@ -68,8 +67,7 @@ _REAL_JIT = None
 
 
 def enabled() -> bool:
-    """Is the sentinel armed right now? (bench.py's judged rounds
-    assert this is False and record it on the summary line)."""
+    """Is the sentinel armed right now?"""
     return ENABLED
 
 

@@ -117,8 +117,7 @@ _ATEXIT_DONE = False
 
 
 def enabled() -> bool:
-    """Is the sanitizer armed right now? (bench.py's judged rounds
-    assert this is False and record it on the summary line)."""
+    """Is the sanitizer armed right now?"""
     return ENABLED
 
 

@@ -19,8 +19,8 @@ from here but mirrors the fingerprint math; the prepare pool runs
 Two concrete tokenizers cover the judged workloads:
 
 - :class:`ByteTokenizer` — UTF-8 bytes shifted past the specials;
-  vocab 260, lossless round-trip, zero build cost. The LM bench family
-  and the examples ride it.
+  vocab 260, lossless round-trip, zero build cost. The tests and the
+  examples ride it.
 - :class:`WordTokenizer` — a corpus-built word/punct vocab, sorted by
   (-count, token) so the SAME corpus always yields the SAME ids; OOV
   maps to ``<unk>``. Lossy decode (single-space join), documented.
@@ -148,7 +148,7 @@ class Tokenizer:
 
 class ByteTokenizer(Tokenizer):
     """UTF-8 bytes shifted past the 4 specials — vocab 260, lossless,
-    build-free; the deterministic default for benches and examples."""
+    build-free; the deterministic default for tests and examples."""
 
     mode = "byte"
 
