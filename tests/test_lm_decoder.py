@@ -44,7 +44,7 @@ BASE = dict(hidden_size=64, intermediate_size=96, moe_intermediate_size=48,
             num_attention_heads=4, num_key_value_heads=2, num_experts=8,
             num_experts_per_tok=2, experts_held=(2, 4), vocab_size=512,
             vocab_slice=(0, 128), norm_eps=1e-5, rope_theta=1e6,
-            conv_L_cache=3, attention_block_q=8, attention_block_k=8)
+            conv_L_cache=3)
 REF = dict(top_k=2, held_first=2, attention_rows=8)
 STACKS = {
     "conv+dense": (["conv"], 1),
@@ -115,6 +115,22 @@ def test_bfloat16_gradients_on_the_programs_routes(stack):
         if not name.endswith("expert_bias"):
             assert got[name].dtype == jnp.float32
             assert rel(got[name], want[name]) < 0.08, name
+
+
+def test_tile_shape_keys_in_a_configuration_are_data_nothing_reads():
+    """The cell's file still carries ``attention_block_q`` /
+    ``attention_block_k`` (and its rehearsal block other values): a tile
+    shape is the kernels' to derive, so the same decoder, the same
+    parameters and the same loss, bit for bit, with the keys or without."""
+    plain, p = build("whole")
+    keyed, p_keyed = build("whole", attention_block_q=512,
+                           attention_block_k=512)
+    assert vars(keyed).keys() == vars(plain).keys()
+    assert set(p) == set(p_keyed)
+    assert all(np.array_equal(p[name], p_keyed[name]) for name in p)
+    ids = tokens()
+    assert (float(jax.jit(plain.loss_fn())(p, ids))
+            == float(jax.jit(keyed.loss_fn())(p, ids)))
 
 
 def test_a_run_of_identical_layers_is_one_scanned_body():
@@ -617,7 +633,11 @@ def lm_train():
                  "benchmark_adapter_lm_train_for_tests")
 
 
-LIMITS = {"grad_rel_l2": {g: 0.08 for g in (
+# the gradient limit lies between two readings at these toy widths, over
+# eight token seeds: clean bfloat16 0.024-0.043 by group, the experts under
+# the scaled-fp8 product 0.077-0.083 (0.08 sat inside that spread, and the
+# case turned on the seed's rounding)
+LIMITS = {"grad_rel_l2": {g: 0.055 for g in (
     "experts", "routers", "conv", "attention", "dense_ff", "table",
     "norms")}, "loss_rel": 0.002, "route_agreement_min": 0.9,
     "update_rel_l2": 3e-4, "moment2_rel_l2": 1e-3}

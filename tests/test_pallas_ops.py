@@ -226,3 +226,230 @@ class TestGroupedQueries:
             flash_attention(q[:, :, :7], k, v, interpret=True)
         with pytest.raises(ValueError, match="query heads"):
             flash_attention(q, k, v[:, :, :1], interpret=True)
+
+
+def _dense(q, k, v, *, causal, q_offset=0, k_offset=0):
+    """The dense oracle with global positions: (out, lse), a row with no
+    visible key reported as the kernels report it (zeros, lse -1e30)."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(a, group, axis=2) for a in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   precision="highest") / q.shape[-1] ** 0.5
+    if causal:
+        seen = ((q_offset + jnp.arange(q.shape[1]))[:, None]
+                >= (k_offset + jnp.arange(k.shape[1]))[None, :])
+        s = jnp.where(seen, s, -jnp.inf)
+    m = s.max(-1, keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    p = jnp.exp(s - m)
+    l = p.sum(-1, keepdims=True)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p / jnp.where(l == 0, 1.0, l), v,
+                     precision="highest")
+    lse = jnp.where(l == 0, -1e30, m + jnp.log(jnp.where(l == 0, 1.0, l)))
+    return out, lse[..., 0].transpose(0, 2, 1)
+
+
+def _weighted(fn, shape_q, heads):
+    """A scalar of BOTH outputs, so the cotangents of out and of lse
+    reach every class of tile; masked rows' lse (a constant) left out."""
+    w = jnp.cos(jnp.arange(np.prod(shape_q), dtype=jnp.float32)
+                ).reshape(shape_q)
+    u = jnp.sin(jnp.arange(np.prod(shape_q[:3]), dtype=jnp.float32)
+                ).reshape(shape_q[:3])
+
+    def loss(q, k, v):
+        out, lse = fn(q, k, v)
+        return (jnp.sum(out * w)
+                + jnp.sum(jnp.where(lse > -1e29, lse, 0.0) * u))
+    return loss
+
+
+# name: (Sq, Sk, block_q, block_k, causal, q_offset, k_offset),
+#       (interior, crossing, dead) tiles a head
+TILE_CASES = {
+    "every tile interior": ((32, 32, 16, 16, True, 64, 0), (4, 0, 0)),
+    "every tile crossing": ((64, 64, 16, 64, True, 0, 0), (0, 4, 0)),
+    "whole rows of dead tiles": ((64, 32, 16, 16, True, 0, 32), (1, 2, 5)),
+    "block_q over block_k": ((64, 64, 32, 16, True, 0, 0), (2, 4, 2)),
+    "block_k over block_q": ((64, 64, 16, 32, True, 0, 0), (2, 4, 2)),
+    "pad in an interior row": ((32, 40, 16, 16, True, 64, 0), (4, 2, 0)),
+    "pad without a mask": ((32, 40, 16, 16, False, 0, 0), (4, 2, 0)),
+    "no mask at all": ((32, 32, 16, 16, False, 0, 0), (4, 0, 0)),
+}
+
+
+class TestTileClasses:
+    """Interior tiles run a body with no mask, no no-key guard and no
+    ``alive`` factor; dead tiles run nothing and fetch nothing: each
+    where it can go wrong, forward and gradients against the dense
+    oracle, and the per-trace counter beside them."""
+
+    @pytest.mark.parametrize("case", list(TILE_CASES))
+    def test_forward_and_gradients_match_dense(self, rng, case):
+        from tpudl import obs
+
+        (sq, sk, bq, bk, causal, qo, ko), want_tiles = TILE_CASES[case]
+        q = jnp.asarray(rng.normal(size=(2, sq, 4, 16)), jnp.float32)
+        k, v = (jnp.asarray(rng.normal(size=(2, sk, 2, 16)), jnp.float32)
+                for _ in range(2))
+        kw = dict(causal=causal, q_offset=qo, k_offset=ko)
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, block_q=bq, block_k=bk,
+                                   interpret=True, return_lse=True, **kw)
+
+        def tiles():
+            snap = obs.snapshot("pallas.flash.tiles.")
+            return [snap.get(f"pallas.flash.tiles.{c}", {"value": 0})["value"]
+                    for c in ("interior", "crossing", "dead")]
+
+        before = tiles()
+        (out, lse), (want_out, want_lse) = flash(q, k, v), _dense(
+            q, k, v, **kw)
+        assert tuple(a - b for a, b in zip(tiles(), before)) == want_tiles
+        np.testing.assert_allclose(out, want_out, rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(lse, want_lse, rtol=2e-5, atol=2e-5)
+        if case == "whole rows of dead tiles":
+            np.testing.assert_array_equal(np.asarray(out[:, :32]), 0.0)
+            assert np.all(np.asarray(lse[:, :32]) < -1e29)
+        got = jax.grad(_weighted(flash, q.shape, 4), (0, 1, 2))(q, k, v)
+        want = jax.grad(_weighted(lambda *a: _dense(*a, **kw), q.shape, 4),
+                        (0, 1, 2))(q, k, v)
+        for g, w_ in zip(got, want):
+            np.testing.assert_allclose(g, w_, rtol=1e-4, atol=1e-4)
+
+    def test_traced_offsets_give_the_bits_of_static_ones(self, rng):
+        """The ring passes its offsets as traced values: the same scalar
+        predicate from SMEM, so the same tiles run the same bodies."""
+        q, k, v = (jnp.asarray(rng.normal(size=(1, 64, 2, 16)), jnp.float32)
+                   for _ in range(3))
+
+        def run(qo, ko):
+            def f(q, k, v):
+                return flash_attention(
+                    q, k, v, causal=True, q_offset=qo, k_offset=ko,
+                    block_q=16, block_k=32, interpret=True,
+                    return_lse=True)
+            loss = _weighted(f, q.shape, 2)
+            return f(q, k, v) + jax.grad(loss, (0, 1, 2))(q, k, v)
+
+        static = run(16, 32)
+        traced = jax.jit(run)(jnp.asarray(16, jnp.int32),
+                              jnp.asarray(32, jnp.int32))
+        for a, b in zip(static, traced):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("head_dim, lse_limit", [(64, 3e-3),
+                                                     (128, 1e-5)])
+    def test_bfloat16_out_lse_and_ring_merge_against_dense(
+            self, rng, head_dim, lse_limit):
+        """bfloat16 operands. Under a 128-wide head the normaliser comes
+        out of ``p @ [V | 1]``: the returned lse is the log of the sum of
+        the bfloat16-ROUNDED weights, at most a rounding (2^-9) from the
+        float32 sum's and 1.0e-3 to 1.3e-3 over four seeds here; a
+        128-wide head has no spare column and keeps the float32 sum
+        (1e-6). The output carries the same 1.9e-3 either way (its own
+        rounding and the weights'), and so does what the ring makes of
+        two K/V blocks merged through their lse."""
+        q = jnp.asarray(rng.normal(size=(1, 256, 4, head_dim)), jnp.bfloat16)
+        k, v = (jnp.asarray(rng.normal(size=(1, 256, 2, head_dim)),
+                            jnp.bfloat16) for _ in range(2))
+        want, want_lse = _dense(*(a.astype(jnp.float32) for a in (q, k, v)),
+                                causal=True)
+        kw = dict(causal=True, block_q=64, block_k=64, interpret=True,
+                  return_lse=True)
+
+        def rel(got):
+            got = np.asarray(got, np.float64)
+            return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+        out, lse = flash_attention(q, k, v, **kw)
+        assert out.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+        assert rel(out) < 4e-3
+        assert np.abs(np.asarray(lse) - want_lse).max() < lse_limit
+        # the ring's merge: rows 0..127 see nothing of the second block,
+        # which reports them masked (zeros, lse -1e30: weight 0)
+        o1, l1 = flash_attention(q, k[:, :128], v[:, :128], **kw)
+        o2, l2 = flash_attention(q, k[:, 128:], v[:, 128:], k_offset=128,
+                                 **kw)
+        m = jnp.maximum(l1, l2)
+        w1, w2 = jnp.exp(l1 - m)[..., None], jnp.exp(l2 - m)[..., None]
+        assert rel((o1 * w1 + o2 * w2) / (w1 + w2)) < 4e-3
+        merged_lse = m + jnp.log(w1 + w2)[..., 0]
+        assert np.abs(np.asarray(merged_lse) - want_lse).max() < lse_limit
+
+    @pytest.mark.parametrize("s", [257, 300, 520, 650, 1000, 2047, 2560,
+                                   8192])
+    def test_a_derived_tile_is_a_multiple_of_the_lane_tile(self, s):
+        """Compiled, a block is a multiple of 128 lanes or Mosaic refuses
+        the lse block beside it: at every length, awkward ones too, and
+        the padded length is a multiple of the block."""
+        from tpudl.pallas_ops import tile_shapes
+
+        block_q, block_k, _ = tile_shapes(s, s + 3, 4)
+        assert block_q % 128 == 0 and block_k % 128 == 0
+        assert block_q == min(1024, s + (-s % 128))
+        assert block_k == min(1024, s + 3 + (-(s + 3) % 128))
+
+    @pytest.mark.parametrize("heads, kv_heads", [(8, 2), (2, 2)])
+    def test_grouped_queries_at_the_derived_tile_shape(self, rng, heads,
+                                                       kv_heads):
+        """No block argument, as the decoder calls it: LFM2's four query
+        heads of 64 to a key/value head (four heads a grid step; its 32
+        over 8 are four times the rows of the same grid, compiled in
+        test_tpu_compile.py and counted below), and group 1, over a
+        length that takes several derived tiles of every class."""
+        from tpudl.pallas_ops import tile_counts, tile_shapes
+
+        s = 1536
+        tiles = tile_shapes(s, s, heads // kv_heads, align=1)
+        assert tiles == (1024, 1024, 4 if heads > kv_heads else 1)
+        assert min(tile_counts(s, s, 1024, 1024,
+                               causal=True).values()) > 0
+        q = jnp.asarray(rng.normal(size=(1, s, heads, 64)), jnp.float32)
+        k, v = (jnp.asarray(rng.normal(size=(1, s, kv_heads, 64)),
+                            jnp.float32) for _ in range(2))
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal=True, interpret=True,
+                                   return_lse=True)
+
+        got = jax.jit(jax.value_and_grad(
+            _weighted(flash, q.shape, heads), (0, 1, 2)))(q, k, v)
+        want = jax.jit(jax.value_and_grad(_weighted(
+            lambda *a: _dense(*a, causal=True), q.shape, heads),
+            (0, 1, 2)))(q, k, v)
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
+        for g, w_ in zip(got[1], want[1]):
+            np.testing.assert_allclose(g, w_, rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("blocks, shape, tiles", [
+        ((512, 512), (512, 512), (120, 16, 120)),   # the cell before PR 31
+        (None, (1024, 1024), (28, 8, 28))])         # what the kernels derive
+    def test_counter_at_the_cells_shape(self, blocks, shape, tiles):
+        """``pallas.flash.*`` per TRACE at four sequences of 8,192 tokens,
+        32 query heads over 8 of 64, bfloat16: the tile shape and the
+        tiles a head by class (a constant of the shapes: nothing runs
+        here)."""
+        from tpudl import obs
+
+        def snap():
+            return {n: m["value"]
+                    for n, m in obs.snapshot("pallas.flash.").items()}
+
+        kw = {} if blocks is None else dict(block_q=blocks[0],
+                                            block_k=blocks[1])
+        q = jax.ShapeDtypeStruct((4, 8192, 32, 64), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((4, 8192, 8, 64), jnp.bfloat16)
+        before = snap()
+        jax.eval_shape(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=False, **kw), q, kv, kv)
+        after = snap()
+        assert after["pallas.flash.launches"] - before.get(
+            "pallas.flash.launches", 0) == 1
+        for cls, n in zip(("interior", "crossing", "dead"), tiles):
+            name = f"pallas.flash.tiles.{cls}"
+            assert after[name] - before.get(name, 0) == n
+        assert (after["pallas.flash.block_q"],
+                after["pallas.flash.block_k"]) == shape
+        assert after["pallas.flash.heads_a_step"] == 4

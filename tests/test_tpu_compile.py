@@ -45,26 +45,43 @@ def no_compile_cache():
     cc.reset_cache()
 
 
-@pytest.mark.parametrize("seq, blocks", [(8192, (512, 512)),
-                                         (2047, (128, 128))])
+@pytest.mark.parametrize("seq, heads, head_dim, dtype, blocks", [
+    (8192, (32, 8), 64, "bfloat16", (512, 512)),
+    (2047, (32, 8), 64, "bfloat16", (128, 128)),
+    # no block argument, as the decoder and zoo/transformer.py call it:
+    # the shapes pallas_ops.tile_shapes derives
+    (8192, (32, 8), 64, "bfloat16", None),       # the LM cell's
+    (2047, (32, 8), 64, "bfloat16", None),       # PR 21's awkward length
+    (2048, (16, 16), 128, "bfloat16", None),     # a head as wide as a lane
+    (2048, (16, 4), 128, "float32", None),       # the most VMEM a tile asks
+    # lengths under and between tile multiples: one tile of the padded
+    # length (640, 384), whose lse block Mosaic takes only lane-aligned
+    (520, (32, 8), 64, "bfloat16", None),
+    (300, (8, 8), 64, "bfloat16", None),
+])
 def test_flash_attention_with_grouped_queries_compiles_for_the_v5e(
-        one_chip, no_compile_cache, seq, blocks):
+        one_chip, no_compile_cache, seq, heads, head_dim, dtype, blocks):
     """LFM2-8B-A1B's attention: 32 query heads over 8 key/value heads of
     64, bfloat16, forward and both backward kernels, at 8,192 tokens in
-    512 x 512 tiles and at an awkward length (PR 21's S = 2,047)."""
+    512 x 512 tiles and at an awkward length (PR 21's S = 2,047); and
+    the tile shapes the kernels derive themselves, so that a derived
+    shape that outgrows VMEM, or a tile body Mosaic refuses, fails here
+    and not on the chip."""
     import jax
     import jax.numpy as jnp
 
     from tpudl.pallas_ops import flash_attention
 
+    kw = {} if blocks is None else dict(block_q=blocks[0],
+                                        block_k=blocks[1])
+
     def loss(q, k, v):
-        out = flash_attention(q, k, v, causal=True, block_q=blocks[0],
-                              block_k=blocks[1], interpret=False)
+        out = flash_attention(q, k, v, causal=True, interpret=False, **kw)
         return jnp.sum(out.astype(jnp.float32))
 
-    q = jax.ShapeDtypeStruct((1, seq, 32, 64), jnp.bfloat16,
+    q = jax.ShapeDtypeStruct((1, seq, heads[0], head_dim), dtype,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, seq, 8, 64), jnp.bfloat16,
+    kv = jax.ShapeDtypeStruct((1, seq, heads[1], head_dim), dtype,
                               sharding=one_chip)
     compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
         q, kv, kv).compile()

@@ -207,6 +207,28 @@ METRICS: tuple[Metric, ...] = (
            "forward/backward pair that puts the experts' rows back at "
            "their tokens slot by slot (4 per trace of lfm2-8b-a1b-ep4; "
            "none in a dense decoder)"),
+    # -- flash kernels (counted per call of flash_attention, which under
+    # jax.jit is per TRACE of the caller's program, not per step) -------
+    Metric("pallas.flash.launches", "counter",
+           "flash_attention calls traced: each launches the forward "
+           "kernel and, under a gradient, dq and dk/dv (1 per trace of "
+           "lfm2-8b-a1b-ep4's loss: one attention layer)"),
+    Metric("pallas.flash.tiles.*", "counter",
+           "the (Q, K) tiles a head by class, where the "
+           "offsets are known while the program is traced: interior "
+           "(every pair visible: no mask is built), crossing (the "
+           "diagonal or padded keys: the masked body), dead (nothing "
+           "computed, nothing fetched); 28 / 8 / 28 at S = 8,192 in the "
+           "derived 1,024 x 1,024 tiles, 120 / 16 / 120 in 512 x 512"),
+    Metric("pallas.flash.block_q", "gauge",
+           "rows of the tile the last traced call runs its three kernels "
+           "at: tile_shapes' derivation (1,024 clipped to the sequence) "
+           "or the caller's block_q"),
+    Metric("pallas.flash.block_k", "gauge",
+           "columns (keys) of that tile"),
+    Metric("pallas.flash.heads_a_step", "gauge",
+           "query heads of one key/value head the last traced call "
+           "takes in one grid step (4 for 32 heads over 8)"),
     # -- routed experts (published by Decoder.route_stats, outside steps)
     Metric("moe.pairs_held", "counter",
            "(token, expert) pairs routed to experts this rank holds, "

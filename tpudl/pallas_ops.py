@@ -2,7 +2,7 @@
 
 Flash attention in Pallas: tiled ``softmax(QKᵀ/√d)·V`` that never
 materializes the full score matrix — Q/K/V tiles stream HBM→VMEM per
-grid step, scores hit the MXU via ``jnp.dot(..,
+grid step, scores hit the MXU via ``dot_general(..,
 preferred_element_type=f32)``, and the online-softmax state (running
 max, normalizer, weighted accumulator) lives in VMEM scratch that
 persists across the innermost (K-tile) grid dimension. Peak VMEM is
@@ -18,6 +18,29 @@ ring/flash-decoding partial-softmax merge.
 causal masking stays correct when the caller holds only a shard of the
 sequence (the ring case).
 
+**A tile does only what its position requires.** The offsets ride in
+SMEM (scalar prefetch), and each (Q, K) tile is one of three classes,
+decided by a scalar predicate from them (:func:`_tile_class`), so a
+traced offset takes the same path as a static one:
+
+* *dead* — no visible pair under the causal mask: no compute, and no
+  DMA either, because the index maps clamp a dead step to the tile the
+  neighbouring live step already holds;
+* *crossing* — the diagonal passes through it, or it holds padded keys:
+  the masked body (iota, compare, select, the no-key guard);
+* *interior* — every pair visible: matrix products, max, exp and the
+  accumulation, nothing else.
+
+**What the shapes allow is decided from the shapes** (never by a
+caller): the scale rides on the query rows, outside the kernels, when
+it is a power of two (exact in any float type), the forward's row sum
+rides in the spare MXU output columns of ``p @ v`` when the head is
+narrower than a lane tile, the query heads that share a K/V head are
+taken ``heads`` at a time in one grid step against one K/V tile, dk/dv
+work on the transposed score tile ``k qᵀ`` (no ``[block_q, block_k]``
+plane is ever transposed), and the tile shape comes from
+:func:`tile_shapes`.
+
 CPU/tests run the same kernel with ``interpret=True`` (pure jax
 semantics, no tiling constraints). Compiled, every block is a multiple
 of the 128-lane tile: a sequence that is not is PADDED up to the next
@@ -29,196 +52,438 @@ that outgrows VMEM.
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attention"]
+from tpudl.obs import metrics as _metrics
+
+__all__ = ["flash_attention", "tile_shapes", "tile_counts"]
 
 _NEG_INF = -1e30  # finite -inf stand-in: exp(x - _NEG_INF) never NaNs
+_LANES = 128
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T, which the MXU takes as it stands
+_MAX_HEADS_A_STEP = 4
+_TILE = 1024
+# three quarters of the 128 MiB of VMEM a v5e / v6e core has, the chips
+# tile_shapes was swept on; a core with less needs a smaller _TILE too
+_VMEM_ASK_MAX = 96 << 20
 
 
-def _tile_live(causal, qoff_ref, koff_ref, iq, ik, block_q, block_k):
-    """Whether this (Q, K) tile has ANY visible pair under causal
-    masking — the shared tile-skip predicate for all three kernels."""
+class Tiles(NamedTuple):
+    """The tile shape of the three kernels and the query heads of one
+    K/V head taken in one grid step."""
+    block_q: int
+    block_k: int
+    heads: int
+
+    def count(self, pad_q, pad_k, causal, q_offset, k_offset, kv_len):
+        """Bump ``pallas.flash.*`` for one call of ``flash_attention``,
+        which under ``jax.jit`` is once per TRACE of the caller's
+        program, on purpose (as ``zoo.conv_bn.folded`` is): the shape
+        chosen and, where the offsets are known now, the tiles a head
+        by class."""
+        _metrics.counter("pallas.flash.launches").inc()
+        _metrics.gauge("pallas.flash.block_q").set(self.block_q)
+        _metrics.gauge("pallas.flash.block_k").set(self.block_k)
+        _metrics.gauge("pallas.flash.heads_a_step").set(self.heads)
+        try:
+            q_offset, k_offset = int(q_offset), int(k_offset)
+        except TypeError:   # a tracer (the ring's): known at run time only
+            return
+        for cls, n in tile_counts(pad_q, pad_k, self.block_q, self.block_k,
+                                  causal=causal, q_offset=q_offset,
+                                  k_offset=k_offset, kv_len=kv_len).items():
+            _metrics.counter(f"pallas.flash.tiles.{cls}").inc(n)
+
+
+# --- what the shapes decide ------------------------------------------------
+def _fit_block(block: int, s: int, align: int) -> int:
+    """A requested block over a length-``s`` axis: clipped to the
+    (aligned) sequence, rounded down to ``align``."""
+    return max(align, min(block, s + (-s % align)) // align * align)
+
+
+def tile_shapes(s_q: int, s_k: int, group: int, *,
+                align: int = _LANES) -> Tiles:
+    """The tile shape the kernels run at, from what the code can see:
+    1,024 x 1,024 for all three, clipped to the (aligned) sequence. The
+    largest tile that stays in VMEM without spills was the fastest for
+    every kernel at every shape a caller runs (swept on the v5e at ``S``
+    = 8,192 and 2,048, ``head_dim`` 64 and 128, groups of 1 and 4,
+    bfloat16 and float32: PERF.md §6, PR 31; 2,048 is twice as slow). A
+    K/V head's query heads go up to four a grid step: one K/V fetch and
+    one step's overhead for the four."""
+    heads = max(h for h in range(1, _MAX_HEADS_A_STEP + 1) if group % h == 0)
+    return Tiles(_fit_block(_TILE, s_q, align), _fit_block(_TILE, s_k, align),
+                 heads)
+
+
+def tile_counts(s_q: int, s_k: int, block_q: int, block_k: int, *,
+                causal: bool, q_offset: int = 0, k_offset: int = 0,
+                kv_len: int | None = None) -> dict:
+    """Tiles a head by class, for static offsets: the same predicate as
+    :func:`_tile_class`, in Python integers."""
+    out = {"interior": 0, "crossing": 0, "dead": 0}
+    for iq in range(-(-s_q // block_q)):
+        for ik in range(-(-s_k // block_k)):
+            live, interior = _tile_class(
+                causal, kv_len, q_offset, k_offset, iq, ik, block_q,
+                block_k)
+            out["interior" if interior else
+                "crossing" if live else "dead"] += 1
+    return out
+
+
+# --- the per-tile predicate and the index maps that follow from it ---------
+def _tile_class(causal, kv_len, qoff, koff, iq, ik, block_q, block_k):
+    """``(live, interior)`` of tile ``(iq, ik)``: *live* has a visible
+    pair, *interior* has nothing but (no pair above the diagonal, no
+    padded key). The ONE predicate of all three kernels; Python bools
+    where nothing depends on a position."""
+    unpadded = True if kv_len is None else (ik + 1) * block_k <= kv_len
     if not causal:
-        return jnp.bool_(True)
-    return (koff_ref[0] + ik * block_k
-            <= qoff_ref[0] + (iq + 1) * block_q - 1)
+        return True, unpadded
+    q_lo, k_lo = qoff + iq * block_q, koff + ik * block_k
+    live = k_lo <= q_lo + block_q - 1
+    interior = k_lo + block_k - 1 <= q_lo
+    return live, (interior & unpadded if kv_len is not None else interior)
 
 
-def _masked_scores(q_ref, k_ref, qoff_ref, koff_ref, iq, ik, *, causal,
-                   scale, block_q, block_k, kv_len, precision):
-    """QKᵀ·scale with the global-position causal mask applied — the ONE
-    definition of the score tile shared by forward, dq and dkv kernels.
-    ``kv_len`` (static; None when the keys were not padded) masks the
-    pad keys past the real sequence end by their LOCAL index."""
-    # operands go to the MXU in their own dtype (bf16 stays one pass);
-    # the product is accumulated, scaled and masked in float32
-    q, k = q_ref[0], k_ref[0]
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32,
-                precision=precision) * scale
-    if causal or kv_len is not None:
-        k_idx = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+def _by_class(live, interior, body):
+    """Run ``body(masked=False)`` on an interior tile, ``body(True)`` on
+    a crossing one, nothing on a dead one."""
+    if interior is True:
+        body(False)
+        return
+    pl.when(interior)(lambda: body(False))
+    crossing = jnp.logical_not(interior)
+    if live is not True:
+        crossing = jnp.logical_and(live, crossing)
+    pl.when(crossing)(lambda: body(True))
+
+
+def _last_live_k(causal, qoff, koff, iq, ik, block_q, block_k):
+    """Forward and dq walk K tiles innermost: past the row's last live
+    tile the index stays there, so a dead step fetches nothing."""
+    if not causal:
+        return ik
+    reach = jnp.maximum(qoff - koff + (iq + 1) * block_q - 1, 0)
+    return jnp.minimum(ik, jax.lax.div(reach, jnp.int32(block_k)))
+
+
+def _first_live_q(causal, qoff, koff, iq, ik, block_q, block_k, q_tiles):
+    """dk/dv walk Q tiles innermost: before the column's first live tile
+    the index already points at it."""
+    if not causal:
+        return iq
+    start = jnp.maximum(koff - qoff + ik * block_k, 0)
+    first = jnp.minimum(jax.lax.div(start, jnp.int32(block_q)), q_tiles - 1)
+    return jnp.maximum(iq, first)
+
+
+def _mask(s, qoff, koff, iq, ik, *, causal, kv_len, block_q, block_k,
+          q_axis):
+    """The global-position causal mask and the padded keys' (by LOCAL
+    index, ``kv_len`` static) on a score tile whose query rows lie along
+    ``q_axis``: a crossing tile's work."""
+    k_idx = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape,
+                                                    1 - q_axis)
     if causal:
-        q_pos = (qoff_ref[0] + iq * block_q
-                 + jax.lax.broadcasted_iota(jnp.int32,
-                                            (block_q, block_k), 0))
-        s = jnp.where(q_pos >= koff_ref[0] + k_idx, s, _NEG_INF)
+        q_pos = (qoff + iq * block_q
+                 + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis))
+        s = jnp.where(q_pos >= koff + k_idx, s, _NEG_INF)
     if kv_len is not None:
         s = jnp.where(k_idx < kv_len, s, _NEG_INF)
-    return q, k, s
+    return s
 
 
-def _bwd_p(s, lse):
-    """Reconstruct softmax weights from the saved log-sum-exp, zeroing
-    rows that saw no key (f32 multiplicand: a bool minor-dim insertion
-    is unsupported in Mosaic for non-32-bit types)."""
-    alive = (lse > _NEG_INF * 0.5).astype(jnp.float32)[:, None]
-    return jnp.exp(s - lse[:, None]) * alive
+def _scaled(x, scale):
+    """``x · scale``; ``scale`` is None where the query rows came in
+    scaled (:func:`_scale_rides_on_q`)."""
+    return x if scale is None else x * scale
 
 
-def _bwd_ds(p, do, v, dlt, scale, precision):
-    """The shared score gradient ``p ⊙ (dO Vᵀ − δ + dlse) · scale``."""
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32,
-                 precision=precision)
-    return p * (dp - dlt[:, None]) * scale
+def _scale_rides_on_q(head_dim: int) -> bool:
+    """``1/√d`` a power of two (d = 16, 64, 256): multiplying the query
+    rows by it changes an exponent and no mantissa bit, in any float
+    type, so it is done once outside the kernels (where XLA folds it
+    into whatever produced the rows) and dq, dk follow by the chain
+    rule. Any other scale is applied to the float32 scores."""
+    return math.frexp(head_dim ** -0.5)[0] == 0.5
 
 
+def _dot(a, b, dims, precision):
+    # operands go to the MXU in their own dtype (bf16 stays one pass);
+    # the product is accumulated in float32
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+# --- kernels ---------------------------------------------------------------
 def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                  m_scr, l_scr, acc_scr, *, causal: bool, scale: float,
-                  block_q: int, block_k: int, kv_len, precision):
-    ik = pl.program_id(2)
-    nk = pl.num_programs(2)
+                  m_scr, acc_scr, side_scr, *, causal: bool, scale,
+                  block_q: int, block_k: int, kv_len, precision,
+                  heads: int, head_dim: int):
+    """Online softmax over the K tiles of one Q tile, for ``heads`` query
+    heads against the one K/V tile. With a head narrower than its lane
+    tile the accumulator has spare columns: ``side_scr`` then holds V
+    beside columns of ones, and the row sum comes out of the product
+    ``p @ [V | 1]`` that was needed anyway (``acc[:, head_dim]``: the
+    same MXU passes, no cross-lane sum, and the normaliser adds the
+    weights the numerator saw: with bfloat16 operands the returned lse
+    is the log of the sum of the bfloat16-rounded weights, 1e-3 from the
+    float32 sum's and what the backward and the ring's merge divide by;
+    with float32 operands it is the float32 sum). Otherwise ``side_scr``
+    is the running sum itself."""
+    iq, ik, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+    sum_on_mxu = acc_scr.shape[-1] > head_dim
 
     @pl.when(ik == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        if sum_on_mxu:
+            side_scr[...] = jnp.ones_like(side_scr)
+        else:
+            side_scr[...] = jnp.zeros_like(side_scr)
 
-    iq = pl.program_id(1)
-    # a K tile strictly in the future of every row of this Q tile
-    # contributes nothing; skip BOTH MXU passes (≈2x for long causal)
-    live = _tile_live(causal, qoff_ref, koff_ref, iq, ik, block_q, block_k)
+    qoff, koff = qoff_ref[0], koff_ref[0]
+    live, interior = _tile_class(causal, kv_len, qoff, koff, iq, ik,
+                                 block_q, block_k)
 
-    @pl.when(live)
-    def _compute():
-        _q, _k, s = _masked_scores(
-            q_ref, k_ref, qoff_ref, koff_ref, iq, ik, causal=causal,
-            scale=scale, block_q=block_q, block_k=block_k,
-            kv_len=kv_len, precision=precision)
+    def body(masked):
+        k = k_ref[0]
+        if sum_on_mxu:
+            side_scr[:, :head_dim] = v_ref[0]
+            v = side_scr[...]
+        else:
+            v = v_ref[0]
+        for h in range(heads):
+            s = _scaled(_dot(q_ref[0, h], k, _NT, precision), scale)
+            if masked:
+                s = _mask(s, qoff, koff, iq, ik, causal=causal,
+                          kv_len=kv_len, block_q=block_q, block_k=block_k,
+                          q_axis=0)
+            m_prev = m_scr[h][:, :1]                        # [TQ, 1]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            if masked:
+                # a row with NO visible key yet has m_new == _NEG_INF
+                # and exp(0)==1 for every masked entry; zero it so l
+                # stays 0 and finalize reports the row as fully masked,
+                # not mean(V)
+                p = jnp.where(m_new <= _NEG_INF * 0.5, 0.0, p)
+            if not sum_on_mxu:
+                side_scr[h] = (side_scr[h] * corr
+                               + p.sum(axis=1, keepdims=True))
+            acc_scr[h] = acc_scr[h] * corr + _dot(p.astype(v.dtype), v,
+                                                  _NN, precision)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
 
-        m_prev = m_scr[:, 0]                          # [TQ]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])               # [TQ, TK]
-        # a row with NO visible key yet has m_new == _NEG_INF and
-        # exp(0)==1 for every masked entry; zero it so l stays 0 and
-        # finalize reports the row as fully masked, not mean(V)
-        p = jnp.where((m_new <= _NEG_INF * 0.5)[:, None], 0.0, p)
-        l_new = l_scr[:, 0] * corr + p.sum(axis=1)
-        v = v_ref[0]
-        acc_scr[:] = (acc_scr[:] * corr[:, None]
-                      + jnp.dot(p.astype(v.dtype), v,
-                                preferred_element_type=jnp.float32,
-                                precision=precision))
-        m_scr[:] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new[:, None], l_scr.shape)
+    _by_class(live, interior, body)
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        l = l_scr[:, 0]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / safe_l[:, None]).astype(o_ref.dtype)
-        # lse = m + log(l); fully-masked rows (l==0) get -inf-equivalent.
-        # The row vector is broadcast over an 8-sublane dim purely to
-        # satisfy the TPU (8, 128) output-tile rule; callers read row 0.
-        lse = jnp.where(l == 0.0, _NEG_INF, m_scr[:, 0] + jnp.log(safe_l))
-        lse_ref[0] = jnp.broadcast_to(lse[None, :], lse_ref.shape[1:])
+        for h in range(heads):
+            acc = acc_scr[h]
+            l = (acc[:, head_dim:head_dim + 1] if sum_on_mxu
+                 else side_scr[h][:, :1])
+            safe_l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, h] = (acc[:, :head_dim] / safe_l).astype(o_ref.dtype)
+            # lse = m + log(l); fully-masked rows (l==0) get
+            # -inf-equivalent. The row vector is broadcast over an
+            # 8-sublane dim purely to satisfy the TPU (8, 128)
+            # output-tile rule; callers read row 0.
+            lse = jnp.where(l == 0.0, _NEG_INF,
+                            m_scr[h][:, :1] + jnp.log(safe_l))
+            lse_ref[0, h] = jnp.broadcast_to(lse[:, 0][None, :],
+                                             lse_ref.shape[2:])
 
 
 def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, dlt_ref, dq_ref, dq_scr, *, causal: bool,
-                   scale: float, block_q: int, block_k: int, kv_len,
-                   precision):
-    """dq = Σ_k  p ⊙ (dOVᵀ − δ + dlse) · scale @ K, accumulated over the
+                   scale, block_q: int, block_k: int, kv_len,
+                   precision, heads: int):
+    """dq = Σ_k  p ⊙ (dOVᵀ − δ + dlse) @ K · scale, accumulated over the
     innermost K-tile grid dim — same tiling discipline as the forward,
     no S² materialization. δ = rowsum(dO ⊙ O), and ``p = exp(s − lse)``
-    reconstructs the softmax weights from the saved log-sum-exp."""
+    reconstructs the softmax weights from the saved log-sum-exp. The
+    scale is applied once, to the float32 sum."""
     iq, ik, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
 
     @pl.when(ik == 0)
     def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    live = _tile_live(causal, qoff_ref, koff_ref, iq, ik, block_q, block_k)
+    qoff, koff = qoff_ref[0], koff_ref[0]
+    live, interior = _tile_class(causal, kv_len, qoff, koff, iq, ik,
+                                 block_q, block_k)
 
-    @pl.when(live)
-    def _compute():
-        q, k, s = _masked_scores(
-            q_ref, k_ref, qoff_ref, koff_ref, iq, ik, causal=causal,
-            scale=scale, block_q=block_q, block_k=block_k,
-            kv_len=kv_len, precision=precision)
-        p = _bwd_p(s, lse_ref[0, 0])
-        ds = _bwd_ds(p, do_ref[0], v_ref[0], dlt_ref[0, 0], scale,
-                     precision)
-        dq_scr[:] += jnp.dot(ds.astype(k.dtype), k,
-                             preferred_element_type=jnp.float32,
-                             precision=precision)
+    def body(masked):
+        k, v = k_ref[0], v_ref[0]
+        for h in range(heads):
+            s = _scaled(_dot(q_ref[0, h], k, _NT, precision), scale)
+            lse = lse_ref[0, h, 0][:, None]
+            if masked:
+                s = _mask(s, qoff, koff, iq, ik, causal=causal,
+                          kv_len=kv_len, block_q=block_q, block_k=block_k,
+                          q_axis=0)
+            p = jnp.exp(s - lse)
+            if masked:
+                # rows that saw no key at all: exp(-inf - -inf) is 1
+                p = p * (lse > _NEG_INF * 0.5).astype(jnp.float32)
+            dp = _dot(do_ref[0, h], v, _NT, precision)
+            ds = p * (dp - dlt_ref[0, h, 0][:, None])
+            dq_scr[h] += _dot(ds.astype(k.dtype), k, _NN, precision)
+
+    _by_class(live, interior, body)
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0] = _scaled(dq_scr[...], scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, dlt_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                    causal: bool, scale: float, block_q: int,
-                    block_k: int, kv_len, precision, q_tiles: int):
-    """dk = Σ_q (p ⊙ (dOVᵀ − δ + dlse) · scale)ᵀ @ Q ; dv = Σ_q pᵀ @ dO —
-    grid over K tiles with the Q-tile dim innermost. Under grouped
-    queries the innermost dim runs over every query head of this K/V
-    head's group in turn (``q_tiles`` tiles each), so the group's sum
-    is accumulated in float32 in the same scratch."""
+                    causal: bool, scale, block_q: int,
+                    block_k: int, kv_len, precision, heads: int,
+                    q_tiles: int):
+    """dk = Σ_q (p ⊙ (dOVᵀ − δ + dlse))ᵀ @ Q · scale ; dv = Σ_q pᵀ @ dO —
+    grid over K tiles with the Q-tile dim innermost, on the TRANSPOSED
+    score tile ``K Qᵀ``: ``lse`` and δ already lie along lanes, and
+    ``pᵀ`` / ``dsᵀ`` are the left operands of both products as they
+    stand. The innermost dim runs over the query heads of this K/V
+    head's group, ``heads`` a step, so the group's sum is accumulated
+    in float32 in the same scratch; the scale is applied once, to that
+    sum."""
     ik, j, nj = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
     iq = j % q_tiles
 
     @pl.when(j == 0)
     def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    live = _tile_live(causal, qoff_ref, koff_ref, iq, ik, block_q, block_k)
+    qoff, koff = qoff_ref[0], koff_ref[0]
+    live, interior = _tile_class(causal, kv_len, qoff, koff, iq, ik,
+                                 block_q, block_k)
 
-    @pl.when(live)
-    def _compute():
-        q, k, s = _masked_scores(
-            q_ref, k_ref, qoff_ref, koff_ref, iq, ik, causal=causal,
-            scale=scale, block_q=block_q, block_k=block_k,
-            kv_len=kv_len, precision=precision)
-        p = _bwd_p(s, lse_ref[0, 0])
-        do = do_ref[0]
-        dv_scr[:] += jnp.dot(p.T.astype(do.dtype), do,
-                             preferred_element_type=jnp.float32,
-                             precision=precision)
-        ds = _bwd_ds(p, do, v_ref[0], dlt_ref[0, 0], scale, precision)
-        dk_scr[:] += jnp.dot(ds.T.astype(q.dtype), q,
-                             preferred_element_type=jnp.float32,
-                             precision=precision)
+    def body(masked):
+        k, v = k_ref[0], v_ref[0]
+        for h in range(heads):
+            q, do = q_ref[0, h], do_ref[0, h]
+            st = _scaled(_dot(k, q, _NT, precision), scale)  # [TK, TQ]
+            lse = lse_ref[0, h, :1, :]                      # [1, TQ]
+            if masked:
+                st = _mask(st, qoff, koff, iq, ik, causal=causal,
+                           kv_len=kv_len, block_q=block_q,
+                           block_k=block_k, q_axis=1)
+            pt = jnp.exp(st - lse)
+            if masked:
+                pt = pt * (lse > _NEG_INF * 0.5).astype(jnp.float32)
+            dv_scr[...] += _dot(pt.astype(do.dtype), do, _NN, precision)
+            dpt = _dot(v, do, _NT, precision)
+            dst = pt * (dpt - dlt_ref[0, h, :1, :])
+            dk_scr[...] += _dot(dst.astype(q.dtype), q, _NN, precision)
+
+    _by_class(live, interior, body)
 
     @pl.when(j == nj - 1)
     def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        dk_ref[0] = _scaled(dk_scr[...], scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _pallas_flash_bwd(qh, kh, vh, out, lse, qoff, koff, do, dlse, *,
-                      causal, block_q, block_k, kv_len, interpret,
-                      precision, group=1):
+# --- launches --------------------------------------------------------------
+def _params(heads, block_q, block_k, d, item):
+    """Mosaic's scoped-VMEM default is 16 MiB; ask for what the largest
+    of the three kernels takes when that is more (its blocks, double
+    buffered; float32 scratch; four score planes), up to
+    ``_VMEM_ASK_MAX``."""
+    need = (2 * item * d * (3 * heads * block_q + 4 * block_k)
+            + 4 * heads * block_q * (3 * _LANES + d)
+            + 4 * 4 * block_q * block_k)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=(None if need <= 12 << 20
+                          else min(2 * need, _VMEM_ASK_MAX)))
+
+
+def _q_major_maps(causal, block_q, block_k, per_kv):
+    """Index maps of the forward and dq grids ``(row, iq, ik)``: the Q
+    tile (and what is shaped like it), the row vectors beside it, and
+    the K/V tile of the ``per_kv`` rows that share a K/V head."""
+    def q_map(r, iq, ik, *_):
+        return (r, 0, iq, 0)
+
+    def row_map(r, iq, ik, *_):
+        return (r, 0, 0, iq)
+
+    def k_map(r, iq, ik, qoff_ref, koff_ref):
+        return (r // per_kv,
+                _last_live_k(causal, qoff_ref[0], koff_ref[0], iq, ik,
+                             block_q, block_k), 0)
+
+    return q_map, row_map, k_map
+
+
+def _pallas_flash_fwd(qg, kh, vh, qoff, koff, *, causal, tiles: Tiles,
+                      kv_len, interpret, precision, group, scale):
+    """The forward launch. Head-major: ``qg`` ``[R, heads, Sq, D]`` (the
+    ``heads`` query heads one grid step takes are neighbours), ``kh`` /
+    ``vh`` ``[B·Hkv, Sk, D]`` → (out like ``qg``, lse ``[R, heads, Sq]``)."""
+    rows, heads, s_q, d = qg.shape
+    s_k = kh.shape[1]
+    block_q, block_k = tiles.block_q, tiles.block_k
+    acc_w = d + (-d % _LANES)
+    q_map, row_map, k_map = _q_major_maps(causal, block_q, block_k,
+                                          group // heads)
+    side = (pltpu.VMEM((block_k, acc_w), vh.dtype) if acc_w > d
+            else pltpu.VMEM((heads, block_q, _LANES), jnp.float32))
+    out, lse8 = pl.pallas_call(
+        functools.partial(
+            _flash_kernel, causal=causal, scale=scale,
+            block_q=block_q, block_k=block_k, kv_len=kv_len,
+            precision=precision, heads=heads, head_dim=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,     # the q and k global offsets
+            grid=(rows, s_q // block_q, s_k // block_k),
+            in_specs=[
+                pl.BlockSpec((1, heads, block_q, d), q_map),
+                pl.BlockSpec((1, block_k, d), k_map),
+                pl.BlockSpec((1, block_k, d), k_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, heads, block_q, d), q_map),
+                pl.BlockSpec((1, heads, 8, block_q), row_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((heads, block_q, _LANES), jnp.float32),  # max
+                pltpu.VMEM((heads, block_q, acc_w), jnp.float32),   # acc
+                side,
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+            # lse rides an 8-sublane broadcast dim for TPU output tiling
+            jax.ShapeDtypeStruct((rows, heads, 8, s_q), jnp.float32),
+        ],
+        compiler_params=_params(heads, block_q, block_k, d,
+                                qg.dtype.itemsize),
+        interpret=interpret,
+    )(qoff, koff, qg, kh, vh)
+    return out, lse8[:, :, 0, :]
+
+
+def _pallas_flash_bwd(qg, kh, vh, out, lse, qoff, koff, do, dlse, *,
+                      causal, tiles: Tiles, kv_len, interpret, precision,
+                      group, scale):
     """Tiled flash backward: (dq, dk, dv) without any S² tensor.
 
     The lse cotangent folds in analytically: ∂lse_i/∂s_ij = p_ij, so the
@@ -226,133 +491,127 @@ def _pallas_flash_bwd(qh, kh, vh, out, lse, qoff, koff, do, dlse, *,
     δ = rowsum(dO ⊙ O) − the δ and dlse terms combine into one per-row
     constant fed to both kernels. ``group`` query rows share each K/V
     row (row ``bh`` reads K/V row ``bh // group``)."""
-    bh_n, s_q, d = qh.shape
+    rows, heads, s_q, d = qg.shape
     s_k = kh.shape[1]
-    scale = 1.0 / (d ** 0.5)
-    do32 = do.astype(jnp.float32)
+    per_kv, item = group // heads, qg.dtype.itemsize
     # per-row constant: −δ + dlse, folded so the kernels need ONE vector
-    dlt = (jnp.sum(do32 * out.astype(jnp.float32), axis=-1)
+    dlt = (jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
            - dlse.astype(jnp.float32))
     # broadcast row vectors over an 8-sublane dim (TPU input tiling)
-    lse8 = jnp.broadcast_to(lse[:, None, :], (bh_n, 8, s_q))
-    dlt8 = jnp.broadcast_to(dlt[:, None, :], (bh_n, 8, s_q))
-    kernel_kw = dict(causal=causal, scale=scale, block_q=block_q,
-                     block_k=block_k, kv_len=kv_len, precision=precision)
+    lse8 = jnp.broadcast_to(lse[:, :, None, :], (rows, heads, 8, s_q))
+    dlt8 = jnp.broadcast_to(dlt[:, :, None, :], (rows, heads, 8, s_q))
+    kernel_kw = dict(causal=causal, scale=scale,
+                     kv_len=kv_len, precision=precision, heads=heads)
 
-    # dq: grid (BH, Sq/TQ, Sk/TK) — q tile fixed per row, K innermost
-    def qi_q(bh, iq, ik):
-        return (bh, iq, 0)
-
-    def qi_k(bh, iq, ik):
-        return (bh // group, ik, 0)
-
+    block_q, block_k = tiles.block_q, tiles.block_k
+    # dq: grid (BH/heads, Sq/TQ, Sk/TK) — q tile fixed per row, K innermost
+    qi_q, qi_row, qi_k = _q_major_maps(causal, block_q, block_k, per_kv)
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **kernel_kw),
-        grid=(bh_n, s_q // block_q, s_k // block_k),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, d), qi_q),
-            pl.BlockSpec((1, block_k, d), qi_k),
-            pl.BlockSpec((1, block_k, d), qi_k),
-            pl.BlockSpec((1, block_q, d), qi_q),
-            pl.BlockSpec((1, 8, block_q), lambda bh, iq, ik: (bh, 0, iq)),
-            pl.BlockSpec((1, 8, block_q), lambda bh, iq, ik: (bh, 0, iq)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), qi_q),
-        out_shape=jax.ShapeDtypeStruct((bh_n, s_q, d), qh.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        functools.partial(_bwd_dq_kernel, block_q=block_q,
+                          block_k=block_k, **kernel_kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows, s_q // block_q, s_k // block_k),
+            in_specs=[
+                pl.BlockSpec((1, heads, block_q, d), qi_q),
+                pl.BlockSpec((1, block_k, d), qi_k),
+                pl.BlockSpec((1, block_k, d), qi_k),
+                pl.BlockSpec((1, heads, block_q, d), qi_q),
+                pl.BlockSpec((1, heads, 8, block_q), qi_row),
+                pl.BlockSpec((1, heads, 8, block_q), qi_row),
+            ],
+            out_specs=pl.BlockSpec((1, heads, block_q, d), qi_q),
+            scratch_shapes=[pltpu.VMEM((heads, block_q, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        compiler_params=_params(heads, block_q, block_k, d, item),
         interpret=interpret,
-    )(qoff, koff, qh, kh, vh, do, lse8, dlt8)
+    )(qoff, koff, qg, kh, vh, do, lse8, dlt8)
 
-    # dk/dv: grid (B·Hkv, Sk/TK, group · Sq/TQ) — k tile fixed per row,
-    # the group's query heads and their Q tiles innermost
+    # dk/dv: grid (B·Hkv, Sk/TK, group/heads · Sq/TQ) — k tile fixed per
+    # row, the group's query heads and their Q tiles innermost
     q_tiles = s_q // block_q
 
-    def ki_k(bkv, ik, j):
+    def ki_k(bkv, ik, j, *_):
         return (bkv, ik, 0)
 
-    def ki_q(bkv, ik, j):
-        return (bkv * group + j // q_tiles, j % q_tiles, 0)
+    def ki_tile(bkv, ik, j, qoff_ref, koff_ref):
+        return (bkv * per_kv + j // q_tiles,
+                _first_live_q(causal, qoff_ref[0], koff_ref[0],
+                              j % q_tiles, ik, block_q, block_k, q_tiles))
 
-    def ki_row(bkv, ik, j):
-        return (bkv * group + j // q_tiles, 0, j % q_tiles)
+    def ki_q(bkv, ik, j, qoff_ref, koff_ref):
+        r, iq = ki_tile(bkv, ik, j, qoff_ref, koff_ref)
+        return (r, 0, iq, 0)
+
+    def ki_row(bkv, ik, j, qoff_ref, koff_ref):
+        r, iq = ki_tile(bkv, ik, j, qoff_ref, koff_ref)
+        return (r, 0, 0, iq)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, q_tiles=q_tiles, **kernel_kw),
-        grid=(bh_n // group, s_k // block_k, group * q_tiles),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, d), ki_q),
-            pl.BlockSpec((1, block_k, d), ki_k),
-            pl.BlockSpec((1, block_k, d), ki_k),
-            pl.BlockSpec((1, block_q, d), ki_q),
-            pl.BlockSpec((1, 8, block_q), ki_row),
-            pl.BlockSpec((1, 8, block_q), ki_row),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), ki_k),
-            pl.BlockSpec((1, block_k, d), ki_k),
-        ],
+        functools.partial(_bwd_dkv_kernel, q_tiles=q_tiles,
+                          block_q=block_q, block_k=block_k, **kernel_kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(kh.shape[0], s_k // block_k, per_kv * q_tiles),
+            in_specs=[
+                pl.BlockSpec((1, heads, block_q, d), ki_q),
+                pl.BlockSpec((1, block_k, d), ki_k),
+                pl.BlockSpec((1, block_k, d), ki_k),
+                pl.BlockSpec((1, heads, block_q, d), ki_q),
+                pl.BlockSpec((1, heads, 8, block_q), ki_row),
+                pl.BlockSpec((1, heads, 8, block_q), ki_row),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), ki_k),
+                pl.BlockSpec((1, block_k, d), ki_k),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ]),
         out_shape=[
             jax.ShapeDtypeStruct(kh.shape, kh.dtype),
             jax.ShapeDtypeStruct(vh.shape, vh.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
+        compiler_params=_params(heads, block_q, block_k, d, item),
         interpret=interpret,
-    )(qoff, koff, qh, kh, vh, do, lse8, dlt8)
+    )(qoff, koff, qg, kh, vh, do, lse8, dlt8)
     return dq, dk, dv
 
 
 @functools.lru_cache(maxsize=32)
-def _flash_fn(causal: bool, block_q: int, block_k: int, kv_len,
-              interpret: bool, precision, group: int = 1):
+def _flash_fn(causal: bool, tiles: Tiles, kv_len, interpret: bool,
+              precision, group: int, scale):
     """One custom-VJP'd head-major flash fn per static config: forward
     AND backward are Pallas kernels (pallas_call has no generic
     autodiff), so neither direction materializes an S² tensor."""
+    kw = dict(causal=causal, tiles=tiles, kv_len=kv_len,
+              interpret=interpret, precision=precision, group=group,
+              scale=scale)
 
-    def fwd_impl(qh, kh, vh, qoff, koff):
-        return _pallas_flash_bh(qh, kh, vh, qoff, koff, causal=causal,
-                                block_q=block_q, block_k=block_k,
-                                kv_len=kv_len, interpret=interpret,
-                                precision=precision, group=group)
+    def fwd_impl(qg, kh, vh, qoff, koff):
+        return _pallas_flash_fwd(qg, kh, vh, qoff, koff, **kw)
 
     f = jax.custom_vjp(fwd_impl)
 
-    def fwd(qh, kh, vh, qoff, koff):
-        out, lse = fwd_impl(qh, kh, vh, qoff, koff)
-        return (out, lse), (qh, kh, vh, out, lse, qoff, koff)
+    def fwd(qg, kh, vh, qoff, koff):
+        out, lse = fwd_impl(qg, kh, vh, qoff, koff)
+        return (out, lse), (qg, kh, vh, out, lse, qoff, koff)
 
     def bwd(res, cots):
-        qh, kh, vh, out, lse, qoff, koff = res
+        qg, kh, vh, out, lse, qoff, koff = res
         do, dlse = cots
-        dq, dk, dv = _pallas_flash_bwd(
-            qh, kh, vh, out, lse, qoff, koff, do, dlse, causal=causal,
-            block_q=block_q, block_k=block_k, kv_len=kv_len,
-            interpret=interpret, precision=precision, group=group)
+        dq, dk, dv = _pallas_flash_bwd(qg, kh, vh, out, lse, qoff, koff,
+                                       do, dlse, **kw)
         return dq, dk, dv, None, None
 
     f.defvjp(fwd, bwd)
     return f
 
 
-def _fit_block(block: int, s: int, align: int) -> tuple[int, int]:
-    """``(block, padded_s)`` for a requested block over a length-``s``
-    axis: the request clipped to the (aligned) sequence and rounded
-    down to ``align``, and ``s`` rounded up to a multiple of it."""
-    block = max(align, min(block, s + (-s % align)) // align * align)
-    return block, s + (-s % block)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret",
-                              "return_lse", "precision"))
 def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
-                    k_offset=0, block_q: int = 128, block_k: int = 128,
+                    k_offset=0, block_q: int | None = None,
+                    block_k: int | None = None,
                     interpret: bool | None = None,
                     return_lse: bool = False, precision=None):
     """Tiled flash attention. q: [B, Sq, H, D], k/v: [B, Sk, Hkv, D] →
@@ -366,15 +625,20 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
 
     ``q_offset``/``k_offset`` are the blocks' GLOBAL sequence positions
     for causal masking; they may be traced values (each ring device
-    passes its rotating source position). Block sizes are advisory: a
+    passes its rotating source position). ``block_q`` / ``block_k`` are
+    None for the shape :func:`tile_shapes` derives; a value (tests that
+    want many small tiles; the ring's shard block) is advisory: a
     compiled block is a multiple of the 128-lane tile, and a sequence
     that is not a block multiple is zero-padded up to one (pad keys
-    masked in-kernel, pad query rows dropped), so any length works
-    with a bounded VMEM footprint.
+    masked in-kernel, pad query rows dropped), so any length works with
+    a bounded VMEM footprint.
 
     ``interpret=None`` picks from the process's default backend: the
     compiled Mosaic kernel on TPU, the Pallas interpreter anywhere else
-    (Mosaic has no other target)."""
+    (Mosaic has no other target).
+
+    Counts ``pallas.flash.*`` once per call, which under ``jax.jit`` is
+    once per TRACE of the caller's program."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, s_q, h, d = q.shape
@@ -382,21 +646,43 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
     if h % h_kv or v.shape[2] != h_kv:
         raise ValueError(f"{h} query heads cannot share {h_kv} key / "
                          f"{v.shape[2]} value heads")
-    align = 1 if interpret else 128
-    block_q, pad_q = _fit_block(block_q, s_q, align)
-    block_k, pad_k = _fit_block(block_k, s_k, align)
+    group, align = h // h_kv, 1 if interpret else _LANES
+    tiles = tile_shapes(s_q, s_k, group, align=align)
+    if block_q is not None:
+        tiles = tiles._replace(block_q=_fit_block(block_q, s_q, align))
+    if block_k is not None:
+        tiles = tiles._replace(block_k=_fit_block(block_k, s_k, align))
+    pad_q, pad_k = s_q + (-s_q % tiles.block_q), s_k + (-s_k % tiles.block_k)
+    kv_len = s_k if pad_k != s_k else None
+    tiles.count(pad_q, pad_k, causal, q_offset, k_offset, kv_len)
+    return _flash_call(q, k, v, q_offset, k_offset, causal=causal,
+                       tiles=tiles, pads=(pad_q, pad_k),
+                       interpret=interpret, return_lse=return_lse,
+                       precision=precision)
 
-    # head-major [B*H, S, D]: each grid row owns one (batch, head) pair
+
+def _flash_traced(q, k, v, q_offset, k_offset, *, causal, tiles, pads,
+                  interpret, return_lse, precision):
+    b, s_q, h, d = q.shape
+    s_k, h_kv = k.shape[1], k.shape[2]
+    pad_q, pad_k = pads
+
+    # head-major: K/V [B·Hkv, Sk, D], a row a (batch, head) pair; Q
+    # [B·H/heads, heads, Sq, D], a row the heads one grid step takes
     def to_bh(x, padded):
         x = jnp.pad(x, ((0, 0), (0, padded - x.shape[1]), (0, 0), (0, 0)))
         return x.transpose(0, 2, 1, 3).reshape(-1, padded, d)
 
-    qh, kh, vh = to_bh(q, pad_q), to_bh(k, pad_k), to_bh(v, pad_k)
+    scale = d ** -0.5
+    if _scale_rides_on_q(d):
+        q, scale = q * jnp.asarray(scale, q.dtype), None
+    qg = to_bh(q, pad_q).reshape(-1, tiles.heads, pad_q, d)
+    kh, vh = to_bh(k, pad_k), to_bh(v, pad_k)
     qoff = jnp.asarray(q_offset, jnp.int32).reshape(1)
     koff = jnp.asarray(k_offset, jnp.int32).reshape(1)
-    out, lse = _flash_fn(causal, block_q, block_k,
-                         s_k if pad_k != s_k else None, interpret,
-                         precision, h // h_kv)(qh, kh, vh, qoff, koff)
+    out, lse = _flash_fn(causal, tiles, s_k if pad_k != s_k else None,
+                         interpret, precision, h // h_kv,
+                         scale)(qg, kh, vh, qoff, koff)
     out = out.reshape(b, h, pad_q, d).transpose(0, 2, 1, 3)[:, :s_q]
     if not return_lse:
         return out
@@ -404,51 +690,8 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
     return out, lse
 
 
-def _pallas_flash_bh(qh, kh, vh, qoff, koff, *, causal, block_q, block_k,
-                     kv_len, interpret, precision=None, group=1):
-    """The raw kernel launch, head-major [BH, S, D] → (out, lse[BH, S])."""
-    bh_n, s_q, d = qh.shape
-    s_k = kh.shape[1]
-    grid = (bh_n, s_q // block_q, s_k // block_k)
-    out, lse8 = _launch(qh, kh, vh, qoff, koff, grid=grid, causal=causal,
-                        block_q=block_q, block_k=block_k, kv_len=kv_len,
-                        interpret=interpret, precision=precision,
-                        group=group)
-    return out, lse8[:, 0, :]
-
-
-def _launch(qh, kh, vh, qoff, koff, *, grid, causal, block_q, block_k,
-            kv_len, interpret, precision=None, group=1):
-    bh_n, s_q, d = qh.shape
-    kernel = functools.partial(
-        _flash_kernel, causal=causal, scale=1.0 / (d ** 0.5),
-        block_q=block_q, block_k=block_k, kv_len=kv_len,
-        precision=precision)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # q global offset
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # k global offset
-            pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda bh, iq, ik: (bh // group, ik, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda bh, iq, ik: (bh // group, ik, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda bh, iq, ik: (bh, 0, iq)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh_n, s_q, d), qh.dtype),
-            # lse rides an 8-sublane broadcast dim for TPU output tiling
-            jax.ShapeDtypeStruct((bh_n, 8, s_q), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running max m
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running norm l
-            pltpu.VMEM((block_q, d), jnp.float32),    # weighted acc
-        ],
-        interpret=interpret,
-    )(qoff, koff, qh, kh, vh)
+# the traced name is what device traces file these kernels under
+# (``jit(flash_attention)/pallas_call``, the ``flash_attention.N`` rows)
+_flash_traced.__name__ = _flash_traced.__qualname__ = "flash_attention"
+_flash_call = jax.jit(_flash_traced, static_argnames=(
+    "causal", "tiles", "pads", "interpret", "return_lse", "precision"))

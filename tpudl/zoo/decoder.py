@@ -93,9 +93,6 @@ class Decoder:
         if c.get("conv_bias"):
             raise ValueError("conv_bias: the short convolution here is "
                              "bias-free")
-        self.attention = {
-            "block_q": int(c.get("attention_block_q", 512)),
-            "block_k": int(c.get("attention_block_k", 512))}
         if self.dense_layers < self.n_layers:
             first, count = self.held
             if not (0 <= first and count > 0
@@ -175,7 +172,7 @@ class Decoder:
         else:
             x = x + B.attention_op(
                 p, "attn", h, heads=self.heads, kv_heads=self.kv_heads,
-                eps=self.eps, theta=self.theta, **self.attention)
+                eps=self.eps, theta=self.theta)
         h = B.rms_norm(x, p["ffn_norm"], self.eps)
         if not routed:
             return x + B.gated_ff(p, "ff", h), None

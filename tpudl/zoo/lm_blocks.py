@@ -71,12 +71,13 @@ def conv_op(p, name: str, x):
 
 
 def attention_op(p, name: str, x, *, heads: int, kv_heads: int, eps: float,
-                 theta: float, block_q: int = 512, block_k: int = 512):
+                 theta: float):
     """Causal grouped-query attention: ``heads`` query heads over
     ``kv_heads`` key/value heads, an RMSNorm over each query and key head
     before the rotation, scale ``1/√head_dim``, through
     :func:`tpudl.pallas_ops.flash_attention` (compiled by Mosaic on a
-    TPU, interpreted elsewhere)."""
+    TPU, interpreted elsewhere; the kernels derive their tile shapes
+    from these shapes)."""
     with named_scope("lm.attention"):
         bsz, s, _ = x.shape
         d = p[name + ".q_norm"].shape[0]
@@ -85,8 +86,7 @@ def attention_op(p, name: str, x, *, heads: int, kv_heads: int, eps: float,
         v = (x @ p[name + ".v_proj"]).reshape(bsz, s, kv_heads, d)
         q = rotary(rms_norm(q, p[name + ".q_norm"], eps), theta)
         k = rotary(rms_norm(k, p[name + ".k_norm"], eps), theta)
-        out = flash_attention(q, k, v, causal=True, block_q=block_q,
-                              block_k=block_k)
+        out = flash_attention(q, k, v, causal=True)
         return out.reshape(bsz, s, heads * d) @ p[name + ".o_proj"]
 
 
