@@ -58,6 +58,9 @@ def no_compile_cache():
     # length (640, 384), whose lse block Mosaic takes only lane-aligned
     (520, (32, 8), 64, "bfloat16", None),
     (300, (8, 8), 64, "bfloat16", None),
+    # the nemotron_h cell's: a group of 16 query heads a K/V head, a head
+    # as wide as a lane
+    (8192, (32, 2), 128, "bfloat16", None),
 ])
 def test_flash_attention_with_grouped_queries_compiles_for_the_v5e(
         one_chip, no_compile_cache, seq, heads, head_dim, dtype, blocks):

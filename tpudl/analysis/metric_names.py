@@ -198,10 +198,19 @@ METRICS: tuple[Metric, ...] = (
            "conv + batch-norm pairs traced as written, on batch "
            "statistics (Store(train=True)): nothing can be folded"),
     Metric("zoo.lm.layers.*", "counter",
-           "decoder layers traced, by kind (conv / attention operators, "
-           "dense / routed feed-forwards): what a config-driven Decoder "
-           "program is made of (4 / 1 / 1 / 4 per trace of "
-           "lfm2-8b-a1b-ep4)"),
+           "decoder parts traced, by kind (conv / attention / ssm mixers, "
+           "dense / routed feed-forwards, shared experts beside routed "
+           "ones): what a config-driven Decoder program is made of (conv "
+           "4, attention 1, dense 1, routed 4 per trace of "
+           "lfm2-8b-a1b-ep4; ssm 3, attention 1, routed 3, shared 3 of "
+           "nemotron-twotower-30b-a3b-ep16)"),
+    Metric("lm.ssm.chunk", "gauge",
+           "positions a chunk of the Mamba-2 mixer's selective scan in "
+           "the last traced program (the configuration's chunk_size: "
+           "128 in nemotron-twotower-30b-a3b-ep16)"),
+    Metric("lm.ssm.chunks", "gauge",
+           "chunks a sequence in that program: the length of the "
+           "lax.scan over chunk states (64 at 8,192 positions)"),
     Metric("moe.combine.fused", "counter",
            "routed layers traced through moe.combine, the hand-written "
            "forward/backward pair that puts the experts' rows back at "

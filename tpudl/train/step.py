@@ -19,7 +19,7 @@ from tpudl import mesh as M
 __all__ = ["make_train_step", "make_eval_step", "with_compute_dtype"]
 
 
-def with_compute_dtype(loss_fn, dtype):
+def with_compute_dtype(loss_fn, dtype, keep=()):
     """Mixed precision the TPU way: fp32 MASTER params, ``dtype``
     (bf16) compute. Wraps ``loss_fn`` so float32 param leaves are cast
     to ``dtype`` for the forward/backward pass while the optimizer
@@ -31,18 +31,24 @@ def with_compute_dtype(loss_fn, dtype):
     ResNet50 convergence bench plateaued exactly this way). The cast is
     free on the MXU path (XLA fuses it into the consuming matmul), and
     grads come back fp32 because the masters are fp32.
+
+    ``keep`` names leaves that stay float32, by the END of the last key
+    on their path (``(".A_log", ".dt_bias")``): scalars of a recurrence,
+    whose rounding to 8 bits compounds over a sequence.
     """
     import jax.numpy as jnp
 
-    target = jnp.dtype(dtype)
+    target, keep = jnp.dtype(dtype), tuple(keep)
 
-    def cast(leaf):
+    def cast(path, leaf):
+        name = str(getattr(path[-1], "key", path[-1])) if path else ""
         return (leaf.astype(target)
                 if hasattr(leaf, "dtype") and leaf.dtype == jnp.float32
-                else leaf)
+                and not name.endswith(keep) else leaf)
 
     def wrapped(params, *batch):
-        return loss_fn(jax.tree.map(cast, params), *batch)
+        return loss_fn(jax.tree_util.tree_map_with_path(cast, params),
+                       *batch)
 
     return wrapped
 

@@ -1,38 +1,58 @@
 """A decoder-only language model assembled from a config dict.
 
-``Decoder(config)`` reads the keys of a published ``config.json``
-(``hidden_size``, ``layer_types``, ``num_dense_layers``, the head counts,
-``intermediate_size`` / ``moe_intermediate_size``, ``num_experts`` /
-``num_experts_per_tok``, ``norm_eps``, ``rope_theta``, ``conv_L_cache``,
-…) and builds the stack out of :mod:`tpudl.zoo.lm_blocks` and
-:mod:`tpudl.zoo.moe`: per layer an operator (``conv`` or
-``full_attention``) and a feed-forward (dense before
-``num_dense_layers``, routed experts after), pre-norm residual blocks, a
-final norm and a head tied to the embedding table.
+``Decoder(config)`` reads the keys of a published ``config.json`` and
+builds the stack out of :mod:`tpudl.zoo.lm_blocks` and
+:mod:`tpudl.zoo.moe`. A layer is a list of PARTS, each behind an RMSNorm
+of its own and added to the residual stream, ``x ← x + part(norm(x))``;
+after the last layer a final norm and the head. Two families are built:
+
+- ``lfm2_moe`` (``layer_types``, ``num_dense_layers``, ``num_experts``,
+  ``conv_L_cache``, ``rope_theta``, …): two parts a layer, an operator
+  (``conv`` or ``full_attention`` with per-head norms and rotary) and a
+  feed-forward (gated SiLU: dense before ``num_dense_layers``, routed
+  experts after); the head is the embedding table;
+- ``nemotron_h`` (``hybrid_override_pattern``, ``mamba_num_heads``,
+  ``mamba_head_dim``, ``n_groups``, ``ssm_state_size``, ``conv_kernel``,
+  ``chunk_size``, ``n_routed_experts``, ``moe_intermediate_size``,
+  ``moe_shared_expert_intermediate_size``, ``routed_scaling_factor``,
+  ``tie_word_embeddings``, …): ONE part a layer by the pattern's letter,
+  ``M`` a Mamba-2 mixer, ``*`` attention with no rotation and no head
+  norm, ``E`` routed ``relu2`` experts (two products, no gate) beside a
+  shared expert that every token passes; an untied head ``[V, D]``.
+  Built causally, for next-token training. Of
+  Nemotron-Labs-TwoTower this is the tower its ``config.json`` defines:
+  the second, denoising tower of the model card (adaLN, cross-tower
+  conditioning, bidirectional in-block attention, block-diffusion
+  decoding) has no key there and is NOT built; nor are ``-`` (dense MLP)
+  layers or a router limited to groups of experts (``n_group > 1``).
 
 Two keys describe the share of a deployment this process holds, as
 expert parallelism and a sharded vocabulary need them:
 
 - ``experts_held = (first, count)``: the router scores all
-  ``num_experts``; this rank stacks and applies ``count`` of them and
-  passes its partial sum on (default: all of them);
-- ``vocab_slice = (first, count)``: the table holds ``count`` rows of
-  ``vocab_size``; ids, logits and the loss are over the slice, ids
-  counted from its first row (default: the whole vocabulary).
+  ``num_experts`` / ``n_routed_experts``; this rank stacks and applies
+  ``count`` of them and passes its partial sum on (default: all of them);
+- ``vocab_slice = (first, count)``: the table (and an untied head) holds
+  ``count`` rows of ``vocab_size``; ids, logits and the loss are over the
+  slice, ids counted from its first row (default: the whole vocabulary).
 
 Parameters are one flat dict of float32 leaves named
 ``layers.<l>.<block>.<leaf>`` (``init(seed)``, host numpy), so a leaf's
-kind is its name and the plain reference
-(``benchmark/configs/lfm2-8b-a1b-ep4.py``) reads the same dict. Train it
-through the normal path::
+kind is its name and the plain references
+(``benchmark/configs/lfm2-8b-a1b-ep4.py``,
+``benchmark/configs/nemotron-twotower-30b-a3b-ep16.py``) read the same
+dict. Train it through the normal path::
 
     lm = Decoder(config)
-    trainer = ctx.trainer(with_compute_dtype(lm.loss_fn(), jnp.bfloat16),
-                          optax.adamw(3e-4, mask=lm.decay_mask))
+    trainer = ctx.trainer(
+        with_compute_dtype(lm.loss_fn(), jnp.bfloat16,
+                           keep=lm.float32_leaves),
+        optax.adamw(3e-4, mask=lm.decay_mask))
     params, opt_state, history = trainer.fit(lm.init(0), data_fn, steps=n)
 
 There is no decode path yet (a slot cache would hold a convolution's last
-``conv_L_cache - 1`` inputs beside keys and values: ROADMAP queue B).
+inputs and a mixer's recurrent state beside keys and values: ROADMAP
+queue B).
 """
 
 from __future__ import annotations
@@ -51,6 +71,11 @@ from tpudl.zoo import moe
 __all__ = ["Decoder"]
 
 OPERATORS = ("conv", "full_attention")
+# nemotron_h's hybrid_override_pattern, a letter a layer
+PATTERN = {"M": "ssm", "*": "attention", "E": "routed"}
+# the leaves' block name by kind of part
+BLOCK = {"conv": "conv", "attention": "attn", "ssm": "ssm", "dense": "ff",
+         "routed": "moe"}
 # a rematerialised block recomputes everything but its routing decision
 # and the ordering of the pairs that follows from it
 _SAVE_ROUTES = jax.checkpoint_policies.save_only_these_names(moe.ROUTES)
@@ -65,22 +90,20 @@ class Decoder:
     def __init__(self, config: dict):
         c = dict(config)
         self.dim = int(c["hidden_size"])
-        self.layer_types = tuple(c["layer_types"])
-        unknown = set(self.layer_types) - set(OPERATORS)
-        if unknown:
-            raise ValueError(f"layer_types {sorted(unknown)}: this decoder "
-                             f"builds {OPERATORS}")
-        self.n_layers = int(c.get("num_hidden_layers",
-                                  len(self.layer_types)))
-        if self.n_layers != len(self.layer_types):
-            raise ValueError(f"num_hidden_layers {self.n_layers} but "
-                             f"{len(self.layer_types)} layer_types")
+        self.hybrid = "hybrid_override_pattern" in c
+        if self.hybrid:
+            self._read_nemotron_h(c)
+        else:
+            self._read_lfm2(c)
+        self.n_layers = int(c.get("num_hidden_layers", len(self.layers)))
+        if self.n_layers != len(self.layers):
+            raise ValueError(
+                f"num_hidden_layers {self.n_layers} but {len(self.layers)} "
+                + ("letters in hybrid_override_pattern" if self.hybrid
+                   else "layer_types"))
         self.heads = int(c["num_attention_heads"])
         self.kv_heads = int(c.get("num_key_value_heads", self.heads))
         self.head_dim = int(c.get("head_dim") or self.dim // self.heads)
-        self.ff_width = int(c["intermediate_size"])
-        self.dense_layers = int(c.get("num_dense_layers", self.n_layers))
-        self.experts = int(c.get("num_experts", 0))
         self.top_k = int(c.get("num_experts_per_tok", 0))
         self.expert_width = int(c.get("moe_intermediate_size", 0))
         self.scaling = float(c.get("routed_scaling_factor", 1.0))
@@ -88,12 +111,7 @@ class Decoder:
         self.vocab = int(c["vocab_size"])
         self.vocab_slice = tuple(c.get("vocab_slice") or (0, self.vocab))
         self.eps = float(c.get("norm_eps", 1e-5))
-        self.theta = float(c.get("rope_theta", 1e4))
-        self.taps = int(c.get("conv_L_cache", 3))
-        if c.get("conv_bias"):
-            raise ValueError("conv_bias: the short convolution here is "
-                             "bias-free")
-        if self.dense_layers < self.n_layers:
+        if self.kinds()["routed"]:
             first, count = self.held
             if not (0 <= first and count > 0
                     and first + count <= self.experts):
@@ -103,16 +121,114 @@ class Decoder:
                 raise ValueError(f"top {self.top_k} of {self.experts}")
         self._programs: dict = {}
 
+    def _read_lfm2(self, c):
+        """An operator and a feed-forward a layer, each behind its norm."""
+        ops = tuple(c["layer_types"])
+        unknown = set(ops) - set(OPERATORS)
+        if unknown:
+            raise ValueError(f"layer_types {sorted(unknown)}: this decoder "
+                             f"builds {OPERATORS}")
+        dense = int(c.get("num_dense_layers", len(ops)))
+        self.layers = tuple(
+            (("operator_norm", "conv" if op == "conv" else "attention"),
+             ("ffn_norm", "dense" if layer < dense else "routed"))
+            for layer, op in enumerate(ops))
+        self.ff_width = int(c["intermediate_size"])
+        self.experts = int(c.get("num_experts", 0))
+        self.act, self.shared_width, self.tied = "silu", 0, True
+        self.theta = float(c.get("rope_theta", 1e4))
+        self.taps = int(c.get("conv_L_cache", 3))
+        self.float32_leaves = ()
+        if c.get("conv_bias"):
+            raise ValueError("conv_bias: the short convolution here is "
+                             "bias-free")
+
+    def _read_nemotron_h(self, c):
+        """One part a layer, by the letter of the pattern."""
+        pattern = str(c["hybrid_override_pattern"])
+        unknown = sorted(set(pattern) - set(PATTERN))
+        if unknown:
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r}: letters {unknown} "
+                f"(this decoder builds {sorted(PATTERN)}; '-', a dense "
+                "MLP layer, is not built)")
+        self.layers = tuple((("norm", PATTERN[ch]),) for ch in pattern)
+        for key, want in (("n_group", 1), ("topk_group", 1)):
+            if int(c.get(key, 1)) != want:
+                raise ValueError(f"{key} {c[key]}: a router limited to "
+                                 "groups of experts is not built")
+        for key in ("use_bias", "mamba_proj_bias", "attention_bias",
+                    "mlp_bias"):
+            if c.get(key):
+                raise ValueError(f"{key}: projections here are bias-free")
+        if not c.get("use_conv_bias", True):
+            raise ValueError("use_conv_bias false: the mixer's "
+                             "convolution here has its bias")
+        if not c.get("norm_topk_prob", True):
+            raise ValueError("norm_topk_prob false: the weights here are "
+                             "renormalised over the selected")
+        limit = tuple(c.get("time_step_limit") or (0.0, None))
+        if limit[0] or limit[1] not in (None, float("inf")):
+            raise ValueError(f"time_step_limit {limit}: the step here is "
+                             "not clamped")
+        self.act = str(c.get("mlp_hidden_act", "relu2"))
+        if self.act != "relu2":
+            raise ValueError(f"mlp_hidden_act {self.act!r}: the experts "
+                             "of this family are built as relu2")
+        self.experts = int(c.get("n_routed_experts", 0))
+        self.shared_width = int(c.get("n_shared_experts", 0)) * int(
+            c.get("moe_shared_expert_intermediate_size", 0))
+        self.tied = bool(c.get("tie_word_embeddings", False))
+        self.theta = None   # the family's code rotates nothing
+        self.ssm_heads = int(c["mamba_num_heads"])
+        self.ssm_head_dim = int(c["mamba_head_dim"])
+        self.ssm_groups = int(c["n_groups"])
+        self.ssm_state = int(c["ssm_state_size"])
+        self.taps = int(c["conv_kernel"])
+        self.chunk = int(c["chunk_size"])
+        self.dt_range = (float(c.get("time_step_min", 1e-3)),
+                         float(c.get("time_step_max", 0.1)),
+                         float(c.get("time_step_floor", 1e-4)))
+        if self.ssm_heads % self.ssm_groups:
+            raise ValueError(f"mamba_num_heads {self.ssm_heads} over "
+                             f"n_groups {self.ssm_groups}")
+        self.float32_leaves = B.MAMBA2_FLOAT32
+
     # ---- structure -------------------------------------------------------
     def routed(self, layer: int) -> bool:
-        return layer >= self.dense_layers
+        return any(kind == "routed" for _, kind in self.layers[layer])
 
     def kinds(self) -> dict:
-        """Layers by kind: the operators and the feed-forwards."""
-        out = {"conv": 0, "attention": 0, "dense": 0, "routed": 0}
-        for layer, op in enumerate(self.layer_types):
-            out["conv" if op == "conv" else "attention"] += 1
-            out["routed" if self.routed(layer) else "dense"] += 1
+        """Parts by kind, over the layers (``shared``: shared experts
+        beside routed ones)."""
+        out = dict.fromkeys(("conv", "attention", "ssm", "dense", "routed",
+                             "shared"), 0)
+        for layer in self.layers:
+            for _, kind in layer:
+                out[kind] += 1
+        out["shared"] = out["routed"] if self.shared_width else 0
+        return out
+
+    def _init_part(self, seed, layer, rng, kind, pre) -> dict:
+        name = pre + BLOCK[kind]
+        if kind == "conv":
+            return B.init_conv(rng, name, self.dim, self.taps)
+        if kind == "attention":
+            return B.init_attention(rng, name, self.dim, self.heads,
+                                    self.kv_heads, self.head_dim,
+                                    head_norms=self.theta is not None)
+        if kind == "ssm":
+            return B.init_mamba2(rng, name, self.dim, self.ssm_heads,
+                                 self.ssm_head_dim, self.ssm_groups,
+                                 self.ssm_state, self.taps, self.dt_range)
+        if kind == "dense":
+            return B.init_ff(rng, name, self.dim, self.ff_width)
+        out = moe.init_routed((seed, layer + 1, 1), name, self.dim,
+                              self.expert_width, self.experts, self.held,
+                              act=self.act)
+        if self.shared_width:
+            out.update(B.init_ff(rng, pre + "shared", self.dim,
+                                 self.shared_width, gated=False))
         return out
 
     def init(self, seed: int) -> dict:
@@ -125,61 +241,69 @@ class Decoder:
         p = {"embed": B.normal(np.random.default_rng([seed, 0, 0]), rows,
                                self.dim, fan_in=1) * np.float32(0.02),
              "embedding_norm": np.ones((self.dim,), np.float32)}
-        for layer, op in enumerate(self.layer_types):
+        if not self.tied:
+            p["head"] = B.normal(np.random.default_rng([seed, 0, 1]), rows,
+                                 self.dim, fan_in=self.dim)
+        for layer, parts in enumerate(self.layers):
             pre = f"layers.{layer}."
             rng = np.random.default_rng([seed, layer + 1, 0])
-            p[pre + "operator_norm"] = np.ones((self.dim,), np.float32)
-            p[pre + "ffn_norm"] = np.ones((self.dim,), np.float32)
-            if op == "conv":
-                p.update(B.init_conv(rng, pre + "conv", self.dim, self.taps))
-            else:
-                p.update(B.init_attention(rng, pre + "attn", self.dim,
-                                          self.heads, self.kv_heads,
-                                          self.head_dim))
-            if self.routed(layer):
-                p.update(moe.init_routed(
-                    (seed, layer + 1, 1), pre + "moe", self.dim,
-                    self.expert_width, self.experts, self.held))
-            else:
-                p.update(B.init_ff(rng, pre + "ff", self.dim, self.ff_width))
+            for norm, _ in parts:
+                p[pre + norm] = np.ones((self.dim,), np.float32)
+            for _, kind in parts:
+                p.update(self._init_part(seed, layer, rng, kind, pre))
         return p
 
     @staticmethod
     def decay_mask(params):
-        """Weight decay on matrices only: norms stay free, and the
-        experts' selection bias stays the constant buffer it is."""
+        """Weight decay on matrices only: norms, biases and a mixer's
+        per-head scalars stay free, and the experts' selection bias stays
+        the constant buffer it is."""
         return jax.tree.map(lambda leaf: np.ndim(leaf) > 1, params)
 
     # ---- forward ---------------------------------------------------------
     def runs(self):
-        """Consecutive layers of one kind (operator and feed-forward) as
-        ``(first, count)``: what one scanned body can stand for."""
+        """Consecutive layers of one kind (the same parts) as ``(first,
+        count)``: what one scanned body can stand for."""
         runs = []
-        for layer, op in enumerate(self.layer_types):
-            kind = (op, self.routed(layer))
-            if runs and runs[-1][2] == kind:
+        for layer, parts in enumerate(self.layers):
+            if runs and runs[-1][2] == parts:
                 runs[-1][1] += 1
             else:
-                runs.append([layer, 1, kind])
+                runs.append([layer, 1, parts])
         return [(first, count) for first, count, _ in runs]
 
-    def _block(self, op: str, routed: bool, p, x, routes):
-        """One pre-norm residual block on its own leaves ``p`` (named
-        without the ``layers.<l>.`` prefix)."""
-        h = B.rms_norm(x, p["operator_norm"], self.eps)
-        if op == "conv":
-            x = x + B.conv_op(p, "conv", h)
-        else:
-            x = x + B.attention_op(
+    def _part(self, kind: str, p, h, routes):
+        """One part on its normed input ``h`` and its own leaves ``p``
+        (named without the ``layers.<l>.`` prefix): what it adds to the
+        residual stream, and the experts a routed part selected."""
+        if kind == "conv":
+            return B.conv_op(p, "conv", h), None
+        if kind == "attention":
+            return B.attention_op(
                 p, "attn", h, heads=self.heads, kv_heads=self.kv_heads,
-                eps=self.eps, theta=self.theta)
-        h = B.rms_norm(x, p["ffn_norm"], self.eps)
-        if not routed:
-            return x + B.gated_ff(p, "ff", h), None
+                eps=self.eps, theta=self.theta), None
+        if kind == "ssm":
+            return B.mamba2_op(
+                p, "ssm", h, heads=self.ssm_heads, groups=self.ssm_groups,
+                state=self.ssm_state, chunk=self.chunk, eps=self.eps), None
+        if kind == "dense":
+            return B.gated_ff(p, "ff", h), None
         y, chosen = moe.routed_ff(p, "moe", h, top_k=self.top_k,
                                   held=self.held, scaling=self.scaling,
-                                  routes=routes)
-        return x + y, chosen
+                                  routes=routes, act=self.act)
+        if self.shared_width:
+            y = y + B.relu2_ff(p, "shared", h)
+        return y, chosen
+
+    def _block(self, parts, p, x, routes):
+        """One layer: every part behind its norm, added to the stream."""
+        chosen = None
+        for norm, kind in parts:
+            y, picked = self._part(kind, p, B.rms_norm(x, p[norm], self.eps),
+                                   routes)
+            x = x + y
+            chosen = picked if kind == "routed" else chosen
+        return x, chosen
 
     def hidden(self, params, ids, routes=None, remat: bool = False):
         """``ids`` ``[B, S]`` -> the normed last hidden state ``[B, S,
@@ -203,8 +327,7 @@ class Decoder:
         chosen = []
         for first, count in self.runs():
             routed = self.routed(first)
-            block = functools.partial(self._block, self.layer_types[first],
-                                      routed)
+            block = functools.partial(self._block, self.layers[first])
             if remat:
                 block = jax.checkpoint(block, policy=_SAVE_ROUTES)
             leaves = [_layer_leaves(params, layer)
@@ -228,8 +351,12 @@ class Decoder:
         inputs (at training sizes the loss never holds them whole)."""
         x, _ = self.hidden(params, ids, routes)
         with named_scope("lm.head"):
-            return jnp.einsum("bsd,vd->bsv", x, params["embed"],
+            return jnp.einsum("bsd,vd->bsv", x, self._head(params),
                               preferred_element_type=jnp.float32)
+
+    def _head(self, params):
+        """``[V, D]``: the embedding table, or the untied head."""
+        return params["embed" if self.tied else "head"]
 
     def routes(self, params, ids):
         """The experts every routed layer selects for ``ids``."""
@@ -252,7 +379,7 @@ class Decoder:
             chunk = loss_chunk if n % loss_chunk == 0 else n
             target = jnp.roll(ids, -1, axis=1)
             counted = jnp.broadcast_to(jnp.arange(s) < s - 1, (bsz, s))
-            table = params["embed"]
+            table = self._head(params)
 
             def chunk_nll(args):
                 xc, yc, mc = args
@@ -289,7 +416,8 @@ class Decoder:
             if compute_dtype is not None:
                 from tpudl.train.step import with_compute_dtype
 
-                fn = with_compute_dtype(fn, compute_dtype)
+                fn = with_compute_dtype(fn, compute_dtype,
+                                        keep=self.float32_leaves)
             self._programs[key] = jax.jit(fn)
         chosen = [np.asarray(c) for c in self._programs[key](params, ids)]
         return self.count_routes(chosen)

@@ -1,14 +1,16 @@
 """Decoder blocks as pure functions over a flat parameter dict.
 
 What a config-driven decoder (:mod:`tpudl.zoo.decoder`) is assembled from:
-RMSNorm, rotary positions, grouped-query attention with a norm on every
-query and key head, LFM2's double-gated short convolution, and the gated
-SiLU feed-forward. Every function takes the dict ``p``, the ``name`` its
-leaves are filed under (``layers.3.attn`` -> ``layers.3.attn.q_proj``),
-and activations ``x`` of shape ``[B, S, D]``; projections are bias-free
-and stored ``[in, out]``. Activations keep the dtype the parameters were
-cast to (``with_compute_dtype``); norms, rotations and the convolution's
-three taps are computed in float32 and cast back.
+RMSNorm, rotary positions, grouped-query attention (with a norm on every
+query and key head and a rotation, or with neither), LFM2's double-gated
+short convolution, the Mamba-2 mixer with its chunked selective scan, and
+the gated SiLU and relu² feed-forwards. Every function takes the dict
+``p``, the ``name`` its leaves are filed under (``layers.3.attn`` ->
+``layers.3.attn.q_proj``), and activations ``x`` of shape ``[B, S, D]``;
+projections are bias-free and stored ``[in, out]``. Activations keep the
+dtype the parameters were cast to (``with_compute_dtype``); norms,
+rotations, the convolutions' taps and the recurrence's decays, sums and
+state are computed in float32 and cast back.
 
 ``init_*`` build the leaves of one block from a ``numpy`` generator, on
 the host: a model is initialised once per process and placed by
@@ -21,11 +23,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpudl.obs import metrics as _metrics
 from tpudl.obs.trace import named_scope
 from tpudl.pallas_ops import flash_attention
 
 __all__ = ["rms_norm", "rotary", "conv_op", "attention_op", "gated_ff",
-           "init_conv", "init_attention", "init_ff", "normal"]
+           "relu2_ff", "mamba2_op", "ssd_scan", "init_conv",
+           "init_attention", "init_ff", "init_mamba2", "normal",
+           "MAMBA2_FLOAT32"]
+
+# the recurrence's per-head scalars: a step casts every other leaf to its
+# compute dtype, these stay float32 (``with_compute_dtype(keep=)``)
+MAMBA2_FLOAT32 = (".A_log", ".dt_bias", ".D")
+# what the recurrence's decays, cumulative sums and carried state are held
+# in (benchmark/controls/hybrid_bf16_scan.py sets bfloat16, and has to fail)
+SCAN_DTYPE = jnp.float32
 
 
 def normal(rng, *shape, fan_in=None):
@@ -71,21 +83,23 @@ def conv_op(p, name: str, x):
 
 
 def attention_op(p, name: str, x, *, heads: int, kv_heads: int, eps: float,
-                 theta: float):
+                 theta):
     """Causal grouped-query attention: ``heads`` query heads over
-    ``kv_heads`` key/value heads, an RMSNorm over each query and key head
-    before the rotation, scale ``1/√head_dim``, through
+    ``kv_heads`` key/value heads, scale ``1/√head_dim``, through
     :func:`tpudl.pallas_ops.flash_attention` (compiled by Mosaic on a
     TPU, interpreted elsewhere; the kernels derive their tile shapes
-    from these shapes)."""
+    from these shapes). With a ``theta``, an RMSNorm over each query and
+    key head and then the rotation (LFM2); with ``theta=None`` neither
+    (``nemotron_h``: the state-space layers carry the positions)."""
     with named_scope("lm.attention"):
         bsz, s, _ = x.shape
-        d = p[name + ".q_norm"].shape[0]
+        d = p[name + ".q_proj"].shape[1] // heads
         q = (x @ p[name + ".q_proj"]).reshape(bsz, s, heads, d)
         k = (x @ p[name + ".k_proj"]).reshape(bsz, s, kv_heads, d)
         v = (x @ p[name + ".v_proj"]).reshape(bsz, s, kv_heads, d)
-        q = rotary(rms_norm(q, p[name + ".q_norm"], eps), theta)
-        k = rotary(rms_norm(k, p[name + ".k_norm"], eps), theta)
+        if theta is not None:
+            q = rotary(rms_norm(q, p[name + ".q_norm"], eps), theta)
+            k = rotary(rms_norm(k, p[name + ".k_norm"], eps), theta)
         out = flash_attention(q, k, v, causal=True)
         return out.reshape(bsz, s, heads * d) @ p[name + ".o_proj"]
 
@@ -97,6 +111,122 @@ def gated_ff(p, name: str, x):
         return gate @ p[name + ".w2"]
 
 
+def relu2_ff(p, name: str, x):
+    """``W₂ relu(W₁x)²``: two products, no gate (``nemotron_h``'s
+    ``relu2``; its shared expert, which every token passes)."""
+    with named_scope("lm.shared_ff"):
+        return jnp.square(jax.nn.relu(x @ p[name + ".w1"])) @ p[name + ".w2"]
+
+
+def ssd_scan(x, dt, a, b, c, chunk: int):
+    """The selective state-space recurrence of ONE sequence, ``H_t =
+    exp(Δ_t A) H_{t-1} + Δ_t x_t ⊗ B_t``, ``y_t = H_t C_t``, in chunks
+    of ``chunk`` positions (Mamba-2's state-space duality), plain XLA
+    ops, autodiff giving the backward.
+
+    ``x`` ``[S, H, P]``, ``dt`` ``[S, H]`` (Δ, after the softplus), ``a``
+    ``[H]`` (negative), ``b`` / ``c`` ``[S, G, N]``; head ``h`` uses
+    group ``h // (H / G)``. Returns ``y`` ``[S, H, P]`` float32. Inside
+    a chunk the masked product ``(L ∘ C Bᵀ)(Δ x)``, ``L_ij = exp(Σ_{j<s≤i}
+    Δ_s A)``; a chunk's end state ``Σ_j exp(Σ_{j<s} Δ_s A) Δ_j x_j ⊗ B_j``;
+    a ``lax.scan`` over the chunk states; what the state entering a chunk
+    adds, ``exp(Σ_{s≤i} Δ_s A) C_i · H``. Δ A, its cumulative sums, ``L``
+    and the carried state are ``SCAN_DTYPE`` (float32); the four products
+    take operands in ``x``'s dtype and accumulate in float32. A length
+    that ``chunk`` does not divide is padded with Δ = 0: no decay, no
+    input."""
+    s, heads, width = x.shape
+    groups, n = b.shape[1:]
+    per = heads // groups
+    pad = -s % chunk
+    if pad:
+        x, dt, b, c = (jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+                       for v in (x, dt, b, c))
+    chunks, dtype, f32 = (s + pad) // chunk, x.dtype, jnp.float32
+    x = x.reshape(chunks, chunk, groups, per, width)
+    b = b.reshape(chunks, chunk, groups, n)
+    c = c.reshape(chunks, chunk, groups, n)
+    dt = dt.reshape(chunks, chunk, groups, per)
+    # log-decay a step, <= 0, and its running sum inside the chunk
+    run = jnp.cumsum(dt.astype(SCAN_DTYPE)
+                     * a.astype(SCAN_DTYPE).reshape(groups, per), axis=1)
+    by_head = run.transpose(0, 2, 3, 1)                   # [c, G, R, Q]
+    dtx = dt.astype(f32)[..., None] * x.astype(f32)       # [c, Q, G, R, P]
+    # inside a chunk: y_i = Σ_{j<=i} (C_i·B_j) L_ij Δ_j x_j
+    scores = jnp.einsum("cign,cjgn->cgij", c, b, preferred_element_type=f32)
+    seen = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(
+        seen, by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
+    y = jnp.einsum("cgrij,cjgrp->cigrp",
+                   (scores[:, :, None] * decay).astype(dtype),
+                   dtx.astype(dtype), preferred_element_type=f32)
+    # a chunk's end state, from zero: Σ_j exp(Σ_{j<s} Δ_s A) Δ_j x_j ⊗ B_j
+    to_end = jnp.exp(by_head[..., -1:] - by_head).transpose(0, 3, 1, 2)
+    ends = jnp.einsum("cjgrp,cjgn->cgrpn",
+                      (dtx * to_end[..., None]).astype(dtype), b,
+                      preferred_element_type=f32)
+
+    def carry(state, chunk_of):
+        whole, end = chunk_of           # the chunk's whole decay, its state
+        return whole[..., None, None] * state + end, state
+
+    _, entering = jax.lax.scan(
+        carry, jnp.zeros(ends.shape[1:], SCAN_DTYPE),
+        (jnp.exp(by_head[..., -1]), ends.astype(SCAN_DTYPE)))
+    # what the state entering the chunk adds: exp(Σ_{s<=i} Δ_s A) C_i · H
+    y = y + jnp.exp(run)[..., None].astype(f32) * jnp.einsum(
+        "cign,cgrpn->cigrp", c, entering.astype(dtype),
+        preferred_element_type=f32)
+    return y.reshape(chunks * chunk, heads, width)[:s]
+
+
+def mamba2_op(p, name: str, x, *, heads: int, groups: int, state: int,
+              chunk: int, eps: float):
+    """The Mamba-2 mixer: ``[z | xBC | dt] = x W_in``; ``xBC ← silu(conv(
+    xBC) + b)`` (depthwise, causal, zeros before the sequence); ``Δ =
+    softplus(dt + dt_bias)``, ``A = -exp(A_log)`` a head; the recurrence
+    (:func:`ssd_scan`); ``y + D x``; ``GroupRMSNorm(y ⊙ silu(z))`` over
+    ``groups`` groups of channels; ``W_out``. ``A_log``, ``dt_bias`` and
+    ``D`` are read as float32 whatever the other leaves were cast to.
+
+    A sequence at a time (``lax.map``), each rematerialised: what is
+    live is one sequence's projections and its ``[chunks, H, Q, Q]``
+    decays, a quarter of the cell's batch. Sets the gauges
+    ``lm.ssm.chunk`` / ``lm.ssm.chunks`` while a program is TRACED."""
+    s, f32 = x.shape[1], jnp.float32
+    d_in = p[name + ".out_proj"].shape[0]
+    conv_dim = d_in + 2 * groups * state
+    _metrics.gauge("lm.ssm.chunk").set(chunk)
+    _metrics.gauge("lm.ssm.chunks").set(-(-s // chunk))
+
+    def mixer(u):                                          # [S, D]
+        z, xbc, dt = jnp.split(u @ p[name + ".in_proj"],
+                               [d_in, d_in + conv_dim], axis=-1)
+        taps = p[name + ".conv_kernel"].astype(f32)
+        k = taps.shape[0]
+        padded = jnp.pad(xbc.astype(f32), ((k - 1, 0), (0, 0)))
+        xbc = jax.nn.silu(
+            sum(taps[j] * padded[j:j + s] for j in range(k))
+            + p[name + ".conv_bias"].astype(f32)).astype(u.dtype)
+        xs, b, c = jnp.split(xbc, [d_in, d_in + groups * state], axis=-1)
+        xs = xs.reshape(s, heads, d_in // heads)
+        dt = jax.nn.softplus(dt.astype(f32)
+                             + p[name + ".dt_bias"].astype(f32))
+        with named_scope("lm.ssm.scan"):
+            y = ssd_scan(xs, dt, -jnp.exp(p[name + ".A_log"].astype(f32)),
+                         b.reshape(s, groups, state),
+                         c.reshape(s, groups, state), chunk)
+        y = y + p[name + ".D"].astype(f32)[:, None] * xs.astype(f32)
+        y = y.reshape(s, d_in) * jax.nn.silu(z.astype(f32))
+        y = y.reshape(s, groups, d_in // groups)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + eps)
+        y = y.reshape(s, d_in) * p[name + ".norm"].astype(f32)
+        return y.astype(u.dtype) @ p[name + ".out_proj"]
+
+    with named_scope("lm.ssm"):
+        return jax.lax.map(jax.checkpoint(mixer), x)
+
+
 def init_conv(rng, name: str, dim: int, taps: int) -> dict:
     return {name + ".in_proj": normal(rng, dim, 3 * dim),
             name + ".kernel": normal(rng, taps, dim, fan_in=taps),
@@ -104,16 +234,41 @@ def init_conv(rng, name: str, dim: int, taps: int) -> dict:
 
 
 def init_attention(rng, name: str, dim: int, heads: int, kv_heads: int,
-                   head_dim: int) -> dict:
-    return {name + ".q_proj": normal(rng, dim, heads * head_dim),
-            name + ".k_proj": normal(rng, dim, kv_heads * head_dim),
-            name + ".v_proj": normal(rng, dim, kv_heads * head_dim),
-            name + ".o_proj": normal(rng, heads * head_dim, dim),
-            name + ".q_norm": np.ones((head_dim,), np.float32),
-            name + ".k_norm": np.ones((head_dim,), np.float32)}
+                   head_dim: int, head_norms: bool = True) -> dict:
+    out = {name + ".q_proj": normal(rng, dim, heads * head_dim),
+           name + ".k_proj": normal(rng, dim, kv_heads * head_dim),
+           name + ".v_proj": normal(rng, dim, kv_heads * head_dim),
+           name + ".o_proj": normal(rng, heads * head_dim, dim)}
+    if head_norms:
+        out[name + ".q_norm"] = np.ones((head_dim,), np.float32)
+        out[name + ".k_norm"] = np.ones((head_dim,), np.float32)
+    return out
 
 
-def init_ff(rng, name: str, dim: int, width: int) -> dict:
-    return {name + ".w1": normal(rng, dim, width),
-            name + ".w3": normal(rng, dim, width),
-            name + ".w2": normal(rng, width, dim)}
+def init_ff(rng, name: str, dim: int, width: int, gated: bool = True) -> dict:
+    out = {name + ".w1": normal(rng, dim, width)}
+    if gated:
+        out[name + ".w3"] = normal(rng, dim, width)
+    out[name + ".w2"] = normal(rng, width, dim)
+    return out
+
+
+def init_mamba2(rng, name: str, dim: int, heads: int, head_dim: int,
+                groups: int, state: int, taps: int, dt_range) -> dict:
+    """``A_log = log U[1, 16]``; ``dt_bias`` the inverse softplus of a
+    step drawn log-uniformly from ``dt_range`` = (min, max, floor) and
+    floored; ``D`` and the norm at 1; the convolution's bias at 0."""
+    d_in, lo, hi = heads * head_dim, *np.log(dt_range[:2])
+    conv_dim = d_in + 2 * groups * state
+    step = np.maximum(np.exp(rng.uniform(lo, hi, heads)), dt_range[2])
+    return {
+        name + ".in_proj": normal(rng, dim, d_in + conv_dim + heads),
+        name + ".conv_kernel": normal(rng, taps, conv_dim, fan_in=taps),
+        name + ".conv_bias": np.zeros((conv_dim,), np.float32),
+        name + ".A_log": np.log(rng.uniform(1.0, 16.0, heads)).astype(
+            np.float32),
+        name + ".dt_bias": (step + np.log(-np.expm1(-step))).astype(
+            np.float32),
+        name + ".D": np.ones((heads,), np.float32),
+        name + ".norm": np.ones((d_in,), np.float32),
+        name + ".out_proj": normal(rng, d_in, dim)}

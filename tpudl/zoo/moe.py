@@ -1,7 +1,8 @@
 """Routed experts that drop nothing and know which experts they hold.
 
 ``routed_ff`` is one expert-parallel rank's part of a top-k mixture of
-gated SiLU experts (sigmoid scores, a selection bias that takes no
+experts (gated SiLU, three products an expert, or ``relu2``, two and no
+gate: ``ACTS``; sigmoid scores, a selection bias that takes no
 gradient, weights renormalised over the selected): the router scores
 every published expert, and the rank computes the terms of ``Σ_e w_e ·
 FF_e(x)`` whose expert it holds (``held = (first, count)``; its leaves
@@ -14,10 +15,11 @@ exchange, and no code stands in for one.
 No capacity factor and no drop. The ``T·k`` (token, expert) pairs are
 ordered by expert with the pairs of absent experts last, so the held
 pairs are one dense prefix ``[0, pairs_held)`` of a buffer that has room
-for every pair that can occur; the three products are grouped matrix
+for every pair that can occur; the experts' products are grouped matrix
 products over that prefix (``jax.lax.ragged_dot``, whose transpose rules
 give the backward), and their work follows ``group_sizes``, that is the
-pairs really routed here.
+pairs really routed here. Widths that are no multiple of 256 (the rows',
+the experts') are padded with zeros for the products (``_TILE``).
 
 Rows move five times a layer, each time as a gather, never as a scatter
 and never as a ``[T, k, D]`` array. ``_dispatch`` gathers the tokens'
@@ -47,10 +49,21 @@ from tpudl.obs.trace import named_scope
 from tpudl.zoo.lm_blocks import normal
 
 __all__ = ["route", "routed_ff", "combine", "init_routed", "pair_order",
-           "ROUTES"]
+           "ROUTES", "ACTS"]
 
 # checkpoint_name of a routed layer's selection and of the ordering made of it
 ROUTES = "moe.routes"
+# an expert's form by the configuration's activation: is there a gate
+# (``W₂(silu(W₁x) ⊙ W₃x)``, three products) or not (``W₂ relu(W₁x)²``, two)
+ACTS = {"silu": True, "relu2": False}
+# XLA's grouped product wants its operands' widths in 256s. At the nemotron_h
+# cell's shapes (rows 2,688 wide, experts 1,856) it ran 1.5 times longer, and
+# 2.2 times longer a further row, than with the experts padded to 2,048, and
+# 1.5 times longer a further row again than with the rows padded to 2,816;
+# 1,920 gained nothing (my chip runs, PR 32, PERF.md section 6). So a width
+# that is no multiple is padded with zeros for the products: they add exactly
+# 0, and their gradient is cut off again. LFM2's 2,048 and 1,792 are multiples.
+_TILE = 256
 
 
 def route(p, name: str, x, *, top_k: int, scaling: float = 1.0,
@@ -163,14 +176,22 @@ combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def routed_ff(p, name: str, x, *, top_k: int, held, scaling: float = 1.0,
-              routes=None):
+              routes=None, act: str = "silu"):
     """The held experts' part of the routed feed-forward on ``x``
     ``[B, S, D]``, and the experts selected ``[B, S, k]``. ``routes``
-    replaces the selection (the weights stay the router's own). The
-    ordering carries the ``checkpoint_name`` that the selection carries:
-    a rematerialised block sorts once a step."""
+    replaces the selection (the weights stay the router's own); ``act``
+    is the experts' form (``ACTS``). The ordering carries the
+    ``checkpoint_name`` that the selection carries: a rematerialised
+    block sorts once a step."""
     bsz, s, dim = x.shape
     tokens = x.reshape(bsz * s, dim)
+    wide, deep = -p[name + ".w1"].shape[2] % _TILE, -dim % _TILE
+
+    def padded(w, rows, cols):
+        """An ``[E, rows, cols]`` leaf with zero rows and columns added
+        (the leaf itself where it needs none)."""
+        return jnp.pad(w, [(0, 0), (0, rows), (0, cols)]) if rows or cols else w
+
     with named_scope("moe.route"):
         if routes is not None:
             routes = routes.reshape(bsz * s, top_k)
@@ -180,20 +201,28 @@ def routed_ff(p, name: str, x, *, top_k: int, held, scaling: float = 1.0,
             checkpoint_name(index, ROUTES)
             for index in pair_order(experts.reshape(-1), held))
         held_rows = group_sizes.sum()
+        if deep:
+            tokens = jnp.pad(tokens, [(0, 0), (0, deep)])
         rows = _dispatch(tokens, order, place, held_rows, top_k)
     with named_scope("moe.experts"):
         dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes)
-        gate = jax.nn.silu(dot(rows, p[name + ".w1"])) * dot(
-            rows, p[name + ".w3"])
-        out = dot(gate, p[name + ".w2"])
+        gate = dot(rows, padded(p[name + ".w1"], deep, wide))
+        if ACTS[act]:
+            gate = jax.nn.silu(gate) * dot(
+                rows, padded(p[name + ".w3"], deep, wide))
+        else:
+            gate = jnp.square(jax.nn.relu(gate))
+        out = dot(gate, padded(p[name + ".w2"], wide, deep))
     with named_scope("moe.route"):
         out = combine(out, weights, order, place, held_rows)
+        if deep:
+            out = out[:, :dim]
     return out.astype(x.dtype).reshape(bsz, s, dim), experts.reshape(
         bsz, s, top_k)
 
 
 def init_routed(seed, name: str, dim: int, width: int, experts: int,
-                held) -> dict:
+                held, act: str = "silu") -> dict:
     """One rank's leaves: the router over all ``experts``, the selection
     bias (a buffer, constant under training; zeros, as a balancing
     scheme starts it: a bias of 0.02 already moves an expert's share of
@@ -210,6 +239,8 @@ def init_routed(seed, name: str, dim: int, width: int, experts: int,
                for e in range(first, first + count)]
     for leaf, shape in (("w1", (dim, width)), ("w3", (dim, width)),
                         ("w2", (width, dim))):
+        if leaf == "w3" and not ACTS[act]:
+            continue
         out[f"{name}.{leaf}"] = np.stack(
             [normal(rng, *shape) for rng in streams])
     return out
