@@ -323,9 +323,9 @@ def test_dispatch_backward_sums_a_tokens_rows_slot_by_slot():
 
 
 def _route_ops(text):
-    """``{(op, dims): n}`` of the sorts, scatters and gathers that a
-    compiled program's text files under ``moe.route`` (unit dimensions
-    dropped)."""
+    """``{(op, dims, looped): n}`` of the sorts, scatters and gathers
+    that a compiled program's text files under ``moe.route`` (unit
+    dimensions dropped; ``looped``: inside the body of a ``while``)."""
     import collections
     import re
 
@@ -336,33 +336,133 @@ def _route_ops(text):
         if m and "moe.route" in line:
             dims = tuple(int(v) for v in m.group(1).split(",")
                          if v and int(v) != 1)
-            found[m.group(2), dims] += 1
+            found[m.group(2), dims, "/while/body/" in line] += 1
     return found
 
 
-def test_a_rematerialised_block_orders_once_and_gathers_five_times():
+def test_a_rematerialised_block_orders_once_and_gathers_five_times(
+        monkeypatch):
     """The compiled gradient of two rematerialised routed blocks: one
     sort and one index scatter a layer (the ordering is saved with the
-    selection, not recomputed; the parent had two of each), and five
-    gathers of ``[T·k, D]`` rows a layer where the parent had six, a
-    slot's ``[T, D]`` gather counted as ``1/k``: dispatch forward and
-    recomputed, the combine's slots, and in the backward the cotangent
-    in sorted order and dispatch's slots."""
+    selection, not recomputed). Of the five moves of rows a layer the
+    three in sorted order (dispatch forward and recomputed, the
+    cotangent of the experts' output) are loops over chunks of the held
+    prefix: no gather of ``[T·k, D]`` rows is left, a chunk's gather
+    stands in a ``while`` body for each. The two in token order are what
+    they were: ``k`` slot gathers of ``[T, D]`` each, for the combine and
+    for dispatch's backward."""
+    monkeypatch.setattr(moe, "_CHUNK", 16)
     lm = Decoder({**BASE, "layer_types": ["conv", "full_attention"],
                   "num_dense_layers": 0})
     ids = tokens()
     ops = _route_ops(jax.jit(jax.grad(lm.loss_fn(remat=True))).lower(
         lm.init(3), ids).compile().as_text())
     layers, t, k = 2, ids.size, BASE["num_experts_per_tok"]
-    assert ops["sort", (t * k,)] == layers
-    assert ops["scatter", (t * k,)] == layers
+    assert moe.chunk_rows(t * k) == 16 < t * k
+    assert ops["sort", (t * k,), False] == layers
+    assert ops["scatter", (t * k,), False] == layers
     # rows 64 wide are padded to the grouped products' tile of 256, and
     # the compiler gathers them at either width (the pad before or after)
-    full = sum(ops["gather", (t * k, dim)] for dim in (64, 256))
-    slot = sum(ops["gather", (t, dim)] for dim in (64, 256))
-    assert full == 3 * layers and slot == 2 * k * layers
-    assert full + slot / k <= 5 * layers
-    assert not any(len(dims) == 3 for _, dims in ops)    # no [T, k, D]
+    def rows(n, looped):
+        return sum(ops["gather", (n, dim), looped] for dim in (64, 256))
+
+    assert rows(t * k, False) == rows(t * k, True) == 0
+    assert rows(16, True) == 3 * layers and rows(16, False) == 0
+    assert rows(t, False) == 2 * k * layers and rows(t, True) == 0
+    # the weights of a chunk's pairs, for the cotangent in sorted order
+    assert ops["gather", (16,), True] == layers
+    assert not any(len(dims) == 3 for _, dims, _ in ops)    # no [T, k, D]
+    assert not any(op != "gather" for op, _, looped in ops if looped)
+
+
+def _held_prefix_layers(dispatch, combine_rows, x, weights, scale, order,
+                        place, held_rows, k):
+    """Two rematerialised layers inside one ``lax.scan``: rows into sorted
+    order, scaled, and back at their tokens under ``weights``."""
+    @functools.partial(jax.checkpoint, prevent_cse=False)
+    def layer(x, scale):
+        rows = dispatch(x, order, place, held_rows, k) * scale
+        return combine_rows(rows, weights, order, place, held_rows), None
+
+    return jax.lax.scan(layer, x, scale)[0]
+
+
+@pytest.mark.parametrize("held_rows", [0, 1, 15, 16, 17, 52])
+def test_held_prefix_loops_against_the_masked_whole_gather(held_rows,
+                                                           monkeypatch):
+    """``_dispatch`` and ``combine`` (the loops of ``_over_held`` in
+    dispatch's forward and combine's backward) against the whole gather
+    masked past the prefix, with nothing held, one row, a row under, at
+    and over a chunk's edge (16) and every row of a buffer (52) that is
+    no multiple of a chunk: values and gradients under ``jit(grad)``,
+    rematerialised inside a ``lax.scan``, the prefix's length a value on
+    the device."""
+    monkeypatch.setattr(moe, "_CHUNK", 16)
+    t, k, dim = 26, 2, 16
+    weights, order, place, _ = _pairs(t, k)
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.standard_normal((t, dim)), jnp.float32)
+    scale = jnp.asarray(rng.standard_normal((2, t * k, dim)), jnp.float32)
+
+    def plain_dispatch(x, order, place, held_rows, k):
+        return jnp.where(jnp.arange(t * k)[:, None] < held_rows,
+                         x[order // k], 0.0)
+
+    def plain_combine(rows, weights, order, place, held_rows):
+        return _plain_combine(rows, weights, place, held_rows)
+
+    def loss(forms, x, weights, held_rows):
+        out = _held_prefix_layers(*forms, x, weights, scale, order, place,
+                                  held_rows, k)
+        return (out * jnp.cos(out)).sum()
+
+    held = jnp.int32(held_rows)
+    rows = np.asarray(jax.jit(moe._dispatch, static_argnums=4)(
+        x, order, place, held, k))
+    turns = -(-held_rows // 16)
+    written = min(turns * 16, t * k)
+    np.testing.assert_array_equal(
+        rows[:written], np.asarray(x)[np.asarray(order) // k][:written])
+    assert not np.any(rows[written:])
+    got = jax.jit(jax.value_and_grad(functools.partial(
+        loss, (moe._dispatch, moe.combine)), (0, 1)))(x, weights, held)
+    want = jax.jit(jax.value_and_grad(functools.partial(
+        loss, (plain_dispatch, plain_combine)), (0, 1)))(x, weights, held)
+    for mine, theirs in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        if np.any(theirs):
+            assert rel(mine, theirs) < 1e-6
+        else:
+            assert not np.any(mine)
+    assert bool(np.any(want[1][0])) == (held_rows > 0)
+
+
+@pytest.mark.parametrize("held_rows", [0, 17, 52])
+@pytest.mark.parametrize("act", list(moe.ACTS))
+def test_the_activations_hand_written_backward(act, held_rows, monkeypatch):
+    """``moe._activate`` and its backward, a chunk of the held prefix a
+    turn, against ``jax.vjp`` of the plain expression on the held rows.
+    Past the prefix's last chunk the value is zeros, and a product's
+    cotangent, written over the product, is still the product."""
+    monkeypatch.setattr(moe, "_CHUNK", 16)
+    n, wide = 52, 24
+    rng = np.random.default_rng(12)
+    products = tuple(
+        jnp.asarray(rng.standard_normal((n, wide)), jnp.float32)
+        for _ in range(1 + moe.ACTS[act]))
+    ct = jnp.asarray(rng.standard_normal((n, wide)), jnp.float32)
+    plain = {"silu": lambda gate, up: jax.nn.silu(gate) * up,
+             "relu2": lambda gate: jnp.square(jnp.maximum(gate, 0))}[act]
+    got, pull = jax.vjp(
+        lambda *rows: moe._activate(act, rows, jnp.int32(held_rows)),
+        *products)
+    want, plain_pull = jax.vjp(plain, *products)
+    written = min(-(-held_rows // 16) * 16, n)
+    for mine, theirs, past in zip((got, *pull(ct)), (want, *plain_pull(ct)),
+                                  (jnp.zeros_like(got), *products)):
+        assert mine.shape == theirs.shape and mine.dtype == theirs.dtype
+        if written:
+            assert rel(mine[:written], theirs[:written]) < 1e-6
+        np.testing.assert_array_equal(mine[written:], past[written:])
 
 
 def test_bfloat16_forward_is_the_parents_formula():
@@ -541,6 +641,15 @@ def test_route_stats_counts_pairs_and_publishes_counters():
 
     assert moved("moe.pairs_held") == held
     assert moved("moe.pairs_total") == stats["pairs_total"]
+    # the turns of the routed layer's held-prefix loops: toy buffers are
+    # one chunk each, run unless a layer holds nothing
+    assert moe.chunk_rows(ids.size * 2) == ids.size * 2
+    assert stats["chunks_total"] == moved("moe.chunks_total") == 2
+    assert stats["chunks_run"] == moved("moe.chunks_run") == sum(
+        rec["pairs_held"] > 0 for rec in stats["layers"])
+    assert moe.chunk_rows(10 ** 6) == moe._CHUNK
+    jax.eval_shape(lm.loss_fn(), p, ids)
+    assert obs.snapshot()["moe.chunk_rows"]["value"] == ids.size * 2
     assert after["moe.expert_tokens_max"]["value"] == stats[
         "expert_tokens_max"]
     # bf16 arithmetic may flip a near-tie, never the totals

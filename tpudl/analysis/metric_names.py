@@ -245,6 +245,16 @@ METRICS: tuple[Metric, ...] = (
     Metric("moe.pairs_total", "counter",
            "pairs routed in all (tokens x experts per token x routed "
            "layers)"),
+    Metric("moe.chunks_run", "counter",
+           "turns the routed layer's held-prefix loops take on the "
+           "route_stats batches: ceil(pairs_held / moe.chunk_rows) a "
+           "routed layer"),
+    Metric("moe.chunks_total", "counter",
+           "turns they would take over the whole buffers: chunks_run / "
+           "chunks_total is the share of the sorted-order work still done"),
+    Metric("moe.chunk_rows", "gauge",
+           "rows a turn of those loops takes in the last TRACED routed "
+           "layer (moe.chunk_rows of its T x k pairs)"),
     Metric("moe.expert_tokens_max", "gauge",
            "tokens of the fullest held expert in the last route_stats "
            "batch (nothing is dropped: the skew, not an overflow)"),

@@ -407,8 +407,12 @@ class Decoder:
         training step casts the parameters): per routed layer and summed
         over them ``pairs_total`` (tokens × k), ``pairs_held`` (pairs
         whose expert this rank holds) and the held experts' token
-        counts. Publishes ``moe.pairs_held`` / ``moe.pairs_total``
-        (counters) and ``moe.expert_tokens_max`` / ``_mean`` (gauges).
+        counts, and ``chunks_run`` of ``chunks_total``: the turns the
+        routed layer's held-prefix loops take on that batch
+        (``ceil(pairs_held / chunk)`` a layer) of those a whole buffer
+        would take. Publishes ``moe.pairs_held`` / ``moe.pairs_total`` /
+        ``moe.chunks_run`` / ``moe.chunks_total`` (counters) and
+        ``moe.expert_tokens_max`` / ``_mean`` (gauges).
         Called outside timed work: ``Trainer``'s loss stays a scalar."""
         key = str(compute_dtype)
         if key not in self._programs:
@@ -429,18 +433,22 @@ class Decoder:
             local = c.reshape(-1).astype(np.int64) - first
             per_expert = np.bincount(
                 local[(local >= 0) & (local < count)], minlength=count)
-            layers.append({"pairs_total": int(c.size),
-                           "pairs_held": int(per_expert.sum()),
-                           "expert_tokens": per_expert.tolist()})
+            held, chunk = int(per_expert.sum()), moe.chunk_rows(c.size)
+            layers.append({"pairs_total": int(c.size), "pairs_held": held,
+                           "expert_tokens": per_expert.tolist(),
+                           "chunks_run": -(-held // chunk),
+                           "chunks_total": -(-c.size // chunk)})
         tokens = np.array([n for rec in layers for n in rec["expert_tokens"]]
                           or [0])
         out = {"layers": layers,
-               "pairs_total": sum(rec["pairs_total"] for rec in layers),
-               "pairs_held": sum(rec["pairs_held"] for rec in layers),
+               **{key: sum(rec[key] for rec in layers) for key in (
+                   "pairs_total", "pairs_held", "chunks_run", "chunks_total")},
                "expert_tokens_max": int(tokens.max()),
                "expert_tokens_mean": float(tokens.mean())}
         _metrics.counter("moe.pairs_held").inc(out["pairs_held"])
         _metrics.counter("moe.pairs_total").inc(out["pairs_total"])
+        _metrics.counter("moe.chunks_run").inc(out["chunks_run"])
+        _metrics.counter("moe.chunks_total").inc(out["chunks_total"])
         _metrics.gauge("moe.expert_tokens_max").set(
             out["expert_tokens_max"])
         _metrics.gauge("moe.expert_tokens_mean").set(

@@ -22,18 +22,38 @@ pairs really routed here. Widths that are no multiple of 256 (the rows',
 the experts') are padded with zeros for the products (``_TILE``).
 
 Rows move five times a layer, each time as a gather, never as a scatter
-and never as a ``[T, k, D]`` array. ``_dispatch`` gathers the tokens'
-rows into sorted order (again when a block is rematerialised);
-``combine`` takes the experts' rows back one slot of ``k`` at a time,
-``[T, D]`` each, weighting and summing them in float32 as they arrive.
-Both backward passes are written by hand in SORTED order: the
-cotangent of the experts' output is one gather of the layer's cotangent
-scaled by the weights, the weights' own gradient is a row sum over that
-same gather (so nothing needs the un-ordered output, and a
-rematerialised block never recomputes ``combine``), and dispatch's
-backward sums a token's ``k`` rows slot by slot. The ordering
-(``order``, ``place``, ``group_sizes``) carries the selection's
-``checkpoint_name``: a rematerialised block sorts once a step.
+and never as a ``[T, k, D]`` array. The three moves in SORTED order
+follow the held prefix: ``_dispatch`` gathers the tokens' rows into
+sorted order (again when a block is rematerialised), and ``combine``'s
+backward gathers the layer's cotangent into it, as loops over chunks of
+rows whose trip count is ``ceil(pairs_held / chunk)``, a value on the
+device (``_over_held``). So does the activation between the grouped
+products, forward and backward (``_activate``). Shapes stay static and
+nothing can overflow; the work is proportional to the pairs routed here,
+as the grouped products' is. The loops are ``while``s, which reverse
+mode cannot differentiate: each is one side of a hand-written
+forward/backward pair. The two moves in TOKEN order cannot follow a
+prefix and are whole: ``combine`` takes the experts' rows back one slot
+of ``k`` at a time, ``[T, D]`` each, weighting and summing them in
+float32 as they arrive, and dispatch's backward sums a token's ``k``
+rows slot by slot; a slot past the prefix is masked as it arrives.
+
+What lies past the prefix. In the buffers the loops fill (the sorted
+rows, the activation, the cotangent of the experts' output): up to the
+end of the prefix's last chunk real rows of absent experts' pairs (zeros
+in the cotangent of the experts' output, which is masked row by row),
+then zeros. In the buffers the grouped products write, and in the
+activation's cotangents, which are written over them: whatever the
+products leave there. Nothing reads either as a number: the grouped
+products stop at ``group_sizes``, the slots are masked.
+
+Both backward passes of the moves are written by hand in sorted order:
+the cotangent of the experts' output is the gathered cotangent scaled by
+the weights, the weights' own gradient a row sum over that same gather
+(so nothing needs the un-ordered output, and a rematerialised block
+never recomputes ``combine``). The ordering (``order``, ``place``,
+``group_sizes``) carries the selection's ``checkpoint_name``: a
+rematerialised block sorts once a step.
 """
 
 from __future__ import annotations
@@ -45,11 +65,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from tpudl.obs import metrics as _metrics
 from tpudl.obs.trace import named_scope
 from tpudl.zoo.lm_blocks import normal
 
 __all__ = ["route", "routed_ff", "combine", "init_routed", "pair_order",
-           "ROUTES", "ACTS"]
+           "chunk_rows", "ROUTES", "ACTS"]
 
 # checkpoint_name of a routed layer's selection and of the ordering made of it
 ROUTES = "moe.routes"
@@ -63,7 +84,25 @@ ACTS = {"silu": True, "relu2": False}
 # 1,920 gained nothing (my chip runs, PR 32, PERF.md section 6). So a width
 # that is no multiple is padded with zeros for the products: they add exactly
 # 0, and their gradient is cut off again. LFM2's 2,048 and 1,792 are multiples.
+# The tokens are padded before dispatch and the output sliced after combine
+# (copies of ``[T, D]`` each way); every buffer of ``T·k`` rows, and so every
+# chunk the loops move, has the padded width.
 _TILE = 256
+# the plain expression between the grouped products, by activation
+_PLAIN = {"silu": lambda gate, up: jax.nn.silu(gate) * up,
+          "relu2": lambda gate: jnp.square(jax.nn.relu(gate))}
+# Rows a turn of a held-prefix loop (``_over_held``) takes, in buffers of more
+# rows than that. A turn's fixed cost hardly shows at 1,024 rows or more: over
+# a whole buffer of 196,608 rows of 2,816 the gather loop takes 13.87, 13.74,
+# 13.66, 13.62 ms in chunks of 1,024, 2,048, 4,096, 8,192, and a prefix of
+# 9,000 rows, rounded up to whole chunks, 2.22, 2.25, 2.38, 2.63. In the cells
+# 1,024 and 2,048 read alike at a held share of 4% (1,027.69 ms a step both)
+# and 759.69 against 758.52 at 39%. A row costs 1.4 (a gather) to 1.9 times (an
+# elementwise pass) what it costs in one pass over the whole buffer, whatever
+# the chunk: the compiler writes a turn's rows back with a
+# ``dynamic-update-slice`` of its own, after the fusion that made them (my
+# chip runs, PR 33, PERF.md section 6).
+_CHUNK = 2048
 
 
 def route(p, name: str, x, *, top_k: int, scaling: float = 1.0,
@@ -109,27 +148,74 @@ def _slots(place, k):
     return place.reshape(-1, k).T
 
 
+def chunk_rows(pairs: int) -> int:
+    """Rows a turn of the held-prefix loops takes in a buffer of
+    ``pairs`` rows (``_CHUNK``, or the whole of a smaller buffer)."""
+    return min(pairs, _CHUNK)
+
+
+def _over_held(body, held_rows, out, *operands):
+    """``out`` (an array or a tuple of arrays of ``T·k`` rows) with the
+    held prefix written in place: turn ``i`` of ``ceil(held_rows /
+    chunk)`` hands ``body`` the first row's index and rows ``[i·chunk,
+    (i+1)·chunk)`` of ``out`` as they stand and of every operand (arrays
+    of ``T·k`` rows), and writes what it returns over those rows of
+    ``out``. Rows past the last turn keep what ``out`` came with. The
+    trip count is a value on the device, so the loop is a ``while`` that
+    reverse mode cannot differentiate: every caller is one side of a
+    hand-written forward/backward pair."""
+    pairs = operands[0].shape[0]
+    chunk = chunk_rows(pairs)
+
+    def rows_of(whole, start):
+        return jax.lax.dynamic_slice_in_dim(whole, start, chunk)
+
+    def turn(i, out):
+        start = jnp.minimum(i * chunk, pairs - chunk)
+        old = jax.tree.map(lambda whole: rows_of(whole, start), out)
+        new = body(start, old, *(rows_of(op, start) for op in operands))
+        if pairs % chunk:
+            # the buffer's last chunk starts early, on rows the turn before
+            # has written (and a body may read ``old``): they stay
+            fresh = start + jnp.arange(chunk) >= i * chunk
+            new = jax.tree.map(lambda n, o: jnp.where(
+                fresh.reshape(-1, *[1] * (n.ndim - 1)), n, o), new, old)
+        return jax.tree.map(
+            lambda whole, part: jax.lax.dynamic_update_slice_in_dim(
+                whole, part, start, 0), out, new)
+
+    return jax.lax.fori_loop(0, -(-held_rows // chunk), turn, out)
+
+
+def _sorted_rows(x, order, held_rows, k):
+    """The held prefix of ``x[order // k]``; zeros past its last chunk."""
+    return _over_held(lambda start, old, pairs: x[pairs // k], held_rows,
+                      jnp.zeros((order.shape[0], x.shape[1]), x.dtype), order)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _dispatch(x, order, place, held_rows, k):
     """Rows of ``x`` ``[T, D]`` in sorted pair order ``[T·k, D]`` (pair
-    ``i`` belongs to token ``i // k``); ``held_rows`` is the length of
-    the held prefix."""
-    return x[order // k]
+    ``i`` belongs to token ``i // k``), as far as the last chunk of the
+    held prefix ``[0, held_rows)``: the grouped products read no row
+    past the prefix, and those past its last chunk are zeros."""
+    return _sorted_rows(x, order, held_rows, k)
 
 
 def _dispatch_fwd(x, order, place, held_rows, k):
-    return x[order // k], (place, held_rows)
+    return _sorted_rows(x, order, held_rows, k), (place, held_rows)
 
 
 def _dispatch_bwd(k, res, g):
     """``Σ_j g[place[:, j]]``, a token's ``k`` rows one slot at a time
-    (no ``[T, k, D]`` array), summed in float32 and rounded once."""
+    (no ``[T, k, D]`` array), summed in float32 and rounded once. The
+    grouped products define no cotangent for rows of no group: a slot
+    past the prefix is masked as it arrives, never read as a number."""
     place, held_rows = res
-    # the grouped products define no cotangent for rows of no group
-    g = jnp.where(jnp.arange(g.shape[0])[:, None] < held_rows, g, 0)
     total = 0.0
     for slot in _slots(place, k):
-        total = total + g[slot].astype(jnp.float32)
+        total = total + jnp.where((slot < held_rows)[:, None], g[slot],
+                                  0).astype(jnp.float32)
     return total.astype(g.dtype), None, None, None
 
 
@@ -156,23 +242,61 @@ def _combine_fwd(out_sorted, weights, order, place, held_rows):
 
 
 def _combine_bwd(res, g):
-    """Both cotangents in SORTED order, from one gather of ``g``: the
-    experts' output gets ``g · weight`` rounded once to its dtype, the
+    """Both cotangents in SORTED order, a chunk of the held prefix a
+    turn, from that chunk's gather of ``g``: the experts' output gets
+    ``g · weight`` rounded once to its dtype (zeros past the prefix), the
     weights get ``Σ_D g · out_sorted`` put back by ``place``. Nothing
     here needs the un-ordered output, so a rematerialised block never
     recomputes the forward combine."""
     out_sorted, weights, order, place, held_rows = res
     k = weights.shape[1]
-    held = (jnp.arange(order.shape[0]) < held_rows)[:, None]
-    g_sorted = g.astype(out_sorted.dtype)[order // k].astype(jnp.float32)
-    d_out = jnp.where(held, g_sorted * weights.reshape(-1)[order][:, None],
-                      0.0).astype(out_sorted.dtype)
-    d_weights = jnp.where(held, g_sorted * out_sorted.astype(jnp.float32),
-                          0.0).sum(-1)[place].reshape(weights.shape)
-    return d_out, d_weights.astype(weights.dtype), None, None, None
+    g, flat = g.astype(out_sorted.dtype), weights.reshape(-1)
+
+    def chunk(start, old, pairs, out_rows):
+        # the last chunk's tail holds real rows of absent experts' pairs
+        held = (start + jnp.arange(pairs.shape[0]) < held_rows)[:, None]
+        g_sorted = g[pairs // k].astype(jnp.float32)
+        d_out = jnp.where(held, g_sorted * flat[pairs][:, None], 0.0)
+        d_weights = jnp.where(held, g_sorted * out_rows.astype(jnp.float32),
+                              0.0).sum(-1)
+        return d_out.astype(out_rows.dtype), d_weights
+
+    d_out, d_weights = _over_held(
+        chunk, held_rows, (jnp.zeros_like(out_sorted),
+                           jnp.zeros(order.shape, jnp.float32)),
+        order, out_sorted)
+    return (d_out, d_weights[place].reshape(weights.shape).astype(
+        weights.dtype), None, None, None)
 
 
 combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _activate(act, products, held_rows):
+    """``_PLAIN[act]`` of the first grouped ``products`` (a tuple of
+    ``[T·k, wide]``: the gate and, in the gated form, the up-projection)
+    on the held prefix, for the last grouped product; zeros past the
+    prefix's last chunk."""
+    return _over_held(lambda start, old, *rows: _PLAIN[act](*rows),
+                      held_rows, jnp.zeros_like(products[0]), *products)
+
+
+def _activate_fwd(act, products, held_rows):
+    return _activate(act, products, held_rows), (products, held_rows)
+
+
+def _activate_bwd(act, res, g):
+    """Autodiff's backward of the plain expression, a chunk a turn, each
+    product's cotangent written over the product: past the prefix's last
+    chunk it holds what the grouped product left there."""
+    products, held_rows = res
+    return _over_held(
+        lambda start, rows, g_rows: jax.vjp(_PLAIN[act], *rows)[1](g_rows),
+        held_rows, products, g), None
+
+
+_activate.defvjp(_activate_fwd, _activate_bwd)
 
 
 def routed_ff(p, name: str, x, *, top_k: int, held, scaling: float = 1.0,
@@ -201,18 +325,16 @@ def routed_ff(p, name: str, x, *, top_k: int, held, scaling: float = 1.0,
             checkpoint_name(index, ROUTES)
             for index in pair_order(experts.reshape(-1), held))
         held_rows = group_sizes.sum()
+        _metrics.gauge("moe.chunk_rows").set(chunk_rows(order.shape[0]))
         if deep:
             tokens = jnp.pad(tokens, [(0, 0), (0, deep)])
         rows = _dispatch(tokens, order, place, held_rows, top_k)
     with named_scope("moe.experts"):
         dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes)
-        gate = dot(rows, padded(p[name + ".w1"], deep, wide))
-        if ACTS[act]:
-            gate = jax.nn.silu(gate) * dot(
-                rows, padded(p[name + ".w3"], deep, wide))
-        else:
-            gate = jnp.square(jax.nn.relu(gate))
-        out = dot(gate, padded(p[name + ".w2"], wide, deep))
+        products = tuple(dot(rows, padded(p[name + leaf], deep, wide))
+                         for leaf in ((".w1", ".w3") if ACTS[act] else (".w1",)))
+        out = dot(_activate(act, products, held_rows),
+                  padded(p[name + ".w2"], wide, deep))
     with named_scope("moe.route"):
         out = combine(out, weights, order, place, held_rows)
         if deep:
