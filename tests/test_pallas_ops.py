@@ -453,3 +453,75 @@ class TestTileClasses:
         assert (after["pallas.flash.block_q"],
                 after["pallas.flash.block_k"]) == shape
         assert after["pallas.flash.heads_a_step"] == 4
+
+
+class TestUnequalHeadWidths:
+    """Latent attention's heads: queries and keys 192 wide (128 without
+    position + 64 rotated), values 128; and a toy 24 / 16 whose value
+    head leaves the accumulator spare columns for the row sum. Every
+    operand keeps its own width; the scale is the query/key width's."""
+
+    @pytest.mark.parametrize("length", [32, 40])      # on and off a tile
+    @pytest.mark.parametrize("heads, kv_heads", [(4, 4), (4, 2)])
+    @pytest.mark.parametrize("d_qk, d_v", [(24, 16), (192, 128)])
+    def test_forward_and_gradients_match_dense(self, rng, d_qk, d_v, heads,
+                                               kv_heads, length):
+        q = jnp.asarray(rng.normal(size=(2, length, heads, d_qk)),
+                        jnp.float32)
+        k = jnp.asarray(rng.normal(size=(2, length, kv_heads, d_qk)),
+                        jnp.float32)
+        v = jnp.asarray(rng.normal(size=(2, length, kv_heads, d_v)),
+                        jnp.float32)
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal=True, block_q=16,
+                                   block_k=16, interpret=True,
+                                   return_lse=True, precision="highest")
+
+        (out, lse), (want_out, want_lse) = flash(q, k, v), _dense(
+            q, k, v, causal=True)
+        assert out.shape == (2, length, heads, d_v)
+        np.testing.assert_allclose(out, want_out, rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(lse, want_lse, rtol=2e-5, atol=2e-5)
+        shape = (2, length, heads, d_v)
+        got = jax.grad(_weighted(flash, shape, heads), (0, 1, 2))(q, k, v)
+        want = jax.grad(_weighted(lambda *a: _dense(*a, causal=True), shape,
+                                  heads), (0, 1, 2))(q, k, v)
+        for g, w_, operand in zip(got, want, (q, k, v)):
+            assert g.shape == operand.shape
+            np.testing.assert_allclose(g, w_, rtol=2e-4, atol=2e-4)
+
+    def test_equal_widths_trace_the_program_they_traced(self, rng):
+        """A value head as wide as the query/key head: the jaxpr names no
+        width twice, and ``out`` has ``q``'s shape as it always had."""
+        q, k, v = (jnp.asarray(rng.normal(size=(1, 32, 2, 16)), jnp.float32)
+                   for _ in range(3))
+
+        def f(q, k, v):
+            return flash_attention(q, k, v, causal=True, block_q=16,
+                                   block_k=16, interpret=True)
+
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda *a: f(*a).sum(), (0, 1, 2)))(q, k, v))
+        assert f(q, k, v).shape == q.shape
+        # the scale of a 16-wide head is a power of two and rides on q
+        assert "mul" in text and text.count("pallas_call") == 3
+
+    def test_the_widths_are_gauged_and_a_mismatch_is_named(self, rng):
+        from tpudl import obs
+
+        q = jax.ShapeDtypeStruct((4, 8192, 32, 192), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((4, 8192, 32, 128), jnp.bfloat16)
+        out = jax.eval_shape(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=False), q, q, v)
+        assert out.shape == v.shape
+        snap = {n: m["value"]
+                for n, m in obs.snapshot("pallas.flash.").items()}
+        assert (snap["pallas.flash.head_dim_qk"],
+                snap["pallas.flash.head_dim_v"]) == (192, 128)
+        assert (snap["pallas.flash.block_q"],
+                snap["pallas.flash.block_k"]) == (1024, 1024)
+        with pytest.raises(ValueError, match="queries 192 wide against "
+                                             "keys 128 wide"):
+            jax.eval_shape(lambda q, k, v: flash_attention(
+                q, k, v, interpret=True), q, v, v)

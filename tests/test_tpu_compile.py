@@ -61,6 +61,12 @@ def no_compile_cache():
     # the nemotron_h cell's: a group of 16 query heads a K/V head, a head
     # as wide as a lane
     (8192, (32, 2), 128, "bfloat16", None),
+    # latent attention's: queries and keys 192 wide (no multiple of the
+    # lane tile), values 128, the joyai-llm-flash-ep32 cell's and a float32
+    # pair at the most VMEM such a tile asks
+    (8192, (32, 32), (192, 128), "bfloat16", None),
+    (2048, (4, 4), (192, 128), "float32", None),
+    (300, (4, 4), (192, 128), "bfloat16", None),
 ])
 def test_flash_attention_with_grouped_queries_compiles_for_the_v5e(
         one_chip, no_compile_cache, seq, heads, head_dim, dtype, blocks):
@@ -82,12 +88,15 @@ def test_flash_attention_with_grouped_queries_compiles_for_the_v5e(
         out = flash_attention(q, k, v, causal=True, interpret=False, **kw)
         return jnp.sum(out.astype(jnp.float32))
 
-    q = jax.ShapeDtypeStruct((1, seq, heads[0], head_dim), dtype,
+    d_qk, d_v = head_dim if isinstance(head_dim, tuple) else (head_dim,) * 2
+    q = jax.ShapeDtypeStruct((1, seq, heads[0], d_qk), dtype,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((1, seq, heads[1], head_dim), dtype,
-                              sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, seq, heads[1], d_qk), dtype,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, seq, heads[1], d_v), dtype,
+                             sharding=one_chip)
     compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
-        q, kv, kv).compile()
+        q, k, v).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3      # forward, dq, dk/dv
     grads = compiled.output_shardings  # compiled: shapes came through

@@ -200,10 +200,13 @@ METRICS: tuple[Metric, ...] = (
     Metric("zoo.lm.layers.*", "counter",
            "decoder parts traced, by kind (conv / attention / ssm mixers, "
            "dense / routed feed-forwards, shared experts beside routed "
-           "ones): what a config-driven Decoder program is made of (conv "
-           "4, attention 1, dense 1, routed 4 per trace of "
-           "lfm2-8b-a1b-ep4; ssm 3, attention 1, routed 3, shared 3 of "
-           "nemotron-twotower-30b-a3b-ep16)"),
+           "ones; mla: latent-attention parts, counted under attention "
+           "too; mtp: multi-token-prediction modules, whose layer counts "
+           "by its parts as well): what a config-driven Decoder program "
+           "is made of (conv 4, attention 1, dense 1, routed 4 per trace "
+           "of lfm2-8b-a1b-ep4; ssm 3, attention 1, routed 3, shared 3 of "
+           "nemotron-twotower-30b-a3b-ep16; mla 6, attention 6, dense 1, "
+           "routed 5, shared 5, mtp 1 of joyai-llm-flash-ep32)"),
     Metric("lm.ssm.chunk", "gauge",
            "positions a chunk of the Mamba-2 mixer's selective scan in "
            "the last traced program (the configuration's chunk_size: "
@@ -238,6 +241,12 @@ METRICS: tuple[Metric, ...] = (
     Metric("pallas.flash.heads_a_step", "gauge",
            "query heads of one key/value head the last traced call "
            "takes in one grid step (4 for 32 heads over 8)"),
+    Metric("pallas.flash.head_dim_qk", "gauge",
+           "width of a query/key head in the last traced call (192 under "
+           "latent attention: 128 without position + 64 rotated)"),
+    Metric("pallas.flash.head_dim_v", "gauge",
+           "width of a value head in that call (128 under latent "
+           "attention; the query/key head's width everywhere else)"),
     # -- routed experts (published by Decoder.route_stats, outside steps)
     Metric("moe.pairs_held", "counter",
            "(token, expert) pairs routed to experts this rank holds, "

@@ -34,8 +34,11 @@ traced offset takes the same path as a static one:
 **What the shapes allow is decided from the shapes** (never by a
 caller): the scale rides on the query rows, outside the kernels, when
 it is a power of two (exact in any float type), the forward's row sum
-rides in the spare MXU output columns of ``p @ v`` when the head is
-narrower than a lane tile, the query heads that share a K/V head are
+rides in the spare MXU output columns of ``p @ v`` when the value head
+is narrower than a lane tile, a value head may be narrower than the
+query/key head (latent attention's 128 beside 192: every operand keeps
+its own width, nothing is padded to the other's), the query heads that
+share a K/V head are
 taken ``heads`` at a time in one grid step against one K/V tile, dk/dv
 work on the transposed score tile ``k qᵀ`` (no ``[block_q, block_k]``
 plane is ever transposed), and the tile shape comes from
@@ -82,16 +85,19 @@ class Tiles(NamedTuple):
     block_k: int
     heads: int
 
-    def count(self, pad_q, pad_k, causal, q_offset, k_offset, kv_len):
+    def count(self, pad_q, pad_k, causal, q_offset, k_offset, kv_len,
+              head_dims):
         """Bump ``pallas.flash.*`` for one call of ``flash_attention``,
         which under ``jax.jit`` is once per TRACE of the caller's
         program, on purpose (as ``zoo.conv_bn.folded`` is): the shape
-        chosen and, where the offsets are known now, the tiles a head
-        by class."""
+        chosen, the heads' widths ``(query/key, value)`` and, where the
+        offsets are known now, the tiles a head by class."""
         _metrics.counter("pallas.flash.launches").inc()
         _metrics.gauge("pallas.flash.block_q").set(self.block_q)
         _metrics.gauge("pallas.flash.block_k").set(self.block_k)
         _metrics.gauge("pallas.flash.heads_a_step").set(self.heads)
+        _metrics.gauge("pallas.flash.head_dim_qk").set(head_dims[0])
+        _metrics.gauge("pallas.flash.head_dim_v").set(head_dims[1])
         try:
             q_offset, k_offset = int(q_offset), int(k_offset)
         except TypeError:   # a tracer (the ring's): known at run time only
@@ -231,7 +237,9 @@ def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                   block_q: int, block_k: int, kv_len, precision,
                   heads: int, head_dim: int):
     """Online softmax over the K tiles of one Q tile, for ``heads`` query
-    heads against the one K/V tile. With a head narrower than its lane
+    heads against the one K/V tile (``head_dim`` is the VALUE head's
+    width, the accumulator's; the score product contracts over whatever
+    width ``q`` and ``k`` have). With a value head narrower than its lane
     tile the accumulator has spare columns: ``side_scr`` then holds V
     beside columns of ones, and the row sum comes out of the product
     ``p @ [V | 1]`` that was needed anyway (``acc[:, head_dim]``: the
@@ -406,7 +414,7 @@ def _params(heads, block_q, block_k, d, item):
     """Mosaic's scoped-VMEM default is 16 MiB; ask for what the largest
     of the three kernels takes when that is more (its blocks, double
     buffered; float32 scratch; four score planes), up to
-    ``_VMEM_ASK_MAX``."""
+    ``_VMEM_ASK_MAX``. ``d`` is the wider of the two head widths."""
     need = (2 * item * d * (3 * heads * block_q + 4 * block_k)
             + 4 * heads * block_q * (3 * _LANES + d)
             + 4 * 4 * block_q * block_k)
@@ -437,31 +445,32 @@ def _q_major_maps(causal, block_q, block_k, per_kv):
 def _pallas_flash_fwd(qg, kh, vh, qoff, koff, *, causal, tiles: Tiles,
                       kv_len, interpret, precision, group, scale):
     """The forward launch. Head-major: ``qg`` ``[R, heads, Sq, D]`` (the
-    ``heads`` query heads one grid step takes are neighbours), ``kh`` /
-    ``vh`` ``[B·Hkv, Sk, D]`` → (out like ``qg``, lse ``[R, heads, Sq]``)."""
+    ``heads`` query heads one grid step takes are neighbours), ``kh``
+    ``[B·Hkv, Sk, D]``, ``vh`` ``[B·Hkv, Sk, Dv]`` → (out ``[R, heads,
+    Sq, Dv]``, lse ``[R, heads, Sq]``)."""
     rows, heads, s_q, d = qg.shape
-    s_k = kh.shape[1]
+    s_k, d_v = vh.shape[1:]
     block_q, block_k = tiles.block_q, tiles.block_k
-    acc_w = d + (-d % _LANES)
+    acc_w = d_v + (-d_v % _LANES)
     q_map, row_map, k_map = _q_major_maps(causal, block_q, block_k,
                                           group // heads)
-    side = (pltpu.VMEM((block_k, acc_w), vh.dtype) if acc_w > d
+    side = (pltpu.VMEM((block_k, acc_w), vh.dtype) if acc_w > d_v
             else pltpu.VMEM((heads, block_q, _LANES), jnp.float32))
     out, lse8 = pl.pallas_call(
         functools.partial(
             _flash_kernel, causal=causal, scale=scale,
             block_q=block_q, block_k=block_k, kv_len=kv_len,
-            precision=precision, heads=heads, head_dim=d),
+            precision=precision, heads=heads, head_dim=d_v),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,     # the q and k global offsets
             grid=(rows, s_q // block_q, s_k // block_k),
             in_specs=[
                 pl.BlockSpec((1, heads, block_q, d), q_map),
                 pl.BlockSpec((1, block_k, d), k_map),
-                pl.BlockSpec((1, block_k, d), k_map),
+                pl.BlockSpec((1, block_k, d_v), k_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, heads, block_q, d), q_map),
+                pl.BlockSpec((1, heads, block_q, d_v), q_map),
                 pl.BlockSpec((1, heads, 8, block_q), row_map),
             ],
             scratch_shapes=[
@@ -470,11 +479,11 @@ def _pallas_flash_fwd(qg, kh, vh, qoff, koff, *, causal, tiles: Tiles,
                 side,
             ]),
         out_shape=[
-            jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+            jax.ShapeDtypeStruct((rows, heads, s_q, d_v), qg.dtype),
             # lse rides an 8-sublane broadcast dim for TPU output tiling
             jax.ShapeDtypeStruct((rows, heads, 8, s_q), jnp.float32),
         ],
-        compiler_params=_params(heads, block_q, block_k, d,
+        compiler_params=_params(heads, block_q, block_k, max(d, d_v),
                                 qg.dtype.itemsize),
         interpret=interpret,
     )(qoff, koff, qg, kh, vh)
@@ -492,8 +501,9 @@ def _pallas_flash_bwd(qg, kh, vh, out, lse, qoff, koff, do, dlse, *,
     constant fed to both kernels. ``group`` query rows share each K/V
     row (row ``bh`` reads K/V row ``bh // group``)."""
     rows, heads, s_q, d = qg.shape
-    s_k = kh.shape[1]
+    s_k, d_v = vh.shape[1:]
     per_kv, item = group // heads, qg.dtype.itemsize
+    params = _params(heads, tiles.block_q, tiles.block_k, max(d, d_v), item)
     # per-row constant: −δ + dlse, folded so the kernels need ONE vector
     dlt = (jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
            - dlse.astype(jnp.float32))
@@ -515,15 +525,15 @@ def _pallas_flash_bwd(qg, kh, vh, out, lse, qoff, koff, do, dlse, *,
             in_specs=[
                 pl.BlockSpec((1, heads, block_q, d), qi_q),
                 pl.BlockSpec((1, block_k, d), qi_k),
-                pl.BlockSpec((1, block_k, d), qi_k),
-                pl.BlockSpec((1, heads, block_q, d), qi_q),
+                pl.BlockSpec((1, block_k, d_v), qi_k),
+                pl.BlockSpec((1, heads, block_q, d_v), qi_q),
                 pl.BlockSpec((1, heads, 8, block_q), qi_row),
                 pl.BlockSpec((1, heads, 8, block_q), qi_row),
             ],
             out_specs=pl.BlockSpec((1, heads, block_q, d), qi_q),
             scratch_shapes=[pltpu.VMEM((heads, block_q, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
-        compiler_params=_params(heads, block_q, block_k, d, item),
+        compiler_params=params,
         interpret=interpret,
     )(qoff, koff, qg, kh, vh, do, lse8, dlt8)
 
@@ -556,24 +566,24 @@ def _pallas_flash_bwd(qg, kh, vh, out, lse, qoff, koff, do, dlse, *,
             in_specs=[
                 pl.BlockSpec((1, heads, block_q, d), ki_q),
                 pl.BlockSpec((1, block_k, d), ki_k),
-                pl.BlockSpec((1, block_k, d), ki_k),
-                pl.BlockSpec((1, heads, block_q, d), ki_q),
+                pl.BlockSpec((1, block_k, d_v), ki_k),
+                pl.BlockSpec((1, heads, block_q, d_v), ki_q),
                 pl.BlockSpec((1, heads, 8, block_q), ki_row),
                 pl.BlockSpec((1, heads, 8, block_q), ki_row),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_k, d), ki_k),
-                pl.BlockSpec((1, block_k, d), ki_k),
+                pl.BlockSpec((1, block_k, d_v), ki_k),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d_v), jnp.float32),
             ]),
         out_shape=[
             jax.ShapeDtypeStruct(kh.shape, kh.dtype),
             jax.ShapeDtypeStruct(vh.shape, vh.dtype),
         ],
-        compiler_params=_params(heads, block_q, block_k, d, item),
+        compiler_params=params,
         interpret=interpret,
     )(qoff, koff, qg, kh, vh, do, lse8, dlt8)
     return dq, dk, dv
@@ -614,9 +624,11 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
                     block_k: int | None = None,
                     interpret: bool | None = None,
                     return_lse: bool = False, precision=None):
-    """Tiled flash attention. q: [B, Sq, H, D], k/v: [B, Sk, Hkv, D] →
-    out [B, Sq, H, D] (and, with ``return_lse``, lse [B, Sq, H] —
-    ``logsumexp(scores)`` per query row, for ring partial merges).
+    """Tiled flash attention. q: [B, Sq, H, D], k: [B, Sk, Hkv, D], v:
+    [B, Sk, Hkv, Dv] → out [B, Sq, H, Dv] (and, with ``return_lse``, lse
+    [B, Sq, H] — ``logsumexp(scores)`` per query row, for ring partial
+    merges). ``Dv`` may differ from ``D`` (latent attention: 192-wide
+    queries and keys, 128-wide values); the scale is ``1/√D``.
 
     Grouped queries: ``Hkv`` divides ``H`` and K/V head ``j`` serves the
     query heads ``j·H/Hkv … (j+1)·H/Hkv − 1``. The kernels read the
@@ -646,6 +658,8 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
     if h % h_kv or v.shape[2] != h_kv:
         raise ValueError(f"{h} query heads cannot share {h_kv} key / "
                          f"{v.shape[2]} value heads")
+    if k.shape[3] != d:
+        raise ValueError(f"queries {d} wide against keys {k.shape[3]} wide")
     group, align = h // h_kv, 1 if interpret else _LANES
     tiles = tile_shapes(s_q, s_k, group, align=align)
     if block_q is not None:
@@ -654,7 +668,8 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
         tiles = tiles._replace(block_k=_fit_block(block_k, s_k, align))
     pad_q, pad_k = s_q + (-s_q % tiles.block_q), s_k + (-s_k % tiles.block_k)
     kv_len = s_k if pad_k != s_k else None
-    tiles.count(pad_q, pad_k, causal, q_offset, k_offset, kv_len)
+    tiles.count(pad_q, pad_k, causal, q_offset, k_offset, kv_len,
+                (d, v.shape[3]))
     return _flash_call(q, k, v, q_offset, k_offset, causal=causal,
                        tiles=tiles, pads=(pad_q, pad_k),
                        interpret=interpret, return_lse=return_lse,
@@ -671,7 +686,7 @@ def _flash_traced(q, k, v, q_offset, k_offset, *, causal, tiles, pads,
     # [B·H/heads, heads, Sq, D], a row the heads one grid step takes
     def to_bh(x, padded):
         x = jnp.pad(x, ((0, 0), (0, padded - x.shape[1]), (0, 0), (0, 0)))
-        return x.transpose(0, 2, 1, 3).reshape(-1, padded, d)
+        return x.transpose(0, 2, 1, 3).reshape(-1, padded, x.shape[3])
 
     scale = d ** -0.5
     if _scale_rides_on_q(d):
@@ -683,7 +698,7 @@ def _flash_traced(q, k, v, q_offset, k_offset, *, causal, tiles, pads,
     out, lse = _flash_fn(causal, tiles, s_k if pad_k != s_k else None,
                          interpret, precision, h // h_kv,
                          scale)(qg, kh, vh, qoff, koff)
-    out = out.reshape(b, h, pad_q, d).transpose(0, 2, 1, 3)[:, :s_q]
+    out = out.reshape(b, h, pad_q, -1).transpose(0, 2, 1, 3)[:, :s_q]
     if not return_lse:
         return out
     lse = lse.reshape(b, h, pad_q).transpose(0, 2, 1)[:, :s_q]

@@ -4,7 +4,8 @@
 builds the stack out of :mod:`tpudl.zoo.lm_blocks` and
 :mod:`tpudl.zoo.moe`. A layer is a list of PARTS, each behind an RMSNorm
 of its own and added to the residual stream, ``x ← x + part(norm(x))``;
-after the last layer a final norm and the head. Two families are built:
+after the last layer a final norm and the head. Three families are built,
+each recognised by its keys:
 
 - ``lfm2_moe`` (``layer_types``, ``num_dense_layers``, ``num_experts``,
   ``conv_L_cache``, ``rope_theta``, …): two parts a layer, an operator
@@ -24,7 +25,24 @@ after the last layer a final norm and the head. Two families are built:
   the second, denoising tower of the model card (adaLN, cross-tower
   conditioning, bidirectional in-block attention, block-diffusion
   decoding) has no key there and is NOT built; nor are ``-`` (dense MLP)
-  layers or a router limited to groups of experts (``n_group > 1``).
+  layers or a router limited to groups of experts (``n_group > 1``);
+- ``joyai_llm_flash``, the DeepSeek-V3 shape (``kv_lora_rank``,
+  ``q_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``,
+  ``v_head_dim``, ``first_k_dense_replace``, ``n_routed_experts``,
+  ``n_shared_experts``, ``num_nextn_predict_layers``, …): two parts a
+  layer, latent attention (``mla``: queries through a low rank, keys and
+  values expanded from one compressed latent, a rotated key that all
+  heads share, interleaved pairs) and a feed-forward (gated SiLU: dense
+  before ``first_k_dense_replace``, then routed experts by sigmoid score
+  + bias beside a gated-SiLU shared expert); an untied head; and with
+  ``num_nextn_predict_layers`` 1 a multi-token-prediction module (the
+  stream before the final norm and the next token's embedding, each
+  normed, concatenated and projected; one more such layer; a norm of
+  its own; the main head) whose loss on the token after next is added
+  with the weight ``mtp_weight``. Training's expanded form only: the
+  absorbed decode form and a cache of the latent are NOT built, nor
+  YaRN scaling, softmax scoring, group-limited routing, or more than one
+  prediction module.
 
 Two keys describe the share of a deployment this process holds, as
 expert parallelism and a sharded vocabulary need them:
@@ -40,8 +58,10 @@ Parameters are one flat dict of float32 leaves named
 ``layers.<l>.<block>.<leaf>`` (``init(seed)``, host numpy), so a leaf's
 kind is its name and the plain references
 (``benchmark/configs/lfm2-8b-a1b-ep4.py``,
-``benchmark/configs/nemotron-twotower-30b-a3b-ep16.py``) read the same
-dict. Train it through the normal path::
+``benchmark/configs/nemotron-twotower-30b-a3b-ep16.py``,
+``benchmark/configs/joyai-llm-flash-ep32.py``) read the same dict; a
+prediction module's leaves are ``mtp.<block>.<leaf>``. Train it through
+the normal path::
 
     lm = Decoder(config)
     trainer = ctx.trainer(
@@ -74,15 +94,19 @@ OPERATORS = ("conv", "full_attention")
 # nemotron_h's hybrid_override_pattern, a letter a layer
 PATTERN = {"M": "ssm", "*": "attention", "E": "routed"}
 # the leaves' block name by kind of part
-BLOCK = {"conv": "conv", "attention": "attn", "ssm": "ssm", "dense": "ff",
-         "routed": "moe"}
+BLOCK = {"conv": "conv", "attention": "attn", "mla": "attn", "ssm": "ssm",
+         "dense": "ff", "routed": "moe"}
+# the multi-token-prediction module's own leaves beside its layer's:
+# the norms of its two inputs, the projection of their concatenation
+# and the norm before the (shared) head
+MTP_NORMS = ("mtp.hnorm", "mtp.enorm", "mtp.final_norm")
 # a rematerialised block recomputes everything but its routing decision
 # and the ordering of the pairs that follows from it
 _SAVE_ROUTES = jax.checkpoint_policies.save_only_these_names(moe.ROUTES)
 
 
-def _layer_leaves(params, layer: int) -> dict:
-    pre = f"layers.{layer}."
+def _leaves(params, pre: str) -> dict:
+    """The leaves named ``pre + ...``, without the prefix."""
     return {k[len(pre):]: v for k, v in params.items() if k.startswith(pre)}
 
 
@@ -91,8 +115,11 @@ class Decoder:
         c = dict(config)
         self.dim = int(c["hidden_size"])
         self.hybrid = "hybrid_override_pattern" in c
+        self.mtp, self.mtp_weight, self.routed_by_sequence = 0, 0.0, False
         if self.hybrid:
             self._read_nemotron_h(c)
+        elif "kv_lora_rank" in c:
+            self._read_mla_moe(c)
         else:
             self._read_lfm2(c)
         self.n_layers = int(c.get("num_hidden_layers", len(self.layers)))
@@ -110,7 +137,7 @@ class Decoder:
         self.held = tuple(c.get("experts_held") or (0, self.experts))
         self.vocab = int(c["vocab_size"])
         self.vocab_slice = tuple(c.get("vocab_slice") or (0, self.vocab))
-        self.eps = float(c.get("norm_eps", 1e-5))
+        self.eps = float(c.get("norm_eps", c.get("rms_norm_eps", 1e-5)))
         if self.kinds()["routed"]:
             first, count = self.held
             if not (0 <= first and count > 0
@@ -142,6 +169,55 @@ class Decoder:
         if c.get("conv_bias"):
             raise ValueError("conv_bias: the short convolution here is "
                              "bias-free")
+
+    def _read_mla_moe(self, c):
+        """Latent attention and a feed-forward a layer, each behind its
+        norm: dense before ``first_k_dense_replace``, routed after."""
+        for key, built, why in (
+                ("n_group", 1, "a router limited to groups of experts"),
+                ("topk_group", 1, "a router limited to groups of experts"),
+                ("scoring_func", "sigmoid", "another score than the sigmoid"),
+                ("topk_method", "noaux_tc", "another selection than the "
+                 "top k of score + bias"),
+                ("rope_scaling", None, "scaled rotary positions (YaRN)"),
+                ("norm_topk_prob", True, "weights that are not renormalised "
+                 "over the selected"),
+                ("attention_bias", False, "a projection with a bias"),
+                ("moe_layer_freq", 1, "dense layers among the routed ones"),
+                ("rope_interleave", True, "half-split pairs in latent "
+                 "attention")):
+            if c.get(key, built) != built:
+                raise ValueError(f"{key} {c[key]!r}: {why} is not built")
+        self.mtp = int(c.get("num_nextn_predict_layers", 0))
+        if self.mtp > 1:
+            raise ValueError(f"num_nextn_predict_layers {self.mtp}: one "
+                             "multi-token-prediction module is built")
+        self.mtp_weight = float(c.get("mtp_weight", 0.0)) if self.mtp else 0.0
+        dense = int(c.get("first_k_dense_replace", 0))
+        self.layers = tuple(
+            (("input_layernorm", "mla"),
+             ("post_attention_layernorm",
+              "dense" if layer < dense else "routed"))
+            for layer in range(int(c["num_hidden_layers"])))
+        self.ff_width = int(c["intermediate_size"])
+        self.experts = int(c.get("n_routed_experts", 0))
+        self.act, self.tied = "silu", bool(c.get("tie_word_embeddings", False))
+        self.shared_width = int(c.get("n_shared_experts", 0)) * int(
+            c.get("moe_intermediate_size", 0))
+        self.theta = float(c["rope_theta"])
+        self.q_rank = int(c["q_lora_rank"])
+        self.kv_rank = int(c["kv_lora_rank"])
+        self.qk_nope = int(c["qk_nope_head_dim"])
+        self.qk_rope = int(c["qk_rope_head_dim"])
+        self.v_head_dim = int(c["v_head_dim"])
+        self.float32_leaves = ()
+        # at 8 experts a token a routed layer's buffers of T·k rows are
+        # twice LFM2's, five of 1.07 GB at 32,768 tokens, and do not fit
+        # beside a latent-attention block's residuals (18.3 GB a step at
+        # the cell's shapes, 14.1 GB so: compiled for a described v5e,
+        # PERF.md section 6): the routed experts take a sequence at a
+        # time, each rematerialised, as the nemotron_h mixer does
+        self.routed_by_sequence = True
 
     def _read_nemotron_h(self, c):
         """One part a layer, by the letter of the pattern."""
@@ -198,14 +274,25 @@ class Decoder:
     def routed(self, layer: int) -> bool:
         return any(kind == "routed" for _, kind in self.layers[layer])
 
+    def _with_module(self):
+        """The layers, and after them a prediction module's: one more
+        layer like the last."""
+        return (*self.layers, *[self.layers[-1]] * self.mtp)
+
     def kinds(self) -> dict:
         """Parts by kind, over the layers (``shared``: shared experts
-        beside routed ones)."""
+        beside routed ones). A latent-attention part counts as ``mla``
+        and as ``attention``, and a multi-token-prediction module
+        (``mtp``) brings one more routed layer's parts: both keys are
+        there for the family that has them."""
         out = dict.fromkeys(("conv", "attention", "ssm", "dense", "routed",
                              "shared"), 0)
-        for layer in self.layers:
+        for layer in self._with_module():
             for _, kind in layer:
-                out[kind] += 1
+                out[kind] = out.get(kind, 0) + 1
+        if "mla" in out:
+            out["attention"] += out["mla"]
+            out["mtp"] = self.mtp
         out["shared"] = out["routed"] if self.shared_width else 0
         return out
 
@@ -217,6 +304,10 @@ class Decoder:
             return B.init_attention(rng, name, self.dim, self.heads,
                                     self.kv_heads, self.head_dim,
                                     head_norms=self.theta is not None)
+        if kind == "mla":
+            return B.init_mla(rng, name, self.dim, self.heads, self.q_rank,
+                              self.kv_rank, self.qk_nope, self.qk_rope,
+                              self.v_head_dim)
         if kind == "ssm":
             return B.init_mamba2(rng, name, self.dim, self.ssm_heads,
                                  self.ssm_head_dim, self.ssm_groups,
@@ -228,7 +319,7 @@ class Decoder:
                               act=self.act)
         if self.shared_width:
             out.update(B.init_ff(rng, pre + "shared", self.dim,
-                                 self.shared_width, gated=False))
+                                 self.shared_width, gated=moe.ACTS[self.act]))
         return out
 
     def init(self, seed: int) -> dict:
@@ -244,13 +335,17 @@ class Decoder:
         if not self.tied:
             p["head"] = B.normal(np.random.default_rng([seed, 0, 1]), rows,
                                  self.dim, fan_in=self.dim)
-        for layer, parts in enumerate(self.layers):
-            pre = f"layers.{layer}."
+        for layer, parts in enumerate(self._with_module()):
+            pre = f"layers.{layer}." if layer < self.n_layers else "mtp."
             rng = np.random.default_rng([seed, layer + 1, 0])
             for norm, _ in parts:
                 p[pre + norm] = np.ones((self.dim,), np.float32)
             for _, kind in parts:
                 p.update(self._init_part(seed, layer, rng, kind, pre))
+            if pre == "mtp.":
+                p.update({norm: np.ones((self.dim,), np.float32)
+                          for norm in MTP_NORMS})
+                p["mtp.merge"] = B.normal(rng, 2 * self.dim, self.dim)
         return p
 
     @staticmethod
@@ -282,17 +377,32 @@ class Decoder:
             return B.attention_op(
                 p, "attn", h, heads=self.heads, kv_heads=self.kv_heads,
                 eps=self.eps, theta=self.theta), None
+        if kind == "mla":
+            return B.mla_op(p, "attn", h, heads=self.heads,
+                            nope=self.qk_nope, rope=self.qk_rope,
+                            eps=self.eps, theta=self.theta), None
         if kind == "ssm":
             return B.mamba2_op(
                 p, "ssm", h, heads=self.ssm_heads, groups=self.ssm_groups,
                 state=self.ssm_state, chunk=self.chunk, eps=self.eps), None
         if kind == "dense":
             return B.gated_ff(p, "ff", h), None
-        y, chosen = moe.routed_ff(p, "moe", h, top_k=self.top_k,
-                                  held=self.held, scaling=self.scaling,
-                                  routes=routes, act=self.act)
-        if self.shared_width:
-            y = y + B.relu2_ff(p, "shared", h)
+        routed = functools.partial(
+            moe.routed_ff, p, "moe", top_k=self.top_k, held=self.held,
+            scaling=self.scaling, act=self.act)
+        if self.routed_by_sequence:
+            def one(seq):
+                y, chosen = routed(seq[0][None], routes=(
+                    None if seq[1] is None else seq[1][None]))
+                return y[0], chosen[0]
+
+            y, chosen = jax.lax.map(jax.checkpoint(one, policy=_SAVE_ROUTES),
+                                    (h, routes))
+        else:
+            y, chosen = routed(h, routes=routes)
+        if self.shared_width:   # the shared expert has the experts' form
+            y = y + (B.gated_ff(p, "shared", h, scope="lm.shared_ff")
+                     if moe.ACTS[self.act] else B.relu2_ff(p, "shared", h))
         return y, chosen
 
     def _block(self, parts, p, x, routes):
@@ -308,15 +418,22 @@ class Decoder:
     def hidden(self, params, ids, routes=None, remat: bool = False):
         """``ids`` ``[B, S]`` -> the normed last hidden state ``[B, S,
         D]`` and the experts each routed layer selected (``[B, S, k]``
-        each). ``routes``, one array per routed layer, replaces the
-        selection. A run of consecutive layers of one kind is one
-        ``jax.lax.scan`` over their stacked leaves: the program holds
-        that block once however often the model repeats it (LFM2's
-        conv, conv, conv between attentions), for a copy of the run's
-        compute-dtype weights a step. Bumps ``zoo.lm.layers.<kind>``
-        once per layer while a program is TRACED, as
-        ``zoo.conv_bn.folded`` is, and ``moe.combine.fused`` once per
-        routed layer."""
+        each; a prediction module's routed layer is the last).
+        ``routes``, one array per routed layer, replaces the selection.
+        A run of consecutive layers of one kind is one ``jax.lax.scan``
+        over their stacked leaves: the program holds that block once
+        however often the model repeats it (LFM2's conv, conv, conv
+        between attentions), for a copy of the run's compute-dtype
+        weights a step. Bumps ``zoo.lm.layers.<kind>`` once per layer
+        while a program is TRACED, as ``zoo.conv_bn.folded`` is, and
+        ``moe.combine.fused`` once per routed layer."""
+        x, _, chosen = self._streams(params, ids, routes, remat)
+        return B.rms_norm(x, params["embedding_norm"], self.eps), chosen
+
+    def _streams(self, params, ids, routes, remat):
+        """One run of the stack: the stream after the last layer, BEFORE
+        the final norm; the prediction module's stream before its norm
+        (``None`` without a module); the experts selected."""
         kinds = self.kinds()
         for kind, n in kinds.items():
             _metrics.counter(f"zoo.lm.layers.{kind}").inc(n)
@@ -330,7 +447,7 @@ class Decoder:
             block = functools.partial(self._block, self.layers[first])
             if remat:
                 block = jax.checkpoint(block, policy=_SAVE_ROUTES)
-            leaves = [_layer_leaves(params, layer)
+            leaves = [_leaves(params, f"layers.{layer}.")
                       for layer in range(first, first + count)]
             mine = ([next(given) for _ in range(count)]
                     if given is not None and routed else None)
@@ -344,7 +461,29 @@ class Decoder:
                      mine and jnp.stack(mine)))
             if routed:
                 chosen.extend(picked)
-        return B.rms_norm(x, params["embedding_norm"], self.eps), chosen
+        if not self.mtp:
+            return x, None, chosen
+        module = functools.partial(self._mtp, self.layers[-1])
+        if remat:
+            module = jax.checkpoint(module, policy=_SAVE_ROUTES)
+        ahead, picked = module(
+            _leaves(params, "mtp."), x,
+            params["embed"][jnp.roll(ids, -1, axis=1)],
+            next(given) if given is not None else None)
+        return x, ahead, [*chosen, picked]
+
+    def _mtp(self, parts, p, x, ahead, routes):
+        """The multi-token-prediction module on the stack's stream ``x``
+        (before the final norm) and ``ahead``, the embedding of each
+        position's NEXT token: ``M [RMSNorm_h(x) ; RMSNorm_e(ahead)]``
+        and one more layer. The last position's next token is the
+        sequence's first, by ``roll``: the loss leaves the last two
+        positions out, and under a causal mask no other sees them."""
+        with named_scope("lm.mtp"):
+            merged = jnp.concatenate(
+                [B.rms_norm(x, p["hnorm"], self.eps),
+                 B.rms_norm(ahead, p["enorm"], self.eps)], -1) @ p["merge"]
+        return self._block(parts, p, merged, routes)
 
     def logits(self, params, ids, routes=None):
         """``[B, S, V]`` float32 over the table's slice: for small
@@ -365,20 +504,22 @@ class Decoder:
     def loss_fn(self, remat: bool = True, loss_chunk: int = 4096,
                 with_routes: bool = False):
         """``loss(params, ids, routes=None)``: next-token cross-entropy,
-        the mean over the batch's ``B·(S-1)`` predicted tokens. One
-        ``jax.checkpoint`` per block with ``remat``. The logits are never
-        whole: the head and the loss run over chunks of ``loss_chunk``
-        tokens, float32 inside a chunk and recomputed in the backward
-        pass, so they cost ``loss_chunk × V × 4`` bytes whatever the
-        batch."""
+        the mean over the batch's ``B·(S-1)`` predicted tokens; with a
+        prediction module, plus ``mtp_weight`` times the module's
+        cross-entropy against the token after next, the mean over the
+        ``B·(S-2)`` positions that have one (a second pass of the same
+        head, on the module's normed stream). One ``jax.checkpoint`` per
+        block with ``remat``. The logits are never whole: the head and
+        the loss run over chunks of ``loss_chunk`` tokens, float32
+        inside a chunk and recomputed in the backward pass, so they cost
+        ``loss_chunk × V × 4`` bytes whatever the batch."""
 
         def loss(params, ids, routes=None):
-            x, chosen = self.hidden(params, ids, routes, remat=remat)
+            x, ahead, chosen = self._streams(params, ids, routes, remat)
+            x = B.rms_norm(x, params["embedding_norm"], self.eps)
             bsz, s, dim = x.shape
             n = bsz * s
             chunk = loss_chunk if n % loss_chunk == 0 else n
-            target = jnp.roll(ids, -1, axis=1)
-            counted = jnp.broadcast_to(jnp.arange(s) < s - 1, (bsz, s))
             table = self._head(params)
 
             def chunk_nll(args):
@@ -390,12 +531,22 @@ class Decoder:
                     z, yc[:, None], axis=-1)[:, 0]
                 return jnp.sum(jnp.where(mc, nll, 0.0))
 
-            with named_scope("lm.head"):
-                parts = jax.lax.map(jax.checkpoint(chunk_nll), (
-                    x.reshape(n // chunk, chunk, dim),
-                    target.reshape(n // chunk, chunk),
-                    counted.reshape(n // chunk, chunk)))
-                value = parts.sum() / (bsz * (s - 1))
+            def mean_nll(x, skip):
+                """Of ``x``'s positions against the token ``skip``
+                further on, where the sequence has one."""
+                target = jnp.roll(ids, -skip, axis=1)
+                counted = jnp.broadcast_to(jnp.arange(s) < s - skip, (bsz, s))
+                with named_scope("lm.head"):
+                    parts = jax.lax.map(jax.checkpoint(chunk_nll), (
+                        x.reshape(n // chunk, chunk, dim),
+                        target.reshape(n // chunk, chunk),
+                        counted.reshape(n // chunk, chunk)))
+                    return parts.sum() / (bsz * (s - skip))
+
+            value = mean_nll(x, 1)
+            if ahead is not None and self.mtp_weight:
+                value = value + self.mtp_weight * mean_nll(B.rms_norm(
+                    ahead, params["mtp.final_norm"], self.eps), 2)
             return (value, chosen) if with_routes else value
 
         return loss

@@ -1,11 +1,13 @@
 """Decoder blocks as pure functions over a flat parameter dict.
 
 What a config-driven decoder (:mod:`tpudl.zoo.decoder`) is assembled from:
-RMSNorm, rotary positions, grouped-query attention (with a norm on every
-query and key head and a rotation, or with neither), LFM2's double-gated
-short convolution, the Mamba-2 mixer with its chunked selective scan, and
-the gated SiLU and relu² feed-forwards. Every function takes the dict
-``p``, the ``name`` its leaves are filed under (``layers.3.attn`` ->
+RMSNorm, rotary positions (half-split or interleaved pairs),
+grouped-query attention (with a norm on every query and key head and a
+rotation, or with neither), latent attention (MLA: low-rank queries, keys
+and values expanded from one compressed latent, one rotated key shared by
+all heads), LFM2's double-gated short convolution, the Mamba-2 mixer with
+its chunked selective scan, and the gated SiLU and relu² feed-forwards.
+Every function takes the dict ``p``, the ``name`` its leaves are filed under (``layers.3.attn`` ->
 ``layers.3.attn.q_proj``), and activations ``x`` of shape ``[B, S, D]``;
 projections are bias-free and stored ``[in, out]``. Activations keep the
 dtype the parameters were cast to (``with_compute_dtype``); norms,
@@ -27,9 +29,9 @@ from tpudl.obs import metrics as _metrics
 from tpudl.obs.trace import named_scope
 from tpudl.pallas_ops import flash_attention
 
-__all__ = ["rms_norm", "rotary", "conv_op", "attention_op", "gated_ff",
-           "relu2_ff", "mamba2_op", "ssd_scan", "init_conv",
-           "init_attention", "init_ff", "init_mamba2", "normal",
+__all__ = ["rms_norm", "rotary", "conv_op", "attention_op", "mla_op",
+           "gated_ff", "relu2_ff", "mamba2_op", "ssd_scan", "init_conv",
+           "init_attention", "init_mla", "init_ff", "init_mamba2", "normal",
            "MAMBA2_FLOAT32"]
 
 # the recurrence's per-head scalars: a step casts every other leaf to its
@@ -54,16 +56,25 @@ def rms_norm(x, weight, eps: float):
     return (y * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-def rotary(x, theta: float):
+def rotary(x, theta: float, interleaved: bool = False):
     """Rotary positions on ``x`` ``[B, S, H, d]``: position ``t`` turns
-    the half-split pair ``(x_i, x_{i+d/2})`` by ``t · theta^(-2i/d)``."""
+    the half-split pair ``(x_i, x_{i+d/2})`` by ``t · theta^(-2i/d)``,
+    or with ``interleaved`` the neighbouring pair ``(x_{2i}, x_{2i+1})``,
+    each staying where it lies (no reshape to pairs: the partner comes by
+    a shift of one lane either way)."""
     s, d = x.shape[1], x.shape[3]
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[None, :, None, :]
-    sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[None, :, None, :]
+    widen = ((lambda a: jnp.repeat(a, 2, -1)) if interleaved
+             else (lambda a: jnp.concatenate([a] * 2, -1)))
+    cos = widen(jnp.cos(angle))[None, :, None, :]
+    sin = widen(jnp.sin(angle))[None, :, None, :]
     x32 = x.astype(jnp.float32)
-    turned = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
+    if interleaved:
+        turned = jnp.where(jnp.arange(d) % 2 == 0, -jnp.roll(x32, -1, -1),
+                           jnp.roll(x32, 1, -1))
+    else:
+        turned = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
     return (x32 * cos + turned * sin).astype(x.dtype)
 
 
@@ -104,9 +115,43 @@ def attention_op(p, name: str, x, *, heads: int, kv_heads: int, eps: float,
         return out.reshape(bsz, s, heads * d) @ p[name + ".o_proj"]
 
 
-def gated_ff(p, name: str, x):
-    """``W₂(silu(W₁x) ⊙ W₃x)``."""
-    with named_scope("lm.dense_ff"):
+def mla_op(p, name: str, x, *, heads: int, nope: int, rope: int,
+           eps: float, theta: float):
+    """Causal multi-head latent attention in its expanded (training)
+    form: ``c_q = RMSNorm(x W_qa)``, ``[q_nope | q_rope] = c_q W_qb`` a
+    head; ``[c_kv | k_r] = x W_kva``, ``[k_nope | v] = RMSNorm(c_kv)
+    W_kvb`` a head. ``q_rope`` and the ONE ``k_r`` that every head
+    shares are rotated on interleaved pairs; scores ``(q_nope·k_nope +
+    q_rope·k_r) / √(nope + rope)``, through
+    :func:`tpudl.pallas_ops.flash_attention` with a value head of its
+    own width; ``W_o``. Per-head keys and values are materialised from
+    the latent (the absorbed form is decoding's, and is not built). The
+    four low-rank projections and their two norms lie under the inner
+    scope ``lm.attention.latent``."""
+    with named_scope("lm.attention"):
+        bsz, s, _ = x.shape
+        with named_scope("lm.attention.latent"):
+            c_q = rms_norm(x @ p[name + ".q_a_proj"], p[name + ".q_a_norm"],
+                           eps)
+            q = (c_q @ p[name + ".q_b_proj"]).reshape(bsz, s, heads,
+                                                      nope + rope)
+            c_kv, k_r = jnp.split(x @ p[name + ".kv_a_proj"], [
+                p[name + ".kv_b_proj"].shape[0]], axis=-1)
+            kv = (rms_norm(c_kv, p[name + ".kv_a_norm"], eps)
+                  @ p[name + ".kv_b_proj"]).reshape(bsz, s, heads, -1)
+        q = jnp.concatenate([q[..., :nope], rotary(
+            q[..., nope:], theta, interleaved=True)], -1)
+        k_r = rotary(k_r[:, :, None, :], theta, interleaved=True)
+        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+            k_r, (bsz, s, heads, rope))], -1)
+        out = flash_attention(q, k, kv[..., nope:], causal=True)
+        return out.reshape(bsz, s, -1) @ p[name + ".o_proj"]
+
+
+def gated_ff(p, name: str, x, scope: str = "lm.dense_ff"):
+    """``W₂(silu(W₁x) ⊙ W₃x)``; a shared expert of this form is filed
+    under the ``scope`` ``lm.shared_ff``."""
+    with named_scope(scope):
         gate = jax.nn.silu(x @ p[name + ".w1"]) * (x @ p[name + ".w3"])
         return gate @ p[name + ".w2"]
 
@@ -243,6 +288,17 @@ def init_attention(rng, name: str, dim: int, heads: int, kv_heads: int,
         out[name + ".q_norm"] = np.ones((head_dim,), np.float32)
         out[name + ".k_norm"] = np.ones((head_dim,), np.float32)
     return out
+
+
+def init_mla(rng, name: str, dim: int, heads: int, q_rank: int,
+             kv_rank: int, nope: int, rope: int, v_dim: int) -> dict:
+    return {name + ".q_a_proj": normal(rng, dim, q_rank),
+            name + ".q_a_norm": np.ones((q_rank,), np.float32),
+            name + ".q_b_proj": normal(rng, q_rank, heads * (nope + rope)),
+            name + ".kv_a_proj": normal(rng, dim, kv_rank + rope),
+            name + ".kv_a_norm": np.ones((kv_rank,), np.float32),
+            name + ".kv_b_proj": normal(rng, kv_rank, heads * (nope + v_dim)),
+            name + ".o_proj": normal(rng, heads * v_dim, dim)}
 
 
 def init_ff(rng, name: str, dim: int, width: int, gated: bool = True) -> dict:
