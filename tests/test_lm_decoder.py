@@ -902,3 +902,12 @@ def test_gradient_groups_cover_every_leaf(lm_train):
         {"num_experts": 8, "vocab_size": 16, "hidden_size": 4,
          "published": {"num_experts": 32, "vocab_size": 64}}) == {
              "num_experts": 32, "vocab_size": 64, "hidden_size": 4}
+
+
+@pytest.mark.parametrize("stack, bodies", [
+    ("whole", 1), ("one period", 1), ("attention+routed", 1),
+    ("conv+dense", 0)])
+def test_the_flash_forward_is_saved_across_rematerialisation(
+        stack, bodies, check_flash_saved_once):
+    lm, p = build(stack)
+    check_flash_saved_once(lm, p, tokens(), bodies=bodies)

@@ -644,3 +644,9 @@ def test_the_adapters_groups_cover_every_leaf(hybrid):
                  "benchmark_adapter_lm_train_beside_hybrid")
     assert "lm.conv_op" in lfm2.SCOPES and "lm.ssm" in hybrid.base.SCOPES
     assert lfm2.group_of("layers.0.conv.kernel") == "conv"
+
+
+def test_the_flash_forward_is_saved_across_rematerialisation(
+        check_flash_saved_once):
+    lm, p = build("MEM*E")
+    check_flash_saved_once(lm, p, tokens(), bodies=1)

@@ -554,3 +554,12 @@ def test_the_adapters_groups_cover_every_leaf(adapter):
                  "benchmark_adapter_lm_train_beside_mla")
     assert "lm.conv_op" in lfm2.SCOPES and "lm.mtp" in adapter.base.SCOPES
     assert lfm2.group_of("layers.0.conv.kernel") == "conv"
+
+
+def test_the_flash_forward_is_saved_across_rematerialisation(
+        check_flash_saved_once):
+    """The dense layer, the scanned run of two routed layers and the
+    module: three traced bodies for four latent-attention layers."""
+    lm, p = build()
+    assert lm.runs() == [(0, 1), (1, 2)] and lm.kinds()["mla"] == 4
+    check_flash_saved_once(lm, p, tokens(), bodies=3)

@@ -15,6 +15,7 @@ from tpudl import mesh as M
 from tpudl.attention import (attention_reference, ring_attention,
                              shard_sequence)
 from tpudl.pallas_ops import flash_attention
+from tpudl.zoo import moe
 
 
 @pytest.fixture(scope="module")
@@ -525,3 +526,124 @@ class TestUnequalHeadWidths:
                                              "keys 128 wide"):
             jax.eval_shape(lambda q, k, v: flash_attention(
                 q, k, v, interpret=True), q, v, v)
+
+
+class TestSavedAcrossRemat:
+    """The forward kernel's output and row statistics carry the
+    ``checkpoint_name`` ``SAVED`` where they are the backward's
+    residuals: a ``jax.checkpoint`` whose policy saves that name (the
+    decoder's) keeps them and launches no second forward kernel; any
+    other caller compiles what it compiled."""
+
+    ROUTES_ONLY = (moe.ROUTES,)
+
+    @staticmethod
+    def _loss(q, k, v, **kw):
+        return flash_attention(q, k, v, causal=True, block_q=16,
+                               block_k=16, interpret=True,
+                               **kw).astype(jnp.float32).sum()
+
+    @staticmethod
+    def _policy(names):
+        return jax.checkpoint_policies.save_only_these_names(*names)
+
+    @pytest.fixture(scope="class")
+    def operands(self, rng):
+        shapes = ((2, 32, 4, 24), (2, 32, 2, 24), (2, 32, 2, 16))
+        return tuple(jnp.asarray(rng.normal(size=s), jnp.bfloat16)
+                     for s in shapes)
+
+    def test_the_residuals_are_listed_by_name_with_their_shapes(
+            self, operands):
+        from jax._src.ad_checkpoint import saved_residuals
+
+        from tpudl import pallas_ops
+        from tpudl.zoo import decoder
+
+        # head-major, as the backward kernels take them: 2 x 4 heads in
+        # rows of 2 (the group), 32 positions, a 16-wide value head
+        out, lse = ((4, 2, 32, 16), "bfloat16"), ((4, 2, 32), "float32")
+
+        def named(fn, *args):
+            return [((aval.shape, str(aval.dtype)), why) for aval, why
+                    in saved_residuals(fn, *args)]
+
+        # the custom_vjp itself: its residuals ARE the named values
+        q, k, v = operands
+        head_major = (q.transpose(0, 2, 1, 3).reshape(4, 2, 32, 24),
+                      k.transpose(0, 2, 1, 3).reshape(4, 32, 24),
+                      v.transpose(0, 2, 1, 3).reshape(4, 32, 16))
+        off = jnp.zeros((1,), jnp.int32)
+        flash = pallas_ops._flash_fn(True, pallas_ops.Tiles(16, 16, 2), None,
+                                     True, None, 2, 24 ** -0.5)
+        inner = jax.checkpoint(lambda *a: flash(*a, off, off),
+                               policy=decoder._SAVE_NAMED)
+        by_name = [what for what, why in named(inner, *head_major)
+                   if f"named '{pallas_ops.SAVED}'" in why]
+        assert sorted(by_name) == [lse, out]
+        # through the public call (a jit of its own) they come out of
+        # that call; under the routes-only policy nothing does
+        for policy, kept in ((decoder._SAVE_NAMED, [lse, out]),
+                             (self._policy(self.ROUTES_ONLY), [])):
+            got = [what for what, why in named(
+                jax.checkpoint(self._loss, policy=policy), *operands)
+                if "from the argument" not in why]
+            assert sorted(got) == kept
+
+    @pytest.mark.parametrize("scanned", [False, True])
+    def test_three_kernels_a_call_where_routes_only_holds_four(
+            self, operands, count_eqns, scanned):
+        from tpudl import pallas_ops
+
+        def layers(policy):
+            layer = jax.checkpoint(self._loss, policy=policy)
+            if not scanned:
+                return layer
+
+            def twice(q, k, v):   # a scanned run of two layers
+                return jax.lax.scan(
+                    lambda total, scale: (total + layer(q * scale, k, v),
+                                          None),
+                    jnp.float32(0), jnp.asarray([1, 2], q.dtype))[0]
+            return twice
+
+        def grads(names):
+            return jax.grad(layers(self._policy(names)), (0, 1, 2))
+
+        kept = (*self.ROUTES_ONLY, pallas_ops.SAVED)
+        counts = {names: count_eqns(jax.make_jaxpr(grads(names))(*operands),
+                                    "pallas_call")
+                  for names in (self.ROUTES_ONLY, kept)}
+        assert counts == {self.ROUTES_ONLY: 4, kept: 3}
+        for got, want in zip(jax.jit(grads(kept))(*operands),
+                             jax.jit(grads(self.ROUTES_ONLY))(*operands)):
+            np.testing.assert_allclose(
+                np.asarray(got, np.float32), np.asarray(want, np.float32),
+                rtol=2e-2, atol=2e-2)
+
+    @pytest.mark.parametrize("return_lse", [False, True])
+    def test_without_a_checkpoint_the_program_is_the_parents(
+            self, operands, count_eqns, return_lse):
+        """Forward only, the jaxpr holds no name at all (the primal call
+        never enters the ``fwd`` rule); under a gradient the parent's
+        three kernels and two ``name`` equations that compile to
+        nothing; and so with ``return_lse`` and traced offsets, as the
+        ring calls it."""
+        def f(q, k, v, offset):
+            got = flash_attention(
+                q, k, v, causal=True, block_q=16, block_k=16,
+                interpret=True, return_lse=return_lse, q_offset=offset,
+                k_offset=offset)
+            return sum(x.astype(jnp.float32).sum()
+                       for x in (got if return_lse else [got]))
+
+        args = (*operands, jnp.int32(0))
+        forward = jax.make_jaxpr(f)(*args)
+        assert count_eqns(forward, "pallas_call") == 1
+        assert count_eqns(forward, "name") == 0
+        backward = jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(*args)
+        assert count_eqns(backward, "pallas_call") == 3
+        assert count_eqns(backward, "name") == 2
+        compiled = jax.jit(jax.grad(f, (0, 1, 2))).lower(
+            *args).compile().as_text()
+        assert "pallas.flash.saved" not in compiled

@@ -5,6 +5,7 @@ is described inside a fixture, so collection is the same in every worker,
 and all such compiles live in this one file (the on-chip-measurement
 guide, section 2)."""
 
+import functools
 import os
 
 import pytest
@@ -101,3 +102,38 @@ def test_flash_attention_with_grouped_queries_compiles_for_the_v5e(
     assert text.count("tpu_custom_call") >= 3      # forward, dq, dk/dv
     grads = compiled.output_shardings  # compiled: shapes came through
     assert grads is not None
+
+
+@pytest.mark.parametrize("saved, kernels", [(True, 3), (False, 4)])
+def test_a_rematerialised_layer_compiles_to_three_kernels_for_the_v5e(
+        one_chip, no_compile_cache, saved, kernels):
+    """What the decoder's policy is for, in the chip's own program: under
+    ``jax.checkpoint`` with the forward's output and row statistics
+    saved, the compiled gradient of a projection, the kernels and
+    ``W_o`` holds forward, dq and dk/dv; under the routes-only policy it
+    holds a second forward."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpudl import pallas_ops
+    from tpudl.zoo import moe
+
+    names = (moe.ROUTES, pallas_ops.SAVED) if saved else (moe.ROUTES,)
+
+    @functools.partial(
+        jax.checkpoint,
+        policy=jax.checkpoint_policies.save_only_these_names(*names))
+    def layer(x, w_in, w_out):
+        q, k, v = jnp.split((x @ w_in).reshape(1, 2048, 12, 128), 3, axis=2)
+        out = pallas_ops.flash_attention(q, k, v, causal=True,
+                                         interpret=False)
+        return x + out.reshape(1, 2048, 512) @ w_out
+
+    x = jax.ShapeDtypeStruct((1, 2048, 512), "bfloat16", sharding=one_chip)
+    w_in = jax.ShapeDtypeStruct((512, 1536), "bfloat16", sharding=one_chip)
+    w_out = jax.ShapeDtypeStruct((512, 512), "bfloat16", sharding=one_chip)
+    # the value keeps the first forward alive, as the next layer does
+    text = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(layer(*a).astype(jnp.float32)),
+        (0, 1, 2))).lower(x, w_in, w_out).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels

@@ -207,6 +207,20 @@ METRICS: tuple[Metric, ...] = (
            "of lfm2-8b-a1b-ep4; ssm 3, attention 1, routed 3, shared 3 of "
            "nemotron-twotower-30b-a3b-ep16; mla 6, attention 6, dense 1, "
            "routed 5, shared 5, mtp 1 of joyai-llm-flash-ep32)"),
+    Metric("zoo.lm.attention.saved", "counter",
+           "attention and latent-attention layers traced under remat: "
+           "each keeps its flash forward kernel's output and row "
+           "statistics (checkpoint_name pallas_ops.SAVED) from the "
+           "forward pass to its backward, so the recomputed block "
+           "launches no forward kernel (6 per trace of "
+           "joyai-llm-flash-ep32's loss, 1 of lfm2-8b-a1b-ep4's and of "
+           "nemotron-twotower-30b-a3b-ep16's)"),
+    Metric("zoo.lm.attention.saved_bytes", "gauge",
+           "bytes of those saved values a step in the last traced "
+           "program, by their shapes: layers x tokens x heads x (value "
+           "head x compute itemsize + 4); 1.64 GB / 0.14 / 0.27 in the "
+           "three cells (a row narrower than the 128-lane tile is padded "
+           "to it on the device: lfm2's 64-wide head holds 0.27)"),
     Metric("lm.ssm.chunk", "gauge",
            "positions a chunk of the Mamba-2 mixer's selective scan in "
            "the last traced program (the configuration's chunk_size: "

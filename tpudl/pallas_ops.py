@@ -44,6 +44,13 @@ work on the transposed score tile ``k qᵀ`` (no ``[block_q, block_k]``
 plane is ever transposed), and the tile shape comes from
 :func:`tile_shapes`.
 
+**The forward kernel runs once under rematerialisation.** Its output and
+row statistics carry the ``checkpoint_name`` :data:`SAVED` where they are
+the backward kernels' residuals. A ``jax.checkpoint`` whose policy saves
+that name (``zoo/decoder.py``'s does) keeps the two from the forward pass
+and recomputes only what feeds the kernels; for every other caller the
+name compiles to nothing.
+
 CPU/tests run the same kernel with ``interpret=True`` (pure jax
 semantics, no tiling constraints). Compiled, every block is a multiple
 of the 128-lane tile: a sequence that is not is PADDED up to the next
@@ -60,12 +67,18 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpudl.obs import metrics as _metrics
 
-__all__ = ["flash_attention", "tile_shapes", "tile_counts"]
+__all__ = ["flash_attention", "tile_shapes", "tile_counts", "SAVED"]
+
+# checkpoint_name of the forward kernel's output and row statistics, as the
+# backward kernels take them: a ``jax.checkpoint`` whose policy saves this
+# name does not launch the forward kernel again to get them back
+SAVED = "pallas.flash.saved"
 
 _NEG_INF = -1e30  # finite -inf stand-in: exp(x - _NEG_INF) never NaNs
 _LANES = 128
@@ -594,7 +607,11 @@ def _flash_fn(causal: bool, tiles: Tiles, kv_len, interpret: bool,
               precision, group: int, scale):
     """One custom-VJP'd head-major flash fn per static config: forward
     AND backward are Pallas kernels (pallas_call has no generic
-    autodiff), so neither direction materializes an S² tensor."""
+    autodiff), so neither direction materializes an S² tensor. The
+    forward's two outputs carry the ``checkpoint_name`` ``SAVED`` where
+    they are the backward's residuals: inside the ``fwd`` rule, because a
+    name put on the public output is a value AFTER the residual and a
+    policy that saves it would still recompute the kernel."""
     kw = dict(causal=causal, tiles=tiles, kv_len=kv_len,
               interpret=interpret, precision=precision, group=group,
               scale=scale)
@@ -605,7 +622,8 @@ def _flash_fn(causal: bool, tiles: Tiles, kv_len, interpret: bool,
     f = jax.custom_vjp(fwd_impl)
 
     def fwd(qg, kh, vh, qoff, koff):
-        out, lse = fwd_impl(qg, kh, vh, qoff, koff)
+        out, lse = (checkpoint_name(x, SAVED)
+                    for x in fwd_impl(qg, kh, vh, qoff, koff))
         return (out, lse), (qg, kh, vh, out, lse, qoff, koff)
 
     def bwd(res, cots):

@@ -83,6 +83,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpudl import pallas_ops
 from tpudl.obs import metrics as _metrics
 from tpudl.obs.trace import named_scope
 from tpudl.zoo import lm_blocks as B
@@ -101,8 +102,12 @@ BLOCK = {"conv": "conv", "attention": "attn", "mla": "attn", "ssm": "ssm",
 # and the norm before the (shared) head
 MTP_NORMS = ("mtp.hnorm", "mtp.enorm", "mtp.final_norm")
 # a rematerialised block recomputes everything but its routing decision
-# and the ordering of the pairs that follows from it
-_SAVE_ROUTES = jax.checkpoint_policies.save_only_these_names(moe.ROUTES)
+# with the ordering of the pairs that follows from it, and the flash
+# forward kernel's output and row statistics: the sort and the kernel run
+# once a step, and the backward kernels and W_o's gradient read what the
+# first launch wrote
+_SAVE_NAMED = jax.checkpoint_policies.save_only_these_names(
+    moe.ROUTES, pallas_ops.SAVED)
 
 
 def _leaves(params, pre: str) -> dict:
@@ -131,6 +136,7 @@ class Decoder:
         self.heads = int(c["num_attention_heads"])
         self.kv_heads = int(c.get("num_key_value_heads", self.heads))
         self.head_dim = int(c.get("head_dim") or self.dim // self.heads)
+        self.v_head_dim = int(c.get("v_head_dim") or self.head_dim)
         self.top_k = int(c.get("num_experts_per_tok", 0))
         self.expert_width = int(c.get("moe_intermediate_size", 0))
         self.scaling = float(c.get("routed_scaling_factor", 1.0))
@@ -209,7 +215,6 @@ class Decoder:
         self.kv_rank = int(c["kv_lora_rank"])
         self.qk_nope = int(c["qk_nope_head_dim"])
         self.qk_rope = int(c["qk_rope_head_dim"])
-        self.v_head_dim = int(c["v_head_dim"])
         self.float32_leaves = ()
         # at 8 experts a token a routed layer's buffers of T·k rows are
         # twice LFM2's, five of 1.07 GB at 32,768 tokens, and do not fit
@@ -396,7 +401,7 @@ class Decoder:
                     None if seq[1] is None else seq[1][None]))
                 return y[0], chosen[0]
 
-            y, chosen = jax.lax.map(jax.checkpoint(one, policy=_SAVE_ROUTES),
+            y, chosen = jax.lax.map(jax.checkpoint(one, policy=_SAVE_NAMED),
                                     (h, routes))
         else:
             y, chosen = routed(h, routes=routes)
@@ -424,9 +429,15 @@ class Decoder:
         over their stacked leaves: the program holds that block once
         however often the model repeats it (LFM2's conv, conv, conv
         between attentions), for a copy of the run's compute-dtype
-        weights a step. Bumps ``zoo.lm.layers.<kind>`` once per layer
-        while a program is TRACED, as ``zoo.conv_bn.folded`` is, and
-        ``moe.combine.fused`` once per routed layer."""
+        weights a step. With ``remat`` every block is one
+        ``jax.checkpoint`` that saves what carries a name (its routes
+        and their ordering; an attention part's kernel output and row
+        statistics) and recomputes the rest. Bumps
+        ``zoo.lm.layers.<kind>`` once per layer while a program is
+        TRACED, as ``zoo.conv_bn.folded`` is, ``moe.combine.fused`` once
+        per routed layer and, with ``remat``,
+        ``zoo.lm.attention.saved`` once per attention layer, beside the
+        gauge ``zoo.lm.attention.saved_bytes``."""
         x, _, chosen = self._streams(params, ids, routes, remat)
         return B.rms_norm(x, params["embedding_norm"], self.eps), chosen
 
@@ -440,13 +451,21 @@ class Decoder:
         if kinds["routed"]:   # moe.routed_ff has the one path
             _metrics.counter("moe.combine.fused").inc(kinds["routed"])
         x = params["embed"][ids]
+        if remat and kinds["attention"]:
+            # what _SAVE_NAMED keeps of the flash kernels a step, by the
+            # shapes: a value head a row in the stream's dtype, and a
+            # float32 statistic
+            _metrics.counter("zoo.lm.attention.saved").inc(kinds["attention"])
+            _metrics.gauge("zoo.lm.attention.saved_bytes").set(
+                kinds["attention"] * ids.size * self.heads
+                * (self.v_head_dim * x.dtype.itemsize + 4))
         given = iter(routes) if routes is not None else None
         chosen = []
         for first, count in self.runs():
             routed = self.routed(first)
             block = functools.partial(self._block, self.layers[first])
             if remat:
-                block = jax.checkpoint(block, policy=_SAVE_ROUTES)
+                block = jax.checkpoint(block, policy=_SAVE_NAMED)
             leaves = [_leaves(params, f"layers.{layer}.")
                       for layer in range(first, first + count)]
             mine = ([next(given) for _ in range(count)]
@@ -465,7 +484,7 @@ class Decoder:
             return x, None, chosen
         module = functools.partial(self._mtp, self.layers[-1])
         if remat:
-            module = jax.checkpoint(module, policy=_SAVE_ROUTES)
+            module = jax.checkpoint(module, policy=_SAVE_NAMED)
         ahead, picked = module(
             _leaves(params, "mtp."), x,
             params["embed"][jnp.roll(ids, -1, axis=1)],
@@ -509,7 +528,9 @@ class Decoder:
         cross-entropy against the token after next, the mean over the
         ``B·(S-2)`` positions that have one (a second pass of the same
         head, on the module's normed stream). One ``jax.checkpoint`` per
-        block with ``remat``. The logits are never whole: the head and
+        block with ``remat``, which saves the block's routes and its
+        flash kernel's output and recomputes the rest (:meth:`hidden`).
+        The logits are never whole: the head and
         the loss run over chunks of ``loss_chunk`` tokens, float32
         inside a chunk and recomputed in the backward pass, so they cost
         ``loss_chunk × V × 4`` bytes whatever the batch."""
