@@ -67,49 +67,6 @@ def test_profile_writes_trace(tmp_path):
                for s in inside)
 
 
-def test_summarize_device_trace():
-    """The trace-viewer-JSON aggregation: XLA-Modules lane sums to program time,
-    XLA-Ops lane aggregates per-op with category/bytes; host lanes and
-    non-TPU processes are ignored."""
-    from tpudl.obs import summarize_device_trace
-
-    events = [
-        {"ph": "M", "pid": 3, "name": "process_name",
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "pid": 3, "tid": 2, "name": "thread_name",
-         "args": {"name": "XLA Modules"}},
-        {"ph": "M", "pid": 3, "tid": 3, "name": "thread_name",
-         "args": {"name": "XLA Ops"}},
-        {"ph": "M", "pid": 7, "name": "process_name",
-         "args": {"name": "/host:CPU"}},
-        # two module executions of 1000us each
-        {"ph": "X", "pid": 3, "tid": 2, "name": "jit_step", "dur": 1000.0},
-        {"ph": "X", "pid": 3, "tid": 2, "name": "jit_step", "dur": 1000.0},
-        # ops: fusion.1 twice, conv once
-        {"ph": "X", "pid": 3, "tid": 3, "name": "fusion.1", "dur": 300.0,
-         "args": {"hlo_category": "convolution fusion",
-                  "long_name": "%fusion.1 = ...", "bytes_accessed": "100"}},
-        {"ph": "X", "pid": 3, "tid": 3, "name": "fusion.1", "dur": 300.0,
-         "args": {"hlo_category": "convolution fusion",
-                  "bytes_accessed": "100"}},
-        {"ph": "X", "pid": 3, "tid": 3, "name": "conv", "dur": 400.0,
-         "args": {"bytes_accessed": "0"}},
-        # host event with the same name must NOT count
-        {"ph": "X", "pid": 7, "tid": 1, "name": "fusion.1", "dur": 9e9},
-    ]
-    s = summarize_device_trace(events)
-    assert s["module_us"] == 2000.0 and s["module_count"] == 2
-    assert s["ops"]["fusion.1"]["us"] == 600.0
-    assert s["ops"]["fusion.1"]["count"] == 2
-    assert s["ops"]["fusion.1"]["category"] == "convolution fusion"
-    assert s["ops"]["fusion.1"]["bytes"] == 200
-    assert s["ops"]["conv"]["us"] == 400.0
-    # a CPU-only trace yields an empty summary, not a crash
-    empty = summarize_device_trace(
-        [e for e in events if e.get("pid") != 3])
-    assert empty["module_count"] == 0 and not empty["ops"]
-
-
 def test_persistent_compilation_cache_round_trip(tmp_path, monkeypatch):
     """compilation_cache: second process-equivalent compile of the same
     program must be served from the on-disk cache (observable: cache dir

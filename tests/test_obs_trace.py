@@ -2,11 +2,11 @@
 and the ``python -m tpudl.obs trace`` CLI (ISSUE 3 pillar 1; ISSUE 25:
 the shared clock, idle attribution, queue lead, the spans of ``fit``).
 
-Fixtures: synthetic trace-viewer dumps (gzipped JSON as the jax.profiler
-writes them) for the device-lane aggregation, and hand-written
-``xplane.pb`` files (a text proto serialized by jax's own ProfileData)
-with a ``/device:TPU:0`` plane and the ``Task Environment`` plane that
-carries the session's start and stop.
+Fixtures: hand-written ``xplane.pb`` files (a text proto serialized by
+jax's own ProfileData) with a ``/device:TPU:0`` plane and the ``Task
+Environment`` plane that carries the session's start and stop; a
+trace-viewer dump (gzipped JSON, as the jax.profiler also writes) lies
+beside them where a test shows that nothing reads it.
 """
 
 import gzip
@@ -101,46 +101,64 @@ OPS = [(FUSION, 100_000, 30_000), (FUSION, 220_000, 30_000),
        ("copy.2", 250_000, 10_000)]
 
 
-def _write_xplane(trace_dir, modules=MODULES, ops=OPS, start=START_NS,
-                  stop=STOP_NS, name="host.xplane.pb", task_plane=True):
-    """A hand-written ``xplane.pb``: one ``/device:TPU:0`` plane (when
-    ``modules`` is not None), a host plane that must be ignored, and the
-    ``Task Environment`` plane with the session's start and stop."""
+def _task_plane(start=None, stop=None):
+    """The ``Task Environment`` plane with a session's start and stop."""
+    start, stop = START_NS if start is None else start, (
+        STOP_NS if stop is None else stop)
+    return ('planes { name: "Task Environment"\n'
+            f"stats {{ metadata_id: 1 uint64_value: {start} }}\n"
+            f"stats {{ metadata_id: 2 uint64_value: {stop} }}\n"
+            'stat_metadata { key: 1 value { id: 1 '
+            'name: "profile_start_time" } }\n'
+            'stat_metadata { key: 2 value { id: 2 '
+            'name: "profile_stop_time" } }\n}\n')
+
+
+def _events_line(title, events):
+    """A line of ``(metadata id, start_ns, dur_ns)`` events."""
+    body = "".join(
+        f"events {{ metadata_id: {m} offset_ps: {s * 1000} "
+        f"duration_ps: {d * 1000} }}\n" for m, s, d in events)
+    return f'lines {{ name: "{title}" timestamp_ns: 0\n{body}}}\n'
+
+
+def _write_xspace(trace_dir, name, text):
+    """``text`` (a text-proto XSpace) as ``trace_dir/name``."""
     import jax
 
-    def line(title, events, ids):
-        body = "".join(
-            f"events {{ metadata_id: {ids[n]} offset_ps: {s * 1000} "
-            f"duration_ps: {d * 1000} }}\n" for n, s, d in events)
-        return f'lines {{ name: "{title}" timestamp_ns: 0\n{body}}}\n'
-
-    text = ""
-    if modules is not None:
-        ids = {n: i + 1 for i, n in enumerate(
-            dict.fromkeys(n for n, _, _ in list(modules) + list(ops)))}
-        meta = "".join(
-            f'event_metadata {{ key: {i} value {{ id: {i} name: "{n}" }} }}\n'
-            for n, i in ids.items())
-        text += ('planes { name: "/device:TPU:0"\n'
-                 + line("XLA Modules", modules, ids)
-                 + line("XLA Ops", ops, ids)
-                 + line("Steps", modules, ids)  # a line nobody asked for
-                 + meta + "}\n")
-    text += 'planes { name: "/host:CPU" }\n'
-    if task_plane:
-        text += ('planes { name: "Task Environment"\n'
-                 f"stats {{ metadata_id: 1 uint64_value: {start} }}\n"
-                 f"stats {{ metadata_id: 2 uint64_value: {stop} }}\n"
-                 'stat_metadata { key: 1 value { id: 1 '
-                 'name: "profile_start_time" } }\n'
-                 'stat_metadata { key: 2 value { id: 2 '
-                 'name: "profile_stop_time" } }\n}\n')
     os.makedirs(trace_dir, exist_ok=True)
     path = os.path.join(trace_dir, name)
     with open(path, "wb") as f:
         f.write(jax.profiler.ProfileData.text_proto_to_serialized_xspace(
             text))
     return path
+
+
+def _write_xplane(trace_dir, modules=MODULES, ops=OPS, start=START_NS,
+                  stop=STOP_NS, name="host.xplane.pb", task_plane=True):
+    """A hand-written ``xplane.pb``: one ``/device:TPU:0`` plane (when
+    ``modules`` is not None), a host plane that must be ignored, and the
+    ``Task Environment`` plane with the session's start and stop."""
+    text = ""
+    if modules is not None:
+        ids = {n: i + 1 for i, n in enumerate(
+            dict.fromkeys(n for n, _, _ in list(modules) + list(ops)))}
+
+        def line(title, events):
+            return _events_line(title, [(ids[n], s, d)
+                                        for n, s, d in events])
+
+        meta = "".join(
+            f'event_metadata {{ key: {i} value {{ id: {i} name: "{n}" }} }}\n'
+            for n, i in ids.items())
+        text += ('planes { name: "/device:TPU:0"\n'
+                 + line("XLA Modules", modules) + line("XLA Ops", ops)
+                 + line("Steps", modules)  # a line nobody asked for
+                 + meta + "}\n")
+    text += 'planes { name: "/host:CPU" }\n'
+    if task_plane:
+        text += _task_plane(start, stop)
+    return _write_xspace(trace_dir, name, text)
 
 
 def _host_tracer(base=START_NS):
@@ -164,37 +182,6 @@ def sp(name, start, dur, id, parent=None, tid=1, **attrs):
 
 
 class TestTraceParsing:
-    def test_load_trace_events_reads_gzipped_fixture(self, tmp_path):
-        d = str(tmp_path)
-        _write_device_gz(d, _device_events())
-        events = T.load_trace_events(d)
-        s = T.summarize_device_trace(events)
-        assert s["module_us"] == 110.0 and s["module_count"] == 2
-        assert s["ops"]["fusion.1"]["us"] == 60.0
-        assert s["ops"]["fusion.1"]["count"] == 2
-        assert s["ops"]["fusion.1"]["bytes"] == 200
-        assert s["ops"]["copy.2"]["us"] == 10.0
-
-    def test_load_trace_events_picks_newest(self, tmp_path):
-        d = str(tmp_path)
-        old = _write_device_gz(d, [], name="old.trace.json.gz")
-        _write_device_gz(d, _device_events(), name="new.trace.json.gz")
-        os.utime(old, (1, 1))
-        assert T.summarize_device_trace(
-            T.load_trace_events(d))["module_count"] == 2
-
-    def test_load_trace_events_missing_dir_raises(self, tmp_path):
-        with pytest.raises(FileNotFoundError, match="trace.json.gz"):
-            T.load_trace_events(str(tmp_path / "empty"))
-
-    def test_cpu_only_trace_summarizes_empty(self, tmp_path):
-        d = str(tmp_path)
-        cpu_only = [e for e in _device_events() if e.get("pid") != 3]
-        _write_device_gz(d, cpu_only)
-        s = T.summarize_device_trace(T.load_trace_events(d))
-        assert s["module_count"] == 0 and s["module_us"] == 0.0
-        assert s["ops"] == {}
-
     def test_find_trace_files(self, tmp_path):
         d = str(tmp_path)
         assert T.find_trace_files(d) == {"host": None, "device": None}
@@ -747,8 +734,6 @@ def _write_scoped_xplane(trace_dir, name="scoped.xplane.pb"):
     profiler puts it: the ``tf_op`` statistic of the EVENT METADATA, as a
     string or as a reference into the statistics' own names. Two runs of
     jit_step; a loop and an operation of its body overlap."""
-    import jax
-
     ops = {
         1: ("jit_step(9)", None),
         2: ("%fusion.1", "jit(step)/jvp(lm.conv_op)/dot_general:"),
@@ -774,12 +759,6 @@ def _write_scoped_xplane(trace_dir, name="scoped.xplane.pb"):
              f'stat_metadata {{ key: 4 value {{ id: 4 name: "{ops[4][1]}" '
              '} }\n')
 
-    def line(title, events):
-        body = "".join(
-            f"events {{ metadata_id: {m} offset_ps: {s * 1000} "
-            f"duration_ps: {d * 1000} }}\n" for m, s, d in events)
-        return f'lines {{ name: "{title}" timestamp_ns: 0\n{body}}}\n'
-
     modules = [(1, 1_000, 1_000), (1, 3_000, 1_000)]
     # run 1: conv 100 + ragged 200 + route 50 + loop [1500, 1900) whose
     # body op [1600, 1800) overlaps it + add 20 + an unnamed copy 10
@@ -788,22 +767,11 @@ def _write_scoped_xplane(trace_dir, name="scoped.xplane.pb"):
     events = [(m, 1_000 + s, d) for m, s, d in run]
     events += [(m, 3_000 + s, 2 * d if m == 3 else d) for m, s, d in run]
     events.append((2, 5_000, 999))          # after the last run: dropped
-    text = ('planes { name: "/device:TPU:0"\n' + line("XLA Modules", modules)
-            + line("XLA Ops", events) + meta + "}\n"
-            'planes { name: "/host:CPU" }\n'
-            'planes { name: "Task Environment"\n'
-            f"stats {{ metadata_id: 1 uint64_value: {START_NS} }}\n"
-            f"stats {{ metadata_id: 2 uint64_value: {STOP_NS} }}\n"
-            'stat_metadata { key: 1 value { id: 1 '
-            'name: "profile_start_time" } }\n'
-            'stat_metadata { key: 2 value { id: 2 '
-            'name: "profile_stop_time" } }\n}\n')
-    os.makedirs(trace_dir, exist_ok=True)
-    path = os.path.join(trace_dir, name)
-    with open(path, "wb") as f:
-        f.write(jax.profiler.ProfileData.text_proto_to_serialized_xspace(
-            text))
-    return path
+    return _write_xspace(trace_dir, name, (
+        'planes { name: "/device:TPU:0"\n'
+        + _events_line("XLA Modules", modules)
+        + _events_line("XLA Ops", events) + meta + "}\n"
+        'planes { name: "/host:CPU" }\n' + _task_plane()))
 
 
 SCOPES = ("lm.conv_op", "moe.route", "moe.experts", "lm.head", "lm.attention")
@@ -870,7 +838,9 @@ class TestDeviceScopes:
             str(tmp_path), "jit_step", SCOPES, parent=fit,
             kernels={"ragged-dot": "moe.experts"})
         assert len(runs) == 2
-        mine = [s for s in tracer.spans() if s.parent == fit.id]
+        wanted = {"device." + scope for scope in SCOPES}
+        mine = [s for s in tracer.spans()
+                if s.parent == fit.id and s.name in wanted]
         assert len(mine) == 2 * len(SCOPES)
         experts = [s for s in mine if s.name == "device.moe.experts"]
         assert [s.dur_ns for s in experts] == [200, 400]
@@ -895,3 +865,246 @@ def T_tracer():
     from tpudl.obs import get_tracer
 
     return get_tracer()
+
+
+# ---- the device account (PR 36) -------------------------------------------
+ACCOUNT_SCOPES = ("lm.attention", "moe.experts", "lm.head")
+ACCOUNT_KERNELS = {"ragged-dot": "moe.experts"}
+# what the fixture's program "declares" beside them
+ACCOUNT_DECLARED = ("lm.attention.latent", "lm.norm", "train.update")
+# key: (name, op_name, source, hlo_category); None = no such statistic
+ACCOUNT_OPS = {
+    1: ("jit_step(9)", None, None, None),
+    # a scanned run of layers: one unscoped loop ...
+    2: ("%while.1", None, "/x/tpudl/zoo/decoder.py:458", "while"),
+    # ... around scoped bodies, one of them under a nested declared scope
+    3: ("%fusion.2", "jit(step)/while/body/lm.attention/dot_general:",
+        "/x/tpudl/zoo/lm_blocks.py:110", "convolution fusion"),
+    4: ("%fusion.3", "jit(step)/while/body/lm.attention/"
+                     "lm.attention.latent/dot_general:",
+        "/x/tpudl/zoo/lm_blocks.py:134", "convolution fusion"),
+    5: ("%fusion.4", "jit(step)/while/body/jvp(lm.norm)/add:",
+        "/x/tpudl/zoo/decoder.py:420", "loop fusion"),
+    6: ("%copy.5", None, None, "data formatting"),     # the compiler's own
+    7: ("%ragged-dot-none.6", "ragged-dot-none:", None, "custom-call"),
+    8: ("%fusion.7", "jit(step)/train.update/add:",
+        "/x/tpudl/train/step.py:89", "loop fusion"),
+    # an unscoped operation with a source line
+    9: ("%fusion.8", "jit(step)/add:", "/x/tpudl/train/step.py:45",
+        "loop fusion"),
+    # a scoped loop around an operation the compiler left unnamed
+    10: ("%while.9", "jit(step)/jvp(lm.head)/while", None, "while"),
+    11: ("%copy.10", None, None, "data formatting"),
+    # a declared scope inside a declared scope: the outermost files it
+    12: ("%fusion.11", "jit(step)/train.update/lm.norm/mul:",
+         "/x/tpudl/train/step.py:90", "loop fusion"),
+}
+# (metadata key, start, duration) from the run's start: the account of a
+# run is attention 500, experts 200, head 600 (the requested), norm 100,
+# update 350 (declared), and 150 left: the loop's own 50, the unnamed
+# copy inside it 50 and the add with a source line 50. 1,900 in all
+ACCOUNT_RUN = [(2, 0, 700), (3, 0, 300), (4, 300, 200), (5, 500, 100),
+               (6, 600, 50), (7, 700, 200), (8, 900, 300), (12, 1_200, 50),
+               (9, 1_250, 50), (10, 1_300, 600), (11, 1_400, 100)]
+ACCOUNT_NS = {"lm.attention": 500, "moe.experts": 200, "lm.head": 600,
+              "lm.norm": 100, "train.update": 350, "unscoped": 150}
+
+
+def _write_account_xplane(trace_dir, name="account.xplane.pb"):
+    """Two runs of jit_step with ``ACCOUNT_RUN``'s operations: the first
+    as long as they are, the second with 100 ns at its end in which
+    nothing runs."""
+    stats = {3: "tf_op", 5: "source", 6: "hlo_category"}
+    meta = ""
+    for key, (op, *values) in ACCOUNT_OPS.items():
+        stat = "".join(
+            f'stats {{ metadata_id: {at} str_value: "{value}" }} '
+            for at, value in zip(stats, values) if value is not None)
+        meta += (f'event_metadata {{ key: {key} value {{ id: {key} '
+                 f'name: "{op}" {stat}}} }}\n')
+    meta += "".join(f'stat_metadata {{ key: {at} value {{ id: {at} '
+                    f'name: "{stat}" }} }}\n' for at, stat in stats.items())
+
+    modules = [(1, 1_000, 1_900), (1, 4_000, 2_000)]
+    events = [(m, base + s, d) for base in (1_000, 4_000)
+              for m, s, d in ACCOUNT_RUN]
+    events.append((9, 7_000, 999))          # after the last run: dropped
+    return _write_xspace(trace_dir, name, (
+        'planes { name: "/device:TPU:0"\n'
+        + _events_line("XLA Modules", modules)
+        + _events_line("XLA Ops", events) + meta + "}\n" + _task_plane()))
+
+
+@pytest.fixture(scope="module")
+def account(tmp_path_factory):
+    """A traced fit's two passes over the fixture, as the hybrid and
+    latent-attention adapters make them: the requested scopes, then a
+    scope nested inside one of them, for one parent."""
+    from tpudl import obs
+
+    d = str(tmp_path_factory.mktemp("account"))
+    _write_account_xplane(d)
+    for scope in (*ACCOUNT_SCOPES, *ACCOUNT_DECLARED):
+        T.named_scope(scope)     # declared as a traced program declares
+    tracer = T_tracer()
+    fit = tracer.record("train.fit", START_NS, 10_000, steps=2)
+    runs = T.record_device_scopes(d, "jit_step", ACCOUNT_SCOPES, parent=fit,
+                                  kernels=ACCOUNT_KERNELS)
+    first = [s for s in tracer.spans() if s.parent == fit.id]
+    gap = obs.snapshot()["obs.trace.account_gap_ns"]
+    again = T.record_device_scopes(d, "jit_step", ("lm.attention.latent",),
+                                   parent=fit)
+    second = [s for s in tracer.spans() if s.parent == fit.id][len(first):]
+    return {"dir": d, "runs": runs, "first": first, "gap": gap,
+            "again": again, "second": second}
+
+
+def _durations(spans, name):
+    return [s.dur_ns for s in spans if s.name == name]
+
+
+def _account_closes(a):
+    """Per run, the top-level spans and the remainder make up the step,
+    and the gauge holds what they leave of it."""
+    names = {"device." + scope for scope in ACCOUNT_NS}
+    for i, step in enumerate(_durations(a["first"], "device.step")):
+        filed = sum(s.dur_ns for s in a["first"]
+                    if s.name in names and s.attrs["run"] == i)
+        assert filed == 1_900 and step - filed == (0, 100)[i]
+    assert a["gap"]["value"] == 100 and a["gap"]["count"] >= 2
+
+
+def _account_files_each_operation_once(a):
+    table = T.device_account(a["dir"], kernels=ACCOUNT_KERNELS)
+    assert table["program"] == "jit_step" and table["runs"] == 2
+    assert sum(e["ops"] for e in table["scopes"]) == len(ACCOUNT_RUN)
+    assert table["filed_ms"] == pytest.approx(1_900 / 1e6)
+    assert table["step_ms"] == pytest.approx(1_950 / 1e6)
+    by_span = {s.name[len("device."):]: s.attrs["ops"] for s in a["first"]
+               if "ops" in (s.attrs or {}) and s.attrs["run"] == 0}
+    assert by_span == {"lm.norm": 1, "train.update": 2, "unscoped": 3}
+
+
+def _account_second_call_adds_its_own_spans_only(a):
+    assert [s.name for s in a["second"]] == ["device.lm.attention.latent"] * 2
+    assert [s.dur_ns for s in a["second"]] == [200, 200]
+    assert a["again"][0]["scopes"]["lm.attention.latent"] == 200
+    assert len(_durations(a["first"], "device.step")) == 2
+
+
+def _account_requested_scopes_read_as_before(a):
+    loaded = T.load_device_op_scopes(a["dir"], ACCOUNT_SCOPES,
+                                     ACCOUNT_KERNELS)
+    assert a["runs"] == T.scope_ns_by_run(loaded["modules"], loaded["ops"],
+                                          "jit_step")
+    # the None of the return value is the remainder before it was named
+    assert a["runs"][0]["scopes"] == {
+        **{scope: ACCOUNT_NS[scope] for scope in ACCOUNT_SCOPES}, None: 600}
+    for scope in ACCOUNT_SCOPES:
+        spans = [s for s in a["first"] if s.name == "device." + scope]
+        assert [s.dur_ns for s in spans] == [ACCOUNT_NS[scope]] * 2
+        assert [s.attrs for s in spans] == [{"run": 0}, {"run": 1}]
+        assert [s.start_ns for s in spans] == [START_NS + 1_000,
+                                               START_NS + 4_000]
+
+
+def _account_declared_scopes_get_their_spans(a):
+    assert _durations(a["first"], "device.lm.norm") == [100, 100]
+    # the norm under train.update is the outermost scope's
+    assert _durations(a["first"], "device.train.update") == [350, 350]
+    # a declared scope inside a requested one claims nothing of its own
+    assert _durations(a["first"], "device.lm.attention.latent") == []
+    assert _durations(a["first"], "device.step") == [1_900, 2_000]
+
+
+def _account_remainder_is_none_narrowed(a):
+    assert _durations(a["first"], "device.unscoped") == [150, 150]
+    for run in a["runs"]:
+        assert run["scopes"][None] - 150 == (ACCOUNT_NS["lm.norm"]
+                                            + ACCOUNT_NS["train.update"])
+
+
+def _account_table_names_the_remainder(a):
+    table = T.device_account(a["dir"], "jit_step", ACCOUNT_KERNELS)
+    scopes = [e["scope"] for e in table["scopes"]]
+    assert scopes[-1] == "unscoped" and scopes[0] == "lm.head"
+    by_scope = {e["scope"]: e for e in table["scopes"]}
+    assert {e["scope"]: round(e["ms"] * 1e6) for e in table["scopes"]} \
+        == ACCOUNT_NS
+    assert by_scope["lm.attention"]["share"] == pytest.approx(500 / 1_950)
+    rows = {(r["source"], r["category"]): round(r["ms"] * 1e6)
+            for r in by_scope["unscoped"]["rows"]}
+    assert rows == {("train/step.py:45", "loop fusion"): 50,
+                    ("copy", "data formatting"): 50,
+                    ("zoo/decoder.py:458", "while"): 50}
+    # the scoped loop answers for the unnamed copy inside it
+    assert {(r["source"], r["category"]): round(r["ms"] * 1e6)
+            for r in by_scope["lm.head"]["rows"]} == {
+        ("while", "while"): 500, ("copy", "data formatting"): 100}
+    # without the kernel's name the grouped product is the remainder's
+    bare = {e["scope"]: e for e in T.device_account(a["dir"])["scopes"]}
+    assert "moe.experts" not in bare
+    assert bare["unscoped"]["rows"][0] == {
+        "source": "ragged-dot-none", "category": "custom-call",
+        "ms": pytest.approx(200 / 1e6)}
+
+
+def _account_named_scope_declares(a):
+    from tpudl import obs
+
+    assert "test.account.declared" not in T.declared_scopes()
+    with obs.named_scope("test.account.declared"):
+        pass
+    assert "test.account.declared" in obs.declared_scopes()
+    assert set(ACCOUNT_DECLARED) <= T.declared_scopes()
+
+
+def _account_paths_name_their_scopes(a):
+    """What a reader that traced nothing files by."""
+    assert T._path_scopes(
+        op_name for _, op_name, *_ in ACCOUNT_OPS.values() if op_name) == {
+        "lm.attention", "lm.attention.latent", "lm.norm", "train.update",
+        "lm.head"}
+    assert T._path_scopes([
+        "jit(step)/jit(main)/transpose(jvp())/checkpoint/"
+        "rematted_computation/closed_call/while/body/cond/branch_1_fun/"
+        "jvp(jit(_roll_static))/mul:"]) == set()
+
+
+def _account_cli_prints_the_table(a, capsys):
+    from tpudl.obs.__main__ import main
+
+    assert main(["trace", a["dir"], "--out",
+                 os.path.join(a["dir"], "merged.json")]) == 0
+    out = capsys.readouterr().out
+    assert "device account of jit_step (2 runs" in out
+    assert "unscoped" in out and "train/step.py:45" in out
+
+
+@pytest.mark.parametrize("holds", [
+    _account_closes, _account_files_each_operation_once,
+    _account_second_call_adds_its_own_spans_only,
+    _account_requested_scopes_read_as_before,
+    _account_declared_scopes_get_their_spans,
+    _account_remainder_is_none_narrowed,
+    _account_table_names_the_remainder, _account_named_scope_declares,
+    _account_paths_name_their_scopes, _account_cli_prints_the_table],
+    ids=lambda f: f.__name__.removeprefix("_account_"))
+def test_device_account(account, capsys, holds):
+    if holds is _account_cli_prints_the_table:
+        holds(account, capsys)
+    else:
+        holds(account)
+
+
+def test_device_account_of_a_kept_chip_trace_closes():
+    """On a trace a builder kept from the chip (``chiprun_out/`` is no
+    part of a checkout): every operation filed once makes up the step to
+    within 1%."""
+    d = os.path.join(REPO, "chiprun_out", "xplane")
+    if not (os.path.isdir(d) and T.find_trace_files(d)["device"]):
+        pytest.skip("no kept chip trace under chiprun_out/xplane")
+    table = T.device_account(d)
+    assert table["runs"] >= 1 and len(table["scopes"]) > 2
+    assert abs(table["filed_ms"] - table["step_ms"]) < 0.01 * table["step_ms"]
+    assert table["scopes"][-1]["scope"] == "unscoped"

@@ -293,6 +293,12 @@ METRICS: tuple[Metric, ...] = (
     # -- obs self-metrics ----------------------------------------------
     Metric("obs.watchdog.stalls", "counter",
            "heartbeats flagged stalled (once per episode)"),
+    Metric("obs.trace.account_gap_ns", "gauge",
+           "device account of a traced step (record_device_scopes, once "
+           "per run of the step program): the run's nanoseconds less "
+           "what the requested scopes, the other declared scopes and "
+           "device.unscoped sum to; under 1% of the step when every "
+           "operation is filed once"),
     Metric("tsan.lock_order_inversions", "counter",
            "armed sanitizer: observed ABBA inversions (once per edge "
            "pair)"),

@@ -71,12 +71,11 @@ from tpudl.obs.watchdog import heartbeat, start_watchdog
 from tpudl.obs.pipeline import (PipelineReport, get_pipeline_report,
                                 last_pipeline_report, pipeline_reports,
                                 set_last_pipeline)
-from tpudl.obs.trace import (align, attribute_idle, load_device_planes,
+from tpudl.obs.trace import (align, attribute_idle, declared_scopes,
+                             device_account, load_device_planes,
                              load_host_spans, load_host_trace_events,
-                             load_trace_events, merge_trace_events,
-                             named_scope, profile, profile_window,
-                             queue_lead, summarize_device_trace,
-                             summarize_merged)
+                             merge_trace_events, named_scope, profile,
+                             profile_window, queue_lead, summarize_merged)
 from tpudl.obs.tracer import (children, export_chrome_trace, get_tracer,
                               self_ns, span)
 
@@ -91,9 +90,8 @@ __all__ = [
     "counter", "gauge", "histogram", "snapshot", "flush_metrics",
     "get_registry", "timed", "Meter",
     # device traces + merge
-    "profile", "named_scope", "load_trace_events",
-    "summarize_device_trace", "load_host_trace_events",
-    "merge_trace_events", "summarize_merged",
+    "profile", "named_scope", "declared_scopes", "device_account",
+    "load_host_trace_events", "merge_trace_events", "summarize_merged",
     "load_device_planes", "profile_window", "load_host_spans", "align",
     "attribute_idle", "queue_lead",
     # per-run pipeline reports

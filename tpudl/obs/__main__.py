@@ -8,9 +8,11 @@ clock the trace stamps its session with, writes the combined Chrome
 trace to ``<dir>/merged.trace.json`` (open it in Perfetto /
 chrome://tracing) and prints the merged summary: device busy time, host
 stage totals, overlap, device idle by host span, queue lead, device idle
-and compilations inside each ``train.fit``, top ops. Either stream alone
-still summarizes — a CPU-only run gets host totals, a span-less capture
-gets device lanes.
+and compilations inside each ``train.fit``, top ops, and the device
+account (``obs.trace.device_account``): the step program's time by the
+named scope each operation lies under, the remainder last, each with the
+source lines that take most of it. Either stream alone still summarizes
+— a CPU-only run gets host totals, a span-less capture gets device lanes.
 
 ``metrics <file.jsonl>`` schema-checks and tail-summarizes a
 ``TPUDL_METRICS_FILE`` emission (delegates the check to
@@ -120,7 +122,23 @@ def cmd_trace(trace_dir: str, out_path: str | None = None) -> int:
         for op in s["top_ops"]:
             print(f"  {op['name']:<28} {_fmt_ns(op['ns']):>12}"
                   f"  x{op['count']}")
+    if planes:
+        _print_account(T.device_account(trace_dir))
     return 0
+
+
+def _print_account(account: dict) -> None:
+    if not account:
+        return
+    print(f"device account of {account['program']} ({account['runs']} "
+          f"runs, median {account['step_ms']:.2f} ms; every operation filed "
+          f"once: {account['filed_ms']:.2f} ms):")
+    for entry in account["scopes"]:
+        print(f"  {entry['scope']:<28} {entry['ms']:>9.2f} ms "
+              f"{entry['share']:>6.1%}  x{entry['ops']}")
+        for row in entry["rows"]:
+            print(f"      {row['source']:<32} {row['category']:<24} "
+                  f"{row['ms']:>9.2f} ms")
 
 
 def cmd_metrics(path: str) -> int:

@@ -9,8 +9,12 @@ every device idle gap has a host span to answer for it
 (:func:`attribute_idle`), every run of a step program has the dispatch
 that enqueued it (:func:`queue_lead`), and one Chrome trace
 (:func:`merge_trace_events`) and one summary (:func:`summarize_merged`)
-hold both sides. ``python -m tpudl.obs trace <dir>`` drives all of this
-from the command line.
+hold both sides. The device's own time has an account too: every
+operation of a traced step filed once, under a ``jax.named_scope`` the
+program declared (:func:`named_scope`, :func:`declared_scopes`) or as the
+remainder (:func:`record_device_scopes` writes it into the span ring,
+:func:`device_account` returns the table a person reads). ``python -m
+tpudl.obs trace <dir>`` drives all of this from the command line.
 
 The clock: the profiler stamps every ``xplane.pb`` with
 ``profile_start_time`` / ``profile_stop_time`` in epoch nanoseconds (the
@@ -18,10 +22,6 @@ plane ``Task Environment``) and counts device events in nanoseconds
 since that start. A span's ``start_ns`` is ``time.time_ns()``, so
 ``start_ns - profile_start_time`` is its place on the device's clock: one
 subtraction, no stream is zeroed on its own first event.
-
-:func:`load_trace_events` / :func:`summarize_device_trace` still read the
-trace-viewer JSON (``*.trace.json.gz``) for the tools that aggregate
-device lanes alone.
 """
 
 from __future__ import annotations
@@ -37,20 +37,23 @@ import re
 import statistics
 from collections import Counter
 
+from tpudl.obs import metrics as _metrics
 from tpudl.obs.tracer import Span, children, get_tracer
 
-__all__ = ["profile", "named_scope", "load_trace_events",
-           "summarize_device_trace", "load_host_trace_events",
-           "find_trace_files", "load_device_planes", "profile_window",
-           "load_host_spans", "align", "attribute_idle", "queue_lead",
-           "traced_fit", "merge_trace_events", "summarize_merged",
-           "load_device_op_scopes", "scope_ns_by_run", "record_device_scopes"]
+__all__ = ["profile", "named_scope", "declared_scopes",
+           "load_host_trace_events", "find_trace_files",
+           "load_device_planes", "profile_window", "load_host_spans",
+           "align", "attribute_idle", "queue_lead", "traced_fit",
+           "merge_trace_events", "summarize_merged", "load_device_op_scopes",
+           "scope_ns_by_run", "record_device_scopes", "device_account"]
 
 HOST_PID = 0  # merged-trace pid for the host lane (device pids count up)
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 MODULES, OPS = "XLA Modules", "XLA Ops"
 TASK_PLANE = "Task Environment"
 NO_SPAN = "(no span)"
+UNSCOPED = "unscoped"  # the account's remainder: ``device.unscoped``
+_DECLARED: set = set()
 
 
 @contextlib.contextmanager
@@ -77,23 +80,19 @@ def profile(log_dir: str):
 def named_scope(name: str):
     """Label pipeline stages inside jitted code (jax.named_scope; jax
     imported lazily so host-only Frame pipelines — which report into
-    this module every map_batches call — never pay the jax import)."""
+    this module every map_batches call — never pay the jax import), and
+    declare the name: the device account files operations under the
+    scopes the program said it has (:func:`declared_scopes`). A set
+    insertion while a program is TRACED; nothing when it runs."""
     import jax
 
+    _DECLARED.add(name)
     return jax.named_scope(name)
 
 
-def load_trace_events(trace_dir: str) -> list[dict]:
-    """Events from the newest trace-viewer JSON under ``trace_dir``
-    (written by :func:`profile`; the TPU PJRT plugin populates real
-    device lanes, a CPU backend none)."""
-    paths = glob.glob(os.path.join(trace_dir, "**", "*.trace.json.gz"),
-                      recursive=True)
-    if not paths:
-        raise FileNotFoundError(f"no trace.json.gz under {trace_dir}")
-    with gzip.open(max(paths, key=os.path.getmtime)) as f:
-        tr = json.load(f)
-    return tr["traceEvents"] if isinstance(tr, dict) else tr
+def declared_scopes() -> frozenset:
+    """Every name this process has opened a :func:`named_scope` under."""
+    return frozenset(_DECLARED)
 
 
 def load_host_trace_events(path: str) -> list[dict]:
@@ -184,54 +183,6 @@ def align(spans, start_ns: int) -> list[Span]:
     """``spans`` in nanoseconds since ``start_ns`` (a session's
     ``profile_start_time``): the device events' own unit and origin."""
     return [s.shifted(start_ns) for s in spans]
-
-
-def summarize_device_trace(events: list[dict]) -> dict:
-    """Aggregate DEVICE-side time from a trace-viewer event list.
-
-    Returns ``{"module_us": total_us_across_XLA-Module_executions,
-    "module_count": n, "ops": {name: {us, count, category, long_name,
-    bytes}}}``. The "XLA Modules" lane is the compiled program's
-    on-device wall time — the honest chip-side throughput denominator,
-    independent of host dispatch latency; the "XLA Ops" lane is
-    the per-fusion attribution (SURVEY.md §5.1). Empty summary (count 0)
-    when the trace has no TPU lanes (CPU backend)."""
-    procs, lanes = _trace_metadata(events)
-    device_pids = {p for p, n in procs.items() if "TPU" in (n or "")}
-    module_us, module_count = 0.0, 0
-    ops: dict[str, dict] = {}
-    for e in events:
-        if e.get("ph") != "X" or e.get("pid") not in device_pids:
-            continue
-        lane = lanes.get((e["pid"], e["tid"]), "")
-        if lane == "XLA Modules":
-            module_us += e.get("dur", 0.0)
-            module_count += 1
-        elif lane == "XLA Ops":
-            a = e.get("args", {})
-            rec = ops.setdefault(e["name"], {
-                "us": 0.0, "count": 0, "category": "", "long_name": "",
-                "bytes": 0})
-            rec["us"] += e.get("dur", 0.0)
-            rec["count"] += 1
-            rec["category"] = a.get("hlo_category", rec["category"])
-            rec["long_name"] = a.get("long_name", rec["long_name"])
-            rec["bytes"] += int(a.get("bytes_accessed", 0) or 0)
-    return {"module_us": module_us, "module_count": module_count,
-            "ops": ops}
-
-
-def _trace_metadata(events):
-    """(pid → process name, (pid, tid) → lane name) from "M" events."""
-    procs, lanes = {}, {}
-    for e in events:
-        if e.get("ph") != "M":
-            continue
-        if e.get("name") == "process_name":
-            procs[e["pid"]] = e["args"].get("name", "")
-        elif e.get("name") == "thread_name":
-            lanes[(e["pid"], e["tid"])] = e["args"].get("name", "")
-    return procs, lanes
 
 
 def _merged(intervals) -> list[tuple[float, float]]:
@@ -482,17 +433,15 @@ def _xspace_subset():
         pool.FindMessageTypeByName(f"{pkg}.XSpace"))
 
 
-def load_device_op_scopes(trace_dir: str, scopes, kernels=None) -> dict:
-    """The first chip's plane of the newest trace under ``trace_dir``
-    with every operation filed under one of ``scopes`` (named scopes the
-    program was traced with): ``{"modules": [(name, start_ns, dur_ns)],
-    "ops": [(scope | None, start_ns, dur_ns)]}``, times in nanoseconds
-    since the session's start. An operation's scope is the outermost of
-    ``scopes`` on its ``op_name`` path (the ``tf_op`` statistic of its
-    event metadata). ``kernels`` maps the start of an ``op_name`` to a
-    scope, for kernels the compiler itself puts in and names (XLA's
-    grouped matrix product is ``ragged-dot-…``, under no scope of the
-    program). ``{}`` without a device plane or without protobuf."""
+def _load_ops(trace_dir: str) -> dict:
+    """The first chip's plane of the newest trace under ``trace_dir``,
+    parsed once: ``{"modules": [(name, start_ns, dur_ns)], "ops":
+    [(metadata id, start_ns, dur_ns)], "meta": {metadata id: (name,
+    op_name, source, category)}}``, times in nanoseconds since the
+    session's start. ``op_name`` is the ``tf_op`` statistic of the
+    event's metadata, ``source`` its ``file:line`` and ``category`` its
+    ``hlo_category``, each ``""`` where the compiler left none. ``{}``
+    without a device plane or without protobuf."""
     path = _newest(trace_dir, "*.xplane.pb")
     if path is None:
         raise FileNotFoundError(f"no xplane.pb under {trace_dir}")
@@ -506,24 +455,19 @@ def load_device_op_scopes(trace_dir: str, scopes, kernels=None) -> dict:
     if not planes:
         return {}
     plane = min(planes, key=lambda pl: pl.name)
-    wanted, kernels = set(scopes), dict(kernels or {})
     stat_names = {k: v.name for k, v in plane.stat_metadata.items()}
+    read = {"tf_op": 1, "source": 2, "hlo_category": 3}
 
-    def scope_of(meta):
+    def described(meta):
+        out = [_op(meta.name), "", "", ""]
         for stat in meta.stats:
-            if stat_names.get(stat.metadata_id) != "tf_op":
-                continue
-            op_name = stat.str_value or stat_names.get(stat.ref_value, "")
-            for part in _scope_parts(op_name):
-                if part in wanted:
-                    return part
-            return next((scope for head, scope in kernels.items()
-                         if op_name.startswith(head)), None)
-        return None
+            at = read.get(stat_names.get(stat.metadata_id))
+            if at:
+                out[at] = stat.str_value or stat_names.get(stat.ref_value, "")
+        return tuple(out)
 
-    names = {k: m.name for k, m in plane.event_metadata.items()}
-    by_id = {k: scope_of(m) for k, m in plane.event_metadata.items()}
-    out = {"modules": [], "ops": []}
+    out = {"modules": [], "ops": [],
+           "meta": {k: described(m) for k, m in plane.event_metadata.items()}}
     for line in plane.lines:
         if line.name not in (MODULES, OPS):
             continue
@@ -531,11 +475,59 @@ def load_device_op_scopes(trace_dir: str, scopes, kernels=None) -> dict:
         for e in line.events:
             start, dur = (base + e.offset_ps) // 1000, e.duration_ps // 1000
             if line.name == MODULES:
-                out["modules"].append((names.get(e.metadata_id, ""),
-                                       start, dur))
+                out["modules"].append((
+                    out["meta"].get(e.metadata_id, ("",))[0], start, dur))
             else:
-                out["ops"].append((by_id.get(e.metadata_id), start, dur))
+                out["ops"].append((e.metadata_id, start, dur))
     return out
+
+
+def _filed(op_name: str, wanted, kernels) -> str | None:
+    """The outermost of ``wanted`` on an operation's path, or the scope
+    of the ``kernels`` entry its ``op_name`` starts with, or None."""
+    for part in _scope_parts(op_name):
+        if part in wanted:
+            return part
+    return next((scope for head, scope in kernels.items()
+                 if op_name.startswith(head)), None)
+
+
+def load_device_op_scopes(trace_dir: str, scopes, kernels=None) -> dict:
+    """The first chip's plane of the newest trace under ``trace_dir``
+    with every operation filed under one of ``scopes`` (named scopes the
+    program was traced with): ``{"modules": [(name, start_ns, dur_ns)],
+    "ops": [(scope | None, start_ns, dur_ns)]}``, times in nanoseconds
+    since the session's start. An operation's scope is the outermost of
+    ``scopes`` on its ``op_name`` path (the ``tf_op`` statistic of its
+    event metadata). ``kernels`` maps the start of an ``op_name`` to a
+    scope, for kernels the compiler itself puts in and names (XLA's
+    grouped matrix product is ``ragged-dot-…``, under no scope of the
+    program). ``{}`` without a device plane or without protobuf."""
+    loaded = _load_ops(trace_dir)
+    return _by_scope(loaded, scopes, kernels) if loaded else {}
+
+
+def _by_scope(loaded, scopes, kernels) -> dict:
+    wanted, kernels = set(scopes), dict(kernels or {})
+    by_id = {k: _filed(m[1], wanted, kernels)
+             for k, m in loaded["meta"].items()}
+    return {"modules": loaded["modules"],
+            "ops": [(by_id[k], s, d) for k, s, d in loaded["ops"]]}
+
+
+def _by_run(modules, ops, program: str) -> list[tuple]:
+    """``((start_ns, end_ns), [op, ...])`` per run of ``program`` (its
+    events on ``XLA Modules``, in order) with the ``ops`` ``(x, start_ns,
+    dur_ns)`` that started inside it; the rest are dropped."""
+    runs = sorted((s, s + d) for name, s, d in modules
+                  if _program(name) == program)
+    starts = [s for s, _ in runs]
+    inside: list[list] = [[] for _ in runs]
+    for op in ops:
+        i = bisect.bisect_right(starts, op[1]) - 1
+        if i >= 0 and op[1] < runs[i][1]:
+            inside[i].append(op)
+    return list(zip(runs, inside))
 
 
 def scope_ns_by_run(modules, ops, program: str) -> list[dict]:
@@ -546,16 +538,11 @@ def scope_ns_by_run(modules, ops, program: str) -> list[dict]:
     operations of its body as events that overlap. ``None`` is the time
     of operations filed under no scope that no scoped operation covers:
     a scanned run of layers is one unscoped loop around scoped bodies."""
-    runs = sorted((s, s + d) for name, s, d in modules
-                  if _program(name) == program)
-    starts = [s for s, _ in runs]
-    inside: list[dict] = [{} for _ in runs]
-    for scope, s, d in ops:
-        i = bisect.bisect_right(starts, s) - 1
-        if i >= 0 and s < runs[i][1]:
-            inside[i].setdefault(scope, []).append((s, s + d))
     out = []
-    for (s, e), found in zip(runs, inside):
+    for (s, e), events in _by_run(modules, ops, program):
+        found: dict = {}
+        for scope, start, d in events:
+            found.setdefault(scope, []).append((start, start + d))
         scopes = {scope: _union(iv) for scope, iv in found.items()}
         if None in found:
             scopes[None] -= _intersection(found[None], [
@@ -575,22 +562,196 @@ def record_device_scopes(trace_dir: str, program: str, scopes,
     (:func:`profile_window`), children of ``parent`` (the traced
     ``train.fit`` span). Readers then find device time by scope where
     they find the host loop's. Returns :func:`scope_ns_by_run`'s list;
-    ``[]`` without a device plane."""
-    loaded = load_device_op_scopes(trace_dir, scopes, kernels)
+    ``[]`` without a device plane.
+
+    The FIRST call for a ``parent`` also writes the rest of the run's
+    account beside them, one span a run each, so that every nanosecond
+    of a traced step has a name: ``device.step`` (the run itself),
+    ``device.<scope>`` for every declared scope (:func:`declared_scopes`)
+    outside ``scopes`` that claims operations no scope of ``scopes``
+    claims (the outermost declared scope on the operation's path;
+    attribute ``ops``, their count), and ``device.unscoped``, what is
+    left (``ops``). A later call for the same parent (an adapter's second
+    pass, for scopes nested inside the first's) adds its own spans and
+    nothing more. The scopes of the first call, the other declared
+    scopes and the remainder should make up the step: the gauge
+    ``obs.trace.account_gap_ns`` takes, per run, what they leave of it
+    or claim beyond it."""
+    loaded = _load_ops(trace_dir)
     if not loaded:
         return []
     try:
         epoch = profile_window(trace_dir)[0]
     except ValueError:
         epoch = 0
-    runs = scope_ns_by_run(loaded["modules"], loaded["ops"], program)
+    filed = _by_scope(loaded, scopes, kernels)
+    runs = scope_ns_by_run(filed["modules"], filed["ops"], program)
     tracer = get_tracer()
+    first = parent is None or not any(
+        s.name == "device.step" and s.parent == parent.id
+        for s in tracer.spans())
     for i, run in enumerate(runs):
         for scope in scopes:
             tracer.record(f"device.{scope}", epoch + run["start_ns"],
                           run["scopes"].get(scope, 0), parent=parent,
                           run=i)
+    if first:
+        _record_account(loaded, program, scopes, kernels, runs, epoch,
+                        parent)
     return runs
+
+
+def _account_runs(loaded, program: str, scopes, kernels,
+                  declared) -> list[dict]:
+    """Per run of ``program``, every operation that started inside it
+    filed ONCE: ``{"start_ns", "dur_ns", "ns": {label: device ns}, "ops":
+    {label: count}, "rows": {label: {(source, category): ns}}}``. An
+    operation's own label is its scope by ``scopes`` and ``kernels`` as
+    :func:`load_device_op_scopes` files it, else the outermost of
+    ``declared`` on its path, else None (the remainder). The ``XLA Ops``
+    line carries a loop and the operations of its body as events that
+    nest, so each operation is charged the time none of the operations
+    inside it covers, under the label that answers for it: its own where
+    ``scopes`` gave one, else the label of the loop around it (a scoped
+    loop covers the compiler's unnamed copies inside it, as the union of
+    a scope's intervals does), else its own. ``source`` is the
+    operation's ``file:line`` cut to its last two parts, or the
+    operation's name without its number where the compiler left none."""
+    wanted, kernels = set(scopes), dict(kernels or {})
+    others = set(declared) - wanted
+    label, row = {}, {}
+    for key, (name, op_name, source, category) in loaded["meta"].items():
+        own = _filed(op_name, wanted, kernels)
+        label[key] = ((own, True) if own is not None
+                      else (_filed(op_name, others, {}), False))
+        row[key] = ("/".join(source.split("/")[-2:]) if source
+                    else name.lstrip("%").split(".")[0], category)
+    out = []
+    for (lo, hi), events in _by_run(loaded["modules"], loaded["ops"],
+                                    program):
+        open_: list = []    # (end, label, requested, index), innermost last
+        charged = []        # [label, key, own ns], one an operation
+        # a loop before the operations of its body that start with it
+        for s, neg, key in sorted((s, -d, key) for key, s, d in events):
+            while open_ and open_[-1][0] <= s:
+                open_.pop()
+            own, requested = label[key]
+            if open_:
+                _, around, around_requested, at = open_[-1]
+                charged[at][2] += neg
+                if not requested and around is not None:
+                    own, requested = around, around_requested
+            open_.append((s - neg, own, requested, len(charged)))
+            charged.append([own, key, -neg])
+        run = {"start_ns": lo, "dur_ns": hi - lo, "ns": {}, "ops": {},
+               "rows": {}}
+        for own, key, ns in charged:
+            run["ns"][own] = run["ns"].get(own, 0) + ns
+            run["ops"][own] = run["ops"].get(own, 0) + 1
+            rows = run["rows"].setdefault(own, {})
+            rows[row[key]] = rows.get(row[key], 0) + ns
+        out.append(run)
+    return out
+
+
+def _record_account(loaded, program, scopes, kernels, runs, epoch, parent):
+    """The spans :func:`record_device_scopes` adds on its first call for
+    a parent, and the gauge."""
+    account = _account_runs(loaded, program, scopes, kernels,
+                            declared_scopes())
+    found = sorted({scope for run in account for scope in run["ns"]
+                    if scope is not None and scope not in scopes})
+    tracer, gap = get_tracer(), _metrics.gauge("obs.trace.account_gap_ns")
+    for i, (run, filed) in enumerate(zip(account, runs)):
+        start = epoch + run["start_ns"]
+        tracer.record("device.step", start, run["dur_ns"], parent=parent,
+                      run=i)
+        for scope in found:
+            tracer.record(f"device.{scope}", start, run["ns"].get(scope, 0),
+                          parent=parent, run=i,
+                          ops=run["ops"].get(scope, 0))
+        tracer.record(f"device.{UNSCOPED}", start, run["ns"].get(None, 0),
+                      parent=parent, run=i, ops=run["ops"].get(None, 0))
+        gap.set(run["dur_ns"] - run["ns"].get(None, 0)
+                - sum(filed["scopes"].get(scope, 0) for scope in scopes)
+                - sum(run["ns"].get(scope, 0) for scope in found))
+
+
+# jax's own words on an operation's path: control flow, rematerialisation
+# and calls. A part that is none of them, nor a ``jit(...)`` call, nor the
+# path's last (the primitive), is a named scope of the program.
+_JAX_PARTS = frozenset({"while", "body", "body_pred", "cond", "checkpoint",
+                        "rematted_computation", "closed_call", "core_call",
+                        "custom_jvp_call", "custom_vjp_call", ""})
+
+
+def _path_scopes(op_names) -> set:
+    """The named scopes on the paths ``op_names``, for a reader that
+    traced no program and so has none declared."""
+    found = set()
+    for op_name in op_names:
+        for part in op_name.split("/")[:-1]:
+            while "(" in part and part.endswith(")"):
+                head, part = part[:part.index("(")], part[
+                    part.index("(") + 1:-1]
+                if head in ("jit", "pjit"):
+                    part = ""
+            if part not in _JAX_PARTS and not part.startswith("branch_"):
+                found.add(part)
+    return found
+
+
+def device_account(trace_dir: str, program: str | None = None,
+                   kernels=None) -> dict:
+    """The device's time in the newest trace under ``trace_dir`` as the
+    table a person reads: ``{"program", "runs", "step_ms", "filed_ms",
+    "scopes": [{"scope", "ms", "share", "ops", "rows": [{"source",
+    "category", "ms"}]}]}``. One entry per top-level scope (the outermost
+    declared scope on an operation's path, :func:`declared_scopes`; a
+    reader that declared none takes the scopes the paths themselves
+    name) in order of time, and last ``"unscoped"``, the remainder: the
+    median device ms a run of ``program`` (default: the program with most
+    device time), its share of the median run, the median count of
+    operations, and the five ``(source file:line, hlo_category)`` rows
+    with most time inside it, ms a run. ``filed_ms`` is the sum of the
+    entries' ``ms``: it should be ``step_ms``. Every operation is filed
+    once (:func:`_account_runs`); the compiler's own estimates of FLOPs
+    and bytes, which the trace also carries, are not read: they are 0
+    for a Pallas kernel and count every row of a grouped product.
+    ``{}`` without a device plane."""
+    loaded = _load_ops(trace_dir)
+    if not loaded or not loaded["modules"]:
+        return {}
+    if program is None:
+        total: dict = {}
+        for name, _, d in loaded["modules"]:
+            total[_program(name)] = total.get(_program(name), 0) + d
+        program = max(total, key=total.get)
+    declared = declared_scopes() or _path_scopes(
+        m[1] for m in loaded["meta"].values())
+    runs = _account_runs(loaded, program, (), kernels, declared)
+    if not runs:
+        return {}
+    step = statistics.median(r["dur_ns"] for r in runs)
+    scopes = []
+    for scope in {scope for r in runs for scope in r["ns"]}:
+        rows: dict = {}
+        for r in runs:
+            for key, ns in r["rows"].get(scope, {}).items():
+                rows[key] = rows.get(key, 0) + ns
+        ns = statistics.median(r["ns"].get(scope, 0) for r in runs)
+        scopes.append({
+            "scope": UNSCOPED if scope is None else scope, "ms": ns / 1e6,
+            "share": ns / step if step else 0.0,
+            "ops": int(statistics.median(r["ops"].get(scope, 0)
+                                         for r in runs)),
+            "rows": [{"source": source, "category": category,
+                      "ms": total / len(runs) / 1e6}
+                     for (source, category), total in sorted(
+                         rows.items(), key=lambda kv: -kv[1])[:5]]})
+    scopes.sort(key=lambda e: (e["scope"] == UNSCOPED, -e["ms"]))
+    return {"program": program, "runs": len(runs), "step_ms": step / 1e6,
+            "filed_ms": sum(e["ms"] for e in scopes), "scopes": scopes}
 
 
 def merge_trace_events(spans, planes: dict) -> list[dict]:

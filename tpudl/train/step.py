@@ -15,6 +15,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tpudl import mesh as M
+from tpudl.obs.trace import named_scope
 
 __all__ = ["make_train_step", "make_eval_step", "with_compute_dtype"]
 
@@ -47,8 +48,9 @@ def with_compute_dtype(loss_fn, dtype, keep=()):
                 and not name.endswith(keep) else leaf)
 
     def wrapped(params, *batch):
-        return loss_fn(jax.tree_util.tree_map_with_path(cast, params),
-                       *batch)
+        with named_scope("train.cast"):
+            compute = jax.tree_util.tree_map_with_path(cast, params)
+        return loss_fn(compute, *batch)
 
     return wrapped
 
@@ -85,8 +87,9 @@ def make_train_step(loss_fn, optimizer, mesh=None, donate=True,
                       jax.lax.with_sharding_constraint(
                           params, NamedSharding(mesh, P())))
         loss, grads = jax.value_and_grad(loss_fn)(params, *batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = jax.tree.map(lambda p, u: p + u, params, updates)
+        with named_scope("train.update"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = jax.tree.map(lambda p, u: p + u, params, updates)
         return params, opt_state, loss
 
     donate_argnums = (0, 1) if donate else ()

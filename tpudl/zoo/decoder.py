@@ -414,11 +414,18 @@ class Decoder:
         """One layer: every part behind its norm, added to the stream."""
         chosen = None
         for norm, kind in parts:
-            y, picked = self._part(kind, p, B.rms_norm(x, p[norm], self.eps),
-                                   routes)
-            x = x + y
+            y, picked = self._part(kind, p, self._norm(x, p[norm]), routes)
+            with named_scope("lm.norm"):
+                x = x + y
             chosen = picked if kind == "routed" else chosen
         return x, chosen
+
+    def _norm(self, x, weight):
+        """An RMSNorm outside every operator, under the scope ``lm.norm``
+        (which the residual adds share): before each part and after the
+        last layer."""
+        with named_scope("lm.norm"):
+            return B.rms_norm(x, weight, self.eps)
 
     def hidden(self, params, ids, routes=None, remat: bool = False):
         """``ids`` ``[B, S]`` -> the normed last hidden state ``[B, S,
@@ -439,7 +446,7 @@ class Decoder:
         ``zoo.lm.attention.saved`` once per attention layer, beside the
         gauge ``zoo.lm.attention.saved_bytes``."""
         x, _, chosen = self._streams(params, ids, routes, remat)
-        return B.rms_norm(x, params["embedding_norm"], self.eps), chosen
+        return self._norm(x, params["embedding_norm"]), chosen
 
     def _streams(self, params, ids, routes, remat):
         """One run of the stack: the stream after the last layer, BEFORE
@@ -450,7 +457,7 @@ class Decoder:
             _metrics.counter(f"zoo.lm.layers.{kind}").inc(n)
         if kinds["routed"]:   # moe.routed_ff has the one path
             _metrics.counter("moe.combine.fused").inc(kinds["routed"])
-        x = params["embed"][ids]
+        x = self._embed(params, ids)
         if remat and kinds["attention"]:
             # what _SAVE_NAMED keeps of the flash kernels a step, by the
             # shapes: a value head a row in the stream's dtype, and a
@@ -474,10 +481,12 @@ class Decoder:
                 x, picked = block(leaves[0], x, mine and mine[0])
                 picked = [picked]
             else:
+                with named_scope("lm.stack"):
+                    stacked = (jax.tree.map(lambda *leaf: jnp.stack(leaf),
+                                            *leaves),
+                               mine and jnp.stack(mine))
                 x, picked = jax.lax.scan(
-                    lambda x, per: block(per[0], x, per[1]), x,
-                    (jax.tree.map(lambda *leaf: jnp.stack(leaf), *leaves),
-                     mine and jnp.stack(mine)))
+                    lambda x, per: block(per[0], x, per[1]), x, stacked)
             if routed:
                 chosen.extend(picked)
         if not self.mtp:
@@ -487,9 +496,16 @@ class Decoder:
             module = jax.checkpoint(module, policy=_SAVE_NAMED)
         ahead, picked = module(
             _leaves(params, "mtp."), x,
-            params["embed"][jnp.roll(ids, -1, axis=1)],
+            self._embed(params, jnp.roll(ids, -1, axis=1)),
             next(given) if given is not None else None)
         return x, ahead, [*chosen, picked]
+
+    @staticmethod
+    def _embed(params, ids):
+        """The table's rows for ``ids`` under the scope ``lm.embed``: a
+        gather, and a scatter-add in the backward pass."""
+        with named_scope("lm.embed"):
+            return params["embed"][ids]
 
     def _mtp(self, parts, p, x, ahead, routes):
         """The multi-token-prediction module on the stack's stream ``x``
@@ -537,7 +553,7 @@ class Decoder:
 
         def loss(params, ids, routes=None):
             x, ahead, chosen = self._streams(params, ids, routes, remat)
-            x = B.rms_norm(x, params["embedding_norm"], self.eps)
+            x = self._norm(x, params["embedding_norm"])
             bsz, s, dim = x.shape
             n = bsz * s
             chunk = loss_chunk if n % loss_chunk == 0 else n
@@ -566,8 +582,8 @@ class Decoder:
 
             value = mean_nll(x, 1)
             if ahead is not None and self.mtp_weight:
-                value = value + self.mtp_weight * mean_nll(B.rms_norm(
-                    ahead, params["mtp.final_norm"], self.eps), 2)
+                value = value + self.mtp_weight * mean_nll(self._norm(
+                    ahead, params["mtp.final_norm"]), 2)
             return (value, chosen) if with_routes else value
 
         return loss
