@@ -215,6 +215,55 @@ def test_one_rotated_key_serves_every_head_and_the_scale_is_the_192s():
     assert "lm.attention.latent" in text and "lm.attention" in text
 
 
+def test_nothing_is_copied_between_a_projection_and_a_kernel():
+    """``mla_op``'s jaxpr: the products write head-major inside
+    ``lm.attention.latent``; outside it no ``[B, S, H, d]`` operand is
+    transposed, nothing is concatenated, and the ONE rotated key ``[B, S,
+    rope]`` is never broadcast over the heads: it reaches the kernels as
+    it is, beside a 16-wide key a head, and ``q`` whole."""
+    lm, p = build()
+    layer = _layer(p, 1)
+    b, s, heads, nope, rope, v_dim = 2, 16, 4, 16, 8, 16
+    x = jnp.zeros((b, s, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(functools.partial(
+        lm_blocks.mla_op, layer, "attn", heads=heads, nope=nope, rope=rope,
+        eps=1e-6, theta=32e6))(x)
+
+    def walk(inner):
+        for eqn in getattr(inner, "jaxpr", inner).eqns:
+            yield eqn
+            if eqn.primitive.name == "pallas_call":   # the kernels' own
+                continue
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else [value]):
+                    if hasattr(getattr(sub, "jaxpr", sub), "eqns"):
+                        yield from walk(sub)
+
+    def shapes(eqn, which):
+        return [tuple(v.aval.shape) for v in getattr(eqn, which)]
+
+    inside, outside = [], {}
+    for eqn in walk(jaxpr):
+        if "lm.attention.latent" in str(eqn.source_info.name_stack):
+            inside.append(eqn.primitive.name)
+        else:
+            outside.setdefault(eqn.primitive.name, []).append(eqn)
+    assert "concatenate" not in outside
+    for eqn in outside.get("transpose", []):
+        assert all(len(shape) < 4 for shape in shapes(eqn, "invars")), eqn
+    for eqn in outside.get("broadcast_in_dim", []):
+        assert not any(heads in shape and shape[-1] == rope
+                       for shape in shapes(eqn, "outvars")), eqn
+    (kernel,) = outside["pallas_call"]
+    assert shapes(kernel, "invars")[2:] == [
+        (b * heads, 1, s, nope + rope), (b * heads, s, nope), (b, s, rope),
+        (b * heads, s, v_dim)]
+    # inside the scope a head-major product is a product and the transpose
+    # that names its output's layout
+    assert inside.count("transpose") == 3 and "concatenate" not in inside
+
+
 # ---- the chip's share -----------------------------------------------------
 def test_the_four_shares_and_the_shared_expert_once_add_up():
     """Partial results of the shares (0,4) (4,4) (8,4) (12,4) of a
@@ -464,21 +513,21 @@ def test_the_check_fails_on_each_fault(fault, adapter, monkeypatch):
     ids = tokens(seed=7, shape=(2, 32))
     flash, rotary = lm_blocks.flash_attention, lm_blocks.rotary
     if fault == "half_split_pairs":
-        monkeypatch.setattr(lm_blocks, "rotary",
-                            lambda x, theta, interleaved=False: rotary(
-                                x, theta))
+        def half_split(x, theta, interleaved=False, *, axis=1, first=0):
+            return jnp.concatenate([x[..., :first], rotary(
+                x[..., first:], theta, axis=axis)], -1)
+        monkeypatch.setattr(lm_blocks, "rotary", half_split)
     elif fault == "scale_of_the_128":
         monkeypatch.setattr(
             lm_blocks, "flash_attention", lambda q, k, v, **kw: flash(
                 q * (q.shape[-1] / v.shape[-1]) ** 0.5, k, v, **kw))
     elif fault == "a_rotated_key_a_head":
-        # head h's copy of the shared key turned h positions further
-        def per_head(q, k, v, **kw):
-            rope = q.shape[-1] - v.shape[-1]
-            turned = jnp.stack([jnp.roll(k[:, :, h, -rope:], h, axis=1)
-                                for h in range(k.shape[2])], 2)
-            return flash(q, jnp.concatenate([k[..., :-rope], turned], -1), v,
-                         **kw)
+        # head h's copy of the shared key turned h positions further:
+        # whole keys a head, head-major as the operator hands them over
+        def per_head(q, k, v, *, k_shared, **kw):
+            turned = jnp.stack([jnp.roll(k_shared, h, axis=1)
+                                for h in range(k.shape[1])], 1)
+            return flash(q, jnp.concatenate([k, turned], -1), v, **kw)
         monkeypatch.setattr(lm_blocks, "flash_attention", per_head)
     elif fault == "relu2_shared_expert":
         gated = lm_blocks.gated_ff

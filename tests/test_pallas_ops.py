@@ -258,8 +258,8 @@ def _weighted(fn, shape_q, heads):
     u = jnp.sin(jnp.arange(np.prod(shape_q[:3]), dtype=jnp.float32)
                 ).reshape(shape_q[:3])
 
-    def loss(q, k, v):
-        out, lse = fn(q, k, v)
+    def loss(*operands):
+        out, lse = fn(*operands)
         return (jnp.sum(out * w)
                 + jnp.sum(jnp.where(lse > -1e29, lse, 0.0) * u))
     return loss
@@ -647,3 +647,183 @@ class TestSavedAcrossRemat:
         compiled = jax.jit(jax.grad(f, (0, 1, 2))).lower(
             *args).compile().as_text()
         assert "pallas.flash.saved" not in compiled
+
+
+class TestSharedKeyAndLayout:
+    """Operands as their producers wrote them: a block of key columns that
+    every head of a batch entry shares comes as an operand of its own
+    (``k_shared``: latent attention's ONE rotated key) and is read in
+    place, and ``layout="bhsd"`` takes head-major operands without a
+    copy. Both against the call they replace: the shared columns
+    broadcast over the heads and concatenated to each head's own, in
+    sequence-major order."""
+
+    NOPE, ROPE, D_V = 16, 8, 16
+
+    @staticmethod
+    def _call(q, k, v, **kw):
+        return flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
+                               interpret=True, return_lse=True,
+                               precision="highest", **kw)
+
+    @pytest.mark.parametrize("heads, kv_heads", [(4, 4), (4, 2)])
+    @pytest.mark.parametrize("length", [48, 40])      # on and off a tile
+    @pytest.mark.parametrize("dtype, tol", [("float32", 1e-5),
+                                            ("bfloat16", 2e-2)])
+    def test_against_the_concatenated_sequence_major_call(
+            self, rng, dtype, tol, length, heads, kv_heads):
+        """Three tiles a side at 16 x 16: interior, crossing and dead
+        ones (and a padded last one at 40). Output, lse, dq, dk of the
+        head's own columns, dv; the shared key's cotangent is the head
+        sum of the concatenated call's last columns. The head-major
+        entry equals the sequence-major one to the bit."""
+        from tpudl.pallas_ops import tile_counts
+
+        assert min(tile_counts(48, 48, 16, 16, causal=True).values()) == 3
+        b, (nope, rope, d_v) = 2, (self.NOPE, self.ROPE, self.D_V)
+        q = jnp.asarray(rng.normal(size=(b, length, heads, nope + rope)),
+                        dtype)
+        k_own = jnp.asarray(rng.normal(size=(b, length, kv_heads, nope)),
+                            dtype)
+        k_shared = jnp.asarray(rng.normal(size=(b, length, rope)), dtype)
+        v = jnp.asarray(rng.normal(size=(b, length, kv_heads, d_v)), dtype)
+        shape = (b, length, heads, d_v)
+
+        def concatenated(q, k_own, k_shared, v):
+            everywhere = jnp.broadcast_to(
+                k_shared[:, :, None], (b, length, kv_heads, rope))
+            return self._call(q, jnp.concatenate([k_own, everywhere], -1), v)
+
+        def shared(q, k_own, k_shared, v):
+            return self._call(q, k_own, v, k_shared=k_shared)
+
+        def head_major(q, k_own, k_shared, v):
+            out, lse = self._call(
+                *(x.transpose(0, 2, 1, 3) for x in (q, k_own, v)),
+                k_shared=k_shared, layout="bhsd")
+            assert out.shape == (b, heads, length, d_v)
+            assert lse.shape == (b, heads, length)
+            return out.transpose(0, 2, 1, 3), lse.transpose(0, 2, 1)
+
+        def both(fn):
+            return jax.jit(lambda *a: (fn(*a), jax.grad(_weighted(
+                fn, shape, heads), (0, 1, 2, 3))(*a)))(q, k_own, k_shared, v)
+
+        def close(got, want, limit):
+            got, want = (np.asarray(x, np.float32) for x in (got, want))
+            assert got.shape == want.shape
+            assert np.linalg.norm(got - want) <= limit * max(
+                np.linalg.norm(want), 1e-30)
+
+        (want_out, want_lse), want = both(concatenated)
+        (out, lse), got = both(shared)
+        assert out.dtype == q.dtype and lse.dtype == jnp.float32
+        close(out, want_out, tol)
+        close(lse, want_lse, tol)
+        for g, w_, operand in zip(got, want, (q, k_own, k_shared, v)):
+            assert g.shape == operand.shape and g.dtype == operand.dtype
+            close(g, w_, tol)
+        (hm_out, hm_lse), hm = both(head_major)
+        for g, w_ in zip((hm_out, hm_lse, *hm), (out, lse, *got)):
+            np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                          np.asarray(w_, np.float32))
+
+    def test_the_counter_and_the_gauge_at_the_cells_shape(self):
+        """``pallas.flash.shared_key`` and ``.head_dim_shared`` per call
+        (so per TRACE): 1 / 64 with latent attention's operands, 0 / 0 for
+        the same heads with whole keys; a mismatch is named."""
+        from tpudl import obs
+
+        def snap():
+            got = {n: m["value"]
+                   for n, m in obs.snapshot("pallas.flash.").items()}
+            return (got.get("pallas.flash.shared_key", 0),
+                    got.get("pallas.flash.launches", 0),
+                    got.get("pallas.flash.head_dim_shared"))
+
+        def shapes(*widths):
+            return [jax.ShapeDtypeStruct((4, 32, 8192, w), jnp.bfloat16)
+                    for w in widths]
+
+        k_r = jax.ShapeDtypeStruct((4, 8192, 64), jnp.bfloat16)
+
+        def call(q, k, v, k_shared=None):
+            return flash_attention(q, k, v, causal=True, interpret=False,
+                                   layout="bhsd", k_shared=k_shared)
+
+        engaged, launches, _ = snap()
+        out = jax.eval_shape(call, *shapes(192, 128, 128), k_r)
+        assert out.shape == (4, 32, 8192, 128)
+        assert snap() == (engaged + 1, launches + 1, 64)
+        jax.eval_shape(call, *shapes(192, 192, 128))
+        assert snap() == (engaged + 1, launches + 2, 0)
+        tiles = {n: m["value"] for n, m in obs.snapshot(
+            "pallas.flash.").items()}
+        assert (tiles["pallas.flash.head_dim_qk"],
+                tiles["pallas.flash.head_dim_v"],
+                tiles["pallas.flash.heads_a_step"]) == (192, 128, 1)
+        with pytest.raises(ValueError, match="queries 192 wide against "
+                                             "keys 256 wide"):
+            jax.eval_shape(call, *shapes(192, 192, 128), k_r)
+        with pytest.raises(ValueError, match="a shared key of shape"):
+            jax.eval_shape(call, *shapes(192, 128, 128),
+                           jax.ShapeDtypeStruct((4, 4096, 64), jnp.bfloat16))
+        with pytest.raises(ValueError, match="layout 'bsdh'"):
+            flash_attention(*shapes(192, 192, 128), layout="bsdh")
+
+    def test_without_either_the_program_is_the_parents(self):
+        """No shared key and no layout: forward and gradient trace, jaxpr
+        for jaxpr (kernels, specs, grids and what surrounds them), what
+        the commit before this interface traced, at toy shapes and at the
+        two grouped-query cells'. The digest is of that commit's text,
+        taken with this very loop; a deliberate change to the kernels
+        replaces it."""
+        import hashlib
+
+        text = []
+        for b, s_q, s_k, h, h_kv, d, d_v, kw in [
+                (2, 48, 48, 4, 2, 16, 16,
+                 dict(causal=True, block_q=16, block_k=16)),
+                (2, 40, 56, 4, 4, 24, 16,
+                 dict(causal=True, block_q=16, block_k=16)),
+                (1, 64, 64, 2, 2, 64, 64, dict(causal=False)),
+                (4, 8192, 8192, 32, 8, 64, 64,
+                 dict(causal=True, interpret=False)),
+                (4, 8192, 8192, 32, 2, 128, 128,
+                 dict(causal=True, interpret=False))]:
+            q = jax.ShapeDtypeStruct((b, s_q, h, d), jnp.bfloat16)
+            k = jax.ShapeDtypeStruct((b, s_k, h_kv, d), jnp.bfloat16)
+            v = jax.ShapeDtypeStruct((b, s_k, h_kv, d_v), jnp.bfloat16)
+
+            def loss(q, k, v):
+                out, lse = flash_attention(q, k, v, return_lse=True, **kw)
+                return out.astype(jnp.float32).sum() + lse.sum()
+
+            text.append(str(jax.make_jaxpr(
+                lambda q, k, v: flash_attention(q, k, v, **kw))(q, k, v)))
+            text.append(str(jax.make_jaxpr(
+                jax.grad(loss, (0, 1, 2)))(q, k, v)))
+        assert hashlib.sha256("\n".join(text).encode()).hexdigest() == (
+            "bbf78e920ba65ade51238c281fa25edadcf40cbfb5e3de66ccc2cd6b503bf35e")
+
+    def test_the_shared_key_is_a_residual_and_the_forward_runs_once(
+            self, rng, count_eqns):
+        """Under the decoder's policy the gradient of a call with a shared
+        key holds three kernels, as one without does: the forward's output
+        and row statistics are saved, ``q``, both keys and ``v`` recomputed."""
+        from tpudl.zoo import decoder
+
+        b, s, h = 2, 32, 4
+        q = jnp.asarray(rng.normal(size=(b, h, s, 24)), jnp.bfloat16)
+        k = v = jnp.asarray(rng.normal(size=(b, h, s, 16)), jnp.bfloat16)
+        k_r = jnp.asarray(rng.normal(size=(b, s, 8)), jnp.bfloat16)
+
+        def loss(q, k, k_r, v):
+            return flash_attention(
+                q, k, v, causal=True, block_q=16, block_k=16, interpret=True,
+                layout="bhsd", k_shared=k_r).astype(jnp.float32).sum()
+
+        grads = jax.grad(jax.checkpoint(loss, policy=decoder._SAVE_NAMED),
+                         (0, 1, 2, 3))
+        assert count_eqns(jax.make_jaxpr(grads)(q, k, k_r, v),
+                          "pallas_call") == 3

@@ -104,6 +104,38 @@ def test_flash_attention_with_grouped_queries_compiles_for_the_v5e(
     assert grads is not None
 
 
+@pytest.mark.parametrize("seq, heads, dtype", [
+    (8192, (32, 32), "bfloat16"),    # the joyai-llm-flash-ep32 cell's
+    (2048, (4, 4), "float32"),       # the most VMEM such a tile asks
+    (300, (8, 2), "bfloat16"),       # a padded tile; four heads a step
+])
+def test_a_shared_key_and_head_major_operands_compile_for_the_v5e(
+        one_chip, no_compile_cache, seq, heads, dtype):
+    """Latent attention's operands as ``mla_op`` hands them over: ``q``
+    192 wide and head-major, a 128-wide key a head, the ONE 64-wide
+    rotated key of a batch entry beside it (joined to the head's tile in
+    VMEM, its cotangent split off there), 128-wide values; two batch
+    entries, so the shared tile's row is not the head's."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpudl.pallas_ops import flash_attention
+
+    def loss(q, k, k_shared, v):
+        out, lse = flash_attention(q, k, v, causal=True, interpret=False,
+                                   layout="bhsd", k_shared=k_shared,
+                                   return_lse=True)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+
+    def operand(*shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3))).lower(
+        operand(2, heads[0], seq, 192), operand(2, heads[1], seq, 128),
+        operand(2, seq, 64), operand(2, heads[1], seq, 128)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
 @pytest.mark.parametrize("saved, kernels", [(True, 3), (False, 4)])
 def test_a_rematerialised_layer_compiles_to_three_kernels_for_the_v5e(
         one_chip, no_compile_cache, saved, kernels):

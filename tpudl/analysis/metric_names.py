@@ -261,6 +261,15 @@ METRICS: tuple[Metric, ...] = (
     Metric("pallas.flash.head_dim_v", "gauge",
            "width of a value head in that call (128 under latent "
            "attention; the query/key head's width everywhere else)"),
+    Metric("pallas.flash.shared_key", "counter",
+           "flash_attention calls traced with a shared key (k_shared: "
+           "key columns every head of a batch entry reads in place, "
+           "latent attention's ONE rotated key): as many as "
+           "pallas.flash.launches in a latent-attention program, none "
+           "anywhere else"),
+    Metric("pallas.flash.head_dim_shared", "gauge",
+           "key columns the shared key brings in the last traced call (64 "
+           "of the 192 under latent attention; 0 without one)"),
     # -- routed experts (published by Decoder.route_stats, outside steps)
     Metric("moe.pairs_held", "counter",
            "(token, expert) pairs routed to experts this rank holds, "

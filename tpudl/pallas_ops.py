@@ -44,6 +44,16 @@ work on the transposed score tile ``k qᵀ`` (no ``[block_q, block_k]``
 plane is ever transposed), and the tile shape comes from
 :func:`tile_shapes`.
 
+**Operands come as their producers wrote them.** A caller says which
+axis its heads lie on (``layout``: the kernels work head-major, and a
+head-major operand reaches them without a copy), and a key column block
+that every head of a batch entry shares (latent attention's ONE rotated
+key) is an operand of its own, ``k_shared``: each head's grid steps read
+its tile in place through an index map, as grouped queries read a shared
+K/V tile, and it joins the head's own key tile in VMEM. Nothing is
+broadcast or concatenated in HBM; its cotangent comes back a head and is
+summed outside the kernels.
+
 **The forward kernel runs once under rematerialisation.** Its output and
 row statistics carry the ``checkpoint_name`` :data:`SAVED` where they are
 the backward kernels' residuals. A ``jax.checkpoint`` whose policy saves
@@ -103,14 +113,18 @@ class Tiles(NamedTuple):
         """Bump ``pallas.flash.*`` for one call of ``flash_attention``,
         which under ``jax.jit`` is once per TRACE of the caller's
         program, on purpose (as ``zoo.conv_bn.folded`` is): the shape
-        chosen, the heads' widths ``(query/key, value)`` and, where the
-        offsets are known now, the tiles a head by class."""
+        chosen, the heads' widths ``(query/key, value, the key columns a
+        shared key brings)`` and, where the offsets are known now, the
+        tiles a head by class."""
         _metrics.counter("pallas.flash.launches").inc()
+        if head_dims[2]:
+            _metrics.counter("pallas.flash.shared_key").inc()
         _metrics.gauge("pallas.flash.block_q").set(self.block_q)
         _metrics.gauge("pallas.flash.block_k").set(self.block_k)
         _metrics.gauge("pallas.flash.heads_a_step").set(self.heads)
         _metrics.gauge("pallas.flash.head_dim_qk").set(head_dims[0])
         _metrics.gauge("pallas.flash.head_dim_v").set(head_dims[1])
+        _metrics.gauge("pallas.flash.head_dim_shared").set(head_dims[2])
         try:
             q_offset, k_offset = int(q_offset), int(k_offset)
         except TypeError:   # a tracer (the ring's): known at run time only
@@ -244,6 +258,16 @@ def _dot(a, b, dims, precision):
                                preferred_element_type=jnp.float32)
 
 
+def _key_tile(k_ref):
+    """The K tile of a grid step. With a shared key the operand is the
+    pair (the head's own columns, the batch entry's shared ones): the two
+    tiles side by side, joined in VMEM (the first ends on a lane-tile
+    boundary)."""
+    if isinstance(k_ref, tuple):
+        return jnp.concatenate([ref[0] for ref in k_ref], axis=-1)
+    return k_ref[0]
+
+
 # --- kernels ---------------------------------------------------------------
 def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                   m_scr, acc_scr, side_scr, *, causal: bool, scale,
@@ -279,7 +303,7 @@ def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                                  block_q, block_k)
 
     def body(masked):
-        k = k_ref[0]
+        k = _key_tile(k_ref)
         if sum_on_mxu:
             side_scr[:, :head_dim] = v_ref[0]
             v = side_scr[...]
@@ -348,7 +372,7 @@ def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                                  block_q, block_k)
 
     def body(masked):
-        k, v = k_ref[0], v_ref[0]
+        k, v = _key_tile(k_ref), v_ref[0]
         for h in range(heads):
             s = _scaled(_dot(q_ref[0, h], k, _NT, precision), scale)
             lse = lse_ref[0, h, 0][:, None]
@@ -383,7 +407,9 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
     stand. The innermost dim runs over the query heads of this K/V
     head's group, ``heads`` a step, so the group's sum is accumulated
     in float32 in the same scratch; the scale is applied once, to that
-    sum."""
+    sum. With a shared key ``k_ref`` and ``dk_ref`` are pairs: ``dk`` is
+    accumulated whole and its two column blocks leave by their own
+    outputs."""
     ik, j, nj = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
     iq = j % q_tiles
 
@@ -397,7 +423,7 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                                  block_q, block_k)
 
     def body(masked):
-        k, v = k_ref[0], v_ref[0]
+        k, v = _key_tile(k_ref), v_ref[0]
         for h in range(heads):
             q, do = q_ref[0, h], do_ref[0, h]
             st = _scaled(_dot(k, q, _NT, precision), scale)  # [TK, TQ]
@@ -418,7 +444,13 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
 
     @pl.when(j == nj - 1)
     def _finalize():
-        dk_ref[0] = _scaled(dk_scr[...], scale).astype(dk_ref.dtype)
+        dk = _scaled(dk_scr[...], scale)
+        if isinstance(dk_ref, tuple):   # (the head's own, the shared key's)
+            own = dk_ref[0].shape[-1]
+            dk_ref[0][0] = dk[:, :own].astype(dk_ref[0].dtype)
+            dk_ref[1][0] = dk[:, own:].astype(dk_ref[1].dtype)
+        else:
+            dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
@@ -455,11 +487,31 @@ def _q_major_maps(causal, block_q, block_k, per_kv):
     return q_map, row_map, k_map
 
 
+def _key_specs(kh, block_k, k_map):
+    """Block specs of the key operand (or its cotangent) under ``k_map``,
+    a map to ``(K/V row, K tile, 0)``. With a shared key ``kh`` is the
+    pair ``([B·Hkv, Sk, D − Ds], [B, Sk, Ds])`` and so are the specs: the
+    shared tile is the batch entry's, row ``// Hkv``, read in place by
+    every head. ``Hkv`` is the ratio of the pair's own row counts: 1 for
+    the cotangents, which come back a head."""
+    if not isinstance(kh, tuple):
+        return pl.BlockSpec((1, block_k, kh.shape[2]), k_map)
+    h_kv = kh[0].shape[0] // kh[1].shape[0]
+
+    def shared_map(*at):
+        row, ik, _ = k_map(*at)
+        return (row // h_kv, ik, 0)
+
+    return (pl.BlockSpec((1, block_k, kh[0].shape[2]), k_map),
+            pl.BlockSpec((1, block_k, kh[1].shape[2]), shared_map))
+
+
 def _pallas_flash_fwd(qg, kh, vh, qoff, koff, *, causal, tiles: Tiles,
                       kv_len, interpret, precision, group, scale):
     """The forward launch. Head-major: ``qg`` ``[R, heads, Sq, D]`` (the
     ``heads`` query heads one grid step takes are neighbours), ``kh``
-    ``[B·Hkv, Sk, D]``, ``vh`` ``[B·Hkv, Sk, Dv]`` → (out ``[R, heads,
+    ``[B·Hkv, Sk, D]`` or, with a shared key, the pair ``([B·Hkv, Sk, D −
+    Ds], [B, Sk, Ds])``, ``vh`` ``[B·Hkv, Sk, Dv]`` → (out ``[R, heads,
     Sq, Dv]``, lse ``[R, heads, Sq]``)."""
     rows, heads, s_q, d = qg.shape
     s_k, d_v = vh.shape[1:]
@@ -479,7 +531,7 @@ def _pallas_flash_fwd(qg, kh, vh, qoff, koff, *, causal, tiles: Tiles,
             grid=(rows, s_q // block_q, s_k // block_k),
             in_specs=[
                 pl.BlockSpec((1, heads, block_q, d), q_map),
-                pl.BlockSpec((1, block_k, d), k_map),
+                _key_specs(kh, block_k, k_map),
                 pl.BlockSpec((1, block_k, d_v), k_map),
             ],
             out_specs=[
@@ -537,7 +589,7 @@ def _pallas_flash_bwd(qg, kh, vh, out, lse, qoff, koff, do, dlse, *,
             grid=(rows, s_q // block_q, s_k // block_k),
             in_specs=[
                 pl.BlockSpec((1, heads, block_q, d), qi_q),
-                pl.BlockSpec((1, block_k, d), qi_k),
+                _key_specs(kh, block_k, qi_k),
                 pl.BlockSpec((1, block_k, d_v), qi_k),
                 pl.BlockSpec((1, heads, block_q, d_v), qi_q),
                 pl.BlockSpec((1, heads, 8, block_q), qi_row),
@@ -570,35 +622,38 @@ def _pallas_flash_bwd(qg, kh, vh, out, lse, qoff, koff, do, dlse, *,
         r, iq = ki_tile(bkv, ik, j, qoff_ref, koff_ref)
         return (r, 0, 0, iq)
 
+    # every part of the key's cotangent comes back a K/V head, as K is
+    dk_shape = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        vh.shape[:2] + x.shape[2:], x.dtype), kh)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, q_tiles=q_tiles,
                           block_q=block_q, block_k=block_k, **kernel_kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(kh.shape[0], s_k // block_k, per_kv * q_tiles),
+            grid=(vh.shape[0], s_k // block_k, per_kv * q_tiles),
             in_specs=[
                 pl.BlockSpec((1, heads, block_q, d), ki_q),
-                pl.BlockSpec((1, block_k, d), ki_k),
+                _key_specs(kh, block_k, ki_k),
                 pl.BlockSpec((1, block_k, d_v), ki_k),
                 pl.BlockSpec((1, heads, block_q, d_v), ki_q),
                 pl.BlockSpec((1, heads, 8, block_q), ki_row),
                 pl.BlockSpec((1, heads, 8, block_q), ki_row),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_k, d), ki_k),
+                _key_specs(dk_shape, block_k, ki_k),
                 pl.BlockSpec((1, block_k, d_v), ki_k),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, d_v), jnp.float32),
             ]),
-        out_shape=[
-            jax.ShapeDtypeStruct(kh.shape, kh.dtype),
-            jax.ShapeDtypeStruct(vh.shape, vh.dtype),
-        ],
+        out_shape=[dk_shape, jax.ShapeDtypeStruct(vh.shape, vh.dtype)],
         compiler_params=params,
         interpret=interpret,
     )(qoff, koff, qg, kh, vh, do, lse8, dlt8)
+    if isinstance(kh, tuple):   # the shared key's: the sum over its heads
+        dk = (dk[0], dk[1].reshape(kh[1].shape[0], -1,
+                                   *kh[1].shape[1:]).sum(1))
     return dq, dk, dv
 
 
@@ -641,12 +696,26 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
                     k_offset=0, block_q: int | None = None,
                     block_k: int | None = None,
                     interpret: bool | None = None,
-                    return_lse: bool = False, precision=None):
+                    return_lse: bool = False, precision=None,
+                    layout: str = "bshd", k_shared=None):
     """Tiled flash attention. q: [B, Sq, H, D], k: [B, Sk, Hkv, D], v:
     [B, Sk, Hkv, Dv] → out [B, Sq, H, Dv] (and, with ``return_lse``, lse
     [B, Sq, H] — ``logsumexp(scores)`` per query row, for ring partial
     merges). ``Dv`` may differ from ``D`` (latent attention: 192-wide
     queries and keys, 128-wide values); the scale is ``1/√D``.
+
+    ``layout`` says what the operands ARE: ``"bshd"`` as above, or
+    ``"bhsd"`` for q ``[B, H, Sq, D]``, k ``[B, Hkv, Sk, D]``, v ``[B,
+    Hkv, Sk, Dv]`` → out ``[B, H, Sq, Dv]``, lse ``[B, H, Sq]``. The
+    kernels work head-major: a ``"bhsd"`` operand reaches them as it
+    stands, a ``"bshd"`` one through a transposed copy each way.
+
+    ``k_shared`` ``[B, Sk, Ds]`` is a block of key columns that every head
+    of a batch entry shares (latent attention's ONE rotated key): ``k``
+    then holds the first ``D − Ds`` columns a head and a score is ``q[:D −
+    Ds]·k + q[D − Ds:]·k_shared``, scaled by ``1/√D``. The kernels read
+    its tiles in place (never broadcast over heads, never concatenated in
+    HBM), and its cotangent is the sum over the heads.
 
     Grouped queries: ``Hkv`` divides ``H`` and K/V head ``j`` serves the
     query heads ``j·H/Hkv … (j+1)·H/Hkv − 1``. The kernels read the
@@ -671,13 +740,21 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
     once per TRACE of the caller's program."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    b, s_q, h, d = q.shape
-    s_k, h_kv = k.shape[1], k.shape[2]
-    if h % h_kv or v.shape[2] != h_kv:
+    if layout not in ("bshd", "bhsd"):
+        raise ValueError(f"layout {layout!r} is neither 'bshd' nor 'bhsd'")
+    seq = layout.index("s")
+    s_q, h, d = q.shape[seq], q.shape[3 - seq], q.shape[3]
+    s_k, h_kv = k.shape[seq], k.shape[3 - seq]
+    if h % h_kv or v.shape[3 - seq] != h_kv:
         raise ValueError(f"{h} query heads cannot share {h_kv} key / "
-                         f"{v.shape[2]} value heads")
-    if k.shape[3] != d:
-        raise ValueError(f"queries {d} wide against keys {k.shape[3]} wide")
+                         f"{v.shape[3 - seq]} value heads")
+    d_shared = 0 if k_shared is None else k_shared.shape[2]
+    if k_shared is not None and k_shared.shape[:2] != (k.shape[0], s_k):
+        raise ValueError(f"a shared key of shape {k_shared.shape} beside "
+                         f"{k.shape[0]} x {s_k} keys a head")
+    if k.shape[3] + d_shared != d:
+        raise ValueError(f"queries {d} wide against keys "
+                         f"{k.shape[3] + d_shared} wide")
     group, align = h // h_kv, 1 if interpret else _LANES
     tiles = tile_shapes(s_q, s_k, group, align=align)
     if block_q is not None:
@@ -687,39 +764,50 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
     pad_q, pad_k = s_q + (-s_q % tiles.block_q), s_k + (-s_k % tiles.block_k)
     kv_len = s_k if pad_k != s_k else None
     tiles.count(pad_q, pad_k, causal, q_offset, k_offset, kv_len,
-                (d, v.shape[3]))
-    return _flash_call(q, k, v, q_offset, k_offset, causal=causal,
-                       tiles=tiles, pads=(pad_q, pad_k),
+                (d, v.shape[3], d_shared))
+    return _flash_call(q, k, v, k_shared, q_offset, k_offset, causal=causal,
+                       tiles=tiles, pads=(pad_q, pad_k), layout=layout,
                        interpret=interpret, return_lse=return_lse,
                        precision=precision)
 
 
-def _flash_traced(q, k, v, q_offset, k_offset, *, causal, tiles, pads,
-                  interpret, return_lse, precision):
-    b, s_q, h, d = q.shape
-    s_k, h_kv = k.shape[1], k.shape[2]
+def _flash_traced(q, k, v, k_shared, q_offset, k_offset, *, causal, tiles,
+                  pads, layout, interpret, return_lse, precision):
+    seq = layout.index("s")
+    b, s_q, h, d = q.shape[0], q.shape[seq], q.shape[3 - seq], q.shape[3]
+    s_k, h_kv = k.shape[seq], k.shape[3 - seq]
     pad_q, pad_k = pads
 
     # head-major: K/V [B·Hkv, Sk, D], a row a (batch, head) pair; Q
-    # [B·H/heads, heads, Sq, D], a row the heads one grid step takes
+    # [B·H/heads, heads, Sq, D], a row the heads one grid step takes. Of
+    # a "bhsd" operand that is a view
     def to_bh(x, padded):
-        x = jnp.pad(x, ((0, 0), (0, padded - x.shape[1]), (0, 0), (0, 0)))
-        return x.transpose(0, 2, 1, 3).reshape(-1, padded, x.shape[3])
+        widths = [(0, 0)] * 4
+        widths[seq] = (0, padded - x.shape[seq])
+        x = jnp.pad(x, widths)
+        if seq == 1:
+            x = x.transpose(0, 2, 1, 3)
+        return x.reshape(-1, padded, x.shape[3])
 
     scale = d ** -0.5
     if _scale_rides_on_q(d):
         q, scale = q * jnp.asarray(scale, q.dtype), None
     qg = to_bh(q, pad_q).reshape(-1, tiles.heads, pad_q, d)
     kh, vh = to_bh(k, pad_k), to_bh(v, pad_k)
+    if k_shared is not None:
+        kh = (kh, jnp.pad(k_shared, ((0, 0), (0, pad_k - s_k), (0, 0))))
     qoff = jnp.asarray(q_offset, jnp.int32).reshape(1)
     koff = jnp.asarray(k_offset, jnp.int32).reshape(1)
     out, lse = _flash_fn(causal, tiles, s_k if pad_k != s_k else None,
                          interpret, precision, h // h_kv,
                          scale)(qg, kh, vh, qoff, koff)
-    out = out.reshape(b, h, pad_q, -1).transpose(0, 2, 1, 3)[:, :s_q]
+    out = out.reshape(b, h, pad_q, -1)
+    out = (out.transpose(0, 2, 1, 3)[:, :s_q] if seq == 1
+           else out[:, :, :s_q])
     if not return_lse:
         return out
-    lse = lse.reshape(b, h, pad_q).transpose(0, 2, 1)[:, :s_q]
+    lse = lse.reshape(b, h, pad_q)
+    lse = lse.transpose(0, 2, 1)[:, :s_q] if seq == 1 else lse[:, :, :s_q]
     return out, lse
 
 
@@ -727,4 +815,5 @@ def _flash_traced(q, k, v, q_offset, k_offset, *, causal, tiles, pads,
 # (``jit(flash_attention)/pallas_call``, the ``flash_attention.N`` rows)
 _flash_traced.__name__ = _flash_traced.__qualname__ = "flash_attention"
 _flash_call = jax.jit(_flash_traced, static_argnames=(
-    "causal", "tiles", "pads", "interpret", "return_lse", "precision"))
+    "causal", "tiles", "pads", "layout", "interpret", "return_lse",
+    "precision"))

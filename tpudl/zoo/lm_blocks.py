@@ -21,6 +21,8 @@ the host: a model is initialised once per process and placed by
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -56,26 +58,68 @@ def rms_norm(x, weight, eps: float):
     return (y * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-def rotary(x, theta: float, interleaved: bool = False):
-    """Rotary positions on ``x`` ``[B, S, H, d]``: position ``t`` turns
-    the half-split pair ``(x_i, x_{i+d/2})`` by ``t · theta^(-2i/d)``,
-    or with ``interleaved`` the neighbouring pair ``(x_{2i}, x_{2i+1})``,
-    each staying where it lies (no reshape to pairs: the partner comes by
-    a shift of one lane either way)."""
-    s, d = x.shape[1], x.shape[3]
+def rotary(x, theta: float, interleaved: bool = False, *, axis: int = 1,
+           first: int = 0):
+    """Rotary positions on ``x`` ``[..., d]`` whose positions lie along
+    ``axis`` (``[B, S, H, d]`` or ``[B, S, d]`` as it stands; ``axis=2``
+    for a head-major ``[B, H, S, d]``): position ``t`` turns the
+    half-split pair ``(x_i, x_{i+d/2})`` by ``t · theta^(-2i/d)``, or
+    with ``interleaved`` the neighbouring pair ``(x_{2i}, x_{2i+1})``,
+    each staying where it lies (no reshape to pairs). With ``first``
+    (interleaved pairs only) the columns before it carry no position and
+    pass as they are, cos 1 and sin 0, in the same one pass that turns
+    the columns from it on (latent attention's query: 128 without
+    positions, then 64 with)."""
+    if first and not interleaved:
+        raise ValueError("first= is for interleaved pairs")
+    s, d = x.shape[axis], x.shape[-1] - first
     inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
     widen = ((lambda a: jnp.repeat(a, 2, -1)) if interleaved
              else (lambda a: jnp.concatenate([a] * 2, -1)))
-    cos = widen(jnp.cos(angle))[None, :, None, :]
-    sin = widen(jnp.sin(angle))[None, :, None, :]
-    x32 = x.astype(jnp.float32)
+    others = tuple(i for i in range(x.ndim - 1) if i != axis)
+
+    def table(fn, before):
+        t = widen(fn(angle))
+        if first:
+            t = jnp.pad(t, ((0, 0), (first, 0)), constant_values=before)
+        return jnp.expand_dims(t, others)
+
+    cos, sin = table(jnp.cos, 1.0), table(jnp.sin, 0.0)
     if interleaved:
-        turned = jnp.where(jnp.arange(d) % 2 == 0, -jnp.roll(x32, -1, -1),
-                           jnp.roll(x32, 1, -1))
-    else:
-        turned = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
+        return _turn_pairs(x, cos, sin, first)
+    x32 = x.astype(jnp.float32)
+    turned = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], -1)
     return (x32 * cos + turned * sin).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _turn_pairs(x, cos, sin, first: int):
+    """``x · cos + partner(x) · sin`` in float32, where the partner of the
+    neighbouring pair ``(x_{2i}, x_{2i+1})`` from column ``first`` on is
+    ``(−x_{2i+1}, x_{2i})``. The partner is a product with the 0 / ±1
+    matrix of that signed permutation: exact in any dtype, and the float32
+    arithmetic around it is the product's epilogue, ONE pass over ``x``. A
+    shift of one lane either way, which is what it is, XLA's TPU backend
+    writes out as two float32 arrays of ``x``'s size before the pass that
+    reads them (PERF.md §6, PR 37). ``sin`` is the same on both columns of
+    a pair, so the transpose is the turn by the opposite angle, one such
+    pass over the cotangent as it comes (autodiff's would put the product
+    on the float32 ``g · sin``)."""
+    width = x.shape[-1]
+    turn = np.zeros((width, width), np.float32)
+    even = np.arange(first, width, 2)
+    turn[even + 1, even], turn[even, even + 1] = -1.0, 1.0
+    partner = jnp.einsum("...d,de->...e", x, jnp.asarray(turn, x.dtype),
+                         precision=jax.lax.Precision.HIGHEST,
+                         preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos + partner * sin).astype(x.dtype)
+
+
+_turn_pairs.defvjp(
+    lambda x, cos, sin, first: (_turn_pairs(x, cos, sin, first), (cos, sin)),
+    lambda first, tables, g: (_turn_pairs(g, tables[0], -tables[1], first),
+                              None, None))
 
 
 def conv_op(p, name: str, x):
@@ -122,30 +166,45 @@ def mla_op(p, name: str, x, *, heads: int, nope: int, rope: int,
     head; ``[c_kv | k_r] = x W_kva``, ``[k_nope | v] = RMSNorm(c_kv)
     W_kvb`` a head. ``q_rope`` and the ONE ``k_r`` that every head
     shares are rotated on interleaved pairs; scores ``(q_nope·k_nope +
-    q_rope·k_r) / √(nope + rope)``, through
-    :func:`tpudl.pallas_ops.flash_attention` with a value head of its
-    own width; ``W_o``. Per-head keys and values are materialised from
-    the latent (the absorbed form is decoding's, and is not built). The
-    four low-rank projections and their two norms lie under the inner
-    scope ``lm.attention.latent``."""
+    q_rope·k_r) / √(nope + rope)``; ``W_o``. Per-head keys and values are
+    materialised from the latent (the absorbed form is decoding's, and is
+    not built).
+
+    Nothing is copied between a projection and a kernel: the products
+    write ``q`` ``[B, H, S, nope + rope]``, ``k_nope`` ``[B, H, S, nope]``
+    and ``v`` ``[B, H, S, v]`` head-major (the last two from the two
+    column halves of ``W_kvb``, each a product of its own), which is how
+    :func:`tpudl.pallas_ops.flash_attention` (``layout="bhsd"``) takes
+    them; ``q``'s last ``rope`` columns are turned in one pass over it;
+    ``k_r`` ``[B, S, rope]`` goes to the kernels as ``k_shared``, the one
+    row a batch entry that every head reads in place; the output goes
+    into ``W_o`` head-major. The four low-rank projections and their two
+    norms lie under the inner scope ``lm.attention.latent``."""
+    def heads_first(c, w):
+        # [B, S, C] x [C, H, d] -> [B, H, S, d]. The transpose is the
+        # product's output layout, no copy; asked of einsum itself
+        # ("->bhsd") XLA turns the product round and copies (PERF.md §6)
+        return jnp.einsum("bsc,chd->bshd", c, w).transpose(0, 2, 1, 3)
+
     with named_scope("lm.attention"):
-        bsz, s, _ = x.shape
         with named_scope("lm.attention.latent"):
             c_q = rms_norm(x @ p[name + ".q_a_proj"], p[name + ".q_a_norm"],
                            eps)
-            q = (c_q @ p[name + ".q_b_proj"]).reshape(bsz, s, heads,
-                                                      nope + rope)
-            c_kv, k_r = jnp.split(x @ p[name + ".kv_a_proj"], [
-                p[name + ".kv_b_proj"].shape[0]], axis=-1)
-            kv = (rms_norm(c_kv, p[name + ".kv_a_norm"], eps)
-                  @ p[name + ".kv_b_proj"]).reshape(bsz, s, heads, -1)
-        q = jnp.concatenate([q[..., :nope], rotary(
-            q[..., nope:], theta, interleaved=True)], -1)
-        k_r = rotary(k_r[:, :, None, :], theta, interleaved=True)
-        k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
-            k_r, (bsz, s, heads, rope))], -1)
-        out = flash_attention(q, k, kv[..., nope:], causal=True)
-        return out.reshape(bsz, s, -1) @ p[name + ".o_proj"]
+            q = heads_first(c_q, p[name + ".q_b_proj"].reshape(
+                -1, heads, nope + rope))
+            w_kv = p[name + ".kv_b_proj"]
+            w_kv = w_kv.reshape(w_kv.shape[0], heads, -1)
+            c_kv, k_r = jnp.split(x @ p[name + ".kv_a_proj"],
+                                  [w_kv.shape[0]], axis=-1)
+            c_kv = rms_norm(c_kv, p[name + ".kv_a_norm"], eps)
+            k_nope = heads_first(c_kv, w_kv[..., :nope])
+            v = heads_first(c_kv, w_kv[..., nope:])
+        q = rotary(q, theta, interleaved=True, axis=2, first=nope)
+        k_r = rotary(k_r, theta, interleaved=True)
+        out = flash_attention(q, k_nope, v, causal=True, layout="bhsd",
+                              k_shared=k_r)
+        return jnp.einsum("bhsd,hdo->bso", out, p[name + ".o_proj"].reshape(
+            heads, -1, x.shape[2]))
 
 
 def gated_ff(p, name: str, x, scope: str = "lm.dense_ff"):
