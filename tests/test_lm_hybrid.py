@@ -165,6 +165,43 @@ def test_the_mixer_is_causal_and_a_sequence_at_a_time():
     assert "remat" in text and text.count("scan[") >= 2
 
 
+def _values_outside_kernels(jaxpr):
+    """Every value a traced program defines outside its ``pallas_call``s
+    (a kernel's own values live in VMEM), through every nested jaxpr."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _values_outside_kernels(inner)
+
+
+def test_no_plane_of_decays_reaches_hbm():
+    """Six chunks of four positions: the loss and its gradient hold no
+    float32 value ``[chunks, ·, Q, Q]`` (the decays ``L``, the masked
+    scores, their cotangents) outside a kernel, nor the float32 chunk
+    states ``[chunks, G, R, P, N]`` the scan over chunks carried: what
+    the backward keeps of a mixer's states is in the operands' dtype."""
+    lm, p = build("MEM*E", chunk_size=4)
+    ids = tokens()
+    chunks, n = ids.shape[1] // 4, BASE["ssm_state_size"]
+    cast = functools.partial(with_compute_dtype, dtype=jnp.bfloat16,
+                             keep=lm.float32_leaves)
+    seen = list(_values_outside_kernels(jax.make_jaxpr(jax.value_and_grad(
+        cast(lm.loss_fn(remat=True))))(p, ids)))
+    assert len(seen) > 1000
+    planes = [a for a in seen if getattr(a, "ndim", 0) >= 3
+              and a.shape[-2:] == (4, 4) and chunks in a.shape[:-2]]
+    assert planes == []
+    states = [a for a in seen if getattr(a, "ndim", 0) >= 3
+              and a.shape[0] == chunks and n in a.shape[1:]]
+    assert states and all(a.dtype == jnp.bfloat16 for a in states)
+
+
 # the gradient limit lies between two readings at these toy widths: clean
 # bfloat16, the largest by group over eight token seeds, 0.013-0.032 on 2 x 32
 # tokens in chunks of 8 and 0.012-0.037 on 512 tokens in chunks of 128; the

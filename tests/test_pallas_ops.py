@@ -827,3 +827,182 @@ class TestSharedKeyAndLayout:
                          (0, 1, 2, 3))
         assert count_eqns(jax.make_jaxpr(grads)(q, k, k_r, v),
                           "pallas_call") == 3
+
+
+# ---- the selective scan ----------------------------------------------------
+def _scan_over_time(x, dt, a, b, c):
+    """``H_t = exp(Δ_t A) H_{t-1} + Δ_t x_t ⊗ B_t``, ``y_t = H_t C_t``, one
+    position after the other, float32."""
+    per = x.shape[1] // b.shape[1]
+
+    def step(h, now):
+        xt, dtt, bt, ct = now
+        bt, ct = jnp.repeat(bt, per, 0), jnp.repeat(ct, per, 0)   # [H, N]
+        h = (jnp.exp(dtt * a)[:, None, None] * h
+             + dtt[:, None, None] * xt[:, :, None] * bt[:, None, :])
+        return h, jnp.einsum("hpn,hn->hp", h, ct)
+
+    zero = jnp.zeros((*x.shape[1:], b.shape[2]), jnp.float32)
+    return jax.lax.scan(step, zero, (x, dt, b, c))[1]
+
+
+def _scan_operands(seed, length, groups, per, width, state):
+    rng = np.random.default_rng(seed)
+    heads = groups * per
+    return (jnp.asarray(rng.standard_normal((length, heads, width)),
+                        jnp.float32),
+            jnp.asarray(rng.uniform(0.01, 0.5, (length, heads)), jnp.float32),
+            jnp.asarray(-rng.uniform(1, 16, heads), jnp.float32),
+            jnp.asarray(rng.standard_normal((length, groups, state)),
+                        jnp.float32),
+            jnp.asarray(rng.standard_normal((length, groups, state)),
+                        jnp.float32),
+            jnp.asarray(rng.standard_normal((length, heads, width)),
+                        jnp.float32))
+
+
+def _rel(a, b):
+    a, b = (np.asarray(v, np.float32).ravel() for v in (a, b))
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+class TestSelectiveScan:
+    """``pallas_ops.ssd_scan``, forward and backward kernels through the
+    interpreter, against a loop over time."""
+
+    @pytest.mark.parametrize("chunk, lengths", [(16, (48, 37)),
+                                                (128, (256, 200))])
+    @pytest.mark.parametrize("divides", [True, False])
+    @pytest.mark.parametrize("groups", [1, 8])
+    @pytest.mark.parametrize("per", [1, 8])
+    def test_result_and_five_gradients_against_a_loop_over_time(
+            self, chunk, lengths, divides, groups, per):
+        """Lengths the chunk does and does not divide (padded with Δ = 0),
+        one and eight groups, one and eight heads a group; eight heads of
+        64 are worked on two a lane tile, a lone head of 12 as it is."""
+        from tpudl.pallas_ops import scan_tiles, ssd_scan
+
+        width, length = (64 if per == 8 else 12), lengths[not divides]
+        assert scan_tiles(length, per, width, chunk).heads_a_tile == (
+            2 if per == 8 else 1)
+        *ops, w = _scan_operands(chunk + length, length, groups, per, width, 8)
+        with jax.default_matmul_precision("highest"):
+            got = ssd_scan(*ops, chunk=chunk)
+            want = _scan_over_time(*ops)
+            assert got.shape == want.shape and got.dtype == jnp.float32
+            assert _rel(got, want) < 1e-5
+            g_got = jax.grad(lambda *v: (ssd_scan(*v, chunk=chunk) * w).sum(),
+                             (0, 1, 2, 3, 4))(*ops)
+            g_want = jax.grad(lambda *v: (_scan_over_time(*v) * w).sum(),
+                              (0, 1, 2, 3, 4))(*ops)
+        for name, mine, theirs in zip("x dt a b c".split(), g_got, g_want):
+            assert mine.shape == theirs.shape and mine.dtype == theirs.dtype
+            # a head's one number for `a` is a sum over every position
+            # that cancels: float32 noise of either side shows there
+            assert _rel(mine, theirs) < (3e-4 if name == "a" else 3e-5), name
+
+    def test_bfloat16_operands_against_the_float32_loop(self):
+        """The products' operands in bfloat16 (as the cell trains), the
+        recurrence held in float32: within the limit tests/test_lm_hybrid.py
+        holds a group's gradient to, and the cotangents in their operands'
+        dtypes."""
+        from tpudl.pallas_ops import ssd_scan
+
+        x, dt, a, b, c, w = _scan_operands(5, 300, 2, 4, 32, 16)
+        low = (x.astype(jnp.bfloat16), dt, a, b.astype(jnp.bfloat16),
+               c.astype(jnp.bfloat16))
+        got = ssd_scan(*low, chunk=128)
+        g_got = jax.grad(lambda *v: (ssd_scan(*v, chunk=128) * w).sum(),
+                         (0, 1, 2, 3, 4))(*low)
+        with jax.default_matmul_precision("highest"):
+            want = _scan_over_time(x, dt, a, b, c)
+            g_want = jax.grad(lambda *v: (_scan_over_time(*v) * w).sum(),
+                              (0, 1, 2, 3, 4))(x, dt, a, b, c)
+        assert got.dtype == jnp.float32 and _rel(got, want) < 0.02
+        for mine, mine_of, theirs in zip(g_got, low, g_want):
+            assert mine.dtype == mine_of.dtype
+            assert _rel(mine, theirs) < 0.065
+
+    def test_the_recurrence_is_held_in_the_dtype_of_a(self):
+        """``a``'s dtype is what Δ·A, its running sum, the decays and the
+        carried state are held in: in bfloat16 a chunk of 128 log-decays
+        keeps 8 bits and the result is off by percents, with float32
+        operands everywhere else; the gradient of ``a`` comes back in its
+        dtype."""
+        from tpudl.pallas_ops import ssd_scan
+
+        x, dt, a, b, c, w = _scan_operands(6, 256, 1, 2, 16, 8)
+        with jax.default_matmul_precision("highest"):
+            want = _scan_over_time(x, dt, a, b, c)
+            sound = _rel(ssd_scan(x, dt, a, b, c, chunk=128), want)
+            held_low = _rel(ssd_scan(x, dt, a.astype(jnp.bfloat16), b, c,
+                                     chunk=128), want)
+            da = jax.grad(lambda v: (ssd_scan(x, dt, v, b, c, chunk=128)
+                                     * w).sum())(a.astype(jnp.bfloat16))
+        assert sound < 1e-5 and held_low > 100 * sound and held_low > 3e-3
+        assert da.dtype == jnp.bfloat16 and da.shape == a.shape
+
+    def test_a_batch_of_sequences_through_vmap(self):
+        """``jax.vmap`` over sequences (the decays shared) is each sequence
+        alone: the carried state starts at zero for every one."""
+        from tpudl.pallas_ops import ssd_scan
+
+        x, dt, a, b, c, w = _scan_operands(7, 2 * 40, 2, 2, 8, 4)
+        two = [v.reshape(2, 40, *v.shape[1:]) for v in (x, dt, b, c, w)]
+
+        def loss(x, dt, a, b, c, w):
+            y = jax.vmap(lambda x, dt, b, c: ssd_scan(x, dt, a, b, c,
+                                                      chunk=16))(x, dt, b, c)
+            return (y * w).sum(), y
+
+        with jax.default_matmul_precision("highest"):
+            (_, y), grads = jax.value_and_grad(loss, (0, 2), has_aux=True)(
+                two[0], two[1], a, two[2], two[3], two[4])
+            for i in range(2):
+                alone = [v[i] for v in two]
+                assert _rel(y[i], _scan_over_time(
+                    alone[0], alone[1], a, alone[2], alone[3])) < 1e-5
+            want = jax.grad(lambda x, a: sum(
+                (_scan_over_time(x[i], two[1][i], a, two[2][i], two[3][i])
+                 * two[4][i]).sum() for i in range(2)), (0, 1))(two[0], a)
+        for mine, theirs in zip(grads, want):
+            assert _rel(mine, theirs) < 3e-5
+
+    @pytest.mark.parametrize("shape, tiles", [
+        # the hybrid cell's: 64 chunks of 128 eight a grid step, 64-wide
+        # heads two a lane tile
+        ((8192, 8, 64, 128), (128, 8, 2)),
+        ((8192, 8, 128, 256), (256, 4, 1)),     # a head as wide as a tile
+        ((300, 8, 64, 128), (128, 3, 2)),       # 3 chunks: one step
+        ((1664, 8, 64, 128), (128, 1, 2)),      # 13 chunks: a prime
+        ((4096, 3, 64, 128), (128, 8, 1)),      # three heads: no pairs
+        ((4096, 8, 48, 128), (128, 8, 1)),      # 48 does not divide 128
+        ((4096, 8, 32, 64), (64, 16, 4)),       # four heads a tile
+    ])
+    def test_the_shapes_decide_the_tiles(self, shape, tiles):
+        from tpudl.pallas_ops import scan_tiles
+
+        assert tuple(scan_tiles(*shape)) == tiles
+
+    def test_counters_per_trace_and_a_wrong_shape_named(self):
+        from tpudl import obs
+        from tpudl.pallas_ops import ssd_scan
+
+        def read():
+            snap = obs.snapshot("pallas.ssd.")
+            return {k[len("pallas.ssd."):]: v["value"]
+                    for k, v in snap.items()}
+
+        x, dt, a, b, c, _ = _scan_operands(8, 64, 2, 4, 8, 4)
+        before = read().get("launches", 0)
+        fn = jax.jit(lambda *v: ssd_scan(*v, chunk=16))
+        out = jax.eval_shape(fn, x, dt, a, b, c)        # nothing has to run
+        assert out.shape == x.shape and out.dtype == jnp.float32
+        after = read()
+        assert after["launches"] - before == 1
+        assert (after["chunk"], after["heads_a_step"],
+                after["states_saved"]) == (16, 4, 1)
+        with pytest.raises(ValueError, match="8 heads .* over 3 groups"):
+            ssd_scan(x, dt, a, b[:, :1].repeat(3, 1), c, chunk=16)
+        with pytest.raises(ValueError, match="8 heads"):
+            ssd_scan(x, dt[:, :4], a, b, c, chunk=16)

@@ -169,3 +169,47 @@ def test_a_rematerialised_layer_compiles_to_three_kernels_for_the_v5e(
         lambda *a: jnp.sum(layer(*a).astype(jnp.float32)),
         (0, 1, 2))).lower(x, w_in, w_out).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == kernels
+
+
+@pytest.mark.parametrize("seq, heads, groups, width, chunk, held", [
+    # the nemotron-twotower-30b-a3b-ep16 cell's mixer: 64 heads of 64 in 8
+    # groups, state 128, chunks of 128, eight of them a grid step
+    (8192, 64, 8, 64, 128, "float32"),
+    # the control's: the recurrence held in bfloat16 still lowers
+    (8192, 64, 8, 64, 128, "bfloat16"),
+    (1000, 64, 8, 64, 128, "float32"),      # padded: 8 chunks for 7.8
+    (2048, 16, 8, 128, 256, "float32"),     # a head a lane tile, long chunks
+])
+def test_the_selective_scan_compiles_for_the_v5e(
+        one_chip, no_compile_cache, seq, heads, groups, width, chunk, held):
+    """``pallas_ops.ssd_scan`` with bfloat16 operands, forward and
+    gradient: the forward as called, the forward that writes the chunk
+    states, and the backward, at the tiles the shapes derive. What the
+    interpreter cannot show: lane slices at a chunk's offset inside a
+    grid step's block, the ``[R, Q]`` transposes, the products that
+    contract over rows, VMEM at eight chunks a step."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpudl.pallas_ops import ssd_scan
+
+    def loss(x, dt, a, b, c):
+        return jnp.sum(ssd_scan(x, dt, a, b, c, chunk=chunk,
+                                interpret=False) ** 2)
+
+    def operand(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3, 4))).lower(
+        operand((seq, heads, width)), operand((seq, heads), "float32"),
+        operand((heads,), held), operand((seq, groups, 128)),
+        operand((seq, groups, 128))).compile()
+    text = compiled.as_text()
+    # the value comes from the states-saving forward too: XLA keeps one
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # nothing of [chunks, H, Q, Q] in HBM: the largest temporaries are y,
+    # its cotangent (float32) and the chunk states (bfloat16)
+    pad = -seq % chunk
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        (seq + pad) * heads * width * (4 + 4) * 1.1
+        + (seq + pad) // chunk * heads * width * 128 * 2 * 1.1)

@@ -270,6 +270,24 @@ METRICS: tuple[Metric, ...] = (
     Metric("pallas.flash.head_dim_shared", "gauge",
            "key columns the shared key brings in the last traced call (64 "
            "of the 192 under latent attention; 0 without one)"),
+    # -- the selective scan's kernels (per call of pallas_ops.ssd_scan,
+    # which under jax.jit is per TRACE, as the flash kernels' are) -------
+    Metric("pallas.ssd.launches", "counter",
+           "ssd_scan calls traced: each launches the scan's forward "
+           "kernel and, under a gradient, the forward that also writes "
+           "the chunk states and the backward (3 per trace of "
+           "nemotron-twotower-30b-a3b-ep16's loss: one a mixer)"),
+    Metric("pallas.ssd.chunk", "gauge",
+           "positions a chunk in the last traced call (the caller's: 128 "
+           "in that cell)"),
+    Metric("pallas.ssd.heads_a_step", "gauge",
+           "heads one grid step of the scan's kernels takes: a group's, "
+           "which share B and C (8 for 64 heads in 8 groups)"),
+    Metric("pallas.ssd.states_saved", "gauge",
+           "1 where the backward reads the state entering each chunk "
+           "from what the forward wrote, 0 where it would rebuild them by "
+           "a sweep of its own (1 always: under the mixer's "
+           "rematerialisation they live for one sequence's turn)"),
     # -- routed experts (published by Decoder.route_stats, outside steps)
     Metric("moe.pairs_held", "counter",
            "(token, expert) pairs routed to experts this rank holds, "

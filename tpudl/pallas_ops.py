@@ -1,4 +1,7 @@
-"""Pallas TPU kernels for the hot attention op.
+"""Pallas TPU kernels for the hot operators: flash attention, and the
+selective scan of a Mamba-2 mixer in chunks (:func:`ssd_scan`, at the end
+of the file: a forward and a backward kernel that keep a chunk's decays,
+masked scores and the state carried between chunks in VMEM).
 
 Flash attention in Pallas: tiled ``softmax(QKᵀ/√d)·V`` that never
 materializes the full score matrix — Q/K/V tiles stream HBM→VMEM per
@@ -83,7 +86,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tpudl.obs import metrics as _metrics
 
-__all__ = ["flash_attention", "tile_shapes", "tile_counts", "SAVED"]
+__all__ = ["flash_attention", "tile_shapes", "tile_counts", "SAVED",
+           "ssd_scan", "scan_tiles"]
 
 # checkpoint_name of the forward kernel's output and row statistics, as the
 # backward kernels take them: a ``jax.checkpoint`` whose policy saves this
@@ -817,3 +821,512 @@ _flash_traced.__name__ = _flash_traced.__qualname__ = "flash_attention"
 _flash_call = jax.jit(_flash_traced, static_argnames=(
     "causal", "tiles", "pads", "layout", "interpret", "return_lse",
     "precision"))
+
+
+# === the selective scan in chunks ==========================================
+# No checkpoint name here: the caller (``lm_blocks.mamba2_op``)
+# rematerialises a whole mixer a sequence at a time, so the chunk states the
+# forward saves for the backward live for one sequence's turn only.
+# positions a grid step holds, whole chunks (512, 1,024 and 2,048 ran equal to
+# 0.5% on the v5e at the hybrid cell's shape: PERF.md §6, PR 38)
+_SCAN_ROWS = 1024
+
+
+class ScanTiles(NamedTuple):
+    """What the scan kernels' shapes are derived to: the chunk, the chunks
+    one grid step holds (a divisor of the sequence's chunks, at most
+    ``_SCAN_ROWS`` positions), and the heads that share one lane tile (a
+    64-wide head is half a tile: two heads' columns are worked on as ONE
+    128-lane block, each taking its own lanes of a product the MXU makes
+    128 wide either way)."""
+    chunk: int
+    chunks_a_step: int
+    heads_a_tile: int
+
+
+def scan_tiles(s: int, per: int, width: int, chunk: int) -> ScanTiles:
+    """From the shapes alone: ``s`` positions, ``per`` heads a group of
+    ``width`` columns each."""
+    chunks = -(-s // chunk)
+    most = max(1, _SCAN_ROWS // chunk)
+    a_step = max(n for n in range(1, most + 1) if chunks % n == 0)
+    fill = _LANES // width if width < _LANES and _LANES % width == 0 else 1
+    return ScanTiles(chunk, a_step, fill if per % fill == 0 else 1)
+
+
+def _held(v, dtype):
+    """``v`` as a value HELD in ``dtype``: rounded to it, and float32 again
+    for the arithmetic (the VPU's own type; nothing when ``dtype`` is)."""
+    return v if dtype == jnp.float32 else v.astype(dtype).astype(jnp.float32)
+
+
+def _running_sum(x, reverse: bool = False):
+    """Inclusive running sum along the lanes of ``[R, Q]`` (from the far
+    end with ``reverse``): a shift-and-add ladder of ⌈log₂ Q⌉ float32
+    steps on the VPU. A product with a triangle of ones would round the
+    log-decays to the MXU's default bfloat16 operands."""
+    q = x.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    shift = 1
+    while shift < q:
+        if reverse:
+            x = x + jnp.where(lane < q - shift,
+                              pltpu.roll(x, q - shift, 1), 0.0)
+        else:
+            x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, 1), 0.0)
+        shift *= 2
+    return x
+
+
+# What a chunk's decays are staged as, once a grid step for all its chunks:
+# with heads along sublanes, ``[R, rows]`` (ONE vector register a quantity
+# and chunk at the cell's shape: everything is computed there), and, for
+# what has to be spread over a head's lanes, turned to ``[rows, R]``.
+# ``run`` is Σ_{s<=i} Δ_s A inside the chunk, ``grow`` exp(run) (what an
+# entering state keeps; its last position is the chunk's whole decay),
+# ``to_end`` exp(run_last − run), ``pull`` Δ ∘ to_end.
+_BY_ROW = ("run", "grow", "to_end")
+_BY_COL = ("dt", "run", "grow", "pull")
+
+
+def _stage_decays(dt_ref, a, held, chunk: int, chunks: int, by_row, by_col):
+    """The decays of every chunk of the grid step into VMEM scratch
+    (``by_row`` ``[3, R, rows]``, ``by_col`` ``[4, rows, R]``), before the
+    loop over the chunks. A chunk's are a chain of small dependent steps
+    (a ladder of seven shifts, two exponentials, four ``[R, Q]``
+    transposes) that occupies no unit and waits on every one: inside the
+    loop it cost 0.9 µs a turn, 45% of the forward; here the chunks'
+    chains are independent and overlap (PERF.md §6, PR 38). Every decay is
+    a value held in the scan's dtype, the running sum float32
+    arithmetic."""
+    for j in range(chunks):
+        at = slice(j * chunk, (j + 1) * chunk)
+        dt = dt_ref[0, :, at]                                # [R, Q]
+        run = held(_running_sum(held(held(dt) * a)))
+        grow = held(jnp.exp(run))
+        to_end = held(jnp.exp(held(run[:, -1:] - run)))
+        for i, rows in enumerate((run, grow, to_end)):
+            by_row[i, :, at] = rows
+        for i, rows in enumerate((dt, run, grow, dt * to_end)):
+            by_col[i, at, :] = rows.T
+
+
+class _Lanes:
+    """The lanes of one tile of ``fill`` heads, ``width`` columns each: the
+    masks are built ONCE a kernel, outside its loops."""
+
+    def __init__(self, rows: int, fill: int, width: int):
+        self.fill, self.width = fill, width
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, fill * width), 1)
+        self.upto = [lane < (k + 1) * width for k in range(fill)]
+        self.own = [m if k == 0 else m & ~self.upto[k - 1]
+                    for k, m in enumerate(self.upto)]
+
+    def take(self, parts):
+        """``[rows, fill · width]`` taking head ``k``'s lanes from
+        ``parts[k]``."""
+        out = parts[-1]
+        for k in reversed(range(self.fill - 1)):
+            out = jnp.where(self.upto[k][:out.shape[0]], parts[k], out)
+        return out
+
+    def over(self, v, tile: int):
+        """Column ``tile · fill + k`` of ``v`` ``[rows, R]`` over the
+        lanes of head ``k`` of the tile."""
+        shape = (v.shape[0], self.fill * self.width)
+        at = tile * self.fill
+        return self.take([jnp.broadcast_to(v[:, at + k:at + k + 1], shape)
+                          for k in range(self.fill)])
+
+    def row_over(self, v, tile: int):
+        """The same of ONE row ``[1, R]``. Its last select is kept where
+        nothing is left to choose: a lone ``[1, 1]`` spread over lanes and
+        then over rows folds, in Mosaic's canonical form, into one
+        broadcast along both axes, which it does not lower."""
+        shape = (1, self.fill * self.width)
+        out = jnp.zeros(shape, v.dtype)
+        for k in reversed(range(self.fill)):
+            col = v[:, tile * self.fill + k:tile * self.fill + k + 1]
+            out = jnp.where(self.upto[k][:1], jnp.broadcast_to(col, shape),
+                            out)
+        return out
+
+    def only(self, v, k: int):
+        """``v`` with every lane but head ``k``'s at zero."""
+        return v if self.fill == 1 else jnp.where(
+            self.own[k][:v.shape[0]], v, jnp.zeros_like(v))
+
+
+def _causal(chunk: int, transposed: bool = False):
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    return row <= col if transposed else row >= col
+
+
+def _scan_fwd_kernel(a_ref, dt_ref, x_ref, b_ref, c_ref, y_ref, *rest,
+                     tiles: ScanTiles, width: int, held_in):
+    """A grid step is ``chunks_a_step`` chunks of one group's heads; the
+    state ``[N, R · P]`` is carried from chunk to chunk, and from step to
+    step, in VMEM scratch (zero before the first). Before the loop, every
+    chunk's running sum of Δ·A and its decays (:func:`_stage_decays`). A
+    chunk: ``C Bᵀ`` once for the group; a head's ``L`` and ``(C Bᵀ ∘ L ∘
+    Δ) x`` (Δ rides on the masked scores' columns: ``x`` goes to the MXU
+    as it came); what the entering state adds, ``exp(run) ∘ C H``, as one
+    product a lane tile; the state's update ``exp(run_last) H + Bᵀ (Δ ∘
+    exp(run_last − run) ∘ x)``. With ``states_ref`` the state ENTERING
+    each chunk is written out, in the operands' dtype, as the products
+    read it: the backward's residual."""
+    *states_ref, h_scr, by_row, by_col = rest
+    chunk, fill = tiles.chunk, tiles.heads_a_tile
+    wide, dtype, f32 = fill * width, x_ref.dtype, jnp.float32
+    held = functools.partial(_held, dtype=held_in)
+    a = a_ref[0].astype(f32)                                 # [R, 1]
+    lanes, seen = _Lanes(chunk, fill, width), _causal(chunk)
+    row, col = _BY_ROW.index, _BY_COL.index
+
+    @pl.when(pl.program_id(1) == 0)
+    def _first():
+        h_scr[...] = jnp.zeros_like(h_scr)
+
+    _stage_decays(dt_ref, a, held, chunk, tiles.chunks_a_step, by_row,
+                  by_col)
+
+    def a_chunk(j, carry):
+        at = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
+        dt_rows, run_rows = dt_ref[0, :, at], by_row[row("run"), :, at]
+        run, grow = by_col[col("run"), at, :], by_col[col("grow"), at, :]
+        b, c = b_ref[at, :], c_ref[at, :]
+        b_t = b.T
+        if states_ref:
+            states_ref[0][j, 0] = h_scr[...].astype(dtype)
+        scores = _dot(c, b, _NT, None)                       # [Q, Q]
+        for tile in range(a.shape[0] // fill):
+            cols = slice(tile * wide, (tile + 1) * wide)
+            x = x_ref[at, cols]
+            inside = []
+            for k in range(fill):
+                r = tile * fill + k
+                decay = held(jnp.exp(jnp.where(seen, held(
+                    run[:, r:r + 1] - run_rows[r:r + 1, :]), _NEG_INF)))
+                inside.append(_dot(
+                    (scores * decay * dt_rows[r:r + 1, :]).astype(dtype),
+                    x, _NN, None))
+            h = h_scr[:, cols]
+            y_ref[at, cols] = lanes.take(inside) + lanes.over(
+                grow, tile) * _dot(c, h.astype(dtype), _NN, None)
+            pull = lanes.over(by_col[col("pull"), at, :], tile)
+            ends = _dot(b_t, (pull * x.astype(f32)).astype(dtype), _NN,
+                        None)                                # [N, wide]
+            # the chunk's whole decay is the last row of what a state keeps
+            h_scr[:, cols] = held(lanes.row_over(grow[-1:, :], tile) * h
+                                  + held(ends))
+        return carry
+
+    jax.lax.fori_loop(0, tiles.chunks_a_step, a_chunk, None)
+
+
+def _scan_bwd_kernel(a_ref, dt_ref, x_ref, b_ref, c_ref, states_ref, dy_ref,
+                     da_ref, ddt_ref, dx_ref, db_ref, dc_ref, dh_scr, by_row,
+                     by_col, drun_scr, *, tiles: ScanTiles, width: int,
+                     held_in):
+    """The chunks in reverse, the cotangent of the state LEAVING a chunk
+    carried in VMEM scratch. Everything works on the TRANSPOSED score tile
+    ``B Cᵀ`` ``[j, i]`` (as dk/dv does): ``Lᵀ`` and ``(B Cᵀ ∘ Lᵀ ∘ Δ)`` are
+    the left operands of ``dx``'s product as they stand, and the group's
+    ``G = Σ_heads (x dyᵀ) ∘ Lᵀ ∘ Δ`` gives ``dB = G C`` and ``dC = Gᵀ B``.
+
+    The running sum's cotangent is kept with heads along sublanes, ``[R,
+    Q]``, one register: a head's ``K = (x dyᵀ) ∘ Lᵀ ∘ B Cᵀ`` gives its sums
+    over ``j`` as they stand and those over ``i`` from ``Kᵀ`` (a sum along
+    lanes costs a tree of rotations a register, a transpose and a sum
+    along sublanes a fraction); both come from the ONE matrix, so the pairs
+    that do not straddle a position cancel exactly in the reverse running
+    sum that follows. What the states add (``dy · exp(run) C H``, ``x · Bᵀ
+    dH``) is summed over a head's columns the same way, before the decays
+    multiply it. The reverse running sum itself, one more chain of small
+    steps a chunk, is taken for all the step's chunks after the loop."""
+    chunk, fill = tiles.chunk, tiles.heads_a_tile
+    wide, dtype, f32 = fill * width, x_ref.dtype, jnp.float32
+    held = functools.partial(_held, dtype=held_in)
+    a = a_ref[0].astype(f32)                                 # [R, 1]
+    per = a.shape[0]
+    lanes, seen_t = _Lanes(chunk, fill, width), _causal(chunk, True)
+    row, col = _BY_ROW.index, _BY_COL.index
+    head_row = jax.lax.broadcasted_iota(jnp.int32, (per, chunk), 0)
+    head_lane = jax.lax.broadcasted_iota(jnp.int32, (1, per), 1)
+    last = jax.lax.broadcasted_iota(jnp.int32, (chunk, per), 0) == chunk - 1
+
+    @pl.when(pl.program_id(1) == 0)
+    def _first():
+        dh_scr[...] = jnp.zeros_like(dh_scr)
+        da_ref[...] = jnp.zeros_like(da_ref)
+
+    def of_head(r, vector):     # [1, Q] on head r's sublane, zero elsewhere
+        return jnp.where(head_row == r, vector, 0.0)
+
+    _stage_decays(dt_ref, a, held, chunk, tiles.chunks_a_step, by_row,
+                  by_col)
+
+    def a_chunk(back, carry):
+        j = tiles.chunks_a_step - 1 - back
+        at = pl.ds(pl.multiple_of(j * chunk, chunk), chunk)
+        dt_rows, run_rows = dt_ref[0, :, at], by_row[row("run"), :, at]
+        grow_rows = by_row[row("grow"), :, at]
+        to_end_rows = by_row[row("to_end"), :, at]
+        pull_rows = dt_rows * to_end_rows
+        dt_cols, run = by_col[col("dt"), at, :], by_col[col("run"), at, :]
+        grow = by_col[col("grow"), at, :]
+        b, c = b_ref[at, :], c_ref[at, :]
+        c_t = c.T
+        scores_t = _dot(b, c, _NT, None)                     # [j, i]
+        g_sum = jnp.zeros_like(scores_t)
+        drun = jnp.zeros((per, chunk), f32)     # of the running sum, by row
+        ddt = jnp.zeros((per, chunk), f32)      # of Δ where it is a factor
+        dwhole = jnp.zeros((1, per), f32)       # of exp(run_last), a head
+        db = jnp.zeros(b.shape, f32)
+        dc = jnp.zeros(c.shape, f32)
+        for tile in range(per // fill):
+            cols = slice(tile * wide, (tile + 1) * wide)
+            x, dy = x_ref[at, cols], dy_ref[at, cols]
+            x32, dy_in = x.astype(f32), dy.astype(dtype)
+            h_in = states_ref[j, 0, :, cols]
+            dh = dh_scr[:, cols]
+            dh_in = dh.astype(dtype)
+            inside = []
+            for k in range(fill):
+                r = tile * fill + k
+                dt_col = dt_cols[:, r:r + 1]
+                decay_t = held(jnp.exp(jnp.where(seen_t, held(
+                    run_rows[r:r + 1, :] - run[:, r:r + 1]), _NEG_INF)))
+                inside.append(_dot((scores_t * decay_t * dt_col).astype(
+                    dtype), dy_in, _NN, None))
+                g = _dot(lanes.only(x32, k).astype(dtype), dy_in, _NT,
+                         None) * decay_t
+                pairs = g * scores_t             # K without its factor Δ_j
+                g_sum = g_sum + g * dt_col
+                by_i = jnp.sum(pairs * dt_col, axis=0, keepdims=True)
+                by_j = jnp.sum(pairs.T, axis=0, keepdims=True)
+                drun = drun + of_head(
+                    r, by_i - dt_rows[r:r + 1, :] * by_j)
+                ddt = ddt + of_head(r, by_j)
+            # the state leaving the chunk: Σ_j pull_j B_j ⊗ x_j + whole H
+            from_dh = _dot(b, dh_in, _NN, None)              # [Q, wide]
+            pull = lanes.over(by_col[col("pull"), at, :], tile)
+            dx_ref[at, cols] = (lanes.take(inside) + pull * from_dh).astype(
+                dx_ref.dtype)
+            db = db + _dot((pull * x32).astype(dtype), dh_in, _NT, None)
+            from_h = _dot(c, h_in, _NN, None)
+            dy_grown = (lanes.over(grow, tile) * dy).astype(dtype)
+            dc = dc + _dot(dy_grown, h_in, _NT, None)
+            out_t, in_t = (x32 * from_dh).T, (dy * from_h).T  # [wide, Q]
+            kept = jnp.sum(dh * h_in.astype(f32), axis=0, keepdims=True)
+            for k in range(fill):
+                r = tile * fill + k
+                mine = slice(k * width, (k + 1) * width)
+                out = jnp.sum(out_t[mine], axis=0, keepdims=True)   # [1, Q]
+                into = jnp.sum(in_t[mine], axis=0, keepdims=True)
+                pulled = pull_rows[r:r + 1, :] * out
+                drun = drun + of_head(
+                    r, grow_rows[r:r + 1, :] * into - pulled)
+                ddt = ddt + of_head(r, to_end_rows[r:r + 1, :] * out)
+                dwhole = dwhole + jnp.where(
+                    head_lane == r,
+                    jnp.sum(pulled, axis=1, keepdims=True)
+                    + grow[-1:, r:r + 1] * jnp.sum(
+                        lanes.only(kept, k), axis=1, keepdims=True), 0.0)
+            dh_scr[:, cols] = held(
+                lanes.row_over(grow[-1:, :], tile) * dh
+                + _dot(c_t, dy_grown, _NN, None))
+        db_ref[at, :] = (db + _dot(g_sum.astype(dtype), c, _NN,
+                                   None)).astype(db_ref.dtype)
+        dc_ref[at, :] = (dc + _dot(g_sum.T.astype(dtype), b, _NN,
+                                   None)).astype(dc_ref.dtype)
+        # exp(run_last)'s goes to the running sum's last position
+        drun_scr[:, at] = drun + jnp.where(last, dwhole, 0.0).T
+        ddt_ref[0, :, at] = ddt
+        return carry
+
+    jax.lax.fori_loop(0, tiles.chunks_a_step, a_chunk, None)
+    for j in range(tiles.chunks_a_step):
+        at = slice(j * chunk, (j + 1) * chunk)
+        dda = _running_sum(drun_scr[:, at], reverse=True)    # [R, Q]
+        ddt_ref[0, :, at] += dda * a
+        da_ref[0] += jnp.sum(dda * dt_ref[0, :, at], axis=1, keepdims=True)
+
+
+def _scan_specs(tiles: ScanTiles, per: int, width: int, n: int, blocks,
+                dtype, held_in):
+    """What both launches share: the block specs of ``a`` ``[G, R, 1]``,
+    Δ ``[G, R, S]``, ``x`` ``[S, H · P]`` and ``b`` / ``c`` ``[S, G · N]``
+    on a grid ``(group, block of chunks)`` whose second index ``at`` turns
+    into a block, the saved states' ``[chunks, G, N, R · P]``, the scratch
+    (the carried state or its cotangent, the step's staged decays by row
+    and by column) and the compiler's parameters."""
+    rows, a_step = tiles.chunk * tiles.chunks_a_step, tiles.chunks_a_step
+
+    def spec(shape, index):
+        return pl.BlockSpec(shape, lambda g, i: index(g, blocks(i)))
+
+    group = spec((1, per, 1), lambda g, at: (g, 0, 0))
+    dt = spec((1, per, rows), lambda g, at: (g, 0, at))
+    x = spec((rows, per * width), lambda g, at: (at, g))
+    bc = spec((rows, n), lambda g, at: (at, g))
+    states = spec((a_step, 1, n, per * width), lambda g, at: (at, g, 0, 0))
+    scratch = [pltpu.VMEM((n, per * width), jnp.float32),
+               pltpu.VMEM((len(_BY_ROW), per, rows), jnp.float32),
+               pltpu.VMEM((len(_BY_COL), rows, per), jnp.float32)]
+    # the backward's blocks, double buffered: x, dx, the states in the
+    # operands' dtype, dy float32, b, c, db, dc; the scratch, a column of
+    # the decays by column padded to a lane tile
+    item = jnp.dtype(dtype).itemsize
+    need = (2 * rows * (per * width * (2 * item + 4) + 4 * n * item)
+            + 2 * a_step * n * per * width * item + 4 * n * per * width
+            + 4 * len(_BY_COL) * rows * max(per, _LANES))
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=(None if need <= 12 << 20
+                          else min(2 * need, _VMEM_ASK_MAX)))
+    kw = dict(tiles=tiles, width=width, held_in=jnp.dtype(held_in))
+    return (group, dt, x, bc, states), scratch, params, kw
+
+
+def _pallas_scan_fwd(a, dt, x, b, c, *, tiles: ScanTiles, width: int,
+                     save_states: bool, interpret: bool):
+    """``a`` ``[G, R, 1]`` in the dtype the recurrence is held in, Δ ``[G,
+    R, S]`` float32, ``x`` ``[S, H · P]``, ``b`` / ``c`` ``[S, G · N]``, ``S``
+    whole grid steps → ``y`` ``[S, H · P]`` float32 and, with
+    ``save_states``, the state entering every chunk ``[chunks, G, N, R ·
+    P]`` in ``x``'s dtype."""
+    groups, per, s = dt.shape
+    n = b.shape[1] // groups
+    steps = s // (tiles.chunk * tiles.chunks_a_step)
+    (group, by_row, wide, narrow, states), scratch, params, kw = _scan_specs(
+        tiles, per, width, n, lambda i: i, x.dtype, a.dtype)
+    out_specs = [wide]
+    out_shape = [jax.ShapeDtypeStruct(x.shape, jnp.float32)]
+    if save_states:
+        out_specs.append(states)
+        out_shape.append(jax.ShapeDtypeStruct(
+            (s // tiles.chunk, groups, n, per * width), x.dtype))
+    return pl.pallas_call(
+        functools.partial(_scan_fwd_kernel, **kw),
+        grid=(groups, steps),
+        in_specs=[group, by_row, wide, narrow, narrow],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=params, interpret=interpret,
+        name="ssd_scan_fwd")(a, dt, x, b, c)
+
+
+def _pallas_scan_bwd(a, dt, x, b, c, states, dy, *, tiles: ScanTiles,
+                     width: int, interpret: bool):
+    """→ ``(da [G, R, 1] float32, dΔ [G, R, S] float32, dx, db, dc)``, the
+    last three in their operands' dtypes; ``db`` and ``dc`` are a group's,
+    summed over its heads in the kernel."""
+    groups, per, s = dt.shape
+    n = b.shape[1] // groups
+    steps = s // (tiles.chunk * tiles.chunks_a_step)
+    (group, by_row, wide, narrow, saved), scratch, params, kw = _scan_specs(
+        tiles, per, width, n, lambda i: steps - 1 - i, x.dtype, a.dtype)
+    return pl.pallas_call(
+        functools.partial(_scan_bwd_kernel, **kw),
+        grid=(groups, steps),
+        in_specs=[group, by_row, wide, narrow, narrow, saved, wide],
+        out_specs=[group, by_row, wide, narrow, narrow],
+        out_shape=[jax.ShapeDtypeStruct(a.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(dt.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(x.shape, x.dtype),
+                   jax.ShapeDtypeStruct(b.shape, b.dtype),
+                   jax.ShapeDtypeStruct(c.shape, c.dtype)],
+        # and the running sum's cotangent, by row, of the step's chunks
+        scratch_shapes=[*scratch, pltpu.VMEM((per, s // steps), jnp.float32)],
+        compiler_params=params, interpret=interpret,
+        name="ssd_scan_bwd")(a, dt, x, b, c, states, dy)
+
+
+@functools.lru_cache(maxsize=32)
+def _scan_fn(tiles: ScanTiles, width: int, interpret: bool):
+    """The custom-VJP'd scan over prepared operands. Three kernels: the
+    forward as it is called, the forward that also writes the state
+    entering each chunk (the ``fwd`` rule's: under ``jax.checkpoint`` it
+    runs inside the backward pass, a sequence at a time), and the
+    backward. Residuals are the operands and those states."""
+    kw = dict(tiles=tiles, width=width, interpret=interpret)
+
+    @jax.custom_vjp
+    def f(a, dt, x, b, c):
+        return _pallas_scan_fwd(a, dt, x, b, c, save_states=False, **kw)[0]
+
+    def fwd(a, dt, x, b, c):
+        y, states = _pallas_scan_fwd(a, dt, x, b, c, save_states=True, **kw)
+        return y, (a, dt, x, b, c, states)
+
+    def bwd(res, dy):
+        da, *rest = _pallas_scan_bwd(*res, dy, **kw)
+        return (da.astype(res[0].dtype), *rest)
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def ssd_scan(x, dt, a, b, c, *, chunk: int, interpret: bool | None = None):
+    """The selective state-space recurrence of ONE sequence, ``H_t =
+    exp(Δ_t A) H_{t-1} + Δ_t x_t ⊗ B_t``, ``y_t = H_t C_t``, in chunks of
+    ``chunk`` positions (Mamba-2's state-space duality), as one Pallas
+    kernel with its backward.
+
+    ``x`` ``[S, H, P]``, ``dt`` ``[S, H]`` (Δ, after the softplus), ``a``
+    ``[H]`` (negative), ``b`` / ``c`` ``[S, G, N]``; head ``h`` uses group
+    ``h // (H / G)``. Returns ``y`` ``[S, H, P]`` float32. **``a``'s dtype is
+    what the recurrence is held in**: Δ·A, its running sum inside a chunk,
+    the decays ``L`` and the state carried from chunk to chunk (and its
+    cotangent) are values of that dtype; the arithmetic between them is
+    float32, the running sum a float32 ladder on the VPU. The products take
+    operands in ``x``'s dtype and accumulate in float32. A length that
+    ``chunk`` does not divide is padded with Δ = 0: no decay, no input.
+
+    Nothing of shape ``[chunks, H, Q, Q]`` is ever in HBM: decays, masked
+    scores and the running state stay in VMEM. The backward sweeps the
+    chunks in reverse; its residuals are the operands and the state
+    entering each chunk (``[chunks, G, N, R · P]`` in ``x``'s dtype), which
+    the forward writes only where a gradient is taken. What the shapes
+    allow is decided from the shapes (:func:`scan_tiles`). Compiled, a
+    group's heads have to fill whole lane tiles (``R · P`` and ``N``
+    multiples of 128, or one group) and ``chunk`` has to be one.
+
+    ``interpret=None`` picks from the process's default backend, as
+    :func:`flash_attention` does. Counts ``pallas.ssd.*`` once per call,
+    which under ``jax.jit`` is once per TRACE of the caller's program."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    (s, heads, width), groups = x.shape, b.shape[1]
+    if heads % groups or dt.shape != (s, heads) or a.shape != (heads,):
+        raise ValueError(f"{heads} heads of {dt.shape} steps and {a.shape} "
+                         f"decays over {groups} groups")
+    tiles = scan_tiles(s, heads // groups, width, chunk)
+    _metrics.counter("pallas.ssd.launches").inc()
+    _metrics.gauge("pallas.ssd.chunk").set(chunk)
+    _metrics.gauge("pallas.ssd.heads_a_step").set(heads // groups)
+    _metrics.gauge("pallas.ssd.states_saved").set(1)
+    return _scan_call(x, dt, a, b, c, tiles=tiles, interpret=interpret)
+
+
+def _scan_traced(x, dt, a, b, c, *, tiles: ScanTiles, interpret: bool):
+    (s, heads, width), (groups, n) = x.shape, b.shape[1:]
+    per = heads // groups
+    pad = -s % (tiles.chunk * tiles.chunks_a_step)
+
+    def rows(v):            # [S, ...] -> [S + pad, the rest as columns]
+        return jnp.pad(v.reshape(s, -1), ((0, pad), (0, 0)))
+
+    # Δ with its positions along lanes, a group's heads along sublanes
+    dt = rows(dt.astype(jnp.float32)).reshape(-1, groups, per).transpose(
+        1, 2, 0)
+    y = _scan_fn(tiles, width, interpret)(
+        a.reshape(groups, per, 1), dt, rows(x), rows(b), rows(c))
+    return y[:s].reshape(s, heads, width)
+
+
+# the traced name is what device traces file the kernels under
+_scan_traced.__name__ = _scan_traced.__qualname__ = "ssd_scan"
+_scan_call = jax.jit(_scan_traced, static_argnames=("tiles", "interpret"))

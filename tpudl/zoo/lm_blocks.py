@@ -29,6 +29,7 @@ import numpy as np
 
 from tpudl.obs import metrics as _metrics
 from tpudl.obs.trace import named_scope
+from tpudl import pallas_ops
 from tpudl.pallas_ops import flash_attention
 
 __all__ = ["rms_norm", "rotary", "conv_op", "attention_op", "mla_op",
@@ -225,63 +226,23 @@ def relu2_ff(p, name: str, x):
 def ssd_scan(x, dt, a, b, c, chunk: int):
     """The selective state-space recurrence of ONE sequence, ``H_t =
     exp(Δ_t A) H_{t-1} + Δ_t x_t ⊗ B_t``, ``y_t = H_t C_t``, in chunks
-    of ``chunk`` positions (Mamba-2's state-space duality), plain XLA
-    ops, autodiff giving the backward.
+    of ``chunk`` positions (Mamba-2's state-space duality):
+    :func:`tpudl.pallas_ops.ssd_scan`, a Pallas kernel with its backward
+    (compiled by Mosaic on a TPU, interpreted elsewhere).
 
     ``x`` ``[S, H, P]``, ``dt`` ``[S, H]`` (Δ, after the softplus), ``a``
     ``[H]`` (negative), ``b`` / ``c`` ``[S, G, N]``; head ``h`` uses
     group ``h // (H / G)``. Returns ``y`` ``[S, H, P]`` float32. Inside
     a chunk the masked product ``(L ∘ C Bᵀ)(Δ x)``, ``L_ij = exp(Σ_{j<s≤i}
-    Δ_s A)``; a chunk's end state ``Σ_j exp(Σ_{j<s} Δ_s A) Δ_j x_j ⊗ B_j``;
-    a ``lax.scan`` over the chunk states; what the state entering a chunk
-    adds, ``exp(Σ_{s≤i} Δ_s A) C_i · H``. Δ A, its cumulative sums, ``L``
-    and the carried state are ``SCAN_DTYPE`` (float32); the four products
-    take operands in ``x``'s dtype and accumulate in float32. A length
-    that ``chunk`` does not divide is padded with Δ = 0: no decay, no
-    input."""
-    s, heads, width = x.shape
-    groups, n = b.shape[1:]
-    per = heads // groups
-    pad = -s % chunk
-    if pad:
-        x, dt, b, c = (jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
-                       for v in (x, dt, b, c))
-    chunks, dtype, f32 = (s + pad) // chunk, x.dtype, jnp.float32
-    x = x.reshape(chunks, chunk, groups, per, width)
-    b = b.reshape(chunks, chunk, groups, n)
-    c = c.reshape(chunks, chunk, groups, n)
-    dt = dt.reshape(chunks, chunk, groups, per)
-    # log-decay a step, <= 0, and its running sum inside the chunk
-    run = jnp.cumsum(dt.astype(SCAN_DTYPE)
-                     * a.astype(SCAN_DTYPE).reshape(groups, per), axis=1)
-    by_head = run.transpose(0, 2, 3, 1)                   # [c, G, R, Q]
-    dtx = dt.astype(f32)[..., None] * x.astype(f32)       # [c, Q, G, R, P]
-    # inside a chunk: y_i = Σ_{j<=i} (C_i·B_j) L_ij Δ_j x_j
-    scores = jnp.einsum("cign,cjgn->cgij", c, b, preferred_element_type=f32)
-    seen = jnp.tril(jnp.ones((chunk, chunk), bool))
-    decay = jnp.exp(jnp.where(
-        seen, by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
-    y = jnp.einsum("cgrij,cjgrp->cigrp",
-                   (scores[:, :, None] * decay).astype(dtype),
-                   dtx.astype(dtype), preferred_element_type=f32)
-    # a chunk's end state, from zero: Σ_j exp(Σ_{j<s} Δ_s A) Δ_j x_j ⊗ B_j
-    to_end = jnp.exp(by_head[..., -1:] - by_head).transpose(0, 3, 1, 2)
-    ends = jnp.einsum("cjgrp,cjgn->cgrpn",
-                      (dtx * to_end[..., None]).astype(dtype), b,
-                      preferred_element_type=f32)
-
-    def carry(state, chunk_of):
-        whole, end = chunk_of           # the chunk's whole decay, its state
-        return whole[..., None, None] * state + end, state
-
-    _, entering = jax.lax.scan(
-        carry, jnp.zeros(ends.shape[1:], SCAN_DTYPE),
-        (jnp.exp(by_head[..., -1]), ends.astype(SCAN_DTYPE)))
-    # what the state entering the chunk adds: exp(Σ_{s<=i} Δ_s A) C_i · H
-    y = y + jnp.exp(run)[..., None].astype(f32) * jnp.einsum(
-        "cign,cgrpn->cigrp", c, entering.astype(dtype),
-        preferred_element_type=f32)
-    return y.reshape(chunks * chunk, heads, width)[:s]
+    Δ_s A)``, and what the state entering the chunk adds, ``exp(Σ_{s≤i}
+    Δ_s A) C_i · H``; the state goes from chunk to chunk in VMEM. Δ A, its
+    running sums, ``L`` and the carried state are ``SCAN_DTYPE`` (float32;
+    read when the program is traced, and handed over as ``a``'s dtype);
+    the products take operands in ``x``'s dtype and accumulate in float32.
+    A length that ``chunk`` does not divide is padded with Δ = 0: no
+    decay, no input."""
+    return pallas_ops.ssd_scan(x, dt, a.astype(SCAN_DTYPE), b, c,
+                               chunk=chunk)
 
 
 def mamba2_op(p, name: str, x, *, heads: int, groups: int, state: int,
@@ -294,8 +255,8 @@ def mamba2_op(p, name: str, x, *, heads: int, groups: int, state: int,
     ``D`` are read as float32 whatever the other leaves were cast to.
 
     A sequence at a time (``lax.map``), each rematerialised: what is
-    live is one sequence's projections and its ``[chunks, H, Q, Q]``
-    decays, a quarter of the cell's batch. Sets the gauges
+    live is one sequence's projections and, in its backward, the scan's
+    chunk states, a quarter of the cell's batch. Sets the gauges
     ``lm.ssm.chunk`` / ``lm.ssm.chunks`` while a program is TRACED."""
     s, f32 = x.shape[1], jnp.float32
     d_in = p[name + ".out_proj"].shape[0]
