@@ -80,8 +80,8 @@ def count_eqns():
 def check_flash_saved_once(monkeypatch):
     """``check(lm, params, ids, bodies)`` for a decoder of any family
     whose stack TRACES ``bodies`` attention parts (a scanned run once):
-    under ``loss_fn(remat=True)`` the gradient holds three kernels a
-    body where the routes-only policy of PR 34 holds four, the loss and
+    under ``loss_fn(remat=True)`` the gradient holds two kernels a
+    body where the routes-only policy of PR 34 holds three, the loss and
     every gradient leaf are what that policy gives (to float32 rounding:
     the interpreted kernel is XLA operations, which a second compilation
     in another place contracts apart; on the chip a Mosaic kernel is one
@@ -109,7 +109,7 @@ def check_flash_saved_once(monkeypatch):
             assert after["saved_bytes"] == layers * ids.size * lm.heads * (
                 lm.v_head_dim * params["embed"].dtype.itemsize + 4)
         # the flash kernels; a mixer's scan kernels are named, and left out
-        assert _count_eqns(jaxpr, "pallas_call", but="ssd_scan") == 3 * bodies
+        assert _count_eqns(jaxpr, "pallas_call", but="ssd_scan") == 2 * bodies
         with jax.default_matmul_precision("highest"):
             got = jax.jit(step_of(lm))(params, ids)
         with monkeypatch.context() as m:
@@ -117,7 +117,7 @@ def check_flash_saved_once(monkeypatch):
                       jax.checkpoint_policies.save_only_these_names(
                           moe.ROUTES))
             assert _count_eqns(jax.make_jaxpr(step_of(lm))(params, ids),
-                               "pallas_call", but="ssd_scan") == 4 * bodies
+                               "pallas_call", but="ssd_scan") == 3 * bodies
             with jax.default_matmul_precision("highest"):
                 want = jax.jit(step_of(lm))(params, ids)
         assert abs(float(got[0]) - float(want[0])) < 1e-6

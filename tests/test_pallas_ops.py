@@ -2,7 +2,7 @@
 identical kernel semantics; the TPU path compiles the same pallas_call).
 The kernel must match the dense oracle exactly, compose across blocks
 via its log-sum-exp output, and back-propagate to the oracle's gradients
-through the tiled Pallas dq/dk/dv backward kernels (custom VJP from the
+through the ONE tiled Pallas backward kernel (custom VJP from the
 saved log-sum-exp — no S^2 tensor in either direction)."""
 
 import numpy as np
@@ -81,7 +81,7 @@ class TestFlashKernel:
     def test_indivisible_length_pads_and_masks(self, qkv, causal):
         """A length that is no block multiple (50 over blocks of 16/24)
         is padded up to one — pad keys masked in-kernel, pad query rows
-        dropped — in all three kernels: values AND grads match dense."""
+        dropped — in both kernels: values AND grads match dense."""
         q, k, v = (jnp.asarray(a[:, :50]) for a in qkv)
 
         def flash(a, b, c):
@@ -387,7 +387,7 @@ class TestTileClasses:
         the padded length is a multiple of the block."""
         from tpudl.pallas_ops import tile_shapes
 
-        block_q, block_k, _ = tile_shapes(s, s + 3, 4)
+        block_q, block_k, *_ = tile_shapes(s, s + 3, 4, 64, 2)
         assert block_q % 128 == 0 and block_k % 128 == 0
         assert block_q == min(1024, s + (-s % 128))
         assert block_k == min(1024, s + 3 + (-(s + 3) % 128))
@@ -403,8 +403,9 @@ class TestTileClasses:
         from tpudl.pallas_ops import tile_counts, tile_shapes
 
         s = 1536
-        tiles = tile_shapes(s, s, heads // kv_heads, align=1)
-        assert tiles == (1024, 1024, 4 if heads > kv_heads else 1)
+        tiles = tile_shapes(s, s, heads // kv_heads, 64, 4, align=1)
+        assert tiles == (1024, 1024, *((4, 512) if heads > kv_heads
+                                       else (1, 1024)), 2048)
         assert min(tile_counts(s, s, 1024, 1024,
                                causal=True).values()) > 0
         q = jnp.asarray(rng.normal(size=(1, s, heads, 64)), jnp.float32)
@@ -506,7 +507,7 @@ class TestUnequalHeadWidths:
             lambda *a: f(*a).sum(), (0, 1, 2)))(q, k, v))
         assert f(q, k, v).shape == q.shape
         # the scale of a 16-wide head is a power of two and rides on q
-        assert "mul" in text and text.count("pallas_call") == 3
+        assert "mul" in text and text.count("pallas_call") == 2
 
     def test_the_widths_are_gauged_and_a_mismatch_is_named(self, rng):
         from tpudl import obs
@@ -574,7 +575,7 @@ class TestSavedAcrossRemat:
                       k.transpose(0, 2, 1, 3).reshape(4, 32, 24),
                       v.transpose(0, 2, 1, 3).reshape(4, 32, 16))
         off = jnp.zeros((1,), jnp.int32)
-        flash = pallas_ops._flash_fn(True, pallas_ops.Tiles(16, 16, 2), None,
+        flash = pallas_ops._flash_fn(True, pallas_ops.Tiles(16, 16, 2, 16, 32), None,
                                      True, None, 2, 24 ** -0.5)
         inner = jax.checkpoint(lambda *a: flash(*a, off, off),
                                policy=decoder._SAVE_NAMED)
@@ -614,7 +615,7 @@ class TestSavedAcrossRemat:
         counts = {names: count_eqns(jax.make_jaxpr(grads(names))(*operands),
                                     "pallas_call")
                   for names in (self.ROUTES_ONLY, kept)}
-        assert counts == {self.ROUTES_ONLY: 4, kept: 3}
+        assert counts == {self.ROUTES_ONLY: 3, kept: 2}
         for got, want in zip(jax.jit(grads(kept))(*operands),
                              jax.jit(grads(self.ROUTES_ONLY))(*operands)):
             np.testing.assert_allclose(
@@ -626,7 +627,7 @@ class TestSavedAcrossRemat:
             self, operands, count_eqns, return_lse):
         """Forward only, the jaxpr holds no name at all (the primal call
         never enters the ``fwd`` rule); under a gradient the parent's
-        three kernels and two ``name`` equations that compile to
+        two kernels and two ``name`` equations that compile to
         nothing; and so with ``return_lse`` and traced offsets, as the
         ring calls it."""
         def f(q, k, v, offset):
@@ -642,7 +643,7 @@ class TestSavedAcrossRemat:
         assert count_eqns(forward, "pallas_call") == 1
         assert count_eqns(forward, "name") == 0
         backward = jax.make_jaxpr(jax.grad(f, (0, 1, 2)))(*args)
-        assert count_eqns(backward, "pallas_call") == 3
+        assert count_eqns(backward, "pallas_call") == 2
         assert count_eqns(backward, "name") == 2
         compiled = jax.jit(jax.grad(f, (0, 1, 2))).lower(
             *args).compile().as_text()
@@ -777,7 +778,7 @@ class TestSharedKeyAndLayout:
         the commit before this interface traced, at toy shapes and at the
         two grouped-query cells'. The digest is of that commit's text,
         taken with this very loop; a deliberate change to the kernels
-        replaces it."""
+        replaces it (PR 39 did: the one backward kernel)."""
         import hashlib
 
         text = []
@@ -804,12 +805,12 @@ class TestSharedKeyAndLayout:
             text.append(str(jax.make_jaxpr(
                 jax.grad(loss, (0, 1, 2)))(q, k, v)))
         assert hashlib.sha256("\n".join(text).encode()).hexdigest() == (
-            "bbf78e920ba65ade51238c281fa25edadcf40cbfb5e3de66ccc2cd6b503bf35e")
+            "3ae1916243467a57912a48c91a1711a11693e85974f269c08fec32398ccf2631")
 
     def test_the_shared_key_is_a_residual_and_the_forward_runs_once(
             self, rng, count_eqns):
         """Under the decoder's policy the gradient of a call with a shared
-        key holds three kernels, as one without does: the forward's output
+        key holds two kernels, as one without does: the forward's output
         and row statistics are saved, ``q``, both keys and ``v`` recomputed."""
         from tpudl.zoo import decoder
 
@@ -826,7 +827,106 @@ class TestSharedKeyAndLayout:
         grads = jax.grad(jax.checkpoint(loss, policy=decoder._SAVE_NAMED),
                          (0, 1, 2, 3))
         assert count_eqns(jax.make_jaxpr(grads)(q, k, k_r, v),
-                          "pallas_call") == 3
+                          "pallas_call") == 2
+
+
+class TestOneBackwardKernel:
+    """The backward is ONE kernel: every live score tile formed once for
+    dq, dk and dv, dq held in VMEM for a span of Q rows while the K tiles
+    pass. The span is derived from the shapes and ``_VMEM_ASK_MAX``
+    (never asked for); a K/V head whose cotangents leave in parts (more
+    spans than one, more rows of heads than one) gets them summed in
+    float32 outside."""
+
+    @staticmethod
+    def _gauges():
+        from tpudl import obs
+
+        snap = obs.snapshot("pallas.flash.dq_")
+        return (snap["pallas.flash.dq_span"]["value"],
+                snap["pallas.flash.dq_spans"]["value"])
+
+    def _spans(self, rng, monkeypatch, spans):
+        """Six Q tiles swept as ``spans`` spans against all K tiles (a
+        padded K length, dead tiles above the diagonal, a lse cotangent):
+        the gradients of the one span that the shapes derive, to float32
+        round-off. The span is forced through the derivation's own
+        input."""
+        from tpudl import pallas_ops
+
+        heads, kv_heads, d, block = 4, 2, 24, 16
+        q = jnp.asarray(rng.normal(size=(2, 96, heads, d)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(2, 72, kv_heads, d)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(2, 72, kv_heads, 16)), jnp.float32)
+
+        def grads():
+            loss = _weighted(lambda *a: flash_attention(
+                *a, causal=True, q_offset=8, block_q=block, block_k=block,
+                interpret=True, return_lse=True), (2, 96, heads, 16), heads)
+            return jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+
+        want = grads()
+        assert self._gauges() == (96, 1)
+        need = pallas_ops._vmem_need(2, block, block, d, 4)
+        room = 6 // spans * pallas_ops._dq_bytes(2, block, d)
+        monkeypatch.setattr(pallas_ops, "_VMEM_ASK_MAX", 2 * need + room)
+        got = grads()
+        assert self._gauges() == (96 // spans, spans)
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+        for g, w_ in zip(got[1], want[1]):
+            np.testing.assert_allclose(g, w_, rtol=1e-5, atol=1e-5)
+
+    def _group_of_16(self, rng):
+        """The hybrid cell's heads at toy size: 16 query heads over ONE
+        K/V head, four a grid step, so four rows' float32 parts of dk and
+        dv are summed outside the kernel."""
+        q = jnp.asarray(rng.normal(size=(1, 48, 32, 16)), jnp.float32)
+        k, v = (jnp.asarray(rng.normal(size=(1, 48, 2, 16)), jnp.float32)
+                for _ in range(2))
+        got = jax.value_and_grad(_weighted(lambda *a: flash_attention(
+            *a, causal=True, block_q=16, block_k=16, interpret=True,
+            return_lse=True), q.shape, 32), (0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(_weighted(
+            lambda *a: _dense(*a, causal=True), q.shape, 32),
+            (0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+        for g, w_ in zip(got[1], want[1]):
+            assert g.shape == w_.shape and g.dtype == w_.dtype
+            np.testing.assert_allclose(g, w_, rtol=2e-5, atol=2e-5)
+        text = str(jax.make_jaxpr(jax.grad(lambda *a: flash_attention(
+            *a, causal=True, block_q=16, block_k=16,
+            interpret=True).sum(), (0, 1, 2)))(q, k, v))
+        assert text.count("pallas_call") == 2
+        # the parts: 8 rows of four heads, float32, for 2 K/V heads
+        assert "f32[8,48,16]" in text
+
+    def _gauges_at(self, heads, kv_heads, d_qk, d_v):
+        """Per TRACE at a cell's shape (nothing runs): the whole sequence
+        is one span."""
+        q = jax.ShapeDtypeStruct((4, 8192, heads, d_qk), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((4, 8192, kv_heads, d_qk), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((4, 8192, kv_heads, d_v), jnp.bfloat16)
+        jax.eval_shape(lambda *a: flash_attention(
+            *a, causal=True, interpret=False), q, k, v)
+        assert self._gauges() == (8192, 1)
+
+    @pytest.mark.parametrize("case", [
+        "two spans", "three spans", "a group of 16 at four heads a step",
+        "gauges: lfm2-8b-a1b-ep4", "gauges: nemotron-twotower-30b-a3b-ep16",
+        "gauges: joyai-llm-flash-ep32"])
+    def test_one_kernel_in_spans_in_parts_and_counted(self, rng,
+                                                      monkeypatch, case):
+        if case.endswith("spans"):
+            self._spans(rng, monkeypatch, {"two": 2, "three": 3}[
+                case.split()[0]])
+        elif case.startswith("a group"):
+            self._group_of_16(rng)
+        else:
+            self._gauges_at(*{"lfm2-8b-a1b-ep4": (32, 8, 64, 64),
+                              "nemotron-twotower-30b-a3b-ep16":
+                                  (32, 2, 128, 128),
+                              "joyai-llm-flash-ep32": (32, 32, 192, 128)}[
+                                  case.split()[1]])
 
 
 # ---- the selective scan ----------------------------------------------------

@@ -72,7 +72,7 @@ def no_compile_cache():
 def test_flash_attention_with_grouped_queries_compiles_for_the_v5e(
         one_chip, no_compile_cache, seq, heads, head_dim, dtype, blocks):
     """LFM2-8B-A1B's attention: 32 query heads over 8 key/value heads of
-    64, bfloat16, forward and both backward kernels, at 8,192 tokens in
+    64, bfloat16, forward and the backward kernel, at 8,192 tokens in
     512 x 512 tiles and at an awkward length (PR 21's S = 2,047); and
     the tile shapes the kernels derive themselves, so that a derived
     shape that outgrows VMEM, or a tile body Mosaic refuses, fails here
@@ -99,7 +99,7 @@ def test_flash_attention_with_grouped_queries_compiles_for_the_v5e(
     compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).lower(
         q, k, v).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 3      # forward, dq, dk/dv
+    assert text.count("tpu_custom_call") >= 2      # forward, backward
     grads = compiled.output_shardings  # compiled: shapes came through
     assert grads is not None
 
@@ -133,16 +133,16 @@ def test_a_shared_key_and_head_major_operands_compile_for_the_v5e(
     compiled = jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3))).lower(
         operand(2, heads[0], seq, 192), operand(2, heads[1], seq, 128),
         operand(2, seq, 64), operand(2, heads[1], seq, 128)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
-@pytest.mark.parametrize("saved, kernels", [(True, 3), (False, 4)])
-def test_a_rematerialised_layer_compiles_to_three_kernels_for_the_v5e(
+@pytest.mark.parametrize("saved, kernels", [(True, 2), (False, 3)])
+def test_a_rematerialised_layer_compiles_to_two_kernels_for_the_v5e(
         one_chip, no_compile_cache, saved, kernels):
     """What the decoder's policy is for, in the chip's own program: under
     ``jax.checkpoint`` with the forward's output and row statistics
     saved, the compiled gradient of a projection, the kernels and
-    ``W_o`` holds forward, dq and dk/dv; under the routes-only policy it
+    ``W_o`` holds forward and backward; under the routes-only policy it
     holds a second forward."""
     import jax
     import jax.numpy as jnp

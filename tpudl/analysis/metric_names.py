@@ -237,8 +237,8 @@ METRICS: tuple[Metric, ...] = (
     # jax.jit is per TRACE of the caller's program, not per step) -------
     Metric("pallas.flash.launches", "counter",
            "flash_attention calls traced: each launches the forward "
-           "kernel and, under a gradient, dq and dk/dv (1 per trace of "
-           "lfm2-8b-a1b-ep4's loss: one attention layer)"),
+           "kernel and, under a gradient, the ONE backward kernel (1 per "
+           "trace of lfm2-8b-a1b-ep4's loss: one attention layer)"),
     Metric("pallas.flash.tiles.*", "counter",
            "the (Q, K) tiles a head by class, where the "
            "offsets are known while the program is traced: interior "
@@ -247,11 +247,26 @@ METRICS: tuple[Metric, ...] = (
            "computed, nothing fetched); 28 / 8 / 28 at S = 8,192 in the "
            "derived 1,024 x 1,024 tiles, 120 / 16 / 120 in 512 x 512"),
     Metric("pallas.flash.block_q", "gauge",
-           "rows of the tile the last traced call runs its three kernels "
+           "rows of the tile the last traced call runs its forward kernel "
            "at: tile_shapes' derivation (1,024 clipped to the sequence) "
            "or the caller's block_q"),
     Metric("pallas.flash.block_k", "gauge",
-           "columns (keys) of that tile"),
+           "columns (keys) of that tile, the backward kernel's too"),
+    Metric("pallas.flash.bwd_block_q", "gauge",
+           "rows of the backward kernel's tile in that call: block_q "
+           "halved until heads_a_step x rows x block_k is at most 2 Mi "
+           "score-plane elements (1,024 at one or two heads a step, 512 "
+           "at four)"),
+    Metric("pallas.flash.dq_span", "gauge",
+           "rows of Q whose dq the backward kernel of that call holds in "
+           "float32 VMEM scratch while the K tiles pass, derived from "
+           "the shapes and the VMEM a kernel may ask for (the whole "
+           "padded sequence, 8,192, in every LM cell)"),
+    Metric("pallas.flash.dq_spans", "gauge",
+           "spans the backward kernel of that call sweeps the padded "
+           "sequence in (1 in every LM cell; more only where dq of the "
+           "whole sequence outgrows VMEM: each span's dk/dv part is "
+           "then summed in float32 outside the kernel)"),
     Metric("pallas.flash.heads_a_step", "gauge",
            "query heads of one key/value head the last traced call "
            "takes in one grid step (4 for 32 heads over 8)"),

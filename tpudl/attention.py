@@ -83,8 +83,8 @@ def ring_attention(q, k, v, mesh, *, axis: str = M.DATA_AXIS,
 
     ``use_pallas=True`` computes each ring step with the Pallas flash
     kernel (:func:`tpudl.pallas_ops.flash_attention`): forward AND
-    backward are tiled kernels (the custom VJP launches flash dq/dk/dv
-    kernels from the saved log-sum-exp), so neither direction
+    backward are tiled kernels (the custom VJP launches ONE backward
+    kernel from the saved log-sum-exp), so neither direction
     materializes an (S/n)² matrix per device, and strictly-future
     hops/tiles are skipped under causal masking. Partials merge exactly
     via their log-sum-exps (the standard ring/flash-decoding merge).

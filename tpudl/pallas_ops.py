@@ -41,11 +41,24 @@ rides in the spare MXU output columns of ``p @ v`` when the value head
 is narrower than a lane tile, a value head may be narrower than the
 query/key head (latent attention's 128 beside 192: every operand keeps
 its own width, nothing is padded to the other's), the query heads that
-share a K/V head are
-taken ``heads`` at a time in one grid step against one K/V tile, dk/dv
-work on the transposed score tile ``k qᵀ`` (no ``[block_q, block_k]``
-plane is ever transposed), and the tile shape comes from
+share a K/V head are taken ``heads`` at a time in one grid step against
+one K/V tile, and the tile shapes and the backward's dq span come from
 :func:`tile_shapes`.
+
+**The backward is ONE kernel** (:func:`_bwd_kernel`): on a live tile
+the transposed score tile ``k qᵀ``, ``pᵀ = exp(sᵀ − lse)`` and ``dsᵀ =
+pᵀ ∘ (v dOᵀ − δ + dlse)`` are formed once and give ``dv += pᵀ dO``,
+``dk += dsᵀ q`` and ``dqᵀ += kᵀ dsᵀ``: five products a tile where a dq
+and a dk/dv kernel ran seven, and no ``[block_q, block_k]`` plane is
+ever transposed. The grid walks K tiles with Q tiles innermost; dk and
+dv of a K tile sit in scratch while its Q tiles pass, and **dq of every
+Q tile of a span of rows sits in float32 VMEM scratch while the K tiles
+pass** and leaves once, as its last live K tile is done. The span is
+derived (the whole padded sequence at every shape a cell runs; a
+sequence whose dq outgrows VMEM is swept in several spans), never asked
+for. Where a K/V head's dk and dv come in parts (more spans than one;
+more rows of ``heads`` query heads than one) the parts leave in float32
+and are summed outside, as the shared key's cotangent always is.
 
 **Operands come as their producers wrote them.** A caller says which
 axis its heads lie on (``layout``: the kernels work head-major, and a
@@ -59,7 +72,7 @@ summed outside the kernels.
 
 **The forward kernel runs once under rematerialisation.** Its output and
 row statistics carry the ``checkpoint_name`` :data:`SAVED` where they are
-the backward kernels' residuals. A ``jax.checkpoint`` whose policy saves
+the backward kernel's residuals. A ``jax.checkpoint`` whose policy saves
 that name (``zoo/decoder.py``'s does) keeps the two from the forward pass
 and recomputes only what feeds the kernels; for every other caller the
 name compiles to nothing.
@@ -90,7 +103,7 @@ __all__ = ["flash_attention", "tile_shapes", "tile_counts", "SAVED",
            "ssd_scan", "scan_tiles"]
 
 # checkpoint_name of the forward kernel's output and row statistics, as the
-# backward kernels take them: a ``jax.checkpoint`` whose policy saves this
+# backward kernel takes them: a ``jax.checkpoint`` whose policy saves this
 # name does not launch the forward kernel again to get them back
 SAVED = "pallas.flash.saved"
 
@@ -103,14 +116,21 @@ _TILE = 1024
 # three quarters of the 128 MiB of VMEM a v5e / v6e core has, the chips
 # tile_shapes was swept on; a core with less needs a smaller _TILE too
 _VMEM_ASK_MAX = 96 << 20
+# score-plane elements (heads x block_q x block_k) one grid step of the
+# backward may form: at 4 Mi it runs 2.2 times slower (PERF.md §6, PR 39)
+_BWD_PLANES = 2 << 20
 
 
 class Tiles(NamedTuple):
-    """The tile shape of the three kernels and the query heads of one
-    K/V head taken in one grid step."""
+    """What :func:`tile_shapes` derives: the forward's tile, the query
+    heads of one K/V head taken in one grid step, the backward's Q block
+    (its K block is the forward's) and the rows of Q whose dq the
+    backward holds in VMEM while the K tiles pass."""
     block_q: int
     block_k: int
     heads: int
+    bwd_block_q: int
+    dq_span: int
 
     def count(self, pad_q, pad_k, causal, q_offset, k_offset, kv_len,
               head_dims):
@@ -126,6 +146,9 @@ class Tiles(NamedTuple):
         _metrics.gauge("pallas.flash.block_q").set(self.block_q)
         _metrics.gauge("pallas.flash.block_k").set(self.block_k)
         _metrics.gauge("pallas.flash.heads_a_step").set(self.heads)
+        _metrics.gauge("pallas.flash.bwd_block_q").set(self.bwd_block_q)
+        _metrics.gauge("pallas.flash.dq_span").set(self.dq_span)
+        _metrics.gauge("pallas.flash.dq_spans").set(pad_q // self.dq_span)
         _metrics.gauge("pallas.flash.head_dim_qk").set(head_dims[0])
         _metrics.gauge("pallas.flash.head_dim_v").set(head_dims[1])
         _metrics.gauge("pallas.flash.head_dim_shared").set(head_dims[2])
@@ -146,19 +169,66 @@ def _fit_block(block: int, s: int, align: int) -> int:
     return max(align, min(block, s + (-s % align)) // align * align)
 
 
-def tile_shapes(s_q: int, s_k: int, group: int, *,
-                align: int = _LANES) -> Tiles:
-    """The tile shape the kernels run at, from what the code can see:
-    1,024 x 1,024 for all three, clipped to the (aligned) sequence. The
-    largest tile that stays in VMEM without spills was the fastest for
-    every kernel at every shape a caller runs (swept on the v5e at ``S``
-    = 8,192 and 2,048, ``head_dim`` 64 and 128, groups of 1 and 4,
+def tile_shapes(s_q: int, s_k: int, group: int, head_dim: int,
+                itemsize: int, *, block_q: int | None = None,
+                block_k: int | None = None, align: int = _LANES) -> Tiles:
+    """The shapes the kernels run at, from what the code can see. The
+    forward's tile is 1,024 x 1,024 clipped to the (aligned) sequence
+    (``block_q`` / ``block_k``: a caller's wish in their place, clipped
+    the same way): the largest tile that stays in VMEM without spills
+    was the fastest at every shape a caller runs (swept on the v5e at
+    ``S`` = 8,192 and 2,048, ``head_dim`` 64 and 128, groups of 1 and 4,
     bfloat16 and float32: PERF.md §6, PR 31; 2,048 is twice as slow). A
     K/V head's query heads go up to four a grid step: one K/V fetch and
-    one step's overhead for the four."""
+    one step's overhead for the four. **The backward's Q block is the
+    forward's halved until the score planes of a grid step,** ``heads ×
+    block_q × block_k``, **are at most** ``_BWD_PLANES`` (its K block is
+    the forward's): 1,024 x 1,024 for one or two heads a step, 512 x
+    1,024 for four (PERF.md §6, PR 39: at four heads of 1,024 x 1,024
+    the one kernel runs 2.2 times slower, at 512 x 2,048 too; 512 x 512
+    is within 1%, 256 x 1,024 7% slower, 2,048 x 1,024 at one head 8%).
+    The rows of Q the backward holds dq for, from ``head_dim`` (the wider
+    of the two widths) and the operands' ``itemsize``: :func:`_dq_span`."""
     heads = max(h for h in range(1, _MAX_HEADS_A_STEP + 1) if group % h == 0)
-    return Tiles(_fit_block(_TILE, s_q, align), _fit_block(_TILE, s_k, align),
-                 heads)
+    block_q = _fit_block(_TILE if block_q is None else block_q, s_q, align)
+    block_k = _fit_block(_TILE if block_k is None else block_k, s_k, align)
+    bwd_q = block_q
+    while heads * bwd_q * block_k > _BWD_PLANES and bwd_q % (2 * align) == 0:
+        bwd_q //= 2
+    pad_q = s_q + -s_q % block_q
+    return Tiles(block_q, block_k, heads, bwd_q, _dq_span(
+        pad_q // bwd_q, heads, bwd_q, block_k, head_dim, itemsize))
+
+
+def _vmem_need(heads: int, block_q: int, block_k: int, d: int,
+               item: int) -> int:
+    """Bytes of VMEM a kernel's tile takes, whichever of the two: its
+    blocks, double buffered; float32 scratch a tile; four score planes.
+    ``d`` is the wider of the two head widths."""
+    return (2 * item * d * (3 * heads * block_q + 4 * block_k)
+            + 4 * heads * block_q * (3 * _LANES + d)
+            + 4 * 4 * block_q * block_k)
+
+
+def _dq_bytes(heads: int, rows: int, d: int) -> int:
+    """``heads`` float32 accumulators of ``rows`` rows, held transposed:
+    the rows along lanes, the width along sublanes."""
+    return 4 * heads * rows * (d + -d % 8)
+
+
+def _dq_span(q_tiles: int, heads: int, block_q: int, block_k: int, d: int,
+             item: int) -> int:
+    """The rows of Q the backward kernel holds dq for while the K tiles
+    pass: as many whole Q blocks as ``_VMEM_ASK_MAX`` leaves room for
+    beside twice the tile's own need (what :func:`_params` asks for),
+    and a divisor of the padded sequence's ``q_tiles``. Every shape a
+    cell runs fits whole (8,192 rows: 6.3 MB a head at 192 wide, 8.4 MB
+    for four heads of 64, 16.8 MB of 128); a longer sequence is swept in
+    several spans."""
+    room = _VMEM_ASK_MAX - 2 * _vmem_need(heads, block_q, block_k, d, item)
+    fit = max(1, room // _dq_bytes(heads, block_q, d))
+    return block_q * max(n for n in range(1, q_tiles + 1)
+                         if q_tiles % n == 0 and n <= fit)
 
 
 def tile_counts(s_q: int, s_k: int, block_q: int, block_k: int, *,
@@ -181,7 +251,7 @@ def tile_counts(s_q: int, s_k: int, block_q: int, block_k: int, *,
 def _tile_class(causal, kv_len, qoff, koff, iq, ik, block_q, block_k):
     """``(live, interior)`` of tile ``(iq, ik)``: *live* has a visible
     pair, *interior* has nothing but (no pair above the diagonal, no
-    padded key). The ONE predicate of all three kernels; Python bools
+    padded key). The ONE predicate of both kernels; Python bools
     where nothing depends on a position."""
     unpadded = True if kv_len is None else (ik + 1) * block_k <= kv_len
     if not causal:
@@ -206,7 +276,7 @@ def _by_class(live, interior, body):
 
 
 def _last_live_k(causal, qoff, koff, iq, ik, block_q, block_k):
-    """Forward and dq walk K tiles innermost: past the row's last live
+    """The forward walks K tiles innermost: past the row's last live
     tile the index stays there, so a dead step fetches nothing."""
     if not causal:
         return ik
@@ -214,14 +284,41 @@ def _last_live_k(causal, qoff, koff, iq, ik, block_q, block_k):
     return jnp.minimum(ik, jax.lax.div(reach, jnp.int32(block_k)))
 
 
-def _first_live_q(causal, qoff, koff, iq, ik, block_q, block_k, q_tiles):
-    """dk/dv walk Q tiles innermost: before the column's first live tile
-    the index already points at it."""
+def _dead_before(qoff, koff, ik, block_q, block_k):
+    """Under the causal mask, the Q tiles before this one see nothing of
+    K tile ``ik``, nor of any after it."""
+    start = jnp.maximum(koff - qoff + ik * block_k, 0)
+    return jax.lax.div(start, jnp.int32(block_q))
+
+
+def _first_live_q(causal, qoff, koff, iq, ik, block_q, block_k, q_end):
+    """The backward walks Q tiles innermost, up to ``q_end``: before the
+    column's first live tile the index already points at it."""
     if not causal:
         return iq
-    start = jnp.maximum(koff - qoff + ik * block_k, 0)
-    first = jnp.minimum(jax.lax.div(start, jnp.int32(block_q)), q_tiles - 1)
-    return jnp.maximum(iq, first)
+    return jnp.maximum(iq, jnp.minimum(
+        _dead_before(qoff, koff, ik, block_q, block_k), q_end - 1))
+
+
+def _dq_done(causal, qoff, koff, ik, k_tiles, block_q, block_k, span,
+             q_tiles):
+    """``(first, end)``: of the Q tiles of sweep ``span``, ``q_tiles`` of
+    them, ``[first, end)`` meet their LAST live K tile at ``ik`` (a tile
+    no K tile is live for meets it at 0, and leaves as the zeros it was
+    filled with) and those before ``first`` have met it before. Both
+    rise with ``ik`` and ``end`` is the next ``first``, so in the order
+    the grid walks, the tiles are completed in their own order: what lets
+    ONE output block follow them."""
+    lo, hi = span * q_tiles, (span + 1) * q_tiles
+    if not causal:
+        return lo, jnp.where(ik == k_tiles - 1, hi, lo)
+
+    def dead_before(ik):
+        return jnp.clip(_dead_before(qoff, koff, ik, block_q, block_k),
+                        lo, hi)
+
+    return (jnp.where(ik == 0, lo, dead_before(ik)),
+            jnp.where(ik == k_tiles - 1, hi, dead_before(ik + 1)))
 
 
 def _mask(s, qoff, koff, iq, ik, *, causal, kv_len, block_q, block_k,
@@ -356,78 +453,49 @@ def _flash_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                                              lse_ref.shape[2:])
 
 
-def _bwd_dq_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                   lse_ref, dlt_ref, dq_ref, dq_scr, *, causal: bool,
-                   scale, block_q: int, block_k: int, kv_len,
-                   precision, heads: int):
-    """dq = Σ_k  p ⊙ (dOVᵀ − δ + dlse) @ K · scale, accumulated over the
-    innermost K-tile grid dim — same tiling discipline as the forward,
-    no S² materialization. δ = rowsum(dO ⊙ O), and ``p = exp(s − lse)``
-    reconstructs the softmax weights from the saved log-sum-exp. The
-    scale is applied once, to the float32 sum."""
-    iq, ik, nk = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+def _bwd_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                dlt_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
+                causal: bool, scale, block_q: int, block_k: int, kv_len,
+                precision, heads: int, q_tiles: int):
+    """dq = Σ_k ds K · scale, dk = Σ_q dsᵀ Q · scale, dv = Σ_q pᵀ dO with
+    ``p = exp(s − lse)`` rebuilt from the saved log-sum-exp and ``ds = p ⊙
+    (dO Vᵀ − δ + dlse)``, δ = rowsum(dO ⊙ O): every live tile's ``p`` and
+    ``ds`` are formed ONCE and give all three, five products a tile.
 
-    @pl.when(ik == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    qoff, koff = qoff_ref[0], koff_ref[0]
-    live, interior = _tile_class(causal, kv_len, qoff, koff, iq, ik,
-                                 block_q, block_k)
-
-    def body(masked):
-        k, v = _key_tile(k_ref), v_ref[0]
-        for h in range(heads):
-            s = _scaled(_dot(q_ref[0, h], k, _NT, precision), scale)
-            lse = lse_ref[0, h, 0][:, None]
-            if masked:
-                s = _mask(s, qoff, koff, iq, ik, causal=causal,
-                          kv_len=kv_len, block_q=block_q, block_k=block_k,
-                          q_axis=0)
-            p = jnp.exp(s - lse)
-            if masked:
-                # rows that saw no key at all: exp(-inf - -inf) is 1
-                p = p * (lse > _NEG_INF * 0.5).astype(jnp.float32)
-            dp = _dot(do_ref[0, h], v, _NT, precision)
-            ds = p * (dp - dlt_ref[0, h, 0][:, None])
-            dq_scr[h] += _dot(ds.astype(k.dtype), k, _NN, precision)
-
-    _by_class(live, interior, body)
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        dq_ref[0] = _scaled(dq_scr[...], scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
-                    lse_ref, dlt_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
-                    causal: bool, scale, block_q: int,
-                    block_k: int, kv_len, precision, heads: int,
-                    q_tiles: int):
-    """dk = Σ_q (p ⊙ (dOVᵀ − δ + dlse))ᵀ @ Q · scale ; dv = Σ_q pᵀ @ dO —
-    grid over K tiles with the Q-tile dim innermost, on the TRANSPOSED
-    score tile ``K Qᵀ``: ``lse`` and δ already lie along lanes, and
-    ``pᵀ`` / ``dsᵀ`` are the left operands of both products as they
-    stand. The innermost dim runs over the query heads of this K/V
-    head's group, ``heads`` a step, so the group's sum is accumulated
-    in float32 in the same scratch; the scale is applied once, to that
-    sum. With a shared key ``k_ref`` and ``dk_ref`` are pairs: ``dk`` is
-    accumulated whole and its two column blocks leave by their own
-    outputs."""
-    ik, j, nj = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
-    iq = j % q_tiles
+    The grid walks K tiles with the Q tiles of a span innermost, on the
+    TRANSPOSED score tile ``K Qᵀ``: ``lse`` and δ already lie along
+    lanes, and ``pᵀ`` / ``dsᵀ`` are the left operands of dv's and dk's
+    products as they stand and the RIGHT operand of dq's, which is formed
+    transposed, ``dqᵀ += Kᵀ dsᵀ`` (no ``[block_q, block_k]`` plane is
+    ever transposed; the K tile is, ``head_dim`` rows of it). dk and dv
+    of a K tile are summed over the Q tiles (and the ``heads`` query
+    heads of the step) in float32 scratch and leave when its last Q tile
+    is done. **dqᵀ of every Q tile of the span stays in float32 scratch
+    while the K tiles pass**: filled with zeros at the first, and turned
+    and written out once, at the Q tile's last live K tile
+    (:func:`_dq_done`; the output's index map holds still until then).
+    The scale is applied once, to the float32 sums. With a shared key
+    ``k_ref`` and ``dk_ref`` are pairs: ``dk`` is accumulated whole and
+    its two column blocks leave by their own outputs."""
+    span, ik, j = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    iq = span * q_tiles + j
 
     @pl.when(j == 0)
-    def _init():
+    def _a_k_tile_begins():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
+    @pl.when(ik == 0)
+    def _a_q_tile_begins():
+        dq_scr[j] = jnp.zeros(dq_scr.shape[1:], dq_scr.dtype)
+
     qoff, koff = qoff_ref[0], koff_ref[0]
     live, interior = _tile_class(causal, kv_len, qoff, koff, iq, ik,
                                  block_q, block_k)
 
     def body(masked):
         k, v = _key_tile(k_ref), v_ref[0]
+        kt = k.T                                            # [D, TK]
         for h in range(heads):
             q, do = q_ref[0, h], do_ref[0, h]
             st = _scaled(_dot(k, q, _NT, precision), scale)  # [TK, TQ]
@@ -438,16 +506,18 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
                            block_k=block_k, q_axis=1)
             pt = jnp.exp(st - lse)
             if masked:
+                # rows that saw no key at all: exp(-inf - -inf) is 1
                 pt = pt * (lse > _NEG_INF * 0.5).astype(jnp.float32)
             dv_scr[...] += _dot(pt.astype(do.dtype), do, _NN, precision)
             dpt = _dot(v, do, _NT, precision)
-            dst = pt * (dpt - dlt_ref[0, h, :1, :])
-            dk_scr[...] += _dot(dst.astype(q.dtype), q, _NN, precision)
+            dst = (pt * (dpt - dlt_ref[0, h, :1, :])).astype(q.dtype)
+            dk_scr[...] += _dot(dst, q, _NN, precision)
+            dq_scr[j, h] += _dot(kt, dst, _NN, precision)    # [D, TQ]
 
     _by_class(live, interior, body)
 
-    @pl.when(j == nj - 1)
-    def _finalize():
+    @pl.when(j == q_tiles - 1)
+    def _a_k_tile_ends():
         dk = _scaled(dk_scr[...], scale)
         if isinstance(dk_ref, tuple):   # (the head's own, the shared key's)
             own = dk_ref[0].shape[-1]
@@ -457,24 +527,31 @@ def _bwd_dkv_kernel(qoff_ref, koff_ref, q_ref, k_ref, v_ref, do_ref,
             dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
+    first, end = _dq_done(causal, qoff, koff, ik, pl.num_programs(2),
+                          block_q, block_k, span, q_tiles)
+
+    @pl.when(jnp.logical_and(iq >= first, iq < end))
+    def _a_q_tile_ends():
+        for h in range(heads):
+            dq_ref[0, h] = _scaled(dq_scr[j, h].T, scale).astype(
+                dq_ref.dtype)
+
 
 # --- launches --------------------------------------------------------------
-def _params(heads, block_q, block_k, d, item):
-    """Mosaic's scoped-VMEM default is 16 MiB; ask for what the largest
-    of the three kernels takes when that is more (its blocks, double
-    buffered; float32 scratch; four score planes), up to
-    ``_VMEM_ASK_MAX``. ``d`` is the wider of the two head widths."""
-    need = (2 * item * d * (3 * heads * block_q + 4 * block_k)
-            + 4 * heads * block_q * (3 * _LANES + d)
-            + 4 * 4 * block_q * block_k)
+def _params(semantics: tuple, need: int, held: int = 0):
+    """Mosaic's scoped-VMEM default is 16 MiB; ask for twice what
+    :func:`_vmem_need` counts when that is more (the count is of the
+    operands, not of what the compiler adds), and for all of ``held``,
+    the backward's dq accumulator, whose size is exact: up to
+    ``_VMEM_ASK_MAX``."""
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=(None if need <= 12 << 20
-                          else min(2 * need, _VMEM_ASK_MAX)))
+        dimension_semantics=semantics,
+        vmem_limit_bytes=(None if need + held <= 12 << 20
+                          else min(2 * need + held, _VMEM_ASK_MAX)))
 
 
 def _q_major_maps(causal, block_q, block_k, per_kv):
-    """Index maps of the forward and dq grids ``(row, iq, ik)``: the Q
+    """Index maps of the forward's grid ``(row, iq, ik)``: the Q
     tile (and what is shaped like it), the row vectors beside it, and
     the K/V tile of the ``per_kv`` rows that share a K/V head."""
     def q_map(r, iq, ik, *_):
@@ -552,8 +629,10 @@ def _pallas_flash_fwd(qg, kh, vh, qoff, koff, *, causal, tiles: Tiles,
             # lse rides an 8-sublane broadcast dim for TPU output tiling
             jax.ShapeDtypeStruct((rows, heads, 8, s_q), jnp.float32),
         ],
-        compiler_params=_params(heads, block_q, block_k, max(d, d_v),
-                                qg.dtype.itemsize),
+        compiler_params=_params(
+            ("parallel", "parallel", "arbitrary"),
+            _vmem_need(heads, block_q, block_k, max(d, d_v),
+                       qg.dtype.itemsize)),
         interpret=interpret,
     )(qoff, koff, qg, kh, vh)
     return out, lse8[:, :, 0, :]
@@ -562,103 +641,107 @@ def _pallas_flash_fwd(qg, kh, vh, qoff, koff, *, causal, tiles: Tiles,
 def _pallas_flash_bwd(qg, kh, vh, out, lse, qoff, koff, do, dlse, *,
                       causal, tiles: Tiles, kv_len, interpret, precision,
                       group, scale):
-    """Tiled flash backward: (dq, dk, dv) without any S² tensor.
+    """Tiled flash backward, ONE launch: (dq, dk, dv) without any S²
+    tensor, every live score tile formed once (:func:`_bwd_kernel`).
 
     The lse cotangent folds in analytically: ∂lse_i/∂s_ij = p_ij, so the
-    shared score gradient is ds = p ⊙ (dOVᵀ − δ + dlse) with
-    δ = rowsum(dO ⊙ O) − the δ and dlse terms combine into one per-row
-    constant fed to both kernels. ``group`` query rows share each K/V
-    row (row ``bh`` reads K/V row ``bh // group``)."""
+    score gradient is ds = p ⊙ (dOVᵀ − δ + dlse) with δ = rowsum(dO ⊙
+    O): the δ and dlse terms combine into one per-row constant. ``group``
+    query rows share each K/V row (row ``bh`` reads K/V row ``bh //
+    group``).
+
+    Grid ``(row of heads, dq span, K tile, Q tile of the span)``. A row's
+    dk and dv are one PART of its K/V head's: the head has ``group //
+    heads`` rows, and a sequence longer than ``tiles.dq_span`` is swept a
+    span of Q rows at a time against all K tiles. Where a K/V head has
+    more parts than one they leave the kernel in float32 and are summed
+    here, as the shared key's cotangent, a head, always is."""
     rows, heads, s_q, d = qg.shape
     s_k, d_v = vh.shape[1:]
-    per_kv, item = group // heads, qg.dtype.itemsize
-    params = _params(heads, tiles.block_q, tiles.block_k, max(d, d_v), item)
-    # per-row constant: −δ + dlse, folded so the kernels need ONE vector
+    block_q, block_k = tiles.bwd_block_q, tiles.block_k
+    per_kv, spans = group // heads, s_q // tiles.dq_span
+    q_tiles, k_tiles = tiles.dq_span // block_q, s_k // block_k
+    # per-row constant: −δ + dlse, folded so the kernel needs ONE vector
     dlt = (jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
            - dlse.astype(jnp.float32))
     # broadcast row vectors over an 8-sublane dim (TPU input tiling)
     lse8 = jnp.broadcast_to(lse[:, :, None, :], (rows, heads, 8, s_q))
     dlt8 = jnp.broadcast_to(dlt[:, :, None, :], (rows, heads, 8, s_q))
-    kernel_kw = dict(causal=causal, scale=scale,
-                     kv_len=kv_len, precision=precision, heads=heads)
 
-    block_q, block_k = tiles.block_q, tiles.block_k
-    # dq: grid (BH/heads, Sq/TQ, Sk/TK) — q tile fixed per row, K innermost
-    qi_q, qi_row, qi_k = _q_major_maps(causal, block_q, block_k, per_kv)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_q=block_q,
-                          block_k=block_k, **kernel_kw),
+    def q_tile(span, ik, j, qoff_ref, koff_ref):
+        return _first_live_q(causal, qoff_ref[0], koff_ref[0],
+                             span * q_tiles + j, ik, block_q, block_k,
+                             (span + 1) * q_tiles)
+
+    def q_map(r, *at):
+        return (r, 0, q_tile(*at), 0)
+
+    def row_map(r, *at):
+        return (r, 0, 0, q_tile(*at))
+
+    def k_map(r, span, ik, j, *_):
+        return (r // per_kv, ik, 0)
+
+    def part_map(r, span, ik, j, *_):
+        return (r * spans + span, ik, 0)
+
+    def dq_map(r, span, ik, j, qoff_ref, koff_ref):
+        # the next Q tile to be completed: the block stays in VMEM, not
+        # written, until the step that completes it, and moves on then
+        first, end = _dq_done(causal, qoff_ref[0], koff_ref[0], ik, k_tiles,
+                              block_q, block_k, span, q_tiles)
+        return (r, 0, jnp.minimum(jnp.clip(span * q_tiles + j, first, end),
+                                  (span + 1) * q_tiles - 1), 0)
+
+    # a K/V head's cotangents in parts (and the shared key's a head): in
+    # float32 where they are summed here
+    part = jnp.float32 if per_kv * spans > 1 else None
+    dk_shape, dv_shape = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct((rows * spans, s_k, x.shape[2]),
+                                       part or x.dtype), (kh, vh))
+    need = _vmem_need(heads, block_q, block_k, max(d, d_v),
+                      qg.dtype.itemsize)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, causal=causal, scale=scale, block_q=block_q,
+            block_k=block_k, kv_len=kv_len, precision=precision,
+            heads=heads, q_tiles=q_tiles),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(rows, s_q // block_q, s_k // block_k),
+            grid=(rows, spans, k_tiles, q_tiles),
             in_specs=[
-                pl.BlockSpec((1, heads, block_q, d), qi_q),
-                _key_specs(kh, block_k, qi_k),
-                pl.BlockSpec((1, block_k, d_v), qi_k),
-                pl.BlockSpec((1, heads, block_q, d_v), qi_q),
-                pl.BlockSpec((1, heads, 8, block_q), qi_row),
-                pl.BlockSpec((1, heads, 8, block_q), qi_row),
-            ],
-            out_specs=pl.BlockSpec((1, heads, block_q, d), qi_q),
-            scratch_shapes=[pltpu.VMEM((heads, block_q, d), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
-        compiler_params=params,
-        interpret=interpret,
-    )(qoff, koff, qg, kh, vh, do, lse8, dlt8)
-
-    # dk/dv: grid (B·Hkv, Sk/TK, group/heads · Sq/TQ) — k tile fixed per
-    # row, the group's query heads and their Q tiles innermost
-    q_tiles = s_q // block_q
-
-    def ki_k(bkv, ik, j, *_):
-        return (bkv, ik, 0)
-
-    def ki_tile(bkv, ik, j, qoff_ref, koff_ref):
-        return (bkv * per_kv + j // q_tiles,
-                _first_live_q(causal, qoff_ref[0], koff_ref[0],
-                              j % q_tiles, ik, block_q, block_k, q_tiles))
-
-    def ki_q(bkv, ik, j, qoff_ref, koff_ref):
-        r, iq = ki_tile(bkv, ik, j, qoff_ref, koff_ref)
-        return (r, 0, iq, 0)
-
-    def ki_row(bkv, ik, j, qoff_ref, koff_ref):
-        r, iq = ki_tile(bkv, ik, j, qoff_ref, koff_ref)
-        return (r, 0, 0, iq)
-
-    # every part of the key's cotangent comes back a K/V head, as K is
-    dk_shape = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-        vh.shape[:2] + x.shape[2:], x.dtype), kh)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, q_tiles=q_tiles,
-                          block_q=block_q, block_k=block_k, **kernel_kw),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(vh.shape[0], s_k // block_k, per_kv * q_tiles),
-            in_specs=[
-                pl.BlockSpec((1, heads, block_q, d), ki_q),
-                _key_specs(kh, block_k, ki_k),
-                pl.BlockSpec((1, block_k, d_v), ki_k),
-                pl.BlockSpec((1, heads, block_q, d_v), ki_q),
-                pl.BlockSpec((1, heads, 8, block_q), ki_row),
-                pl.BlockSpec((1, heads, 8, block_q), ki_row),
+                pl.BlockSpec((1, heads, block_q, d), q_map),
+                _key_specs(kh, block_k, k_map),
+                pl.BlockSpec((1, block_k, d_v), k_map),
+                pl.BlockSpec((1, heads, block_q, d_v), q_map),
+                pl.BlockSpec((1, heads, 8, block_q), row_map),
+                pl.BlockSpec((1, heads, 8, block_q), row_map),
             ],
             out_specs=[
-                _key_specs(dk_shape, block_k, ki_k),
-                pl.BlockSpec((1, block_k, d_v), ki_k),
+                pl.BlockSpec((1, heads, block_q, d), dq_map),
+                _key_specs(dk_shape, block_k, part_map),
+                pl.BlockSpec((1, block_k, d_v), part_map),
             ],
             scratch_shapes=[
+                pltpu.VMEM((q_tiles, heads, d, block_q), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, d_v), jnp.float32),
             ]),
-        out_shape=[dk_shape, jax.ShapeDtypeStruct(vh.shape, vh.dtype)],
-        compiler_params=params,
+        out_shape=[jax.ShapeDtypeStruct(qg.shape, qg.dtype), dk_shape,
+                   dv_shape],
+        compiler_params=_params(
+            ("parallel", "parallel", "arbitrary", "arbitrary"), need,
+            _dq_bytes(heads, tiles.dq_span, d)),
         interpret=interpret,
     )(qoff, koff, qg, kh, vh, do, lse8, dlt8)
-    if isinstance(kh, tuple):   # the shared key's: the sum over its heads
-        dk = (dk[0], dk[1].reshape(kh[1].shape[0], -1,
-                                   *kh[1].shape[1:]).sum(1))
-    return dq, dk, dv
+
+    def summed(x, like):
+        if x.shape[0] == like.shape[0]:
+            return x
+        return x.reshape(like.shape[0], -1, *x.shape[1:]).sum(1).astype(
+            like.dtype)
+
+    return dq, jax.tree.map(summed, dk, kh), summed(dv, vh)
 
 
 @functools.lru_cache(maxsize=32)
@@ -726,6 +809,13 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
     shared K/V tiles in place (no repeated copy), and dk/dv come back
     with ``Hkv`` heads, summed over each group in float32.
 
+    Under a gradient ONE backward kernel runs: every live score tile is
+    formed once and gives dq, dk and dv; dq of a span of Q rows (the
+    whole padded sequence wherever it fits VMEM: ``tile_shapes(...)
+    .dq_span``) is held in float32 scratch while the K tiles pass and
+    written once. Nothing about it is asked for: tile, span and the
+    parts a K/V head's cotangents are summed from follow from the shapes.
+
     ``q_offset``/``k_offset`` are the blocks' GLOBAL sequence positions
     for causal masking; they may be traced values (each ring device
     passes its rotating source position). ``block_q`` / ``block_k`` are
@@ -741,7 +831,8 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
     (Mosaic has no other target).
 
     Counts ``pallas.flash.*`` once per call, which under ``jax.jit`` is
-    once per TRACE of the caller's program."""
+    once per TRACE of the caller's program (the gauges ``.dq_span`` and
+    ``.dq_spans`` say what the backward holds and in how many sweeps)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if layout not in ("bshd", "bhsd"):
@@ -760,11 +851,9 @@ def flash_attention(q, k, v, *, causal: bool = False, q_offset=0,
         raise ValueError(f"queries {d} wide against keys "
                          f"{k.shape[3] + d_shared} wide")
     group, align = h // h_kv, 1 if interpret else _LANES
-    tiles = tile_shapes(s_q, s_k, group, align=align)
-    if block_q is not None:
-        tiles = tiles._replace(block_q=_fit_block(block_q, s_q, align))
-    if block_k is not None:
-        tiles = tiles._replace(block_k=_fit_block(block_k, s_k, align))
+    tiles = tile_shapes(s_q, s_k, group, max(d, v.shape[3]),
+                        q.dtype.itemsize, block_q=block_q, block_k=block_k,
+                        align=align)
     pad_q, pad_k = s_q + (-s_q % tiles.block_q), s_k + (-s_k % tiles.block_k)
     kv_len = s_k if pad_k != s_k else None
     tiles.count(pad_q, pad_k, causal, q_offset, k_offset, kv_len,
