@@ -8,6 +8,7 @@ tolerances, on the program's own routes."""
 import functools
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -267,6 +268,11 @@ def _plain_combine(out, weights, place, held_rows):
     return jnp.where(mine[..., None], rows * weights[..., None], 0.0).sum(1)
 
 
+# ``moe._SCATTER_UNDER`` that sends the token-order moves down each path
+# whatever the held share (a buffer with nothing held scatters either way)
+_MOVES = {"scatter": 0, "slots": 10 ** 6}
+
+
 def _pairs(t, k, seed=5):
     """A seeded routing of ``t`` tokens to ``k`` of 8 experts, 4 held."""
     rng = np.random.default_rng(seed)
@@ -279,11 +285,14 @@ def _pairs(t, k, seed=5):
 
 @pytest.mark.parametrize("top_k", [2, 4])
 @pytest.mark.parametrize("held_rows", ["none", "some", "all"])
-def test_combine_and_its_hand_written_backward(held_rows, top_k):
-    """``moe.combine`` (slot by slot, no ``[T, k, D]`` array) and its
-    backward in sorted order against ``jax.grad`` of the plain form, in
-    float32: the value, ``d out`` and ``d weights``, with no row held, the
-    routed prefix held, and every row held."""
+def test_combine_and_its_hand_written_backward(held_rows, top_k,
+                                               monkeypatch):
+    """``moe.combine`` (the held prefix scatter-added, or the slots
+    gathered; no ``[T, k, D]`` array either way) and its backward in
+    sorted order against ``jax.grad`` of the plain form, in float32: the
+    value, ``d out`` and ``d weights``, with no row held, the routed
+    prefix held, and every row held, on both paths of the token-order
+    move."""
     t, dim = 24, 16
     weights, order, place, routed = _pairs(t, top_k)
     held = {"none": 0, "some": routed, "all": t * top_k}[held_rows]
@@ -291,89 +300,183 @@ def test_combine_and_its_hand_written_backward(held_rows, top_k):
     rng = np.random.default_rng(6)
     out = jnp.asarray(rng.standard_normal((t * top_k, dim)), jnp.float32)
     ct = jnp.asarray(rng.standard_normal((t, dim)), jnp.float32)
-    got, pull = jax.vjp(lambda o, w: moe.combine(o, w, order, place, held),
-                        out, weights)
     want, plain = jax.vjp(lambda o, w: _plain_combine(o, w, place, held),
                           out, weights)
-    assert got.dtype == jnp.float32 and got.shape == (t, dim)
-    grads = pull(ct)
-    if held == 0:
-        assert not np.any(got)
-        assert not any(np.any(g) for g in grads)
-    else:
-        assert rel(got, want) < 1e-6
-        for mine, theirs in zip(grads, plain(ct)):
-            assert rel(mine, theirs) < 1e-6
-    # rows of no group get no cotangent
-    assert not np.any(np.asarray(grads[0])[held:])
+    for path in _MOVES:
+        monkeypatch.setattr(moe, "_SCATTER_UNDER", _MOVES[path])
+        got, pull = jax.vjp(
+            lambda o, w: moe.combine(o, w, order, place, held), out, weights)
+        assert got.dtype == jnp.float32 and got.shape == (t, dim)
+        grads = pull(ct)
+        if held == 0:
+            assert not np.any(got)
+            assert not any(np.any(g) for g in grads)
+        else:
+            assert rel(got, want) < 1e-6, path
+            for mine, theirs in zip(grads, plain(ct)):
+                assert rel(mine, theirs) < 1e-6
+        # rows of no group get no cotangent
+        assert not np.any(np.asarray(grads[0])[held:])
 
 
-def test_dispatch_backward_sums_a_tokens_rows_slot_by_slot():
+def test_dispatch_backward_sums_a_tokens_rows_slot_by_slot(monkeypatch):
+    """A token's cotangent is the sum of its held rows' cotangents, on
+    both paths of the token-order move (the held rows scatter-added, or
+    the ``k`` slots gathered and masked)."""
     t, k, dim = 24, 2, 16
     _, order, place, routed = _pairs(t, k)
     rng = np.random.default_rng(8)
     x = jnp.asarray(rng.standard_normal((t, dim)), jnp.float32)
     ct = jnp.asarray(rng.standard_normal((t * k, dim)), jnp.float32)
-    rows, pull = jax.vjp(
-        lambda x: moe._dispatch(x, order, place, routed, k), x)
-    np.testing.assert_array_equal(rows, np.asarray(x)[np.asarray(order) // k])
     masked = np.where(np.arange(t * k)[:, None] < routed, ct, 0)
     want = masked[np.asarray(place)].reshape(t, k, dim).sum(1)
-    assert rel(pull(ct)[0], want) < 1e-6
+    for path in _MOVES:
+        monkeypatch.setattr(moe, "_SCATTER_UNDER", _MOVES[path])
+        rows, pull = jax.vjp(
+            lambda x: moe._dispatch(x, order, place, routed, k), x)
+        np.testing.assert_array_equal(
+            rows, np.asarray(x)[np.asarray(order) // k])
+        assert rel(pull(ct)[0], want) < 1e-6, path
+
+
+@pytest.mark.parametrize("held_rows", [0, 1, 15, 16, 17, "all"])
+@pytest.mark.parametrize("top_k", [1, 2, 4, 8])
+def test_held_rows_reach_their_tokens_as_the_masked_slot_sum(
+        top_k, held_rows, monkeypatch):
+    """``moe._to_tokens`` on both paths (scatter-added a chunk of 16 a
+    turn, or ``k`` slots gathered) against the plain masked ``k``-slot
+    sum: nothing held, one row, a row under, at and over a chunk's edge
+    and every row of a buffer of ``27·k`` rows, no multiple of the chunk;
+    the first chunk holds one token twice (``k`` over 1). Float32 rows,
+    weighted, to 1e-6; bfloat16 rows summed in float32 to 1e-6, and
+    dispatch's backward that sum rounded once."""
+    monkeypatch.setattr(moe, "_CHUNK", 16)
+    t, dim = 27, 8
+    pairs = t * top_k
+    held = pairs if held_rows == "all" else held_rows
+    assert pairs % 16
+    rng = np.random.default_rng(13)
+    # sorted row r holds pair order[r]; pairs 0 and 1 (token 0's first two
+    # when k > 1) lead, the rest in a seeded order
+    order = np.concatenate([[0, 1][:pairs], 2 + rng.permutation(pairs - 2)])
+    order, place = jnp.asarray(order, jnp.int32), jnp.asarray(
+        np.argsort(order), jnp.int32)
+    weights = jnp.asarray(rng.random((t, top_k)), jnp.float32)
+    rows = jnp.asarray(rng.standard_normal((pairs, dim)), jnp.float32)
+    halves = rows.astype(jnp.bfloat16)
+    ones = jnp.ones((t, top_k), jnp.float32)
+    want = _plain_combine(rows, weights, place, held)
+    want_halves = _plain_combine(halves, ones, place, held)
+    for path in _MOVES:
+        monkeypatch.setattr(moe, "_SCATTER_UNDER", _MOVES[path])
+        move = jax.jit(functools.partial(moe._to_tokens, k=top_k))
+        got = move(rows, order, place, jnp.int32(held), weights=weights)
+        got_halves = move(halves, order, place, jnp.int32(held))
+        assert got.dtype == got_halves.dtype == jnp.float32
+        assert got.shape == (t, dim)
+        if held:
+            assert rel(got, want) < 1e-6, path
+            assert rel(got_halves, want_halves) < 1e-6, path
+        else:
+            assert not np.any(got) and not np.any(got_halves)
+        ct = jax.vjp(lambda x: moe._dispatch(x, order, place, jnp.int32(held),
+                                             top_k),
+                     jnp.zeros((t, dim), jnp.bfloat16))[1](halves)[0]
+        assert ct.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(ct, np.float32), np.asarray(
+            got_halves.astype(jnp.bfloat16), np.float32))
 
 
 def _route_ops(text):
-    """``{(op, dims, looped): n}`` of the sorts, scatters and gathers
-    that a compiled program's text files under ``moe.route`` (unit
-    dimensions dropped; ``looped``: inside the body of a ``while``)."""
+    """``{(op, dims, where): n}`` of the sorts, scatters and gathers that
+    a compiled program's text files under ``moe.route``, and of the rows
+    each scatter moves (its updates) as ``op`` ``"scattered"``: unit
+    dimensions dropped; ``where`` is ``"while"`` inside the body of a
+    ``while``, ``"slots"`` inside the token-order moves' slot-gather
+    branch (the ``cond``'s first), else ``""``."""
     import collections
-    import re
 
+    def dims(text):
+        return tuple(int(v) for v in text.split(",") if v and int(v) != 1)
+
+    shapes = dict(re.findall(r"(%[\w.\-]+) = \(?[a-z0-9]+\[([0-9,]*)\]",
+                             text))
     found = collections.Counter()
     for line in text.splitlines():
         m = re.search(r"= \(?[a-z0-9]+\[([0-9,]*)\][^=]*? "
-                      r"(sort|scatter|gather)\(", line)
+                      r"(sort|scatter|gather)\(([^)]*)\)", line)
         if m and "moe.route" in line:
-            dims = tuple(int(v) for v in m.group(1).split(",")
-                         if v and int(v) != 1)
-            found[m.group(2), dims, "/while/body/" in line] += 1
+            where = ("while" if "/while/body/" in line else
+                     "slots" if "/cond/branch_0_fun/" in line else "")
+            found[m.group(2), dims(m.group(1)), where] += 1
+            if m.group(2) == "scatter":
+                updates = shapes[m.group(3).split(", ")[-1]]
+                found["scattered", dims(updates), where] += 1
     return found
 
 
-def test_a_rematerialised_block_orders_once_and_gathers_five_times(
+def _route_loop_bodies(text):
+    """The text of each ``while`` body under ``moe.route`` that
+    scatter-adds, by name."""
+    import re
+
+    bodies = {}
+    for body, op in re.findall(
+            r" while\(.*?body=(%[\w.\-]+).*?op_name=\"([^\"]+)\"", text):
+        block = re.search("^" + re.escape(body) + r" .*?^}", text,
+                          re.S | re.M).group(0)
+        if "moe.route" in op and "scatter-add" in block:
+            bodies[body] = block
+    return bodies
+
+
+def test_a_rematerialised_block_orders_once_gathers_thrice_and_scatters_twice(
         monkeypatch):
     """The compiled gradient of two rematerialised routed blocks: one
     sort and one index scatter a layer (the ordering is saved with the
     selection, not recomputed). Of the five moves of rows a layer the
     three in sorted order (dispatch forward and recomputed, the
     cotangent of the experts' output) are loops over chunks of the held
-    prefix: no gather of ``[T·k, D]`` rows is left, a chunk's gather
-    stands in a ``while`` body for each. The two in token order are what
-    they were: ``k`` slot gathers of ``[T, D]`` each, for the combine and
-    for dispatch's backward."""
+    prefix that gather a chunk a turn: no gather of ``[T·k, D]`` rows is
+    left. The two in token order (the combine and dispatch's backward)
+    are loops that scatter-add a chunk of ``(chunk, D)`` rows a turn into
+    the ``[T, D]`` sum they carry, in place: no copy of a ``[T, D]``
+    buffer in their bodies. Their ``k`` slot gathers of ``[T, D]`` are
+    left only in the branch a large held share takes, none elsewhere."""
     monkeypatch.setattr(moe, "_CHUNK", 16)
     lm = Decoder({**BASE, "layer_types": ["conv", "full_attention"],
                   "num_dense_layers": 0})
     ids = tokens()
-    ops = _route_ops(jax.jit(jax.grad(lm.loss_fn(remat=True))).lower(
-        lm.init(3), ids).compile().as_text())
+    text = jax.jit(jax.grad(lm.loss_fn(remat=True))).lower(
+        lm.init(3), ids).compile().as_text()
+    ops = _route_ops(text)
     layers, t, k = 2, ids.size, BASE["num_experts_per_tok"]
     assert moe.chunk_rows(t * k) == 16 < t * k
-    assert ops["sort", (t * k,), False] == layers
-    assert ops["scatter", (t * k,), False] == layers
+    assert ops["sort", (t * k,), ""] == layers
+    assert ops["scatter", (t * k,), ""] == layers
     # rows 64 wide are padded to the grouped products' tile of 256, and
-    # the compiler gathers them at either width (the pad before or after)
-    def rows(n, looped):
-        return sum(ops["gather", (n, dim), looped] for dim in (64, 256))
+    # the compiler moves them at either width (the pad before or after)
+    def rows(op, n, where):
+        return sum(ops[op, (n, dim), where] for dim in (64, 256))
 
-    assert rows(t * k, False) == rows(t * k, True) == 0
-    assert rows(16, True) == 3 * layers and rows(16, False) == 0
-    assert rows(t, False) == 2 * k * layers and rows(t, True) == 0
-    # the weights of a chunk's pairs, for the cotangent in sorted order
-    assert ops["gather", (16,), True] == layers
+    assert rows("gather", t * k, "") == rows("gather", t * k, "while") == 0
+    assert rows("gather", 16, "while") == 3 * layers
+    assert rows("gather", 16, "") == 0
+    # the token-order moves: a chunk's rows scatter-added in a loop into
+    # the [T, D] sum
+    assert rows("scattered", 16, "while") == rows("scatter", t, "while") == (
+        2 * layers)
+    assert rows("gather", t, "") == rows("gather", t, "while") == 0
+    assert rows("gather", t, "slots") == 2 * k * layers
+    # the weights of a chunk's pairs: for the cotangent in sorted order
+    # and for the combine
+    assert ops["gather", (16,), "while"] == 2 * layers
     assert not any(len(dims) == 3 for _, dims, _ in ops)    # no [T, k, D]
-    assert not any(op != "gather" for op, _, looped in ops if looped)
-
+    assert not any(op == "sort" for op, _, where in ops if where)
+    bodies = _route_loop_bodies(text)
+    assert len(bodies) == 2 * layers
+    for body in bodies.values():
+        assert not re.search(rf"= f32\[{t},(64|256)\]\S* copy\(", body)
 
 def _held_prefix_layers(dispatch, combine_rows, x, weights, scale, order,
                         place, held_rows, k):
@@ -391,10 +494,12 @@ def _held_prefix_layers(dispatch, combine_rows, x, weights, scale, order,
 def test_held_prefix_loops_against_the_masked_whole_gather(held_rows,
                                                            monkeypatch):
     """``_dispatch`` and ``combine`` (the loops of ``_over_held`` in
-    dispatch's forward and combine's backward) against the whole gather
+    dispatch's forward and combine's backward; the token-order moves'
+    scatter-add loops, and their slot gathers) against the whole gather
     masked past the prefix, with nothing held, one row, a row under, at
     and over a chunk's edge (16) and every row of a buffer (52) that is
-    no multiple of a chunk: values and gradients under ``jit(grad)``,
+    no multiple of a chunk, whose last chunk starts on rows the turn
+    before has added: values and gradients under ``jit(grad)``,
     rematerialised inside a ``lax.scan``, the prefix's length a value on
     the device."""
     monkeypatch.setattr(moe, "_CHUNK", 16)
@@ -424,15 +529,17 @@ def test_held_prefix_loops_against_the_masked_whole_gather(held_rows,
     np.testing.assert_array_equal(
         rows[:written], np.asarray(x)[np.asarray(order) // k][:written])
     assert not np.any(rows[written:])
-    got = jax.jit(jax.value_and_grad(functools.partial(
-        loss, (moe._dispatch, moe.combine)), (0, 1)))(x, weights, held)
     want = jax.jit(jax.value_and_grad(functools.partial(
         loss, (plain_dispatch, plain_combine)), (0, 1)))(x, weights, held)
-    for mine, theirs in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        if np.any(theirs):
-            assert rel(mine, theirs) < 1e-6
-        else:
-            assert not np.any(mine)
+    for path in _MOVES:
+        monkeypatch.setattr(moe, "_SCATTER_UNDER", _MOVES[path])
+        got = jax.jit(jax.value_and_grad(functools.partial(
+            loss, (moe._dispatch, moe.combine)), (0, 1)))(x, weights, held)
+        for mine, theirs in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            if np.any(theirs):
+                assert rel(mine, theirs) < 1e-6, path
+            else:
+                assert not np.any(mine)
     assert bool(np.any(want[1][0])) == (held_rows > 0)
 
 
@@ -465,7 +572,7 @@ def test_the_activations_hand_written_backward(act, held_rows, monkeypatch):
         np.testing.assert_array_equal(mine[written:], past[written:])
 
 
-def test_bfloat16_forward_is_the_parents_formula():
+def test_bfloat16_forward_is_the_parents_formula(monkeypatch):
     """``routed_ff`` in bfloat16 against ordering, grouped products and
     the ``[T, k, D]`` weighted sum written as the parent had them: the
     float32 sum before its one rounding to 1e-6, the rounded layer to a
@@ -493,8 +600,11 @@ def test_bfloat16_forward_is_the_parents_formula():
 
     want = jax.jit(parent)(p, x)
     assert np.any(np.asarray(want))
-    assert rel(jax.jit(lambda p, x: moe.combine(*sorted_outputs(p, x)))(
-        p, x), want) < 1e-6
+    for path in _MOVES:
+        monkeypatch.setattr(moe, "_SCATTER_UNDER", _MOVES[path])
+        assert rel(jax.jit(lambda p, x: moe.combine(*sorted_outputs(p, x)))(
+            p, x), want) < 1e-6, path
+    monkeypatch.undo()
     got, _ = jax.jit(lambda p, x: moe.routed_ff(
         p, name, x, top_k=k, held=held))(p, x)
     assert got.dtype == jnp.bfloat16
@@ -648,6 +758,17 @@ def test_route_stats_counts_pairs_and_publishes_counters():
     assert stats["chunks_run"] == moved("moe.chunks_run") == sum(
         rec["pairs_held"] > 0 for rec in stats["layers"])
     assert moe.chunk_rows(10 ** 6) == moe._CHUNK
+    # the token-order moves: a layer holding under a sixth of its rows
+    # scatter-adds them, its prefix rounded up to a chunk (here the
+    # buffer), else gathers all; twice a layer, of 2·T·k
+    assert stats["token_rows_total"] == moved("moe.token_rows_total") == (
+        2 * stats["pairs_total"])
+    assert stats["token_rows_run"] == moved("moe.token_rows_run") == sum(
+        2 * (0 if rec["pairs_held"] == 0 else ids.size * 2)
+        for rec in stats["layers"])
+    assert [moe.token_rows(held, 6 * 2048) for held in (0, 1, 1024, 1025,
+                                                        2047, 2048)] == [
+        0, 1024, 1024, 2048, 2048, 6 * 2048]
     jax.eval_shape(lm.loss_fn(), p, ids)
     assert obs.snapshot()["moe.chunk_rows"]["value"] == ids.size * 2
     assert after["moe.expert_tokens_max"]["value"] == stats[

@@ -231,8 +231,8 @@ METRICS: tuple[Metric, ...] = (
     Metric("moe.combine.fused", "counter",
            "routed layers traced through moe.combine, the hand-written "
            "forward/backward pair that puts the experts' rows back at "
-           "their tokens slot by slot (4 per trace of lfm2-8b-a1b-ep4; "
-           "none in a dense decoder)"),
+           "their tokens (4 per trace of lfm2-8b-a1b-ep4; none in a dense "
+           "decoder)"),
     # -- flash kernels (counted per call of flash_attention, which under
     # jax.jit is per TRACE of the caller's program, not per step) -------
     Metric("pallas.flash.launches", "counter",
@@ -317,6 +317,15 @@ METRICS: tuple[Metric, ...] = (
     Metric("moe.chunks_total", "counter",
            "turns they would take over the whole buffers: chunks_run / "
            "chunks_total is the share of the sorted-order work still done"),
+    Metric("moe.token_rows_run", "counter",
+           "rows the routed layer's two token-order moves take on the "
+           "route_stats batches: twice the held prefix rounded up to whole "
+           "chunks where they scatter-add it, twice T x k where they "
+           "gather every slot (moe.token_rows)"),
+    Metric("moe.token_rows_total", "counter",
+           "rows the slot gathers take, 2 x T x k a routed layer: "
+           "token_rows_run / token_rows_total is the share of that work "
+           "still done"),
     Metric("moe.chunk_rows", "gauge",
            "rows a turn of those loops takes in the last TRACED routed "
            "layer (moe.chunk_rows of its T x k pairs)"),

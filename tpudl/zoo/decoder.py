@@ -595,11 +595,15 @@ class Decoder:
         training step casts the parameters): per routed layer and summed
         over them ``pairs_total`` (tokens × k), ``pairs_held`` (pairs
         whose expert this rank holds) and the held experts' token
-        counts, and ``chunks_run`` of ``chunks_total``: the turns the
+        counts, ``chunks_run`` of ``chunks_total``: the turns the
         routed layer's held-prefix loops take on that batch
         (``ceil(pairs_held / chunk)`` a layer) of those a whole buffer
-        would take. Publishes ``moe.pairs_held`` / ``moe.pairs_total`` /
-        ``moe.chunks_run`` / ``moe.chunks_total`` (counters) and
+        would take, and ``token_rows_run`` of ``token_rows_total``: the
+        rows its two token-order moves take (``moe.token_rows`` each) of
+        the ``2·T·k`` that gathering every slot takes. Publishes
+        ``moe.pairs_held`` / ``moe.pairs_total`` / ``moe.chunks_run`` /
+        ``moe.chunks_total`` / ``moe.token_rows_run`` /
+        ``moe.token_rows_total`` (counters) and
         ``moe.expert_tokens_max`` / ``_mean`` (gauges).
         Called outside timed work: ``Trainer``'s loss stays a scalar."""
         key = str(compute_dtype)
@@ -625,18 +629,23 @@ class Decoder:
             layers.append({"pairs_total": int(c.size), "pairs_held": held,
                            "expert_tokens": per_expert.tolist(),
                            "chunks_run": -(-held // chunk),
-                           "chunks_total": -(-c.size // chunk)})
+                           "chunks_total": -(-c.size // chunk),
+                           "token_rows_run": 2 * moe.token_rows(held, c.size),
+                           "token_rows_total": 2 * c.size})
         tokens = np.array([n for rec in layers for n in rec["expert_tokens"]]
                           or [0])
         out = {"layers": layers,
                **{key: sum(rec[key] for rec in layers) for key in (
-                   "pairs_total", "pairs_held", "chunks_run", "chunks_total")},
+                   "pairs_total", "pairs_held", "chunks_run", "chunks_total",
+                   "token_rows_run", "token_rows_total")},
                "expert_tokens_max": int(tokens.max()),
                "expert_tokens_mean": float(tokens.mean())}
         _metrics.counter("moe.pairs_held").inc(out["pairs_held"])
         _metrics.counter("moe.pairs_total").inc(out["pairs_total"])
         _metrics.counter("moe.chunks_run").inc(out["chunks_run"])
         _metrics.counter("moe.chunks_total").inc(out["chunks_total"])
+        _metrics.counter("moe.token_rows_run").inc(out["token_rows_run"])
+        _metrics.counter("moe.token_rows_total").inc(out["token_rows_total"])
         _metrics.gauge("moe.expert_tokens_max").set(
             out["expert_tokens_max"])
         _metrics.gauge("moe.expert_tokens_mean").set(

@@ -21,22 +21,25 @@ give the backward), and their work follows ``group_sizes``, that is the
 pairs really routed here. Widths that are no multiple of 256 (the rows',
 the experts') are padded with zeros for the products (``_TILE``).
 
-Rows move five times a layer, each time as a gather, never as a scatter
-and never as a ``[T, k, D]`` array. The three moves in SORTED order
-follow the held prefix: ``_dispatch`` gathers the tokens' rows into
-sorted order (again when a block is rematerialised), and ``combine``'s
-backward gathers the layer's cotangent into it, as loops over chunks of
-rows whose trip count is ``ceil(pairs_held / chunk)``, a value on the
-device (``_over_held``). So does the activation between the grouped
-products, forward and backward (``_activate``). Shapes stay static and
-nothing can overflow; the work is proportional to the pairs routed here,
-as the grouped products' is. The loops are ``while``s, which reverse
-mode cannot differentiate: each is one side of a hand-written
-forward/backward pair. The two moves in TOKEN order cannot follow a
-prefix and are whole: ``combine`` takes the experts' rows back one slot
-of ``k`` at a time, ``[T, D]`` each, weighting and summing them in
-float32 as they arrive, and dispatch's backward sums a token's ``k``
-rows slot by slot; a slot past the prefix is masked as it arrives.
+Rows move five times a layer, never as a ``[T, k, D]`` array, and every
+move may follow the held prefix: a loop over chunks of sorted rows whose
+trip count is ``ceil(pairs_held / chunk)``, a value on the device. The
+three moves in SORTED order are gathers (``_over_held``): ``_dispatch``
+gathers the tokens' rows into sorted order (again when a block is
+rematerialised), and ``combine``'s backward gathers the layer's
+cotangent into it. So does the activation between the grouped products,
+forward and backward (``_activate``). The two moves in TOKEN order are
+scatter-adds (``_to_tokens``): ``combine`` adds each held row, weighted
+in float32, at its token, and dispatch's backward adds each held row's
+cotangent at its token, into one float32 ``[T, D]`` sum the loop
+carries; a row past the prefix is dropped. A scattered row costs two
+to eight gathered ones, so where a sixth of the rows or more is held
+they gather ``k`` whole slots of ``[T, D]`` instead, masking a slot past
+the prefix as it arrives (``_SCATTER_UNDER``, a ``cond`` on the held
+rows). Shapes stay static and nothing can overflow; the work is
+proportional to the pairs routed here, as the grouped products' is. The
+loops are ``while``s, which reverse mode cannot differentiate: each is
+one side of a hand-written forward/backward pair.
 
 What lies past the prefix. In the buffers the loops fill (the sorted
 rows, the activation, the cotangent of the experts' output): up to the
@@ -45,7 +48,8 @@ in the cotangent of the experts' output, which is masked row by row),
 then zeros. In the buffers the grouped products write, and in the
 activation's cotangents, which are written over them: whatever the
 products leave there. Nothing reads either as a number: the grouped
-products stop at ``group_sizes``, the slots are masked.
+products stop at ``group_sizes``, the token-order moves drop or mask
+those rows.
 
 Both backward passes of the moves are written by hand in sorted order:
 the cotangent of the experts' output is the gathered cotangent scaled by
@@ -70,7 +74,7 @@ from tpudl.obs.trace import named_scope
 from tpudl.zoo.lm_blocks import normal
 
 __all__ = ["route", "routed_ff", "combine", "init_routed", "pair_order",
-           "chunk_rows", "ROUTES", "ACTS"]
+           "chunk_rows", "token_rows", "ROUTES", "ACTS"]
 
 # checkpoint_name of a routed layer's selection and of the ordering made of it
 ROUTES = "moe.routes"
@@ -91,18 +95,34 @@ _TILE = 256
 # the plain expression between the grouped products, by activation
 _PLAIN = {"silu": lambda gate, up: jax.nn.silu(gate) * up,
           "relu2": lambda gate: jnp.square(jax.nn.relu(gate))}
-# Rows a turn of a held-prefix loop (``_over_held``) takes, in buffers of more
-# rows than that. A turn's fixed cost hardly shows at 1,024 rows or more: over
-# a whole buffer of 196,608 rows of 2,816 the gather loop takes 13.87, 13.74,
-# 13.66, 13.62 ms in chunks of 1,024, 2,048, 4,096, 8,192, and a prefix of
-# 9,000 rows, rounded up to whole chunks, 2.22, 2.25, 2.38, 2.63. In the cells
-# 1,024 and 2,048 read alike at a held share of 4% (1,027.69 ms a step both)
-# and 759.69 against 758.52 at 39%. A row costs 1.4 (a gather) to 1.9 times (an
-# elementwise pass) what it costs in one pass over the whole buffer, whatever
-# the chunk: the compiler writes a turn's rows back with a
-# ``dynamic-update-slice`` of its own, after the fusion that made them (my
-# chip runs, PR 33, PERF.md section 6).
-_CHUNK = 2048
+# Rows a turn of a held-prefix loop (``_over_held``, ``_to_tokens``) takes, in
+# buffers of more rows than that. A gather turn's fixed cost hardly shows at
+# 1,024 rows or more: over a whole buffer of 196,608 rows of 2,816 the gather
+# loop takes 13.87, 13.74, 13.66, 13.62 ms in chunks of 1,024, 2,048, 4,096,
+# 8,192, and a prefix of 9,000 rows, rounded up to whole chunks, 2.22, 2.25,
+# 2.38, 2.63. In the cells 1,024 and 2,048 read alike at a held share of 4%
+# (1,027.69 ms a step both) and 759.69 against 758.52 at 39%. A row costs 1.4
+# (a gather) to 1.9 times (an elementwise pass) what it costs in one pass over
+# the whole buffer, whatever the chunk: the compiler writes a turn's rows back
+# with a ``dynamic-update-slice`` of its own, after the fusion that made them.
+# The scatter-add of a held prefix into token order runs faster in chunks of
+# 1,024: 3,276 held rows of 2,048 into 8,192 tokens take 1.71, 1.08, 1.05 ms a
+# call in chunks of 2,048, 1,024, 512; 8,847 rows of 2,816 into 32,768 tokens
+# 4.54, 4.23, 4.30, and 49,152 of them 17.79, 17.77, 17.89 (TPU v5e, each
+# call waited for; PERF.md section 6).
+_CHUNK = 1024
+# The two token-order moves scatter-add the held prefix while fewer than one
+# row in ``_SCATTER_UNDER`` of the buffer is held, and gather ``k`` whole slots
+# of ``[T, D]`` otherwise. On a TPU v5e, with rows in the route's own order,
+# a move takes (device-paced, ms; held share: scatter-add, against the slot
+# gathers, which take every row whatever the share): 32,768 tokens of 2,048 at
+# ``k`` 4, 10%: 4.3, 20%: 7.8, 30%: 11.3, against 5.7; 32,768 of 2,816 at
+# ``k`` 6, 4.5%: 4.1, 10%: 7.9, 20%: 14.5, against 11.0; 8,192 of 2,048 at
+# ``k`` 8, 5%: 0.54, 15%: 1.17, 35%: 2.54, 50%: 3.49, against 2.71 (PERF.md
+# section 6). A scattered row costs 280-440 ns into 32,768 tokens and 107-131
+# into 8,192, a gathered one 41-56: the two cross at 14-15% of the rows held
+# in the larger buffers and at 37% in the smaller.
+_SCATTER_UNDER = 6
 
 
 def route(p, name: str, x, *, top_k: int, scaling: float = 1.0,
@@ -140,12 +160,6 @@ def pair_order(experts, held):
         jnp.arange(n, dtype=jnp.int32), unique_indices=True)
     group_sizes = jnp.bincount(local, length=count + 1)[:count]
     return order, place, group_sizes.astype(jnp.int32)
-
-
-def _slots(place, k):
-    """``place`` ``[T·k]`` as ``[k, T]``: row ``j`` holds the sorted row
-    of every token's ``j``-th pair."""
-    return place.reshape(-1, k).T
 
 
 def chunk_rows(pairs: int) -> int:
@@ -193,6 +207,71 @@ def _sorted_rows(x, order, held_rows, k):
                       jnp.zeros((order.shape[0], x.shape[1]), x.dtype), order)
 
 
+def _scatters(held, pairs: int):
+    """Does a token-order move scatter-add ``held`` rows of a buffer of
+    ``pairs`` (fewer than one in ``_SCATTER_UNDER``), or gather the
+    slots? ``held`` a count, or a value on the device."""
+    return held * _SCATTER_UNDER < pairs
+
+
+def token_rows(held: int, pairs: int) -> int:
+    """Rows each token-order move of a routed layer takes in a buffer of
+    ``pairs`` rows of which ``held`` are held: the held prefix rounded up
+    to whole chunks where ``_to_tokens`` scatter-adds it, every row where
+    it gathers ``k`` whole slots."""
+    if _scatters(held, pairs):
+        chunk = chunk_rows(pairs)
+        return -(-held // chunk) * chunk
+    return pairs
+
+
+def _to_tokens(rows, order, place, held_rows, k, weights=None):
+    """``[T, D]`` float32: ``Σ rows[r]`` (times the router's weight of
+    pair ``order[r]`` where ``weights`` ``[T, k]`` are given) over the
+    held sorted rows ``r < held_rows``, each at its token ``order[r] //
+    k``. Where fewer than one row in ``_SCATTER_UNDER`` is held, a loop
+    of ``ceil(held_rows / chunk)`` turns scatter-adds a chunk of rows a
+    turn into the float32 sum it carries: a row at or past ``held_rows``
+    (the tail of the last chunk, whatever the grouped products left
+    there), and a row the turn before has added where the buffer's last
+    chunk starts early, is sent past the last token and dropped. Else
+    each of the ``k`` slots gathers its ``[T, D]`` rows from ``place``,
+    masked past the prefix as they arrive. Either way no row is read as
+    a number unless it is held, and the sums are float32."""
+    pairs, dim = rows.shape
+    tokens, chunk = pairs // k, chunk_rows(pairs)
+
+    def turn(i, total):
+        start = jnp.minimum(i * chunk, pairs - chunk)
+        index = start + jnp.arange(chunk)
+        mine = jax.lax.dynamic_slice_in_dim(order, start, chunk)
+        part = jax.lax.dynamic_slice_in_dim(rows, start, chunk).astype(
+            jnp.float32)
+        if weights is not None:
+            part = part * weights.reshape(-1)[mine][:, None]
+        keep = index < held_rows
+        if pairs % chunk:
+            keep &= index >= i * chunk
+        return total.at[jnp.where(keep, mine // k, tokens)].add(
+            part, mode="drop")
+
+    def scatter():
+        return jax.lax.fori_loop(0, -(-held_rows // chunk), turn,
+                                 jnp.zeros((tokens, dim), jnp.float32))
+
+    def gather():
+        total = 0.0
+        for j, slot in enumerate(place.reshape(tokens, k).T):
+            part = jnp.where((slot < held_rows)[:, None], rows[slot],
+                             0).astype(jnp.float32)
+            if weights is not None:
+                part = part * weights[:, j, None]
+            total = total + part
+        return total
+
+    return jax.lax.cond(_scatters(held_rows, pairs), scatter, gather)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _dispatch(x, order, place, held_rows, k):
     """Rows of ``x`` ``[T, D]`` in sorted pair order ``[T·k, D]`` (pair
@@ -203,19 +282,16 @@ def _dispatch(x, order, place, held_rows, k):
 
 
 def _dispatch_fwd(x, order, place, held_rows, k):
-    return _sorted_rows(x, order, held_rows, k), (place, held_rows)
+    return _sorted_rows(x, order, held_rows, k), (order, place, held_rows)
 
 
 def _dispatch_bwd(k, res, g):
-    """``Σ_j g[place[:, j]]``, a token's ``k`` rows one slot at a time
-    (no ``[T, k, D]`` array), summed in float32 and rounded once. The
-    grouped products define no cotangent for rows of no group: a slot
-    past the prefix is masked as it arrives, never read as a number."""
-    place, held_rows = res
-    total = 0.0
-    for slot in _slots(place, k):
-        total = total + jnp.where((slot < held_rows)[:, None], g[slot],
-                                  0).astype(jnp.float32)
+    """A token's cotangent is the sum of its held rows' ``g``, moved into
+    token order by ``_to_tokens``, in float32 and rounded once. The
+    grouped products define no cotangent for rows of no group: those are
+    dropped or masked, never read as a number."""
+    order, place, held_rows = res
+    total = _to_tokens(g, order, place, held_rows, k)
     return total.astype(g.dtype), None, None, None
 
 
@@ -226,14 +302,13 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 def combine(out_sorted, weights, order, place, held_rows):
     """``Σ_j weights[:, j] · out_sorted[place[:, j]]`` ``[T, D]`` float32:
     the sorted rows ``[T·k, D]`` back at their tokens under the router's
-    weights ``[T, k]``, one slot at a time, the product and the sum in
-    float32. Rows past the held prefix belong to no group: whatever the
-    grouped product left there is masked, never multiplied."""
-    total = 0.0
-    for j, slot in enumerate(_slots(place, weights.shape[1])):
-        rows = jnp.where((slot < held_rows)[:, None], out_sorted[slot], 0)
-        total = total + rows.astype(jnp.float32) * weights[:, j, None]
-    return total
+    weights ``[T, k]`` (``_to_tokens``: the held prefix scatter-added a
+    chunk a turn, or at a large held share the ``k`` slots gathered), the
+    product and the sum in float32. Rows past the prefix belong to no
+    group: whatever the grouped product left there is dropped or masked,
+    never multiplied."""
+    return _to_tokens(out_sorted, order, place, held_rows, weights.shape[1],
+                      weights)
 
 
 def _combine_fwd(out_sorted, weights, order, place, held_rows):
