@@ -52,22 +52,26 @@ def mesh4x2():
     return M.build_mesh(n_data=4, n_model=2)
 
 
-def _count_eqns(jaxpr, primitive: str, but: str | None = None) -> int:
+def _count_eqns(jaxpr, primitive: str, but: str | None = None,
+                named: str | None = None) -> int:
     """Equations of ``primitive`` in ``jaxpr`` and in every jaxpr its
     equations carry (``pjit``, ``checkpoint``, ``scan``, ``custom_vjp``
-    bodies), each body once however often a loop runs it; with ``but``,
-    those whose ``name`` parameter (a ``pallas_call``'s) starts with it
-    are left out."""
+    bodies), each body once however often a loop runs it; with ``but``
+    (a prefix or a tuple of them), those whose ``name`` parameter (a
+    ``pallas_call``'s or a ``jit``'s) starts with it are left out, with
+    ``named`` only those whose ``name`` is ``named`` count."""
     jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
     n = 0
     for eqn in jaxpr.eqns:
-        n += (eqn.primitive.name == primitive and not (
-            but and str(eqn.params.get("name")).startswith(but)))
+        name = str(eqn.params.get("name"))
+        n += (eqn.primitive.name == primitive
+              and not (but and name.startswith(but))
+              and named in (None, name))
         for value in eqn.params.values():
             for inner in value if isinstance(value, (list, tuple)) else [value]:
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    n += _count_eqns(inner, primitive, but)
+                    n += _count_eqns(inner, primitive, but, named)
     return n
 
 
@@ -108,8 +112,10 @@ def check_flash_saved_once(monkeypatch):
         if layers:    # the gauge is of the last program that saved
             assert after["saved_bytes"] == layers * ids.size * lm.heads * (
                 lm.v_head_dim * params["embed"].dtype.itemsize + 4)
-        # the flash kernels; a mixer's scan kernels are named, and left out
-        assert _count_eqns(jaxpr, "pallas_call", but="ssd_scan") == 2 * bodies
+        # the flash kernels; a mixer's scan kernels and the experts'
+        # grouped products are named, and left out
+        assert _count_eqns(jaxpr, "pallas_call", but=(
+            "ssd_scan", "grouped_dot")) == 2 * bodies
         with jax.default_matmul_precision("highest"):
             got = jax.jit(step_of(lm))(params, ids)
         with monkeypatch.context() as m:
@@ -117,7 +123,8 @@ def check_flash_saved_once(monkeypatch):
                       jax.checkpoint_policies.save_only_these_names(
                           moe.ROUTES))
             assert _count_eqns(jax.make_jaxpr(step_of(lm))(params, ids),
-                               "pallas_call", but="ssd_scan") == 3 * bodies
+                               "pallas_call", but=(
+                                   "ssd_scan", "grouped_dot")) == 3 * bodies
             with jax.default_matmul_precision("highest"):
                 want = jax.jit(step_of(lm))(params, ids)
         assert abs(float(got[0]) - float(want[0])) < 1e-6

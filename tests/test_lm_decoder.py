@@ -16,6 +16,7 @@ import numpy as np
 import optax
 import pytest
 
+from tpudl import pallas_ops
 from tpudl.train import Trainer, with_compute_dtype
 from tpudl.zoo import lm_blocks, moe
 from tpudl.zoo.decoder import Decoder
@@ -134,7 +135,7 @@ def test_tile_shape_keys_in_a_configuration_are_data_nothing_reads():
             == float(jax.jit(keyed.loss_fn())(p, ids)))
 
 
-def test_a_run_of_identical_layers_is_one_scanned_body():
+def test_a_run_of_identical_layers_is_one_scanned_body(count_eqns):
     """LFM2's conv, conv, conv between attentions: the program holds the
     block once (one scan, one set of grouped products), and the leaves
     stay one flat dict of single layers."""
@@ -143,10 +144,11 @@ def test_a_run_of_identical_layers_is_one_scanned_body():
     assert Decoder({**BASE, "layer_types": ["conv", "conv", "full_attention"],
                     "num_dense_layers": 1}).runs() == [(0, 1), (1, 1),
                                                         (2, 1)]
-    text = str(jax.make_jaxpr(lm.loss_fn(remat=True))(p, tokens()))
-    assert text.count("scan[") == 2          # the run, and the chunked head
-    # layer 1 alone, and once for layers 2-4
-    assert text.count("ragged_dot_general[") == 2 * 3
+    jaxpr = jax.make_jaxpr(lm.loss_fn(remat=True))(p, tokens())
+    assert str(jaxpr).count("scan[") == 2    # the run, and the chunked head
+    # layer 1 alone, and once for layers 2-4: two grouped products a
+    # layer, the gate and up-projection one call
+    assert count_eqns(jaxpr, "jit", named="grouped_dot") == 2 * 2
     assert all(v.ndim <= 3 and ".moe.w" in k or v.ndim <= 2
                for k, v in p.items())
 
@@ -548,8 +550,10 @@ def test_held_prefix_loops_against_the_masked_whole_gather(held_rows,
 def test_the_activations_hand_written_backward(act, held_rows, monkeypatch):
     """``moe._activate`` and its backward, a chunk of the held prefix a
     turn, against ``jax.vjp`` of the plain expression on the held rows.
-    Past the prefix's last chunk the value is zeros, and a product's
-    cotangent, written over the product, is still the product."""
+    Past the prefix's last chunk the value is zeros, the gate's
+    cotangent, written over the incoming cotangent, is still that
+    cotangent, and the up-projection's, written over the up-projection,
+    still the up-projection."""
     monkeypatch.setattr(moe, "_CHUNK", 16)
     n, wide = 52, 24
     rng = np.random.default_rng(12)
@@ -565,7 +569,7 @@ def test_the_activations_hand_written_backward(act, held_rows, monkeypatch):
     want, plain_pull = jax.vjp(plain, *products)
     written = min(-(-held_rows // 16) * 16, n)
     for mine, theirs, past in zip((got, *pull(ct)), (want, *plain_pull(ct)),
-                                  (jnp.zeros_like(got), *products)):
+                                  (jnp.zeros_like(got), ct, *products[1:])):
         assert mine.shape == theirs.shape and mine.dtype == theirs.dtype
         if written:
             assert rel(mine[:written], theirs[:written]) < 1e-6
@@ -727,6 +731,46 @@ def test_fit_consume_hands_the_state_over_without_a_copy():
 
 
 # ---- what the routing did, and what was traced ----------------------------
+@pytest.mark.parametrize("by_sequence", [False, True])
+def test_count_routes_counts_the_grouped_products_row_tiles(by_sequence):
+    """``moe.row_tiles_run`` / ``_total``: per call of ``routed_ff`` (a
+    sequence at a time where the layer takes one) the row tiles the held
+    experts' rows touch, at the tile the call's buffer derives, and the
+    buffer's tiles. Top 8 of 256 with 8 held, as the latent-attention
+    cell routes: about one row in 32 is held, and the grouped products
+    visit a few hundredths of the tiles."""
+    from tpudl import obs
+
+    lm = Decoder({**BASE, "layer_types": ["conv"], "num_dense_layers": 0,
+                  "num_experts": 256, "num_experts_per_tok": 8,
+                  "experts_held": (8, 8)})
+    lm.routed_by_sequence = by_sequence
+    rng = np.random.default_rng(11)
+    chosen = [rng.integers(0, 256, (4, 8192, 8)) for _ in range(2)]
+    before = obs.snapshot("moe.row_tiles")
+    stats = lm.count_routes(chosen)
+    after = obs.snapshot("moe.row_tiles")
+    calls = [c for layer in chosen
+             for c in (layer if by_sequence else [layer])]
+    pairs = calls[0].size
+    block = pallas_ops.row_tile(pairs)
+    assert block == 256
+    run = sum(pallas_ops.tiles_visited(np.bincount(
+        c.reshape(-1)[(c.reshape(-1) >= 8) & (c.reshape(-1) < 16)] - 8,
+        minlength=8), block) for c in calls)
+    assert stats["row_tiles_run"] == run
+    assert stats["row_tiles_total"] == len(calls) * pairs // block == 2048
+    for key in ("row_tiles_run", "row_tiles_total"):
+        assert (after[f"moe.{key}"]["value"]
+                - before.get(f"moe.{key}", {"value": 0})["value"]) == stats[
+                    key]
+        assert stats[key] == sum(rec[key] for rec in stats["layers"])
+    # the held rows over the tile, and at most one more tile an expert
+    held = stats["pairs_held"]
+    assert held / block <= run <= held / block + 8 * len(calls)
+    assert run / stats["row_tiles_total"] <= 0.08
+
+
 def test_route_stats_counts_pairs_and_publishes_counters():
     from tpudl import obs
 
@@ -972,9 +1016,14 @@ def test_the_check_fails_on_each_fault(fault, lm_train, monkeypatch):
         monkeypatch.setattr(moe, "route", unnormalised)
     elif fault == "fp8_product":
         fp8 = _fp8_control().fp8_ragged_dot
-        monkeypatch.setattr(
-            jax.lax, "ragged_dot",
-            lambda lhs, rhs, group_sizes, **kw: fp8(lhs, rhs, group_sizes))
+
+        def fp8_grouped(lhs, rhs, group_sizes, **kw):
+            # the program's grouped product, with a tuple of weights
+            if isinstance(rhs, tuple):
+                return tuple(fp8(lhs, w, group_sizes) for w in rhs)
+            return fp8(lhs, rhs, group_sizes)
+
+        monkeypatch.setattr(pallas_ops, "grouped_dot", fp8_grouped)
     elif fault == "lowest_k":
         real = jax.lax.top_k
         monkeypatch.setattr(jax.lax, "top_k",

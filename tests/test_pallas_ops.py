@@ -1106,3 +1106,156 @@ class TestSelectiveScan:
             ssd_scan(x, dt, a, b[:, :1].repeat(3, 1), c, chunk=16)
         with pytest.raises(ValueError, match="8 heads"):
             ssd_scan(x, dt[:, :4], a, b, c, chunk=16)
+
+
+# ---- grouped matrix products (the routed experts) --------------------------
+class TestGroupedDot:
+    """``pallas_ops.grouped_dot`` in interpret mode at small shapes, against
+    ``jax.lax.ragged_dot`` and its ``jax.vjp`` on the held prefix. Rows past
+    the prefix hold NaN in the rows and in the cotangent, as the routed
+    layer's buffers may: no held output and no weights' gradient may see
+    them."""
+
+    M, K, N, E = 1024, 128, 256, 4
+
+    @staticmethod
+    def _rel(a, b):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        return np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30)
+
+    @pytest.mark.parametrize("sizes, pair", [
+        ((200, 120, 74, 400), False),  # a prefix, NaN past it
+        ((300, 0, 600, 80), True),     # groups cross the 256-row tiles' edges
+        ((0, 0, 77, 0), False),        # empty groups around the one held
+        ((0, 0, 0, 0), True),          # nothing held at all
+        ((256, 256, 256, 256), True),  # every row held, on the tiles' edges
+    ])
+    def test_held_rows_and_both_gradients_against_ragged_dot(self, sizes,
+                                                             pair):
+        from tpudl.pallas_ops import grouped_dot
+
+        rng = np.random.default_rng(sum(sizes))
+        m, k, n, e = self.M, self.K, self.N, self.E
+        sizes = jnp.asarray(sizes, jnp.int32)
+        held = int(sizes.sum())
+        past = (np.arange(m) >= held)[:, None]
+        x = np.where(past, np.nan, rng.standard_normal((m, k)))
+        g = np.where(past[None], np.nan, rng.standard_normal((2, m, n)))
+        x, g = jnp.asarray(x, jnp.float32), jnp.asarray(g, jnp.float32)
+        ws = tuple(jnp.asarray(rng.standard_normal((e, k, n)), jnp.float32)
+                   for _ in range(2 if pair else 1))
+        clean = jnp.where(past, 0.0, x)
+        cots = tuple(jnp.where(past, 0.0, g[i]) for i in range(len(ws)))
+
+        def mine(a, *w):
+            return grouped_dot(a, w if pair else w[0], sizes)
+
+        def theirs(a, *w):
+            out = tuple(jax.lax.ragged_dot(a, v, sizes) for v in w)
+            return out if pair else out[0]
+
+        out, pull = jax.vjp(mine, x, *ws)
+        want, want_pull = jax.vjp(theirs, clean, *ws)
+        got_grads = pull(tuple(g[:len(ws)]) if pair else g[0])
+        want_grads = want_pull(cots if pair else cots[0])
+        for o, w in zip(out if pair else [out], want if pair else [want]):
+            assert o.shape == (m, n) and o.dtype == jnp.float32
+            assert self._rel(o[:held], w[:held]) < 1e-6
+        # d_lhs: the row cotangents of a pair summed inside the kernel
+        assert self._rel(got_grads[0][:held], want_grads[0][:held]) < 1e-5
+        for d_w, want_w in zip(got_grads[1:], want_grads[1:]):
+            assert bool(jnp.isfinite(d_w).all())
+            assert self._rel(d_w, want_w) < 1e-5 or not held
+            assert not held or np.abs(np.asarray(want_w)).max() > 0
+            # an empty group's weights get an exact zero
+            for i in np.flatnonzero(np.asarray(sizes) == 0):
+                assert not np.asarray(d_w[i]).any()
+
+    def test_the_fused_row_cotangent_is_the_sum_of_two_calls(self):
+        from tpudl.pallas_ops import grouped_dot
+
+        rng = np.random.default_rng(5)
+        sizes = jnp.asarray((180, 420, 0, 66), jnp.int32)
+        x = jnp.asarray(rng.standard_normal((self.M, self.K)), jnp.float32)
+        w1, w3 = (jnp.asarray(rng.standard_normal(
+            (self.E, self.K, self.N)), jnp.float32) for _ in range(2))
+        g1, g3 = (jnp.asarray(rng.standard_normal((self.M, self.N)),
+                              jnp.float32) for _ in range(2))
+        _, pull = jax.vjp(lambda a: grouped_dot(a, (w1, w3), sizes), x)
+        (fused,) = pull((g1, g3))
+        one = [jax.vjp(lambda a: grouped_dot(a, w, sizes), x)[1](g)[0]
+               for w, g in ((w1, g1), (w3, g3))]
+        held = int(sizes.sum())
+        assert self._rel(fused[:held], (one[0] + one[1])[:held]) < 1e-6
+        # one kernel for the rows' cotangent, one for each weight
+        text = str(jax.make_jaxpr(lambda a: pull((g1, g3)))(x))
+        assert text.count("name=grouped_dot_rows") == 1
+
+    @pytest.mark.parametrize("shape, tiles", [
+        # the three LM cells': joyai-llm-flash-ep32 (a sequence's 65,536
+        # pairs, gated), nemotron-twotower (196,608, relu2), lfm2 (131,072,
+        # gated): K and N whole
+        ((65536, 2048, 768, 2), (256, 2048, 768)),
+        ((196608, 2816, 2048, 1), (256, 2816, 2048)),
+        ((131072, 2048, 1792, 2), (256, 2048, 1792)),
+        ((200, 256, 512, 1), (208, 256, 512)),   # a buffer under one tile
+        ((64, 64, 64, 2), (64, 64, 64)),         # a toy buffer
+        # widths whose blocks outgrow the VMEM a step may ask: N is cut first
+        ((8192, 8192, 8192, 2), (256, 8192, 512)),
+    ])
+    def test_the_tile_rule_from_the_shapes(self, shape, tiles):
+        from tpudl.pallas_ops import _GROUP_VMEM, _group_vmem, group_tiles
+
+        m, k, n, products = shape
+        got = group_tiles(m, k, n, products=products)
+        assert tuple(got) == tiles
+        assert _group_vmem(got, products, 2) <= _GROUP_VMEM
+
+    @pytest.mark.parametrize("sizes, block, visited", [
+        ((150, 0, 300, 40), 128, 2 + 3 + 1),
+        ((128, 128, 0, 0), 128, 2),
+        ((0, 0, 0, 0), 128, 0),
+        ((1000, 1, 1), 256, 4 + 1 + 1),
+    ])
+    def test_the_grid_visits_only_the_held_groups_tiles(self, sizes, block,
+                                                        visited):
+        from tpudl.pallas_ops import _group_steps, tiles_visited
+
+        sizes = jnp.asarray(sizes, jnp.int32)
+        held, m_tiles = int(sizes.sum()), 2048 // block
+        assert tiles_visited(sizes, block) == visited
+        offsets, groups, tiles, steps = _group_steps(sizes, m_tiles, block,
+                                                     False)
+        assert int(steps) == visited
+        groups, tiles = np.asarray(groups)[:visited], np.asarray(tiles)[
+            :visited]
+        # in order, no tile past the prefix, each group's rows covered
+        assert (np.diff(tiles) >= 0).all() and (np.diff(groups) >= 0).all()
+        assert not visited or tiles.max() <= (held - 1) // block
+        for g, size in enumerate(np.asarray(sizes)):
+            start = int(offsets[g])
+            mine = tiles[groups == g]
+            assert list(mine) == list(range(
+                start // block, (start + size - 1) // block + 1)) if size \
+                else not len(mine)
+        # the weights' kernel visits an empty group once, to write zeros
+        _, _, _, every = _group_steps(sizes, m_tiles, block, True)
+        assert int(every) == visited + int((np.asarray(sizes) == 0).sum())
+
+    def test_gauges_per_trace_and_a_wrong_shape_named(self):
+        from tpudl import obs
+        from tpudl.pallas_ops import grouped_dot
+
+        x = jax.ShapeDtypeStruct((1000, 256), jnp.bfloat16)
+        w = jax.ShapeDtypeStruct((8, 256, 384), jnp.bfloat16)
+        sizes = jax.ShapeDtypeStruct((8,), jnp.int32)
+        out = jax.eval_shape(lambda a, b, s: grouped_dot(
+            a, (b, b), s), x, w, sizes)
+        assert [o.shape for o in out] == [(1000, 384)] * 2
+        assert out[0].dtype == jnp.bfloat16
+        snap = obs.snapshot("pallas.gmm.")
+        assert [snap[f"pallas.gmm.{a}"]["value"] for a in (
+            "block_m", "block_k", "block_n")] == [256, 256, 384]
+        with pytest.raises(ValueError, match="weights"):
+            grouped_dot(jnp.ones((8, 4)), jnp.ones((2, 5, 3)),
+                        jnp.ones((2,), jnp.int32))

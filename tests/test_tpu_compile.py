@@ -213,3 +213,40 @@ def test_the_selective_scan_compiles_for_the_v5e(
     assert compiled.memory_analysis().temp_size_in_bytes < (
         (seq + pad) * heads * width * (4 + 4) * 1.1
         + (seq + pad) // chunk * heads * width * 128 * 2 * 1.1)
+
+
+@pytest.mark.parametrize("m, k, n, gated", [
+    (65536, 2048, 768, True),      # joyai-llm-flash-ep32, a sequence
+    (196608, 2816, 2048, False),   # nemotron-twotower, relu2
+    (131072, 2048, 1792, True),    # lfm2-8b-a1b
+])
+def test_the_grouped_products_compile_for_the_v5e(
+        one_chip, no_compile_cache, m, k, n, gated):
+    """``pallas_ops.grouped_dot`` as a routed layer calls it, at the LM
+    cells' shapes with bfloat16 operands: the gate and up-projection as
+    one call, the down-projection, and the gradient of every operand.
+    What the interpreter cannot show: the grid's length as a value on the
+    device, whole K and N blocks in VMEM, the weights read transposed,
+    the product that contracts over rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpudl.pallas_ops import grouped_dot
+
+    def loss(x, ws, w2, sizes):
+        outs = grouped_dot(x, ws, sizes, interpret=False)
+        h = outs[0] * outs[-1]
+        y = grouped_dot(h, w2, sizes, interpret=False)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    def operand(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ws = tuple(operand((8, k, n)) for _ in range(2 if gated else 1))
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        operand((m, k)), ws, operand((8, n, k)),
+        operand((8,), "int32")).compile()
+    # two forward kernels; the rows' cotangent of each call; one weights'
+    # kernel a right-hand side
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 2 + 2 + len(ws) + 1

@@ -285,6 +285,15 @@ METRICS: tuple[Metric, ...] = (
     Metric("pallas.flash.head_dim_shared", "gauge",
            "key columns the shared key brings in the last traced call (64 "
            "of the 192 under latent attention; 0 without one)"),
+    # -- the grouped products' kernels (per call of pallas_ops.grouped_dot,
+    # which under jax.jit is per TRACE) ---------------------------------------
+    Metric("pallas.gmm.block_m", "gauge",
+           "row tile of the last traced grouped product: 256 rows, or the "
+           "whole of a smaller buffer (pallas_ops.row_tile)"),
+    Metric("pallas.gmm.block_k", "gauge",
+           "contraction block of that call (K whole where VMEM allows)"),
+    Metric("pallas.gmm.block_n", "gauge",
+           "column block of that call (N whole where VMEM allows)"),
     # -- the selective scan's kernels (per call of pallas_ops.ssd_scan,
     # which under jax.jit is per TRACE, as the flash kernels' are) -------
     Metric("pallas.ssd.launches", "counter",
@@ -326,6 +335,14 @@ METRICS: tuple[Metric, ...] = (
            "rows the slot gathers take, 2 x T x k a routed layer: "
            "token_rows_run / token_rows_total is the share of that work "
            "still done"),
+    Metric("moe.row_tiles_run", "counter",
+           "row tiles a grouped product of the routed layer visits on the "
+           "route_stats batches: per call of routed_ff the tiles the held "
+           "experts' rows touch (moe.row_tiles, as the kernel counts them)"),
+    Metric("moe.row_tiles_total", "counter",
+           "row tiles its whole buffers hold, T x k / tile a call: "
+           "row_tiles_run / row_tiles_total is how far the grouped products "
+           "follow the held prefix"),
     Metric("moe.chunk_rows", "gauge",
            "rows a turn of those loops takes in the last TRACED routed "
            "layer (moe.chunk_rows of its T x k pairs)"),

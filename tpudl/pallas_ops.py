@@ -100,7 +100,8 @@ from jax.experimental.pallas import tpu as pltpu
 from tpudl.obs import metrics as _metrics
 
 __all__ = ["flash_attention", "tile_shapes", "tile_counts", "SAVED",
-           "ssd_scan", "scan_tiles"]
+           "ssd_scan", "scan_tiles", "grouped_dot", "group_tiles",
+           "row_tile", "tiles_visited"]
 
 # checkpoint_name of the forward kernel's output and row statistics, as the
 # backward kernel takes them: a ``jax.checkpoint`` whose policy saves this
@@ -1419,3 +1420,382 @@ def _scan_traced(x, dt, a, b, c, *, tiles: ScanTiles, interpret: bool):
 # the traced name is what device traces file the kernels under
 _scan_traced.__name__ = _scan_traced.__qualname__ = "ssd_scan"
 _scan_call = jax.jit(_scan_traced, static_argnames=("tiles", "interpret"))
+
+
+# === grouped matrix products: the routed experts ===========================
+# The row tile. Swept on a TPU v5e at the three LM cells' shapes (one gated
+# or relu2 layer's forward plus forward with backward, the activation a pass
+# over the whole buffer, in ms at 128 / 256 / 512 / 1,024 rows): 65,536 rows
+# of 2,048 into 768, 3% held, 2.18 / 2.21 / 2.64 / 3.60; 196,608 of 2,816
+# into 2,048, 4.2% held, 12.84 / 12.96 / 13.86 / 16.86; 131,072 of 2,048 into
+# 1,792, 39% held, 31.53 / 31.16 / 31.68 / 34.94 (XLA's ragged_dot 4.84,
+# 18.49, 51.49; PERF.md section 6). A larger tile computes more rows of no
+# group at the groups' edges; 128 reads within 1% and doubles the steps.
+_ROW_TILE = 256
+# The VMEM that one grid step of any of the three kernels may ask for:
+# double-buffered blocks, plus the float32 accumulator. Above it, the
+# columns are split first, then the contraction.
+_GROUP_VMEM = 64 << 20
+
+
+class GroupTiles(NamedTuple):
+    """What :func:`group_tiles` derives for ``[M, K] @ [E, K, N]``: the
+    row tile that the grid steps over, and the K and N blocks (whole where
+    they fit)."""
+    block_m: int
+    block_k: int
+    block_n: int
+
+
+def row_tile(m: int) -> int:
+    """The row tile of a grouped product over ``m`` rows: ``_ROW_TILE``,
+    or ``m`` rounded up to a bfloat16 sublane tile where that is less."""
+    return min(_ROW_TILE, m + -m % 16)
+
+
+def _rows_need(lhs: int, rhs: int, outs: int, bm: int, bk: int, bn: int,
+               item: int, split: bool) -> int:
+    """Bytes of VMEM a step of a row kernel (:func:`_gmm`) takes: its
+    blocks, double-buffered, and a float32 accumulator an output where
+    the contraction ``bk`` is split."""
+    return (item * 2 * (lhs * bm * bk + rhs * bk * bn + outs * bm * bn)
+            + (4 * outs * bm * bn if split else 0))
+
+
+def _weights_need(bm: int, bk: int, bn: int, item: int) -> int:
+    """Bytes of VMEM a step of the weights' kernel (:func:`_tgmm`) takes:
+    its blocks, double-buffered, and the float32 accumulator."""
+    return item * 2 * (bm * bk + bm * bn + bk * bn) + 4 * bk * bn
+
+
+def _group_vmem(tiles: GroupTiles, products: int, item: int) -> int:
+    """Bytes of VMEM the largest of the three kernels asks for at
+    ``tiles``, for ``products`` right-hand sides (accumulators counted
+    whether or not the contraction is split)."""
+    bm, bk, bn = tiles
+    return max(_rows_need(1, products, products, bm, bk, bn, item, True),
+               _rows_need(products, products, 1, bm, bn, bk, item, True),
+               _weights_need(bm, bk, bn, item))
+
+
+def group_tiles(m: int, k: int, n: int, *, products: int = 1,
+                itemsize: int = 2) -> GroupTiles:
+    """The tiles of a grouped product from its shapes alone: the row tile
+    from :func:`row_tile`, and K and N whole, so that ``lhs`` is read once
+    a product and a group's weights once a row tile, unless the three
+    kernels' blocks would ask for more than ``_GROUP_VMEM``. In that case
+    N, and then K, is cut to a smaller divisor that is a multiple of the
+    lane tile."""
+    def cuts(width):
+        return sorted((b for b in range(_LANES, width, _LANES)
+                       if width % b == 0), reverse=True)
+
+    tiles = GroupTiles(row_tile(m), k, n)
+    for axis, width in (("block_n", n), ("block_k", k)):
+        for block in cuts(width):
+            if _group_vmem(tiles, products, itemsize) <= _GROUP_VMEM:
+                break
+            tiles = tiles._replace(**{axis: block})
+    return tiles
+
+
+def _group_spans(group_sizes, block_m: int, visit_empty: bool):
+    """The row tiles each group's rows touch: a tile that two groups share
+    counts for both. An empty group touches none, or one with
+    ``visit_empty``."""
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    return jnp.where(group_sizes > 0,
+                     (ends - 1) // block_m - starts // block_m + 1,
+                     int(visit_empty))
+
+
+def tiles_visited(group_sizes, block_m: int) -> int:
+    """The row tiles that a grouped product's forward grid visits over
+    these groups, as the kernel counts them."""
+    return int(jnp.sum(_group_spans(jnp.asarray(group_sizes, jnp.int32),
+                                    block_m, False)))
+
+
+def _group_steps(group_sizes, m_tiles: int, block_m: int, visit_empty: bool):
+    """The grid of a grouped product: ``(offsets [E + 1], groups [n],
+    tiles [n], steps)``. These are where each group's rows start and end,
+    the group and the row tile of each step, and how many steps run (a
+    value on the device). A group visits every tile its rows touch, in
+    order, so a tile that two groups share is visited twice in a row. An
+    empty group visits no tile, or one tile with ``visit_empty``, where
+    its weights' gradient is written as zeros. Entries past ``steps``
+    repeat a valid index; no step reads them."""
+    count = group_sizes.shape[0]
+    spans = _group_spans(group_sizes, block_m, visit_empty)
+    ends = jnp.cumsum(group_sizes)
+    step_ends = jnp.cumsum(spans)
+    i = jnp.arange(m_tiles + 2 * count, dtype=jnp.int32)
+    group = jnp.minimum((step_ends[None, :] <= i[:, None]).sum(1), count - 1)
+    first = (ends - group_sizes) // block_m
+    tile = jnp.clip(first[group] + i - (step_ends - spans)[group], 0,
+                    m_tiles - 1)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    return (offsets, group.astype(jnp.int32), tile.astype(jnp.int32),
+            step_ends[-1])
+
+
+def _held_rows(offsets, groups, tiles, i, block_m: int, shape):
+    """Which rows of step ``i``'s tile belong to its group."""
+    g = groups[i]
+    rows = tiles[i] * block_m + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return (rows >= offsets[g]) & (rows < offsets[g + 1])
+
+
+def _gmm_kernel(offsets, groups, tiles, *refs, pairs, n_lhs: int,
+                n_rhs: int, outs: int, block_m: int, k_steps: int, dims):
+    """One grid step ``(column block, step, contraction block)``: for each
+    output ``j``, the sum of ``lhs[a] @ rhs[b]`` over the pairs
+    ``(a, b, j)`` on the step's row tile against its group's weights.
+    The sum is written over the rows that belong to the group and no
+    others. Rows of another group, or past every group, are computed from
+    whatever they hold and then dropped by a select, never used as
+    numbers."""
+    lhs, rhs = refs[:n_lhs], refs[n_lhs:n_lhs + n_rhs]
+    out, acc = refs[n_lhs + n_rhs:][:outs], refs[n_lhs + n_rhs + outs:]
+    i = pl.program_id(1)
+
+    def products(j):
+        terms = [jax.lax.dot_general(lhs[a][...], rhs[b][...], dims,
+                                     preferred_element_type=jnp.float32)
+                 for a, b, o in pairs if o == j]
+        return functools.reduce(jnp.add, terms)
+
+    def store(j, value):
+        held = _held_rows(offsets, groups, tiles, i, block_m, value.shape)
+        out[j][...] = jnp.where(held, value, out[j][...].astype(
+            jnp.float32)).astype(out[j].dtype)
+
+    if k_steps == 1:
+        for j in range(outs):
+            store(j, products(j))
+        return
+    k_i = pl.program_id(2)
+
+    @pl.when(k_i == 0)
+    def _():
+        for a in acc:
+            a[...] = jnp.zeros_like(a)
+
+    for j in range(outs):
+        acc[j][...] += products(j)
+
+    @pl.when(k_i == k_steps - 1)
+    def _():
+        for j in range(outs):
+            store(j, acc[j][...])
+
+
+def _gmm(lhs, rhs, grid, pairs, *, tiles: GroupTiles, transposed: bool,
+         out_dtype, interpret: bool, name: str):
+    """The row launch: ``outs[j] = Σ lhs[a] @ rhs[b][g]`` over the pairs
+    ``(a, b, j)`` on each group ``g``'s rows, ``rhs[b][g]ᵀ`` where
+    ``transposed`` (the weights as they are stored, ``[E, K, N]``, read
+    with the contraction over N: no copy). ``lhs`` are ``[M, ·]``;
+    ``grid`` is :func:`_group_steps` without empty groups."""
+    offsets, groups, row_tiles, steps = grid
+    m, bm = lhs[0].shape[0], tiles.block_m
+    _, w_k, w_n = rhs[0].shape
+    # the contraction's width and block, and the output's
+    k, n = (w_n, w_k) if transposed else (w_k, w_n)
+    bk, bn = ((tiles.block_n, tiles.block_k) if transposed
+              else (tiles.block_k, tiles.block_n))
+    outs = 1 + max(j for *_, j in pairs)
+
+    def lhs_map(n_i, i, k_i, offsets, groups, row_tiles):
+        return row_tiles[i], k_i
+
+    def rhs_map(n_i, i, k_i, offsets, groups, row_tiles):
+        return (groups[i], n_i, k_i) if transposed else (groups[i], k_i, n_i)
+
+    def out_map(n_i, i, k_i, offsets, groups, row_tiles):
+        return row_tiles[i], n_i
+
+    need = _rows_need(len(lhs), len(rhs), outs, bm, bk, bn,
+                      lhs[0].dtype.itemsize, k > bk)
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, pairs=pairs, n_lhs=len(lhs),
+                          n_rhs=len(rhs), outs=outs, block_m=bm,
+                          k_steps=k // bk, dims=_NT if transposed else _NN),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n // bn, jnp.maximum(steps, 1), k // bk),
+            in_specs=[*[pl.BlockSpec((bm, bk), lhs_map) for _ in lhs],
+                      *[pl.BlockSpec((None, bn, bk) if transposed
+                                     else (None, bk, bn), rhs_map)
+                        for _ in rhs]],
+            out_specs=[pl.BlockSpec((bm, bn), out_map)] * outs,
+            scratch_shapes=([pltpu.VMEM((bm, bn), jnp.float32)] * outs
+                            if k > bk else [])),
+        out_shape=[jax.ShapeDtypeStruct((m, n), out_dtype)] * outs,
+        compiler_params=_params(("parallel", "arbitrary", "arbitrary"),
+                                need),
+        interpret=interpret, name=name,
+    )(offsets, groups, row_tiles, *lhs, *rhs)
+
+
+def _tgmm_kernel(offsets, groups, tiles, lhs, grad, out, acc, *,
+                 block_m: int):
+    """One grid step ``(column block, contraction block, step)`` of the
+    weights' gradient: ``lhsᵀ @ grad`` over the step's rows of its group,
+    accumulated in float32 while the group's tiles pass and written when
+    the next step's group is another one. Rows outside the group are set
+    to 0 by a select before the product: they may hold any bits."""
+    i, last = pl.program_id(2), pl.num_programs(2) - 1
+    g = groups[i]
+
+    @pl.when((i == 0) | (groups[jnp.maximum(i - 1, 0)] != g))
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+
+    @pl.when(offsets[g + 1] > offsets[g])
+    def _():
+        held = _held_rows(offsets, groups, tiles, i, block_m,
+                          (block_m, 1))
+        acc[...] += jax.lax.dot_general(
+            jnp.where(held, lhs[...], 0), jnp.where(held, grad[...], 0),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+    @pl.when((i == last) | (groups[jnp.minimum(i + 1, last)] != g))
+    def _():
+        out[...] = acc[...].astype(out.dtype)
+
+
+def _tgmm(lhs, grad, grid, *, tiles: GroupTiles, out_dtype,
+          interpret: bool):
+    """The weights' launch: ``[E, K, N]``, for each group ``g`` the
+    product ``lhs[rows of g]ᵀ @ grad[rows of g]``. ``grid`` is
+    :func:`_group_steps` with empty groups visited, so their gradient is
+    written as zeros."""
+    offsets, groups, row_tiles, steps = grid
+    (_, k), n, e = lhs.shape, grad.shape[1], offsets.shape[0] - 1
+    bm, bk, bn = tiles
+
+    def lhs_map(n_i, k_i, i, offsets, groups, row_tiles):
+        return row_tiles[i], k_i
+
+    def grad_map(n_i, k_i, i, offsets, groups, row_tiles):
+        return row_tiles[i], n_i
+
+    def out_map(n_i, k_i, i, offsets, groups, row_tiles):
+        return groups[i], k_i, n_i
+
+    need = _weights_need(bm, bk, bn, lhs.dtype.itemsize)
+    return pl.pallas_call(
+        functools.partial(_tgmm_kernel, block_m=bm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(n // bn, k // bk, steps),
+            in_specs=[pl.BlockSpec((bm, bk), lhs_map),
+                      pl.BlockSpec((bm, bn), grad_map)],
+            out_specs=pl.BlockSpec((None, bk, bn), out_map),
+            scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((e, k, n), out_dtype),
+        compiler_params=_params(("parallel", "arbitrary", "arbitrary"),
+                                need),
+        interpret=interpret, name="grouped_dot_weights",
+    )(offsets, groups, row_tiles, lhs, grad)
+
+
+@functools.lru_cache(maxsize=32)
+def _grouped_fn(tiles: GroupTiles, interpret: bool):
+    """The custom-VJP'd grouped product of one ``lhs`` with a tuple of
+    right-hand sides, for tiles ``tiles``. The forward is one kernel that
+    reads each row tile once for every right-hand side. The backward has
+    one kernel for ``d_lhs = Σ g_b @ rhs_bᵀ``, summed over the
+    right-hand sides inside its contraction loop, and one for each
+    ``d_rhs_b``."""
+    kw = dict(tiles=tiles, interpret=interpret)
+
+    def grid(group_sizes, m, visit_empty):
+        return _group_steps(group_sizes, m // tiles.block_m, tiles.block_m,
+                            visit_empty)
+
+    def forward(lhs, rhs, group_sizes):
+        return tuple(_gmm(
+            (lhs,), rhs, grid(group_sizes, lhs.shape[0], False),
+            tuple((0, b, b) for b in range(len(rhs))), transposed=False,
+            out_dtype=lhs.dtype, name="grouped_dot", **kw))
+
+    f = jax.custom_vjp(forward)
+
+    def fwd(lhs, rhs, group_sizes):
+        return forward(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+    def bwd(res, grads):
+        lhs, rhs, group_sizes = res
+        m = lhs.shape[0]
+        d_lhs, = _gmm(grads, rhs, grid(group_sizes, m, False),
+                      tuple((b, b, 0) for b in range(len(rhs))),
+                      transposed=True, out_dtype=lhs.dtype,
+                      name="grouped_dot_rows", **kw)
+        every = grid(group_sizes, m, True)
+        return d_lhs, tuple(_tgmm(lhs, g, every, out_dtype=w.dtype, **kw)
+                            for g, w in zip(grads, rhs)), None
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
+def grouped_dot(lhs, rhs, group_sizes, *, interpret: bool | None = None):
+    """``out[r] = lhs[r] @ rhs[g(r)]`` on the rows of each group: ``lhs``
+    ``[M, K]``, ``rhs`` ``[E, K, N]`` (or a tuple of such, which gives a
+    tuple of outputs), and ``group_sizes`` ``[E]``. Group ``g`` holds
+    the next ``group_sizes[g]`` rows, so the groups fill one prefix
+    ``[0, Σ group_sizes)``. The operands are in ``lhs``'s dtype and the
+    products accumulate in float32, as ``jax.lax.ragged_dot`` has them.
+
+    **Only the held groups' row tiles are visited.** Each Pallas kernel
+    steps over the row tiles that the groups' rows touch, a count on the
+    device. No tile past the prefix is fetched, computed or written. Rows
+    past it in the output keep whatever the buffer held, and no row past
+    it is read as a number: they may hold any bits, NaN included, in
+    ``lhs`` and in the cotangent alike. The backward computes ``d_lhs``
+    in one kernel against the stored weights, read transposed (with a
+    tuple, ``Σ_b g_b @ rhs_bᵀ``: the cotangents of the right-hand sides
+    are summed in its contraction loop, never by a pass over the whole
+    buffer). ``d_rhs`` is a kernel that accumulates ``lhsᵀ g`` over each
+    group's rows in float32; rows outside the group are removed by a
+    select.
+
+    The row tile is ``_ROW_TILE`` rows (:func:`row_tile`); K and N stay
+    whole where VMEM allows (:func:`group_tiles`). ``M`` that the row tile
+    does not divide is padded. ``interpret=None`` picks from the process's default backend,
+    as :func:`flash_attention` does. Sets the gauges
+    ``pallas.gmm.block_m`` / ``.block_k`` / ``.block_n`` each time it is
+    called, which under ``jax.jit`` is once per TRACE."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    many = isinstance(rhs, (tuple, list))
+    rhs = tuple(rhs) if many else (rhs,)
+    (m, k), (e, _, n) = lhs.shape, rhs[0].shape
+    if any(w.shape != (e, k, n) for w in rhs) or group_sizes.shape != (e,):
+        raise ValueError(f"rows {lhs.shape} against weights "
+                         f"{[w.shape for w in rhs]} in {group_sizes.shape} "
+                         "groups")
+    tiles = group_tiles(m, k, n, products=len(rhs),
+                        itemsize=lhs.dtype.itemsize)
+    _metrics.gauge("pallas.gmm.block_m").set(tiles.block_m)
+    _metrics.gauge("pallas.gmm.block_k").set(tiles.block_k)
+    _metrics.gauge("pallas.gmm.block_n").set(tiles.block_n)
+    pad = -m % tiles.block_m
+    out = _grouped_call(jnp.pad(lhs, ((0, pad), (0, 0))) if pad else lhs,
+                        rhs, group_sizes.astype(jnp.int32), tiles=tiles,
+                        interpret=interpret)
+    out = tuple(o[:m] for o in out) if pad else out
+    return out if many else out[0]
+
+
+def _grouped_traced(lhs, rhs, group_sizes, *, tiles, interpret):
+    return _grouped_fn(tiles, interpret)(lhs, rhs, group_sizes)
+
+
+# the traced name is what device traces file these kernels under
+_grouped_traced.__name__ = _grouped_traced.__qualname__ = "grouped_dot"
+_grouped_call = jax.jit(_grouped_traced, static_argnames=("tiles",
+                                                          "interpret"))

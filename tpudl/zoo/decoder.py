@@ -600,10 +600,15 @@ class Decoder:
         (``ceil(pairs_held / chunk)`` a layer) of those a whole buffer
         would take, and ``token_rows_run`` of ``token_rows_total``: the
         rows its two token-order moves take (``moe.token_rows`` each) of
-        the ``2·T·k`` that gathering every slot takes. Publishes
+        the ``2·T·k`` that gathering every slot takes, and
+        ``row_tiles_run`` of ``row_tiles_total``: the row tiles a grouped
+        product visits on that batch (``moe.row_tiles``, a call of
+        ``routed_ff`` each: a sequence at a time where the layer takes
+        one) of those its whole buffers hold. Publishes
         ``moe.pairs_held`` / ``moe.pairs_total`` / ``moe.chunks_run`` /
         ``moe.chunks_total`` / ``moe.token_rows_run`` /
-        ``moe.token_rows_total`` (counters) and
+        ``moe.token_rows_total`` / ``moe.row_tiles_run`` /
+        ``moe.row_tiles_total`` (counters) and
         ``moe.expert_tokens_max`` / ``_mean`` (gauges).
         Called outside timed work: ``Trainer``'s loss stays a scalar."""
         key = str(compute_dtype)
@@ -620,24 +625,34 @@ class Decoder:
 
     def count_routes(self, chosen) -> dict:
         first, count = self.held
+
+        def per_expert(pairs):
+            local = pairs.reshape(-1).astype(np.int64) - first
+            return np.bincount(local[(local >= 0) & (local < count)],
+                               minlength=count)
+
         layers = []
         for c in chosen:
-            local = c.reshape(-1).astype(np.int64) - first
-            per_expert = np.bincount(
-                local[(local >= 0) & (local < count)], minlength=count)
-            held, chunk = int(per_expert.sum()), moe.chunk_rows(c.size)
+            tokens = per_expert(c)
+            held, chunk = int(tokens.sum()), moe.chunk_rows(c.size)
+            # the grouped products' row tiles, a call of routed_ff each
+            tiles = [moe.row_tiles(per_expert(call), call.size)
+                     for call in (c if self.routed_by_sequence else [c])]
             layers.append({"pairs_total": int(c.size), "pairs_held": held,
-                           "expert_tokens": per_expert.tolist(),
+                           "expert_tokens": tokens.tolist(),
                            "chunks_run": -(-held // chunk),
                            "chunks_total": -(-c.size // chunk),
                            "token_rows_run": 2 * moe.token_rows(held, c.size),
-                           "token_rows_total": 2 * c.size})
+                           "token_rows_total": 2 * c.size,
+                           "row_tiles_run": sum(run for run, _ in tiles),
+                           "row_tiles_total": sum(n for _, n in tiles)})
         tokens = np.array([n for rec in layers for n in rec["expert_tokens"]]
                           or [0])
         out = {"layers": layers,
                **{key: sum(rec[key] for rec in layers) for key in (
                    "pairs_total", "pairs_held", "chunks_run", "chunks_total",
-                   "token_rows_run", "token_rows_total")},
+                   "token_rows_run", "token_rows_total", "row_tiles_run",
+                   "row_tiles_total")},
                "expert_tokens_max": int(tokens.max()),
                "expert_tokens_mean": float(tokens.mean())}
         _metrics.counter("moe.pairs_held").inc(out["pairs_held"])
@@ -646,6 +661,8 @@ class Decoder:
         _metrics.counter("moe.chunks_total").inc(out["chunks_total"])
         _metrics.counter("moe.token_rows_run").inc(out["token_rows_run"])
         _metrics.counter("moe.token_rows_total").inc(out["token_rows_total"])
+        _metrics.counter("moe.row_tiles_run").inc(out["row_tiles_run"])
+        _metrics.counter("moe.row_tiles_total").inc(out["row_tiles_total"])
         _metrics.gauge("moe.expert_tokens_max").set(
             out["expert_tokens_max"])
         _metrics.gauge("moe.expert_tokens_mean").set(

@@ -16,10 +16,13 @@ No capacity factor and no drop. The ``T·k`` (token, expert) pairs are
 ordered by expert with the pairs of absent experts last, so the held
 pairs are one dense prefix ``[0, pairs_held)`` of a buffer that has room
 for every pair that can occur; the experts' products are grouped matrix
-products over that prefix (``jax.lax.ragged_dot``, whose transpose rules
-give the backward), and their work follows ``group_sizes``, that is the
-pairs really routed here. Widths that are no multiple of 256 (the rows',
-the experts') are padded with zeros for the products (``_TILE``).
+products over that prefix (``pallas_ops.grouped_dot``: Pallas kernels
+whose grid visits only the row tiles the held groups touch, forward and
+backward, the gated form's two products one call whose row cotangents
+are summed inside the kernel), and their work follows ``group_sizes``,
+that is the pairs really routed here. Widths that are no multiple of 256
+(the rows', the experts') are padded with zeros for the products
+(``_TILE``).
 
 Rows move five times a layer, never as a ``[T, k, D]`` array, and every
 move may follow the held prefix: a loop over chunks of sorted rows whose
@@ -48,8 +51,9 @@ in the cotangent of the experts' output, which is masked row by row),
 then zeros. In the buffers the grouped products write, and in the
 activation's cotangents, which are written over them: whatever the
 products leave there. Nothing reads either as a number: the grouped
-products stop at ``group_sizes``, the token-order moves drop or mask
-those rows.
+products neither fetch nor write a row tile past ``group_sizes`` and
+select away every row of a visited tile outside its group; the
+token-order moves drop or mask those rows.
 
 Both backward passes of the moves are written by hand in sorted order:
 the cotangent of the experts' output is the gathered cotangent scaled by
@@ -69,26 +73,28 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from tpudl import pallas_ops
 from tpudl.obs import metrics as _metrics
 from tpudl.obs.trace import named_scope
 from tpudl.zoo.lm_blocks import normal
 
 __all__ = ["route", "routed_ff", "combine", "init_routed", "pair_order",
-           "chunk_rows", "token_rows", "ROUTES", "ACTS"]
+           "chunk_rows", "token_rows", "row_tiles", "ROUTES", "ACTS"]
 
 # checkpoint_name of a routed layer's selection and of the ordering made of it
 ROUTES = "moe.routes"
 # an expert's form by the configuration's activation: is there a gate
 # (``W₂(silu(W₁x) ⊙ W₃x)``, three products) or not (``W₂ relu(W₁x)²``, two)
 ACTS = {"silu": True, "relu2": False}
-# XLA's grouped product wants its operands' widths in 256s. At the nemotron_h
-# cell's shapes (rows 2,688 wide, experts 1,856) it ran 1.5 times longer, and
-# 2.2 times longer a further row, than with the experts padded to 2,048, and
-# 1.5 times longer a further row again than with the rows padded to 2,816;
-# 1,920 gained nothing (my chip runs, PR 32, PERF.md section 6). So a width
-# that is no multiple is padded with zeros for the products: they add exactly
-# 0, and their gradient is cut off again. LFM2's 2,048 and 1,792 are multiples.
-# The tokens are padded before dispatch and the output sliced after combine
+# XLA's grouped product (``jax.lax.ragged_dot``, the experts' product before
+# ``pallas_ops.grouped_dot``) wanted its operands' widths in 256s. At the
+# nemotron_h cell's shapes (rows 2,688 wide, experts 1,856) it ran 1.5 times
+# longer, and 2.2 times longer a further row, than with the experts padded to
+# 2,048, and 1.5 times longer a further row again than with the rows padded to
+# 2,816; 1,920 gained nothing (TPU v5e, PERF.md section 6). So a width that is
+# no multiple is padded with zeros for the products: they add exactly 0, and
+# their gradient is cut off again. LFM2's 2,048 and 1,792 are multiples. The
+# tokens are padded before dispatch and the output sliced after combine
 # (copies of ``[T, D]`` each way); every buffer of ``T·k`` rows, and so every
 # chunk the loops move, has the padded width.
 _TILE = 256
@@ -160,6 +166,15 @@ def pair_order(experts, held):
         jnp.arange(n, dtype=jnp.int32), unique_indices=True)
     group_sizes = jnp.bincount(local, length=count + 1)[:count]
     return order, place, group_sizes.astype(jnp.int32)
+
+
+def row_tiles(group_sizes, pairs: int) -> tuple[int, int]:
+    """``(run, total)``: the row tiles a grouped product of a routed layer
+    visits over ``group_sizes`` (the held experts' rows, in a buffer of
+    ``pairs``), as its kernel counts them, and those a whole buffer
+    holds."""
+    block = pallas_ops.row_tile(pairs)
+    return pallas_ops.tiles_visited(group_sizes, block), -(-pairs // block)
 
 
 def chunk_rows(pairs: int) -> int:
@@ -362,13 +377,19 @@ def _activate_fwd(act, products, held_rows):
 
 
 def _activate_bwd(act, res, g):
-    """Autodiff's backward of the plain expression, a chunk a turn, each
-    product's cotangent written over the product: past the prefix's last
-    chunk it holds what the grouped product left there."""
+    """Autodiff's backward of the plain expression, a chunk a turn: the
+    gate's cotangent written over ``g``, the up-projection's over the
+    up-projection (past the prefix's last chunk each holds what a grouped
+    product left there). The gate product is only read: a buffer that a
+    loop both reads and writes, and that the recomputed activation reads
+    too, the compiler copies whole first."""
     products, held_rows = res
-    return _over_held(
-        lambda start, rows, g_rows: jax.vjp(_PLAIN[act], *rows)[1](g_rows),
-        held_rows, products, g), None
+
+    def turn(start, old, gate):
+        g_rows, *rest = old
+        return jax.vjp(_PLAIN[act], gate, *rest)[1](g_rows)
+
+    return _over_held(turn, held_rows, (g, *products[1:]), products[0]), None
 
 
 _activate.defvjp(_activate_fwd, _activate_bwd)
@@ -405,9 +426,11 @@ def routed_ff(p, name: str, x, *, top_k: int, held, scaling: float = 1.0,
             tokens = jnp.pad(tokens, [(0, 0), (0, deep)])
         rows = _dispatch(tokens, order, place, held_rows, top_k)
     with named_scope("moe.experts"):
-        dot = functools.partial(jax.lax.ragged_dot, group_sizes=group_sizes)
-        products = tuple(dot(rows, padded(p[name + leaf], deep, wide))
-                         for leaf in ((".w1", ".w3") if ACTS[act] else (".w1",)))
+        dot = functools.partial(pallas_ops.grouped_dot,
+                                group_sizes=group_sizes)
+        products = dot(rows, tuple(
+            padded(p[name + leaf], deep, wide)
+            for leaf in ((".w1", ".w3") if ACTS[act] else (".w1",))))
         out = dot(_activate(act, products, held_rows),
                   padded(p[name + ".w2"], wide, deep))
     with named_scope("moe.route"):
