@@ -31,7 +31,7 @@ echo "== chaos subset (fault-containment matrix, ISSUE 14 acceptance) =="
 # and skip the matrix.
 python -m pytest tests/test_supervisor.py -q "$@" -m chaos
 
-echo "== compile subset (ISSUE 15: buckets + AOT store acceptance) =="
+echo "== compile subset (buckets + warm starts through the persistent cache) =="
 python -m pytest tests/test_compile.py -q "$@"
 
 echo "== virtual-mesh executor subset (ISSUE 11 acceptance) =="

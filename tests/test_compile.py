@@ -1,21 +1,20 @@
-"""tpudl.compile — shape-bucketed AOT program store (ISSUE 15).
+"""tpudl.compile — the persistent compilation cache and shape buckets.
 
 Covers the compilation-cache module (placement by
 JAX_COMPILATION_CACHE_DIR, fixed in-checkout default, loud failure),
-the bucket ladder, the program store (manifest
-round trip, serialized-executable restore, corruption recovery), the
+warm starts through that cache (two fresh processes share one cache
+directory: the second compiles nothing at ten entry points and matches
+the first bitwise; damaged entries recompile), the bucket ladder, the
 executor wiring (bucketed-vs-exact bitwise parity across
-depth×donate×fuse×mesh8, AOT hit/miss accounting), the traceck-armed
-zero-retrace ragged sweep, the kill-mid-precompile fault-plan case,
-the LM prompt bucketing + precompile, the roofline `precompile` rec,
-the obs-top compile line, and the tools/validate_programs audit
-(tier-1-wired here, the validate_shards pattern).
+depth×donate×fuse×mesh8), the traceck-armed zero-retrace ragged sweep,
+LM prompt bucketing, the roofline cold-start attribution and the
+obs-top compile line.
 """
 
 import importlib.util
 import json
 import os
-import signal
+import random
 import subprocess
 import sys
 import warnings
@@ -25,11 +24,9 @@ import pytest
 
 import jax
 
-from tpudl import compile as C
 from tpudl import obs
 from tpudl.compile import buckets as bk
 from tpudl.compile import cache as ccache
-from tpudl.compile import store as cstore
 from tpudl.frame import Frame
 from tpudl.obs import metrics as obs_metrics
 
@@ -39,20 +36,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 @pytest.fixture(autouse=True)
 def registry():
     obs_metrics.get_registry().reset()
-    C.reset_program_store()
     yield
     obs_metrics.get_registry().reset()
-    C.reset_program_store()
-
-
-@pytest.fixture(scope="module")
-def validator():
-    spec = importlib.util.spec_from_file_location(
-        "validate_programs", os.path.join(REPO, "tools",
-                                          "validate_programs.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _metric(name):
@@ -66,13 +51,12 @@ def _metric(name):
 class TestCompilationCache:
     """Placement is decided outside the code: JAX_COMPILATION_CACHE_DIR
     when set (jax reads it itself — nothing is set here), else ONE
-    fixed in-checkout path. The program store's default follows."""
+    fixed in-checkout path."""
 
     def test_jax_env_wins_and_config_is_untouched(self, tmp_path,
                                                   monkeypatch):
         placed = str(tmp_path / "placed")
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
-        monkeypatch.delenv("TPUDL_COMPILE_AOT", raising=False)
         before = jax.config.jax_compilation_cache_dir
         assert ccache.enable_compilation_cache() == placed
         # an explicit path does not override the operator's placement
@@ -80,11 +64,9 @@ class TestCompilationCache:
             str(tmp_path / "arg")) == placed
         assert jax.config.jax_compilation_cache_dir == before
         assert not (tmp_path / "arg").exists()
-        assert C.store_dir() == os.path.join(placed, "programs")
 
     def test_unset_env_uses_fixed_in_checkout_path(self, monkeypatch):
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-        monkeypatch.delenv("TPUDL_COMPILE_AOT", raising=False)
         fixed = os.path.join(REPO, ".jax_cache")
         assert ccache.DEFAULT_CACHE_DIR == fixed
         prev = jax.config.jax_compilation_cache_dir
@@ -93,7 +75,6 @@ class TestCompilationCache:
             assert jax.config.jax_compilation_cache_dir == fixed
         finally:
             jax.config.update("jax_compilation_cache_dir", prev)
-        assert C.store_dir() == os.path.join(fixed, "programs")
 
     def test_explicit_path_when_env_unset(self, tmp_path, monkeypatch):
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
@@ -124,6 +105,329 @@ class TestCompilationCache:
                 in str(w.message)]
         assert len(loud) == 1  # warn-once
         assert _metric("compile.cache_disabled") == 2  # count-always
+
+
+# ---------------------------------------------------------------------------
+# warm starts through the persistent cache: two fresh processes, one cache
+# ---------------------------------------------------------------------------
+
+# One script, run in two fresh processes over one cache directory. Per
+# entry point it records the jax.monitoring cache hit/miss events (a
+# miss is a compile written to the cache) and a digest of the outputs.
+# Every entry point compiles at least one program no earlier one did,
+# so a warm process must show a hit at each.
+_WARM_SCRIPT = r"""
+import hashlib, json, os, sys
+# two cores at most: these processes share the machine with the test
+# workers, some of which time themselves
+if hasattr(os, "sched_setaffinity"):
+    os.sched_setaffinity(0, sorted(os.sched_getaffinity(0))[:2])
+import numpy as np
+import jax
+jax.config.update("jax_platforms", "cpu")
+import jax.monitoring
+import jax.numpy as jnp
+from tpudl import compile as C
+from tpudl.compile import cache as ccache
+
+cache_dir, out_path, workdir = sys.argv[1], sys.argv[2], sys.argv[3]
+only = sys.argv[4].split(",") if len(sys.argv) > 4 else None
+assert C.enable_compilation_cache(cache_dir) == cache_dir
+
+events = {}
+current = [None]
+
+
+def on_event(event, **_kw):
+    if current[0] is None:
+        return
+    rec = events.setdefault(current[0], {"hits": 0, "misses": 0})
+    if event == ccache._CACHE_HIT:
+        rec["hits"] += 1
+    elif event == ccache._CACHE_MISS:
+        rec["misses"] += 1
+
+
+jax.monitoring.register_event_listener(on_event)
+
+
+def digest(*arrays):
+    h = hashlib.sha256()
+    for a in jax.tree.leaves(arrays):
+        a = np.ascontiguousarray(np.asarray(a))
+        h.update(f"{a.dtype}{a.shape}".encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+def map_rows(frame, fn, **kw):
+    out = frame.map_batches(fn, ["x"], ["y"], autotune=False, **kw)
+    return np.stack(list(out["y"]))
+
+
+def frame_exact():
+    from tpudl.frame import Frame
+
+    x = np.random.default_rng(1).standard_normal((40, 6)).astype(np.float32)
+    fn = jax.jit(lambda b: jnp.tanh(b) * 2.0 + 1.0)
+    return digest(map_rows(Frame({"x": x}), fn, batch_size=16,
+                           buckets=False))
+
+
+def frame_bucketed():
+    from tpudl.frame import Frame
+
+    fn = jax.jit(lambda b: jnp.sin(b) * 3.0)
+    outs = []
+    for n in (33, 40, 45, 50, 57, 63):
+        x = np.random.default_rng(n).standard_normal((n, 5)).astype(
+            np.float32)
+        outs.append(map_rows(Frame({"x": x}), fn, batch_size=64,
+                             buckets="pow2"))
+    return digest(*outs)
+
+
+def frame_mesh8():
+    from tpudl import mesh as M
+    from tpudl.frame import Frame
+
+    mesh = M.build_mesh()
+    assert mesh.devices.size == 8, mesh
+    x = np.random.default_rng(2).standard_normal((48, 7)).astype(np.float32)
+    fn = jax.jit(lambda b: b * b - 0.5)
+    return digest(map_rows(Frame({"x": x}), fn, batch_size=16, mesh=mesh))
+
+
+def _lm():
+    from tpudl.zoo.transformer import TinyCausalLM
+
+    lm = TinyCausalLM(vocab=64, dim=32, heads=4, layers=2, max_len=64)
+    return lm, lm.init(0)
+
+
+def lm_generate():
+    lm, params = _lm()
+    prompt = np.random.default_rng(3).integers(1, 64, (2, 9)).astype(
+        np.int32)
+    return digest(lm.generate(params, prompt, 6))
+
+
+def lm_generate_buckets():
+    lm, params = _lm()
+    rng = np.random.default_rng(4)
+    outs = [lm.generate(params, rng.integers(1, 64, (2, n)).astype(
+        np.int32), 5, prompt_buckets="pow2") for n in (10, 11, 13)]
+    return digest(*outs)
+
+
+def lm_generate_tp():
+    from tpudl import mesh as M
+
+    lm, params = _lm()
+    mesh = M.build_mesh(n_data=4, n_model=2)
+    placed = lm.shard_params(params, mesh)
+    prompt = np.random.default_rng(5).integers(1, 64, (1, 7)).astype(
+        np.int32)
+    return digest(lm.generate(placed, prompt, 4, mesh=mesh, tp=True))
+
+
+def serve_request():
+    from tpudl.serve import ModelRegistry, RequestQueue, Server
+
+    lm, params = _lm()
+    reg = ModelRegistry()
+    reg.add_model("default", lm, params, slots=2, cache_len=32)
+    srv = Server(reg, RequestQueue(cap=4))
+    prompt = np.random.default_rng(6).integers(1, 64, 5).astype(np.int32)
+    req = srv.submit(prompt, 4)
+    srv._stop.set()  # deterministic synchronous drain
+    srv.run()
+    return digest(req.result(timeout=60))
+
+
+def image_warmup():
+    from tpudl.frame import Frame
+    from tpudl.image import imageIO
+    from tpudl.ml import TFImageTransformer
+
+    rng = np.random.default_rng(7)
+    frame = Frame({"image": [imageIO.imageArrayToStruct(
+        rng.integers(0, 256, (24, 24, 3), dtype=np.uint8),
+        origin=f"img{i}") for i in range(4)]})
+    g = lambda x: jnp.tanh(x.reshape(x.shape[0], -1)  # noqa: E731
+                           @ jnp.ones((24 * 24 * 3, 5)) * 1e-3)
+    t = TFImageTransformer(inputCol="image", outputCol="y", graph=g,
+                           batchSize=4)
+    t.warmup(24, 24)
+    return digest(np.stack(list(t.transform(frame)["y"])))
+
+
+def _toy(width):
+    rng = np.random.default_rng(width)
+    X = rng.normal(size=(64, width)).astype(np.float32)
+    y = (X @ rng.normal(size=(width, 1)).astype(np.float32)) + 0.1
+
+    def data_fn(step, batch=16):
+        i = (step * batch) % (len(X) - batch + 1)
+        return X[i:i + batch], y[i:i + batch]
+
+    def loss_fn(p, x, t):
+        return jnp.mean((x @ p["w"] + p["b"] - t) ** 2)
+
+    params = {"w": jnp.zeros((width, 1)), "b": jnp.zeros(())}
+    return data_fn, loss_fn, params
+
+
+def trainer_fit():
+    import optax
+    from tpudl.train import Trainer
+
+    data_fn, loss_fn, params = _toy(4)
+    params, _, _ = Trainer(loss_fn, optax.sgd(0.05)).fit(params, data_fn, 3)
+    return digest(params)
+
+
+def job_resume():
+    import optax
+    from tpudl.jobs import JobPreempted, JobRuntime, JobSpec
+    from tpudl.train import Trainer
+
+    data_fn, loss_fn, params = _toy(6)
+    spec = JobSpec("fit", workdir, material={"model": "toy6"},
+                   save_every=2)
+
+    def killed(ctx):
+        def data(step):
+            if step >= 3:
+                ctx.request_stop()
+            return data_fn(step)
+
+        t = Trainer(loss_fn, optax.adam(0.05),
+                    checkpoint_dir=ctx.checkpoint_dir, save_every=2)
+        return t.fit(params, data, 6, stop=ctx.stop_requested)
+
+    try:
+        JobRuntime(spec, install_signals=False).run(killed)
+        raise AssertionError("the first attempt was not preempted")
+    except JobPreempted:
+        pass
+    out = JobRuntime(spec, install_signals=False).run_fit(
+        Trainer(loss_fn, optax.adam(0.05)), params, data_fn, 6)
+    return digest(out[0])
+
+
+ENTRIES = [frame_exact, frame_bucketed, frame_mesh8, lm_generate,
+           lm_generate_buckets, lm_generate_tp, serve_request,
+           image_warmup, trainer_fit, job_resume]
+result = {}
+for fn in ENTRIES:
+    if only is not None and fn.__name__ not in only:
+        continue
+    current[0] = fn.__name__
+    d = fn()
+    current[0] = None
+    result[fn.__name__] = dict(events.get(fn.__name__,
+                                          {"hits": 0, "misses": 0}),
+                               digest=d)
+with open(out_path, "w") as f:
+    json.dump(result, f)
+"""
+
+WARM_ENTRIES = ("frame_exact", "frame_bucketed", "frame_mesh8",
+                "lm_generate", "lm_generate_buckets", "lm_generate_tp",
+                "serve_request", "image_warmup", "trainer_fit",
+                "job_resume")
+
+
+def _warm_env():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
+    # the cache directory is the script's argument, never the caller's
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    return env
+
+
+def _run_warm_script(root, cache, tag, only=None):
+    """One fresh process over ``cache``; returns ({entry: {hits,
+    misses, digest}}, stderr)."""
+    script = os.path.join(root, "two_starts.py")
+    if not os.path.exists(script):
+        with open(script, "w") as f:
+            f.write(_WARM_SCRIPT)
+    out = os.path.join(root, f"{tag}.json")
+    argv = [sys.executable, script, cache, out,
+            os.path.join(root, f"job-{tag}")]
+    if only:
+        argv.append(",".join(only))
+    r = subprocess.run(argv, capture_output=True, text=True,
+                       env=_warm_env(), timeout=300, cwd=REPO)
+    assert r.returncode == 0, r.stderr[-3000:]
+    with open(out) as f:
+        return json.load(f), r.stderr
+
+
+@pytest.fixture(scope="module")
+def warm_runs(tmp_path_factory):
+    """(cold, warm): the same script in two fresh processes sharing one
+    cache directory, the first against an empty cache."""
+    root = str(tmp_path_factory.mktemp("two_starts"))
+    cache = os.path.join(root, "cache")
+    cold, _ = _run_warm_script(root, cache, "cold")
+    warm, _ = _run_warm_script(root, cache, "warm")
+    return cold, warm
+
+
+@pytest.mark.parametrize("entry", WARM_ENTRIES)
+class TestWarmStartThroughCache:
+    """A fresh process that shares the cache directory compiles
+    nothing at any of these entry points, and computes the same bits."""
+
+    def test_warm_process_hits_and_never_misses(self, warm_runs, entry):
+        cold, warm = warm_runs
+        assert cold[entry]["misses"] >= 1, cold[entry]  # it compiled
+        assert warm[entry]["misses"] == 0, warm[entry]
+        assert warm[entry]["hits"] >= 1, warm[entry]
+
+    def test_outputs_bitwise_equal_to_cold(self, warm_runs, entry):
+        cold, warm = warm_runs
+        assert warm[entry]["digest"] == cold[entry]["digest"]
+
+
+def _damage(path, how, rng):
+    with open(path, "rb") as f:
+        blob = f.read()
+    if how == "emptied":
+        blob = b""
+    elif how == "truncated":
+        blob = blob[:len(blob) // 2]
+    else:
+        blob = bytes(rng.getrandbits(8) for _ in range(len(blob)))
+    with open(path, "wb") as f:
+        f.write(blob)
+
+
+@pytest.mark.parametrize("how", ["emptied", "truncated", "random_bytes"])
+def test_damaged_cache_entry_recompiles_and_matches(tmp_path, how):
+    """Every entry a cold process wrote is damaged one way; the next
+    process warns, recompiles, does not raise, and returns the cold
+    outputs bitwise. The cache key includes the directory, so each
+    case makes its own cold cache in place rather than copying one."""
+    only = ("frame_bucketed", "lm_generate", "trainer_fit")
+    cache = str(tmp_path / "cache")
+    cold, _ = _run_warm_script(str(tmp_path), cache, "cold", only)
+    rng = random.Random(0)
+    names = sorted(os.listdir(cache))
+    assert names
+    for name in names:
+        _damage(os.path.join(cache, name), how, rng)
+    again, err = _run_warm_script(str(tmp_path), cache, "again", only)
+    assert "Error reading persistent compilation cache entry" in err
+    for entry in only:
+        assert again[entry]["hits"] == 0, again[entry]
+        assert again[entry]["misses"] >= 1, again[entry]
+        assert again[entry]["digest"] == cold[entry]["digest"], entry
 
 
 # ---------------------------------------------------------------------------
@@ -172,226 +476,7 @@ class TestBucketLadder:
 
 
 # ---------------------------------------------------------------------------
-# fn fingerprints
-# ---------------------------------------------------------------------------
-
-class TestFingerprint:
-    def test_same_code_same_closures_same_fp(self):
-        w = np.ones((4,), np.float32)
-
-        def mk():
-            return jax.jit(lambda x: x * w)
-
-        fp1, p1 = cstore.fn_fingerprint(mk())
-        fp2, p2 = cstore.fn_fingerprint(mk())
-        assert fp1 == fp2 and p1 and p2
-
-    def test_changed_closure_weights_rekey(self):
-        def mk(w):
-            return jax.jit(lambda x: x * w)
-
-        fp1, _ = cstore.fn_fingerprint(mk(np.ones((4,), np.float32)))
-        fp2, _ = cstore.fn_fingerprint(mk(np.full((4,), 2.0,
-                                                  np.float32)))
-        assert fp1 != fp2
-
-    def test_jax_array_closure_is_non_portable(self):
-        w = jax.numpy.ones((4,))
-        fp, portable = cstore.fn_fingerprint(jax.jit(lambda x: x * w))
-        assert fp is not None and not portable
-
-    def test_aot_token_wins_and_is_portable(self):
-        """A closure reaching device weights ONLY through a token-
-        carrying owner (the TinyCausalLM pattern) stays portable: the
-        token IS the owner's content identity, so the jax arrays behind
-        it are never walked."""
-        class Owner:
-            aot_token = "model:v1:crc123"
-
-            def __init__(self):
-                self.w = jax.numpy.ones((4,))
-
-        owner = Owner()
-        fp, portable = cstore.fn_fingerprint(
-            jax.jit(lambda x: x * owner.w))
-        assert portable and fp is not None
-        # two owners with different tokens re-key
-        owner2 = Owner()
-        owner2.aot_token = "model:v2:crc456"
-        fp2, _ = cstore.fn_fingerprint(jax.jit(lambda x: x * owner2.w))
-        assert fp2 != fp
-
-
-# ---------------------------------------------------------------------------
-# program store: manifest round trip, restore, corruption
-# ---------------------------------------------------------------------------
-
-def _store_with_one_program(root):
-    st = cstore.ProgramStore(str(root))
-    f = jax.jit(lambda x: x * 3.0)
-    x = np.ones((8, 4), np.float32)
-    out = st.call(f, [x])
-    st.drain(60)
-    return st, f, x, np.asarray(out)
-
-
-class TestProgramStore:
-    def test_miss_records_compiles_persists_then_restores(self, tmp_path):
-        st, f, x, out = _store_with_one_program(tmp_path / "s")
-        entries = st.entries()
-        assert len(entries) == 1
-        e = list(entries.values())[0]
-        assert e["exe"] and e["portable"] and e["compile_s"] is not None
-        assert e["crc"] == cstore._entry_crc(e)
-        # fresh-process simulation: a NEW instance restores the
-        # serialized executable and the same call HITS, bitwise
-        st2 = cstore.ProgramStore(str(tmp_path / "s"))
-        assert st2.ensure_restored(block=True) == 1
-        out2 = np.asarray(st2.call(f, [x]))
-        np.testing.assert_array_equal(out, out2)
-        assert _metric("compile.hits") == 1
-        assert _metric("compile.programs_restored") == 1
-
-    def test_restore_skips_foreign_backend(self, tmp_path):
-        st, f, x, out = _store_with_one_program(tmp_path / "s")
-        mpath = os.path.join(str(tmp_path / "s"), cstore.MANIFEST_NAME)
-        with open(mpath) as fh:
-            m = json.load(fh)
-        for e in m["entries"].values():
-            e["backend"] = {"platform": "tpu", "device_kind": "v5e",
-                            "n_devices": 8, "jax": "9.9.9"}
-        with open(mpath, "w") as fh:
-            json.dump(m, fh)
-        st2 = cstore.ProgramStore(str(tmp_path / "s"))
-        assert st2.ensure_restored(block=True) == 0  # not ours: skipped
-
-    def test_corrupt_manifest_quarantines_and_starts_empty(self,
-                                                           tmp_path):
-        root = tmp_path / "s"
-        _store_with_one_program(root)
-        mpath = os.path.join(str(root), cstore.MANIFEST_NAME)
-        with open(mpath, "w") as fh:
-            fh.write("{ torn json")
-        st2 = cstore.ProgramStore(str(root))
-        assert st2.entries() == {}
-        assert os.path.exists(mpath + ".corrupt")
-        assert _metric("compile.store_corrupt") == 1
-
-    def test_corrupt_exe_is_skipped_never_fatal(self, tmp_path):
-        st, f, x, out = _store_with_one_program(tmp_path / "s")
-        e = list(st.entries().values())[0]
-        epath = os.path.join(str(tmp_path / "s"), e["exe"])
-        blob = bytearray(open(epath, "rb").read())
-        blob[len(blob) // 2] ^= 0xFF
-        open(epath, "wb").write(bytes(blob))
-        st2 = cstore.ProgramStore(str(tmp_path / "s"))
-        assert st2.ensure_restored(block=True) == 0
-        assert _metric("compile.store_corrupt") == 1
-        # the jit path still serves the program (miss, not crash)
-        np.testing.assert_array_equal(out, np.asarray(st2.call(f, [x])))
-
-    def test_compile_signature_no_execution(self, tmp_path):
-        """The warmup contract: declared-aval compile runs NO data —
-        a fn that would fail on real zeros still AOT-compiles."""
-        st = cstore.ProgramStore(str(tmp_path / "s"))
-        f = jax.jit(lambda x: x * 2.0)
-        aval = jax.ShapeDtypeStruct((16, 3), np.float32)
-        assert st.compile_signature(f, [aval], block=True)
-        assert st.programs() == 1
-        x = np.ones((16, 3), np.float32)
-        np.asarray(st.call(f, [x]))
-        assert _metric("compile.hits") == 1
-        assert _metric("compile.misses") is None
-
-
-# ---------------------------------------------------------------------------
-# tools/validate_programs.py — the seventh validator (tier-1-wired)
-# ---------------------------------------------------------------------------
-
-class TestValidator:
-    def test_clean_store_validates(self, tmp_path, validator):
-        _store_with_one_program(tmp_path / "s")
-        errs, n, n_exe = validator.validate_store_dir(str(tmp_path / "s"))
-        assert errs == [] and n == 1 and n_exe == 1
-
-    def test_tampered_entry_fails_checksum(self, tmp_path, validator):
-        _store_with_one_program(tmp_path / "s")
-        mpath = os.path.join(str(tmp_path / "s"), cstore.MANIFEST_NAME)
-        m = json.load(open(mpath))
-        list(m["entries"].values())[0]["donate"] = True  # hand edit
-        json.dump(m, open(mpath, "w"))
-        errs, _, _ = validator.validate_store_dir(str(tmp_path / "s"))
-        assert any("checksum" in e for e in errs)
-
-    def test_inflight_persist_orphan_tolerated_and_swept(self, tmp_path,
-                                                         validator):
-        """A crash between a bin's publish and its manifest seal leaves
-        the entry at exe=null beside the bin: the validator must read
-        that as in-flight (not corruption), and the next store open
-        sweeps it once it ages."""
-        st, f, x, out = _store_with_one_program(tmp_path / "s")
-        key, e = list(st.entries().items())[0]
-        mpath = os.path.join(str(tmp_path / "s"), cstore.MANIFEST_NAME)
-        m = json.load(open(mpath))
-        entry = m["entries"][key]
-        entry["exe"] = entry["exe_crc32"] = entry["exe_nbytes"] = None
-        entry["crc"] = cstore._entry_crc(entry)
-        json.dump(m, open(mpath, "w"))
-        errs, _, n_exe = validator.validate_store_dir(str(tmp_path / "s"))
-        assert errs == [] and n_exe == 0  # bin present but unreferenced
-        # aged past the cross-process guard, the next open sweeps it
-        bin_path = os.path.join(str(tmp_path / "s"), e["exe"])
-        os.utime(bin_path, (1, 1))
-        cstore.ProgramStore(str(tmp_path / "s"))
-        assert not os.path.exists(bin_path)
-
-    def test_stale_executable_flagged(self, tmp_path, validator):
-        _store_with_one_program(tmp_path / "s")
-        open(os.path.join(str(tmp_path / "s"),
-                          "prog-deadbeef.bin"), "wb").write(b"orphan")
-        errs, _, _ = validator.validate_store_dir(str(tmp_path / "s"))
-        assert any("stale executable" in e for e in errs)
-
-    def test_truncated_exe_flagged(self, tmp_path, validator):
-        st, *_ = _store_with_one_program(tmp_path / "s")
-        e = list(st.entries().values())[0]
-        epath = os.path.join(str(tmp_path / "s"), e["exe"])
-        open(epath, "wb").write(open(epath, "rb").read()[:-10])
-        errs, _, _ = validator.validate_store_dir(str(tmp_path / "s"))
-        assert any("size" in e or "truncated" in e for e in errs)
-
-    def test_bucket_ladder_consistency(self, tmp_path, validator):
-        """A bucketed entry whose leading dim is not a rung of the
-        manifest's declared ladder is a store bug."""
-        st = cstore.ProgramStore(str(tmp_path / "s"))
-        st.note_ladder(bk.BucketLadder("pow2"))
-        f = jax.jit(lambda x: x + 1)
-        st.call(f, [np.ones((7, 2), np.float32)], bucketed=True)
-        st.drain(60)
-        errs, _, _ = validator.validate_store_dir(str(tmp_path / "s"))
-        assert any("not a rung" in e for e in errs)
-        # the same shape at a rung size audits clean
-        st.call(f, [np.ones((8, 2), np.float32)], bucketed=True)
-        st.drain(60)
-        errs2, _, _ = validator.validate_store_dir(str(tmp_path / "s"))
-        assert errs2 == errs  # only the 7-row entry flagged
-
-    def test_cli_contract(self, tmp_path, validator):
-        _store_with_one_program(tmp_path / "s")
-        r = subprocess.run(
-            [sys.executable, os.path.join(REPO, "tools",
-                                          "validate_programs.py"),
-             str(tmp_path / "s")], capture_output=True, text=True)
-        assert r.returncode == 0 and "OK" in r.stdout
-        r2 = subprocess.run(
-            [sys.executable, os.path.join(REPO, "tools",
-                                          "validate_programs.py")],
-            capture_output=True, text=True)
-        assert r2.returncode == 2  # usage
-
-
-# ---------------------------------------------------------------------------
-# executor wiring: bitwise parity matrix + AOT accounting
+# executor wiring: bitwise parity matrix
 # ---------------------------------------------------------------------------
 
 def _run(frame, fn, **kw):
@@ -464,51 +549,6 @@ class TestExecutorBuckets:
         assert obs.last_pipeline_report()["buckets"] == "off"
 
 
-class TestExecutorAOT:
-    def test_warm_process_first_dispatch_hits(self, tmp_path,
-                                              monkeypatch):
-        monkeypatch.setenv("TPUDL_COMPILE_AOT", str(tmp_path / "s"))
-        rng = np.random.default_rng(2)
-        frame = Frame({"x": rng.standard_normal((100, 8)).astype(
-            np.float32)})
-        fn = jax.jit(lambda b: b * 2.0)
-        exact = _run(frame, fn, batch_size=32, buckets="pow2")
-        # the background pool may legitimately finish a signature's
-        # compile BETWEEN dispatches (same-process hits are design),
-        # so only the total and the first miss are deterministic
-        hits0 = int(_metric("compile.hits") or 0)
-        misses0 = int(_metric("compile.misses") or 0)
-        assert misses0 >= 1 and hits0 + misses0 == 4
-        rep = obs.last_pipeline_report()
-        assert rep["aot"] is True
-        calls = rep.get("stage_calls") or {}
-        assert (calls.get("aot_hits", 0) + calls["aot_misses"]) == 4
-        assert calls.get("first_dispatch_s")
-        # a miss compiles ONCE, inline (the jit path never traces): the
-        # table already holds both signatures before any drain, and
-        # exactly one compile per signature was paid
-        assert C.get_program_store().programs() == 2
-        assert _metric("compile.programs_compiled") == 2
-        C.get_program_store().drain(60)
-        # "fresh process": drop the singleton (its table dies with it)
-        C.reset_program_store()
-        obs_metrics.get_registry().reset()
-        assert C.warm_start(block=True) == 2  # 32-rung + 4-tail
-        warm = _run(frame, fn, batch_size=32, buckets="pow2")
-        np.testing.assert_array_equal(exact, warm)
-        assert _metric("compile.hits") == 4
-        assert _metric("compile.misses") is None
-        assert (obs.last_pipeline_report().get("stage_calls")
-                or {}).get("aot_hits") == 4
-
-    def test_aot_off_by_default(self, monkeypatch):
-        monkeypatch.delenv("TPUDL_COMPILE_AOT", raising=False)
-        frame = Frame({"x": np.ones((8, 4), np.float32)})
-        _run(frame, jax.jit(lambda b: b + 1), batch_size=8)
-        assert obs.last_pipeline_report()["aot"] is False
-        assert _metric("compile.misses") is None
-
-
 # ---------------------------------------------------------------------------
 # acceptance: traceck-armed ragged sweep — ZERO retraces through the shim
 # ---------------------------------------------------------------------------
@@ -566,7 +606,6 @@ class TestZeroRetraceSweep:
         env = dict(os.environ)
         env["TPUDL_TRACECK"] = "1"
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env.pop("TPUDL_COMPILE_AOT", None)
         r = subprocess.run([sys.executable, script, out_path],
                            capture_output=True, text=True, env=env,
                            timeout=300, cwd=REPO)
@@ -580,68 +619,7 @@ class TestZeroRetraceSweep:
 
 
 # ---------------------------------------------------------------------------
-# acceptance: kill mid-precompile — manifest stays valid, next start resumes
-# ---------------------------------------------------------------------------
-
-_KILL_SCRIPT = r"""
-import os, sys
-import numpy as np
-import jax
-jax.config.update("jax_platforms", "cpu")
-from tpudl import compile as C
-from tpudl.frame import Frame
-from tpudl.testing import faults
-
-faults.install_from_env()  # the cross-process fault-plan contract
-
-frame = Frame({"x": np.ones((80, 4), np.float32)})   # 2 programs:
-fn = jax.jit(lambda b: b * 2.0)                      # 64-full + 16-tail
-out = frame.map_batches(fn, ["x"], ["y"], batch_size=64, autotune=False,
-                        aot=True, buckets="pow2")
-np.stack(list(out["y"]))
-C.get_program_store().drain(120)   # the armed plan SIGTERMs in here
-print("DRAINED-CLEAN")             # only reached when no plan is armed
-"""
-
-
-class TestKillMidPrecompile:
-    def test_manifest_valid_after_kill_and_next_start_resumes(
-            self, tmp_path, validator):
-        store_dir = str(tmp_path / "store")
-        script = str(tmp_path / "kill.py")
-        open(script, "w").write(_KILL_SCRIPT)
-        env = dict(os.environ)
-        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env["TPUDL_COMPILE_AOT"] = store_dir
-        env["TPUDL_FAULT_PLAN"] = json.dumps(
-            [{"point": "compile.precompile", "action": "sigterm",
-              "at_call": 2}])
-        r = subprocess.run([sys.executable, script],
-                           capture_output=True, text=True, env=env,
-                           timeout=300, cwd=REPO)
-        assert r.returncode in (-signal.SIGTERM, 128 + signal.SIGTERM,
-                                143), (r.returncode, r.stderr[-500:])
-        assert "DRAINED-CLEAN" not in r.stdout  # really died mid-drain
-        # the manifest survived the kill VALID (atomic writes only)
-        errs, n_entries, n_exe = validator.validate_store_dir(store_dir)
-        assert errs == [], errs
-        assert n_entries == 2
-        assert n_exe < 2  # at least one compile was killed away
-        # relaunch WITHOUT the plan: the same run resumes compiling the
-        # missing programs and the store completes
-        env2 = dict(env)
-        env2.pop("TPUDL_FAULT_PLAN")
-        r2 = subprocess.run([sys.executable, script],
-                            capture_output=True, text=True, env=env2,
-                            timeout=300, cwd=REPO)
-        assert r2.returncode == 0, r2.stderr[-2000:]
-        assert "DRAINED-CLEAN" in r2.stdout
-        errs, n_entries, n_exe = validator.validate_store_dir(store_dir)
-        assert errs == [] and n_entries == 2 and n_exe == 2
-
-
-# ---------------------------------------------------------------------------
-# LM: prompt bucketing + precompile_generate
+# LM: prompt bucketing
 # ---------------------------------------------------------------------------
 
 class TestLMBuckets:
@@ -664,112 +642,32 @@ class TestLMBuckets:
         # the six ragged lengths share ONE padded-16 program
         assert sum(1 for k in lm._gen_jits if k[1] == 16) == 1
 
-    def test_precompile_generate_then_hit(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("TPUDL_COMPILE_AOT", str(tmp_path / "s"))
-        lm = self._lm()
-        params = lm.init(0)
-        assert lm.precompile_generate(params, 2, 13, 8,
-                                      prompt_buckets="pow2")
-        prompt = np.random.default_rng(0).integers(
-            1, 64, size=(2, 13)).astype(np.int32)
-        out = np.asarray(lm.generate(params, prompt, 8,
-                                     prompt_buckets="pow2"))
-        assert _metric("compile.hits") == 1
-        # fresh process: a NEW model instance over a restored store
-        C.get_program_store().drain(60)
-        C.reset_program_store()
-        obs_metrics.get_registry().reset()
-        lm2 = self._lm()
-        assert C.warm_start(block=True) >= 1
-        out2 = np.asarray(lm2.generate(params, prompt, 8,
-                                       prompt_buckets="pow2"))
-        np.testing.assert_array_equal(out, out2)
-        assert _metric("compile.hits") == 1
-
-    def test_unarmed_generate_unchanged(self, monkeypatch):
-        monkeypatch.delenv("TPUDL_COMPILE_AOT", raising=False)
+    def test_unarmed_generate_unchanged(self):
+        """Without prompt_buckets the prompt runs at its exact length:
+        one program for that geometry, keyed by the unpadded length."""
         lm = self._lm()
         params = lm.init(0)
         prompt = np.ones((1, 4), np.int32)
         out = np.asarray(lm.generate(params, prompt, 4))
         assert out.shape == (1, 4)
-        assert _metric("compile.misses") is None
+        assert list(lm._gen_jits) == [(1, 4, 4, 0.0, None)]
 
 
 # ---------------------------------------------------------------------------
-# warmup as an AOT warm call
+# roofline: cold-start attribution
 # ---------------------------------------------------------------------------
 
-class TestWarmupAOT:
-    def test_warmup_compiles_declared_signature_without_execution(
-            self, tmp_path, monkeypatch):
-        from tpudl.ml.tf_image import ImageBatchWarmup
-
-        monkeypatch.setenv("TPUDL_COMPILE_AOT", str(tmp_path / "s"))
-
-        class W(ImageBatchWarmup):
-            batchSize = 16
-            mesh = None
-            fuseSteps = 1
-
-            def _get_jfn(self):
-                return jax.jit(
-                    lambda b: b.astype(jax.numpy.float32).mean(
-                        axis=(1, 2, 3)))
-
-        w = W()
-        w.warmup(8, 8, 3)
-        st = C.get_program_store()
-        assert st.programs() >= 1
-        assert _metric("compile.programs_compiled") >= 1
-        # the executor's dispatch hits the exact warmed key
-        frame = Frame({"x": np.zeros((16, 8, 8, 3), np.uint8)})
-        _run(frame, w._get_jfn(), batch_size=16)
-        assert _metric("compile.hits") == 1
-
-
-# ---------------------------------------------------------------------------
-# jobs: resume warm-starts the store
-# ---------------------------------------------------------------------------
-
-class TestJobsWarmStart:
-    def test_manifest_records_store_and_resume_restores(self, tmp_path,
-                                                        monkeypatch):
-        from tpudl.jobs import JobRuntime, JobSpec
-
-        monkeypatch.setenv("TPUDL_COMPILE_AOT", str(tmp_path / "s"))
-        _store_with_one_program(tmp_path / "s")
-        C.reset_program_store()
-        spec = JobSpec("featurize", str(tmp_path / "job"),
-                       material={"k": 1})
-        JobRuntime(spec, install_signals=False).run(lambda ctx: 1)
-        from tpudl.jobs.runtime import load_manifest
-
-        m = load_manifest(str(tmp_path / "job"))
-        assert m["program_store"] == str(tmp_path / "s")
-        # relaunch = resume: the warm start restores before the payload
-        obs_metrics.get_registry().reset()
-        C.reset_program_store()
-        JobRuntime(spec, install_signals=False).run(lambda ctx: 2)
-        assert _metric("compile.programs_restored") == 1
-
-
-# ---------------------------------------------------------------------------
-# roofline: cold-start attribution + the precompile rec
-# ---------------------------------------------------------------------------
-
-def _cold_report(aot=False, hits=0, misses=4):
+def _cold_report():
     return {
         "run_id": "r", "rows": 4096, "rows_done": 4096,
         "wall_seconds": 80.0, "finished": True,
         "stage_seconds": {"dispatch": 70.0, "infeed_wait": 0.5,
                           "d2h": 1.0},
         "stage_calls": {"dispatch": 16, "bytes_prepared": 1e6,
-                        "first_dispatch_s": 61.0,
-                        "aot_hits": hits, "aot_misses": misses},
+                        "first_dispatch_s": 61.0},
         "fuse_steps": 1, "dispatch_depth": 1, "prefetch_depth": 2,
         "prepare_workers": 2, "wire_codec": "off", "batch_size": 256,
-        "aot": aot, "mesh": None,
+        "mesh": None,
     }
 
 
@@ -781,16 +679,9 @@ class TestRooflinePrecompile:
                               publish=False, allow_probe=False)
         # first dispatch 61s vs steady (70-61)/15 = 0.6s → cold ~60s
         assert rr.inputs["cold_start_s"] == pytest.approx(60.4, abs=0.5)
-        rec = [r for r in rr.advice if r["knob"] == "precompile"]
-        assert rec and rec[0]["recommended"] == "on"
-        assert rec[0]["predicted_gain_pct"] > 100  # 80s run, 60s cold
-
-    def test_armed_store_suppresses_the_rec(self):
-        from tpudl.obs import roofline
-
-        rr = roofline.analyze(_cold_report(aot=True, hits=4),
-                              h2d_mbps=1000.0, publish=False,
-                              allow_probe=False)
+        # the cold start is attributed, not advised on: there is no
+        # knob that removes it (the persistent cache already does, on
+        # the next process that shares its directory)
         assert not [r for r in rr.advice if r["knob"] == "precompile"]
 
 
@@ -802,18 +693,14 @@ class TestObsTopCompileLine:
     def test_compile_section_and_render(self, tmp_path, monkeypatch):
         from tpudl.obs import live
 
-        obs_metrics.counter("compile.hits").inc(7)
-        obs_metrics.counter("compile.misses").inc(2)
-        obs_metrics.counter("compile.programs_restored").inc(3)
+        obs_metrics.counter("compile.bucket_pad_rows").inc(7)
         obs_metrics.counter("compile.cache_disabled").inc()
         payload = live.collect_status(roofline=False)
         comp = payload.get("compile")
-        assert comp == {"hits": 7, "misses": 2, "programs_restored": 3,
-                        "programs_compiled": 0, "aot_s": 0.0,
-                        "bucket_pad_rows": 0, "cache_disabled": 1}
+        assert comp == {"bucket_pad_rows": 7, "cache_disabled": 1}
         text = live.render([payload])
         assert "compile:" in text
-        assert "hits 7" in text and "restored 3" in text
+        assert "pad_rows 7" in text
         assert "CACHE-DISABLED" in text
         # the written status file still passes the status validator
         monkeypatch.setenv("TPUDL_STATUS_DIR", str(tmp_path))

@@ -4,10 +4,9 @@ The acceptance surface of the GSPMD model-sharded fast path: the
 ``TPUDL_MESH_MODEL`` knob + idle-device rail, Megatron param layouts
 across {8x1, 4x2, 2x4} grids, the transfer_batch pass-through for
 model-resident leaves, the generate/executor parity matrix, the HLO
-collective pin (ZERO all-gathers of param shards), program-store
-topology identity + zero-trace 2-D warm restore, the capacity proof
+collective pin (ZERO all-gathers of param shards), the capacity proof
 (params that only fit sharded), the roofline ``collective`` component,
-and the validate_job / validate_programs topology audits.
+and the validate_job topology audit.
 """
 
 from __future__ import annotations
@@ -16,8 +15,6 @@ import importlib.util
 import json
 import os
 import re
-import subprocess
-import sys
 import warnings
 
 import numpy as np
@@ -27,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tpudl import compile as C
 from tpudl import mesh as M
 from tpudl import obs
 from tpudl.frame import Frame
@@ -41,10 +37,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 @pytest.fixture(autouse=True)
 def registry():
     obs_metrics.get_registry().reset()
-    C.reset_program_store()
     yield
     obs_metrics.get_registry().reset()
-    C.reset_program_store()
 
 
 def _metric(name):
@@ -57,8 +51,7 @@ def _clean_env(monkeypatch):
                 "TPUDL_FRAME_DISPATCH_DEPTH", "TPUDL_FRAME_DONATE",
                 "TPUDL_FRAME_AUTOTUNE", "TPUDL_MESH_FAST_PATH",
                 "TPUDL_WIRE_CODEC", "TPUDL_DATA_CACHE_DIR",
-                "TPUDL_MESH_MODEL", "TPUDL_DATA_HBM_BUDGET_MB",
-                "TPUDL_COMPILE_AOT"):
+                "TPUDL_MESH_MODEL", "TPUDL_DATA_HBM_BUDGET_MB"):
         monkeypatch.delenv(var, raising=False)
 
 
@@ -76,16 +69,6 @@ def lm():
 @pytest.fixture(scope="module")
 def lm_params(lm):
     return lm.init(0)
-
-
-@pytest.fixture(scope="module")
-def validator():
-    spec = importlib.util.spec_from_file_location(
-        "validate_programs", os.path.join(REPO, "tools",
-                                          "validate_programs.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 @pytest.fixture(scope="module")
@@ -433,124 +416,6 @@ class TestHLOPin:
 
 
 # ---------------------------------------------------------------------------
-# acceptance: program-store topology identity
-# ---------------------------------------------------------------------------
-
-class TestStoreIdentity:
-    def test_1d_and_2d_warm_to_distinct_entries(self, tmp_path,
-                                                monkeypatch, lm,
-                                                lm_params, mesh4x2,
-                                                validator):
-        monkeypatch.setenv("TPUDL_COMPILE_AOT", str(tmp_path / "s"))
-        C.reset_program_store()
-        assert lm.precompile_generate(lm_params, 2, 4, 2)
-        placed = lm.shard_params(lm_params, mesh4x2)
-        assert lm.precompile_generate(placed, 2, 4, 2, mesh=mesh4x2,
-                                      tp=True)
-        store = C.get_program_store()
-        store.drain(180)
-        entries = store.entries()
-        assert len(entries) == 2, sorted(entries)
-        topos = sorted(sorted((e.get("mesh_axes") or {}).items())
-                       for e in entries.values())
-        assert topos == [[], [("data", 4), ("model", 2)]]
-        errs, n, n_exe = validator.validate_store_dir(str(tmp_path / "s"))
-        assert errs == [] and n == 2 and n_exe == 2
-
-    def test_mesh_closure_fingerprint_deterministic(self):
-        from tpudl.compile.store import fn_fingerprint
-
-        def mk():
-            mesh = M.build_mesh(n_data=4, n_model=2)
-
-            def f(x):
-                return x * mesh.shape["data"]
-
-            return f
-
-        # two identically-built Mesh objects hash to ONE fingerprint:
-        # the store tokenizes the topology, not per-process device
-        # object pointers (a pointer hash would defeat every cross-
-        # process restore)
-        fp1, p1 = fn_fingerprint(mk())
-        fp2, p2 = fn_fingerprint(mk())
-        assert fp1 is not None and fp1 == fp2
-        assert p1 == p2
-
-    def test_mesh_axes_token_parse(self):
-        from tpudl.compile.store import _mesh_axes_of_token
-
-        assert _mesh_axes_of_token("host") is None
-        assert _mesh_axes_of_token("device") is None
-        assert _mesh_axes_of_token(None) is None
-        tok = "P(None, 'model')|[('data', 4), ('model', 2)]"
-        assert _mesh_axes_of_token(tok) == {"data": 4, "model": 2}
-        assert _mesh_axes_of_token("P()|garbage[") is None
-
-
-_SERVE_SCRIPT = r"""
-import json, os, sys
-import numpy as np
-import jax
-jax.config.update("jax_platforms", "cpu")
-from tpudl import compile as C
-from tpudl import mesh as M
-from tpudl.testing import traceck
-from tpudl.zoo.transformer import TinyCausalLM
-
-mode, out_path = sys.argv[1], sys.argv[2]
-lm = TinyCausalLM(vocab=32, dim=16, heads=4, layers=2, max_len=64)
-params = lm.init(0)
-mesh = M.build_mesh(n_data=4, n_model=2)
-placed = lm.shard_params(params, mesh)
-prompt = np.array([[3, 1, 4, 1]], np.int32)
-if mode == "warm":
-    assert lm.precompile_generate(placed, 1, 4, 3, mesh=mesh, tp=True)
-    C.get_program_store().drain(180)
-    toks = np.asarray(lm.generate(placed, prompt, 3, mesh=mesh, tp=True))
-    json.dump({"tokens": toks.tolist()}, open(out_path, "w"))
-else:
-    C.get_program_store().ensure_restored(block=True)
-    traceck.reset()
-    toks = np.asarray(lm.generate(placed, prompt, 3, mesh=mesh, tp=True))
-    counts = traceck.counts()
-    json.dump({"tokens": toks.tolist(),
-               "traces": sum(counts.values()),
-               "restored": C.get_program_store().programs()},
-              open(out_path, "w"))
-"""
-
-
-class TestWarmStart2D:
-    def test_second_process_restores_2d_program_zero_trace(self,
-                                                           tmp_path):
-        """THE warm-start acceptance: a fresh process restores the 2-D
-        model-sharded executable by its declared avals and serves the
-        first request with ZERO traces — and the tokens match."""
-        script = str(tmp_path / "serve.py")
-        open(script, "w").write(_SERVE_SCRIPT)
-        env = dict(os.environ)
-        env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env["TPUDL_COMPILE_AOT"] = str(tmp_path / "store")
-        env["TPUDL_TRACECK"] = "1"
-        warm_out = str(tmp_path / "warm.json")
-        r = subprocess.run([sys.executable, script, "warm", warm_out],
-                           capture_output=True, text=True, env=env,
-                           timeout=420, cwd=REPO)
-        assert r.returncode == 0, r.stderr[-2000:]
-        serve_out = str(tmp_path / "serve.json")
-        r2 = subprocess.run([sys.executable, script, "serve", serve_out],
-                            capture_output=True, text=True, env=env,
-                            timeout=420, cwd=REPO)
-        assert r2.returncode == 0, r2.stderr[-2000:]
-        warm = json.load(open(warm_out))
-        serve = json.load(open(serve_out))
-        assert serve["restored"] >= 1
-        assert serve["traces"] == 0, serve
-        assert serve["tokens"] == warm["tokens"]
-
-
-# ---------------------------------------------------------------------------
 # acceptance: capacity proof — params that only fit model-sharded
 # ---------------------------------------------------------------------------
 
@@ -667,7 +532,7 @@ class TestObsTopology:
 
 
 # ---------------------------------------------------------------------------
-# satellite: validate_job resume-topology + validate_programs mesh audit
+# satellite: validate_job resume-topology
 # ---------------------------------------------------------------------------
 
 class TestResumeTopology:
@@ -703,71 +568,6 @@ class TestResumeTopology:
         wd = self._workdir(tmp_path, None)
         assert job_validator.check_resume_topology(
             wd, {"data": 4, "model": 2}) == []
-
-
-def _store_manifest(tmp_path, entries):
-    from tpudl.compile import store as cstore
-
-    root = tmp_path / "audit"
-    root.mkdir(exist_ok=True)
-    (root / cstore.MANIFEST_NAME).write_text(json.dumps(
-        {"schema": cstore.MANIFEST_SCHEMA,
-         "version": cstore.MANIFEST_VERSION, "backend": None,
-         "ladder": None, "updated_ts": 0.0, "entries": entries}))
-    return str(root)
-
-
-def _entry(leaves, **over):
-    from tpudl.compile.store import _entry_crc
-
-    e = {"fn": "f" * 40, "tree": "PyTreeDef(*)", "leaves": leaves,
-         "donate": False, "portable": False, "bucketed": False,
-         "mesh": None, "mesh_axes": None, "backend": None,
-         "created_ts": 1.0, "compile_s": None, "exe": None,
-         "exe_crc32": None, "exe_nbytes": None}
-    e.update(over)
-    e["crc"] = _entry_crc(e)
-    return e
-
-
-_TP_TOK = "P(None, 'model')|[('data', 4), ('model', 2)]"
-
-
-class TestValidateProgramsMeshAudit:
-    def test_sharded_entry_without_topology_flagged(self, tmp_path,
-                                                    validator):
-        root = _store_manifest(tmp_path, {"k1": _entry(
-            [[[16, 16], "float32", _TP_TOK]])})
-        errs, _, _ = validator.validate_store_dir(root)
-        assert any("no mesh_axes topology" in e for e in errs), errs
-
-    def test_topology_mismatch_flagged(self, tmp_path, validator):
-        root = _store_manifest(tmp_path, {"k1": _entry(
-            [[[16, 16], "float32", _TP_TOK]],
-            mesh=_TP_TOK, mesh_axes={"data": 8, "model": 1})})
-        errs, _, _ = validator.validate_store_dir(root)
-        assert any("sharding topology" in e for e in errs), errs
-
-    def test_phantom_topology_flagged(self, tmp_path, validator):
-        root = _store_manifest(tmp_path, {"k1": _entry(
-            [[[16], "float32", "host"]],
-            mesh_axes={"data": 4, "model": 2})})
-        errs, _, _ = validator.validate_store_dir(root)
-        assert any("no leaf is mesh-sharded" in e for e in errs), errs
-
-    def test_duplicate_signature_under_two_keys_flagged(self, tmp_path,
-                                                        validator):
-        e = _entry([[[16], "float32", "host"]])
-        root = _store_manifest(tmp_path, {"k1": e, "k2": dict(e)})
-        errs, _, _ = validator.validate_store_dir(root)
-        assert any("same program signature" in e for e in errs), errs
-
-    def test_consistent_2d_entry_clean(self, tmp_path, validator):
-        root = _store_manifest(tmp_path, {"k1": _entry(
-            [[[16, 16], "float32", _TP_TOK]],
-            mesh=_TP_TOK, mesh_axes={"data": 4, "model": 2})})
-        errs, n, _ = validator.validate_store_dir(root)
-        assert errs == [] and n == 1
 
 
 # ---------------------------------------------------------------------------

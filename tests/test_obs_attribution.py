@@ -297,8 +297,7 @@ class TestPropagation:
         lm = TinyCausalLM(vocab=64, dim=32, heads=4, layers=2,
                           max_len=64)
         reg = ModelRegistry()
-        reg.add_model("default", lm, lm.init(0), slots=2, cache_len=32,
-                      warm=False)
+        reg.add_model("default", lm, lm.init(0), slots=2, cache_len=32)
         rng = np.random.default_rng(2)
 
         def make_prompt(i):
@@ -676,8 +675,7 @@ class TestTwoTenantAcceptance:
                           max_len=64)
         params = lm.init(0)
         reg = ModelRegistry()
-        reg.add_model("default", lm, params, slots=2, cache_len=32,
-                      warm=False)
+        reg.add_model("default", lm, params, slots=2, cache_len=32)
         srv = Server(reg, RequestQueue(cap=16)).start_async()
         steps, batch = 12, 32
         train_err = []

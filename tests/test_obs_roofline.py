@@ -98,8 +98,7 @@ class TestRound45Attribution:
                               device_ms_per_dispatch=DEVICE_MS,
                               publish=False)
         valid = {"fuse_steps", "dispatch_depth", "prefetch_depth",
-                 "prepare_workers", "wire_codec", "device_cache",
-                 "precompile"}
+                 "prepare_workers", "wire_codec", "device_cache"}
         for rec in rr.advice:
             assert rec["knob"] in valid
             assert "recommended" in rec and "predicted_gain_pct" in rec

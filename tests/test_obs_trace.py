@@ -1098,12 +1098,10 @@ def test_device_account(account, capsys, holds):
 
 
 def test_device_account_of_a_kept_chip_trace_closes():
-    """On a trace a builder kept from the chip (``chiprun_out/`` is no
-    part of a checkout): every operation filed once makes up the step to
-    within 1%."""
-    d = os.path.join(REPO, "chiprun_out", "xplane")
-    if not (os.path.isdir(d) and T.find_trace_files(d)["device"]):
-        pytest.skip("no kept chip trace under chiprun_out/xplane")
+    """On a trace kept from a TPU v5e (``tests/fixtures/v5e_trace/``, one
+    step of ``tests/fixtures/make_chip_trace.py``'s program): every
+    operation filed once makes up the step to within 1%."""
+    d = os.path.join(REPO, "tests", "fixtures", "v5e_trace")
     table = T.device_account(d)
     assert table["runs"] >= 1 and len(table["scopes"]) > 2
     assert abs(table["filed_ms"] - table["step_ms"]) < 0.01 * table["step_ms"]

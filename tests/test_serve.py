@@ -155,8 +155,7 @@ def _serial(lm, params, prompt, max_new):
 
 def _engine(lm, params, slots):
     reg = ModelRegistry()
-    return reg.add_model("m", lm, params, slots=slots, cache_len=32,
-                         warm=False).engine
+    return reg.add_model("m", lm, params, slots=slots, cache_len=32).engine
 
 
 class TestSlotDecoder:
@@ -247,8 +246,7 @@ class TestServer:
         serial batch-1 generate of the same prompt."""
         lm, params = lm_params
         reg = ModelRegistry()
-        reg.add_model("default", lm, params, slots=2, cache_len=32,
-                      warm=False)
+        reg.add_model("default", lm, params, slots=2, cache_len=32)
         srv = Server(reg, RequestQueue(cap=16))
         rng = np.random.default_rng(3)
         reqs = [srv.submit(_prompt(rng, n), 6)
@@ -270,8 +268,7 @@ class TestServer:
         admitted on tick 1, so the expiry is unambiguously mid-decode."""
         lm, params = lm_params
         reg = ModelRegistry()
-        reg.add_model("default", lm, params, slots=2, cache_len=32,
-                      warm=False)
+        reg.add_model("default", lm, params, slots=2, cache_len=32)
         srv = Server(reg, RequestQueue(cap=16))
         rng = np.random.default_rng(4)
         doomed = srv.submit(_prompt(rng, 5), 20, deadline_s=0.25)
@@ -294,16 +291,14 @@ class TestServer:
     def test_unknown_model_is_immediate(self, lm_params):
         lm, params = lm_params
         reg = ModelRegistry()
-        reg.add_model("default", lm, params, slots=1, cache_len=32,
-                      warm=False)
+        reg.add_model("default", lm, params, slots=1, cache_len=32)
         with pytest.raises(KeyError, match="nope"):
             Server(reg).submit([1, 2], 4, model="nope")
 
     def test_threaded_lifecycle_and_close(self, lm_params):
         lm, params = lm_params
         reg = ModelRegistry()
-        reg.add_model("default", lm, params, slots=2, cache_len=32,
-                      warm=False)
+        reg.add_model("default", lm, params, slots=2, cache_len=32)
         srv = Server(reg).start_async()
         rng = np.random.default_rng(5)
         reqs = [srv.submit(_prompt(rng, n), 4) for n in (3, 6, 10)]
@@ -316,38 +311,14 @@ class TestServer:
 
 
 # ---------------------------------------------------------------------------
-# registry: warm-start forensics
+# registry
 # ---------------------------------------------------------------------------
 
 class TestRegistry:
-    def test_warm_registration_precompiles(self, lm_params, tmp_path,
-                                           monkeypatch):
-        lm, params = lm_params
-        monkeypatch.setenv("TPUDL_COMPILE_AOT", str(tmp_path / "store"))
-        from tpudl import compile as _compile
-
-        _compile.reset_program_store()
-        try:
-            reg = ModelRegistry()
-            entry = reg.add_model("warmed", lm, params, slots=2,
-                                  cache_len=32)
-            assert entry.warm_signatures > 0
-            assert entry.warm_s > 0
-            srv = Server(reg, RequestQueue(cap=8))
-            rng = np.random.default_rng(6)
-            req = srv.submit(_prompt(rng, 5), 4, model="warmed")
-            _drain(srv)
-            np.testing.assert_array_equal(
-                req.result(timeout=1),
-                _serial(lm, params, req.prompt[0], 4))
-        finally:
-            _compile.reset_program_store()
-
     def test_get_unknown_lists_names(self, lm_params):
         lm, params = lm_params
         reg = ModelRegistry()
-        reg.add_model("a", lm, params, slots=1, cache_len=32,
-                      warm=False)
+        reg.add_model("a", lm, params, slots=1, cache_len=32)
         with pytest.raises(KeyError, match="not registered"):
             reg.get("b")
         assert reg.names() == ["a"]
@@ -379,8 +350,7 @@ baseline = {p.tobytes(): np.asarray(
     lm.generate(params, p[None, :], 6))[0] for p in prompts()}
 
 reg = ModelRegistry()
-reg.add_model("default", lm, params, slots=2, cache_len=32,
-              warm=False)
+reg.add_model("default", lm, params, slots=2, cache_len=32)
 srv = Server(reg, RequestQueue(cap=32))
 
 def drain(reqs):
@@ -428,7 +398,6 @@ class TestZeroRetraceServe:
         env = dict(os.environ)
         env["TPUDL_TRACECK"] = "1"
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env.pop("TPUDL_COMPILE_AOT", None)
         env.pop(_faults.PLAN_ENV, None)
         r = subprocess.run([sys.executable, script, out_path],
                            capture_output=True, text=True, env=env,
@@ -463,8 +432,7 @@ faults.install_from_env()
 lm = TinyCausalLM(vocab=64, dim=32, heads=4, layers=2, max_len=64)
 params = lm.init(0)
 reg = ModelRegistry()
-reg.add_model("default", lm, params, slots=2, cache_len=32,
-              warm=False)
+reg.add_model("default", lm, params, slots=2, cache_len=32)
 queue = RequestQueue(cap=4)
 srv = Server(reg, queue).start_async()
 depth_high_water = [0]
@@ -528,7 +496,6 @@ class TestOverloadChaos:
         env[_faults.PLAN_ENV] = _faults.FaultPlan([{
             "point": "serve.tick", "action": "burst", "count": 12,
             "first_calls": 4}]).to_env()
-        env.pop("TPUDL_COMPILE_AOT", None)
         r = subprocess.run([sys.executable, script, out_path],
                            capture_output=True, text=True, env=env,
                            timeout=420, cwd=REPO)

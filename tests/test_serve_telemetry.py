@@ -76,8 +76,7 @@ def _prompt(rng, n):
 
 def _server(lm, params, slots=2, cap=32):
     reg = ModelRegistry()
-    reg.add_model("default", lm, params, slots=slots, cache_len=32,
-                  warm=False)
+    reg.add_model("default", lm, params, slots=slots, cache_len=32)
     return Server(reg, RequestQueue(cap=cap))
 
 

@@ -421,7 +421,6 @@ class TestZeroRetracePromptSweep:
         env = dict(os.environ)
         env["TPUDL_TRACECK"] = "1"
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env.pop("TPUDL_COMPILE_AOT", None)
         r = subprocess.run([sys.executable, script, out_path],
                            capture_output=True, text=True, env=env,
                            timeout=300, cwd=REPO)
@@ -479,8 +478,7 @@ class TestServeRegistration:
         gen = LMGenerator(inputCol="text", outputCol="gen", model=lm,
                           weights=w, tokenizer=tok, maxNew=4)
         reg = ModelRegistry()
-        entry = reg.add_generator("story", gen, slots=2, cache_len=32,
-                                  warm=False)
+        entry = reg.add_generator("story", gen, slots=2, cache_len=32)
         assert entry.tokenizer is tok
         assert entry.model is lm
         assert reg.get("story") is entry

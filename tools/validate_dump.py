@@ -75,7 +75,7 @@ _REQUEST_FORBIDDEN = ("prompt", "tokens", "text")
 # the validator family is pure stdlib, importable without tpudl)
 _LEDGER_FIELDS = ("rows_in", "rows_out", "tokens_in", "tokens_out",
                   "wire_bytes", "hbm_bytes", "hbm_peak_bytes",
-                  "dispatch_s", "compile_s", "retries", "degradations",
+                  "dispatch_s", "retries", "degradations",
                   "serve_completed", "slo_samples")
 # one status/dump ledger holds at most this many scope rows: the table
 # is LRU-bounded at TPUDL_OBS_SCOPES (default 64) — orders of magnitude

@@ -176,12 +176,6 @@ KNOBS: tuple[Knob, ...] = (
          "1 enables the space-to-depth conv stem (defaults OFF: slower "
          "on this backend, see zoo/s2d.py)"),
     # -- compile subsystem (COMPILE.md) --------------------------------
-    Knob("TPUDL_COMPILE_AOT", "str", "", "compile",
-         "arms the AOT program store: 1 = on at "
-         "<compile cache dir>/programs, a path = on at that "
-         "directory, unset/0 = off. Dispatch consults precompiled "
-         "executables; misses background-compile + persist for the "
-         "next process"),
     Knob("TPUDL_COMPILE_BUCKETS", "str", "", "compile",
          "shape-bucket ladder: pow2 | pow2ish (also 1/auto) | an "
          "explicit comma list of rungs | unset/0 = off. Ragged "

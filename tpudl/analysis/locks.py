@@ -55,11 +55,6 @@ LOCKS: tuple[LockDecl, ...] = (
     LockDecl("jobs.runtime.manifest", "tpudl.jobs.runtime", "lock",
              "instance", 10,
              "JobRuntime resume-manifest read/modify/write"),
-    LockDecl("compile.program_store", "tpudl.compile.store", "lock",
-             "instance", 10,
-             "ProgramStore entry/table maps, pending set, pool "
-             "futures + manifest file IO (the shard-manifest "
-             "contract)"),
     # -- rank 12: checkpoint store (acquired under an estimator trial's
     #    save lock when a trial persists its result) ------------------
     LockDecl("train.checkpoint.manifest", "tpudl.train.checkpoint",
@@ -88,10 +83,6 @@ LOCKS: tuple[LockDecl, ...] = (
              "lock", "module", 16,
              "process-wide DeviceBatchCache create/reset (construction "
              "publishes the budget gauges — metrics locks are higher)"),
-    LockDecl("compile.store.singleton", "tpudl.compile.store", "lock",
-             "module", 16,
-             "process-wide ProgramStore create/re-root (a changed "
-             "TPUDL_COMPILE_AOT dir swaps the instance)"),
     # -- rank 18 -------------------------------------------------------
     LockDecl("data.codec.plan", "tpudl.data.codec", "lock", "instance",
              18, "CodecPlan per-column codec resolution/adoption"),
@@ -184,10 +175,6 @@ LOCKS: tuple[LockDecl, ...] = (
     LockDecl("data.device_cache.token_memo", "tpudl.data.device_cache",
              "lock", "module", 30,
              "array_token memo map (concurrent estimator trial "
-             "threads share it; pure dict ops under the lock)"),
-    LockDecl("compile.fingerprint_memo", "tpudl.compile.store", "lock",
-             "module", 30,
-             "fn_fingerprint weak memo map (dispatch pool + warmup "
              "threads share it; pure dict ops under the lock)"),
 )
 

@@ -385,37 +385,9 @@ METRICS: tuple[Metric, ...] = (
            "armed sentinel: identities tracing past "
            "TPUDL_TRACECK_STORM (one recompile-storm finding each)"),
     # -- compile subsystem (COMPILE.md) --------------------------------
-    Metric("compile.hits", "counter",
-           "AOT program-store dispatch hits (precompiled/restored "
-           "executable ran — no trace possible)"),
-    Metric("compile.misses", "counter",
-           "AOT program-store dispatch misses (jitted path ran; "
-           "signature recorded + background-compiled)"),
-    Metric("compile.aot_s", "counter",
-           "seconds spent AOT-compiling, serializing and restoring "
-           "programs (off the dispatch hot path)"),
     Metric("compile.bucket_pad_rows", "counter",
            "rows of bucket-ladder padding shipped and stripped "
            "(the price of O(log n) program signatures)"),
-    Metric("compile.observed", "counter",
-           "novel program signatures recorded into the manifest"),
-    Metric("compile.programs_compiled", "counter",
-           "programs AOT-compiled (warmup + background misses)"),
-    Metric("compile.programs_restored", "counter",
-           "serialized executables deserialized into the program "
-           "table at process start (the zero-cold-start path)"),
-    Metric("compile.serialize_failed", "counter",
-           "programs whose executable could not be serialized "
-           "(table-only for this process; a restart re-lowers them)"),
-    Metric("compile.deserialize_failed", "counter",
-           "persisted executables that failed to deserialize "
-           "(skipped; the jit path covers them)"),
-    Metric("compile.exec_failed", "counter",
-           "table hits whose executable refused its args (dropped; "
-           "fell back to the jitted path)"),
-    Metric("compile.store_corrupt", "counter",
-           "corrupt program-store artifacts quarantined (manifest or "
-           "executable checksum)"),
     Metric("compile.cache_disabled", "counter",
            "persistent-compilation-cache setup failures (a cold fleet "
            "is diagnosable: warn-once + flight breadcrumb ride along)"),
@@ -472,8 +444,7 @@ METRICS: tuple[Metric, ...] = (
            "end-to-end request latency, submit to completion "
            "(p50/p99 are the serving SLO line)"),
     Metric("serve.ttft_s", "histogram",
-           "time-to-first-token, submit to prefill completion (the "
-           "warm-start win: deserialization, not a 60s jit)"),
+           "time-to-first-token, submit to prefill completion"),
     Metric("serve.models", "gauge",
            "models registered in the serve registry"),
     # -- serve SLO plane (ISSUE 18: tpudl.obs.slo windows) -------------

@@ -8,7 +8,7 @@ a :class:`BucketLadder` *prevents* it: a batch of ``n`` rows pads up to
 the smallest ladder rung ≥ ``n`` (repeating row 0, the bitwise-honest
 ``mesh.pad_batch`` discipline — pad rows are stripped from the outputs
 before the caller sees them), so a workload of arbitrary ragged sizes
-runs through a handful of precompiled programs instead of one compile
+runs through a handful of compiled programs instead of one compile
 per novel shape.
 
 Ladders (``TPUDL_COMPILE_BUCKETS``, or the ``buckets=`` kwarg on
@@ -26,8 +26,8 @@ Ladders (``TPUDL_COMPILE_BUCKETS``, or the ``buckets=`` kwarg on
 - ``0`` / ``off`` / unset: bucketing disabled (every shape exact —
   today's behavior).
 
-Numpy-only at import: the ladder runs on the executor's prepare path
-and in the offline validator, neither of which may drag jax in.
+Numpy-only at import: the ladder runs on the executor's prepare path,
+which must not drag jax in.
 """
 
 from __future__ import annotations
@@ -87,8 +87,7 @@ class BucketLadder:
         return int(n) > 0 and self.pick(int(n)) == int(n)
 
     def rungs_up_to(self, n: int) -> list[int]:
-        """Every distinct rung the ladder can emit for sizes 1..n —
-        the declared-signature set precompilation walks."""
+        """Every distinct rung the ladder can emit for sizes 1..n."""
         out, seen = [], set()
         for i in range(1, int(n) + 1):
             r = self.pick(i)
@@ -97,12 +96,6 @@ class BucketLadder:
                 out.append(r)
         return out
 
-    def to_meta(self) -> dict:
-        """JSON-shippable identity (the program manifest persists it so
-        the validator can audit shapes↔ladder consistency)."""
-        return {"spec": self.spec,
-                "rungs": list(self.rungs) if self.rungs else None}
-
     def __repr__(self):
         return f"BucketLadder({self.spec!r})"
 
@@ -110,7 +103,7 @@ class BucketLadder:
 def resolve_ladder(value=None) -> BucketLadder | None:
     """The one resolution rule: explicit value beats the
     ``TPUDL_COMPILE_BUCKETS`` env, and ``None`` means *consult the
-    env* (unset env = bucketing OFF — opt-in, like the AOT store).
+    env* (unset env = bucketing OFF — opt-in).
     Accepts a :class:`BucketLadder`, a spec string, ``True`` (the
     default ladder) or ``False``/``"off"``."""
     if isinstance(value, BucketLadder):

@@ -5,10 +5,9 @@ The reference pays Spark task-dispatch overhead per stage; our analogous
 fixed cost is XLA compilation — a minute or more for InceptionV3, paid
 again every process start. JAX's persistent compilation cache
 (serialized executables keyed by HLO+flags+topology) removes the
-*compile* for repeat runs; the AOT program store
-(:mod:`tpudl.compile.store`) sits above it and removes the *trace* too.
-This module turns the JAX cache on; ``benchmark/run.py`` and
-``chip_smoke.py`` call it, library code never does.
+*compile* for repeat runs. This module turns the JAX cache on;
+``benchmark/run.py`` and ``chip_smoke.py`` call it, library code never
+does.
 
 Where the cache lives is decided OUTSIDE the code: when
 ``JAX_COMPILATION_CACHE_DIR`` is set, jax itself reads it and this
@@ -42,7 +41,7 @@ import threading
 import time
 import warnings
 
-__all__ = ["enable_compilation_cache", "cache_dir", "DEFAULT_CACHE_DIR"]
+__all__ = ["enable_compilation_cache", "DEFAULT_CACHE_DIR"]
 
 _JAX_ENV = "JAX_COMPILATION_CACHE_DIR"
 
@@ -58,13 +57,6 @@ _CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS = "/jax/compilation_cache/cache_misses"
 _listening = False
 _last_lookup = threading.local()  # .hit: this thread's last cache lookup
-
-
-def cache_dir() -> str:
-    """Where the persistent cache lives: ``$JAX_COMPILATION_CACHE_DIR``
-    when the operator set it, else the fixed in-checkout path. The ONE
-    resolution the program store's default directory shares."""
-    return os.environ.get(_JAX_ENV) or DEFAULT_CACHE_DIR
 
 
 def _note_disabled(path: str, exc: BaseException) -> None:

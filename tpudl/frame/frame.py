@@ -232,7 +232,7 @@ class _DispatchWindow:
         from tpudl.obs import attribution as _attr
 
         # carry the consumer's attribution scope onto the dispatch
-        # thread (dispatch_s and compile_s charges happen there)
+        # thread (dispatch_s charges happen there)
         self._futs.append(self._ex.submit(_attr.carry(call)))
         self._report.gauge("dispatch_inflight", len(self._futs))
 
@@ -715,12 +715,6 @@ class Frame:
           If the primary ``batch_size`` itself is not a rung, fusion
           drops to per-batch dispatch (a fused stack would interleave
           the pad rows).
-        - ``aot`` (env ``TPUDL_COMPILE_AOT``, default off): consult
-          the AOT program store at dispatch — a hit executes a
-          precompiled executable (restored from disk on process start:
-          zero trace, zero compile); a miss runs the jitted path
-          unchanged and background-compiles the signature so the NEXT
-          process starts warm. ``compile.{hits,misses}`` count both.
         ``supervise`` (env ``TPUDL_FRAME_DEGRADE``, default OFF): arm
         the fault-containment supervisor (FAULTS.md,
         :mod:`tpudl.frame.supervisor`). Classified executor faults
@@ -777,7 +771,6 @@ class Frame:
         cache_key: str | None = None,
         device_cache: bool | None = None,
         buckets=None,
-        aot: bool | None = None,
         _supervisor=None,
     ) -> "Frame":
         """One executor attempt: the full staged pipeline (the
@@ -1029,29 +1022,16 @@ class Frame:
                         # contract — same rule as the mesh gate)
                         seeded.remove("fuse_steps")
 
-        # -- tpudl.compile: shape buckets + AOT program store -------------
-        # (COMPILE.md; PIPELINE.md "Bucket pick & AOT dispatch".) The
-        # ladder snaps ragged dispatch shapes onto O(log n) rungs; the
-        # program store serves precompiled executables at dispatch and
-        # records misses for the next process. Both are opt-in
-        # (TPUDL_COMPILE_BUCKETS / TPUDL_COMPILE_AOT or the kwargs),
-        # device fns only, and the serial kill switch disarms them like
-        # every other fast-path stage.
+        # -- tpudl.compile: shape buckets --------------------------------
+        # (COMPILE.md; PIPELINE.md "Bucket pick".) The ladder snaps
+        # ragged dispatch shapes onto O(log n) rungs. Opt-in
+        # (TPUDL_COMPILE_BUCKETS or the kwarg), device fns only, and the
+        # serial kill switch disarms it like every other fast-path stage.
         ladder = None
-        store = None
         if device_fn_real and not killed:
             from tpudl.compile import buckets as _bk
 
             ladder = _bk.resolve_ladder(buckets)
-            from tpudl.compile import store as _aot_store
-
-            if _aot_store.aot_enabled(aot):
-                store = _aot_store.get_program_store()
-                # fresh-process warm start: deserialize persisted
-                # executables on the background pool so the first
-                # batches can already hit (idempotent per process)
-                store.ensure_restored()
-                store.note_ladder(ladder)
         bucket_full = False
         if ladder is not None:
             # does the PRIMARY batch size itself snap to a rung? If it
@@ -1092,11 +1072,8 @@ class Frame:
             "batch_cache": bool(cache is not None),
             "device_cache": bool(dcache is not None),
             # tpudl.compile (COMPILE.md): the bucket ladder in force
-            # ("off" = exact shapes) and whether dispatch consults the
-            # AOT program store — the roofline's cold-start attribution
-            # and the live monitor's compile line both read these
+            # ("off" = exact shapes)
             "buckets": ladder.spec if ladder is not None else "off",
-            "aot": bool(store is not None),
         }
         obs.set_last_pipeline(report)
         if _supervisor is not None:
@@ -1475,8 +1452,7 @@ class Frame:
         # runs alone — window warmup — so no second writer races it)
         first_dispatched: list = []
 
-        def dispatch(call_fn, args, idx, n_pad, fused=False, pin=None,
-                     donate_key=False):
+        def dispatch(call_fn, args, idx, n_pad, fused=False, pin=None):
             """Issue one dispatch: directly on the consumer (serial /
             depth 1) or onto the in-flight window. The dispatch stage
             itself — fault point, fn call, and starting the outputs'
@@ -1505,26 +1481,7 @@ class Frame:
                     t_disp = time.perf_counter()
                     with report.stage("dispatch"):
                         _faults.fire("frame.dispatch", index=idx)
-                        if store is not None:
-                            # AOT program store (COMPILE.md): a hit
-                            # executes a precompiled (possibly
-                            # restored-from-disk) program — no trace
-                            # possible; a miss runs the jitted path
-                            # unchanged and background-compiles the
-                            # signature for the next process. Only
-                            # pure-rung per-batch shapes are marked
-                            # bucketed (the validator's shapes↔ladder
-                            # audit): a fused stack leads with M, and
-                            # a mesh target rounds the rung up to the
-                            # data-axis multiple.
-                            result = store.call(
-                                call_fn, call_args, donate=donate_key,
-                                bucketed=(ladder is not None
-                                          and not fused
-                                          and mesh is None),
-                                report=report)
-                        else:
-                            result = call_fn(*call_args)
+                        result = call_fn(*call_args)
                     # attribution: device seconds this scope consumed
                     # (the quota broker's currency, ROADMAP item 5)
                     _attr.charge("dispatch_s",
@@ -1578,18 +1535,13 @@ class Frame:
                             # group per-batch
                             for packed, n_pad, pin in group:
                                 dispatch(_run_fn_direct(), packed,
-                                         consumed, n_pad, pin=pin,
-                                         donate_key=(donate_flag
-                                                     and plan
-                                                     is not None))
+                                         consumed, n_pad, pin=pin)
                             continue
                         fused_fn = _fused_wrapper(
                             _run_fn(), fuse, n_args=len(input_cols),
                             donate=donate_flag)
                         dispatch(fused_fn, stacked, consumed, 0,
-                                 fused=True,
-                                 donate_key=bool(donate_flag
-                                                 and input_cols))
+                                 fused=True)
                     else:
                         packed, n_pad, pin = next_prepared()
                         if pin is not None:
@@ -1607,9 +1559,7 @@ class Frame:
                                      n_pad, pin=pin)
                         else:
                             dispatch(_run_fn_direct(), packed,
-                                     consumed, n_pad,
-                                     donate_key=(donate_flag
-                                                 and plan is not None))
+                                     consumed, n_pad)
                 while window is not None and len(window):
                     handle(*window.pop())
             finally:

@@ -295,29 +295,6 @@ class JobRuntime:
         m["attempt"] = int(m.get("attempt", 0)) + 1
         m["status"] = "running"
         m["pid"] = os.getpid()
-        try:
-            from tpudl import compile as _compile
-
-            if _compile.aot_enabled():
-                # warm restart (ISSUE 15, COMPILE.md): record the AOT
-                # program store this job compiles into, and on a
-                # RESUME restore its serialized executables before the
-                # payload's first dispatch — a preempted-and-relaunched
-                # job must not re-pay the ~60s/program cold start its
-                # first attempt already paid
-                m["program_store"] = _compile.store_dir()
-                if prev is not None:
-                    restored = _compile.warm_start(block=True)
-                    from tpudl.obs import flight as _flight
-
-                    _flight.get_recorder().record_event(
-                        "job.aot_warm_start", restored=restored,
-                        store=m["program_store"])
-        # tpudl: ignore[swallowed-except] — the warm start is an
-        # accelerator: a broken/foreign store must never block a
-        # resume (the run just compiles cold, as before ISSUE 15)
-        except Exception:
-            pass
         self._manifest = m
         self._persist()
         try:

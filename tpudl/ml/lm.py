@@ -125,7 +125,7 @@ class LMFeaturizer(_LMStage):
             return fn
 
         jfn = self._cached_jit(
-            (model.aot_token, id(w), "featurize", self.tp), build)
+            (model.arch_token, id(w), "featurize", self.tp), build)
         out = frame.map_batches(
             jfn, [self.getInputCol()], [self.getOutputCol()],
             batch_size=self.batchSize, pack=pack, **self._codec_opts())
@@ -197,7 +197,7 @@ class LMClassifier(_LMStage):
             return fn
 
         jfn = self._cached_jit(
-            (model.aot_token, id(w), "classify", tuple(class_ids),
+            (model.arch_token, id(w), "classify", tuple(class_ids),
              self.tp), build)
         out_col = self.getOutputCol()
         out = frame.map_batches(

@@ -49,13 +49,6 @@ class ImageBatchWarmup:
         on-device pipeline resizes: the traced signature is the *input*
         shape). Only the full-batch signature is warmed; a ragged tail
         batch compiles during the transform. Returns ``self``.
-
-        With the AOT program store armed (``TPUDL_COMPILE_AOT``,
-        COMPILE.md) this becomes a pure AOT warm call: the program is
-        ``lower().compile()``-d from declared abstract shapes — no
-        synthetic batch, no real-data trace, no device execution at
-        all — and lands in the store, so the NEXT process restores it
-        serialized and skips even this compile.
         """
         import os as _os
 
@@ -75,54 +68,6 @@ class ImageBatchWarmup:
         # match the executor's donation setting, or this warms a
         # program variant the timed window never runs
         donate = _os.environ.get("TPUDL_FRAME_DONATE", "1") != "0"
-        from tpudl import compile as _compile
-
-        if _compile.aot_enabled():
-            # AOT warm call (ISSUE 15): declared-signature compile
-            # through the program store — the executor's dispatch hits
-            # these exact keys, and the serialized executables make the
-            # next process's warmup a deserialization
-            store = _compile.get_program_store()
-            store.ensure_restored(block=True)
-            # mirror the executor's bucket pick EXACTLY: with a ladder
-            # armed the dispatch shape is the rung (mesh: rounded up to
-            # the data axis), and a non-rung batchSize drops fusion —
-            # warming the raw batchSize would compile a program the
-            # timed window never runs
-            ladder = _compile.resolve_ladder(None)
-            rows = int(self.batchSize)
-            if ladder is not None:
-                rows = ladder.pick(rows)
-                if rows != int(self.batchSize):
-                    warm_fused = False
-            if mesh is not None:
-                from tpudl import mesh as M
-
-                axis = mesh.shape[M.DATA_AXIS]
-                pad_shape = ((-(-rows // axis)) * axis,) + x.shape[1:]
-                aval = jax.ShapeDtypeStruct(
-                    pad_shape, dtype,
-                    sharding=M.batch_sharding(mesh,
-                                              ndim=len(pad_shape)))
-            else:
-                aval = jax.ShapeDtypeStruct((rows,) + x.shape[1:],
-                                            dtype)
-            store.compile_signature(
-                jfn, [aval], donate=False,
-                bucketed=(ladder is not None and mesh is None))
-            if warm_fused:
-                fused = _frame._fused_wrapper(jfn, int(fuse), n_args=1,
-                                              donate=donate)
-                stacked_shape = (int(fuse),) + tuple(aval.shape)
-                if mesh is not None:
-                    sds = jax.ShapeDtypeStruct(
-                        stacked_shape, dtype,
-                        sharding=M.stacked_batch_sharding(
-                            mesh, ndim=len(stacked_shape)))
-                else:
-                    sds = jax.ShapeDtypeStruct(stacked_shape, dtype)
-                store.compile_signature(fused, [sds], donate=donate)
-            return self
         if mesh is not None:
             from tpudl import mesh as M
 

@@ -17,7 +17,7 @@ loop and a fine-tune sharing the process are indistinguishable in
   scope (pinned by tests/test_obs_attribution.py's interleaved runs).
 - **ScopeLedger** — bounded per-scope running aggregates (exact, not
   sampled): rows/tokens in+out, wire bytes shipped, HBM bytes resident
-  + peak, dispatch/compile seconds, retry/degradation counts, serve
+  + peak, dispatch seconds, retry/degradation counts, serve
   completions and SLO samples. LRU-bounded at ``TPUDL_OBS_SCOPES``
   scopes under ONE registered named lock (``obs.attribution.ledger``,
   locks.py); an evicted scope folds its totals into the explicit
@@ -57,7 +57,7 @@ __all__ = ["Scope", "scope", "current_scope", "carry", "ScopeLedger",
 # floats throughout — bytes/counts stay integral in practice)
 LEDGER_FIELDS = ("rows_in", "rows_out", "tokens_in", "tokens_out",
                  "wire_bytes", "hbm_bytes", "hbm_peak_bytes",
-                 "dispatch_s", "compile_s", "retries", "degradations",
+                 "dispatch_s", "retries", "degradations",
                  "serve_completed", "slo_samples")
 
 # the reconciliation contract: ledger field → the global metric it must
@@ -68,7 +68,6 @@ LEDGER_FIELDS = ("rows_in", "rows_out", "tokens_in", "tokens_out",
 RECONCILED = (
     ("wire_bytes", "data.wire.bytes_shipped", "counter"),
     ("hbm_bytes", "data.hbm.bytes_resident", "gauge"),
-    ("compile_s", "compile.aot_s", "counter"),
     ("retries", "retry.attempts", "counter"),
     ("degradations", "frame.degraded.rungs", "counter"),
     ("serve_completed", "serve.completed", "counter"),

@@ -103,9 +103,9 @@ SLO_REMEDIES = {
                   "TPUDL_SERVE_SLOTS or add serving capacity",
     "batching": "rung packing is the cost — check the prompt-bucket "
                 "ladder (TPUDL_BUCKET_LADDER)",
-    "prefill": "first-token work dominates — warm the AOT program "
-               "store (TPUDL_COMPILE_AOT) so prefill rungs restore, "
-               "not compile",
+    "prefill": "first-token work dominates — keep "
+               "JAX_COMPILATION_CACHE_DIR across restarts so prefill "
+               "rungs load from the persistent cache, not compile",
     "decode": "decode steps dominate — lower max_new, raise "
               "TPUDL_SERVE_SLOTS, or add device capacity",
 }

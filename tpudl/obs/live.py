@@ -236,32 +236,21 @@ def _hbm_section(metrics: dict, now: float) -> dict | None:
 
 
 def _compile_section(metrics: dict) -> dict | None:
-    """The status file's compile line (ISSUE 15): AOT program-store
-    hit rate, programs restored/compiled, seconds spent in AOT work,
-    bucket pad rows, and whether the persistent cache ever disabled —
-    a fleet cold-starting (misses climbing, nothing restored, or
-    cache_disabled > 0) is visible LIVE. None when no compile metric
+    """The status file's compile line: bucket pad rows, and whether
+    the persistent cache ever disabled — a fleet cold-starting
+    (cache_disabled > 0) is visible LIVE. None when no compile metric
     ever published in this process."""
     def val(name):
         entry = metrics.get(name) or {}
         v = entry.get("value")
         return v if isinstance(v, (int, float)) else None
 
-    hits = val("compile.hits")
-    misses = val("compile.misses")
-    if hits is None and misses is None \
-            and val("compile.programs_restored") is None \
-            and val("compile.cache_disabled") is None:
+    pad_rows = val("compile.bucket_pad_rows")
+    disabled = val("compile.cache_disabled")
+    if pad_rows is None and disabled is None:
         return None
-    return {
-        "hits": int(hits or 0),
-        "misses": int(misses or 0),
-        "programs_restored": int(val("compile.programs_restored") or 0),
-        "programs_compiled": int(val("compile.programs_compiled") or 0),
-        "aot_s": round(val("compile.aot_s") or 0.0, 3),
-        "bucket_pad_rows": int(val("compile.bucket_pad_rows") or 0),
-        "cache_disabled": int(val("compile.cache_disabled") or 0),
-    }
+    return {"bucket_pad_rows": int(pad_rows or 0),
+            "cache_disabled": int(disabled or 0)}
 
 
 def _serve_section(metrics: dict) -> dict | None:
@@ -678,16 +667,8 @@ def render(statuses: list[dict], now: float | None = None) -> str:
             lines.append(line)
         comp = st.get("compile") or {}
         if comp:
-            line = (f"  compile:    hits {comp.get('hits', 0)}"
-                    f"  misses {comp.get('misses', 0)}")
-            if comp.get("programs_restored"):
-                line += f"  restored {comp['programs_restored']}"
-            if comp.get("programs_compiled"):
-                line += f"  aot {comp['programs_compiled']}"
-            if comp.get("aot_s"):
-                line += f" ({comp['aot_s']:.1f}s)"
-            if comp.get("bucket_pad_rows"):
-                line += f"  pad_rows {comp['bucket_pad_rows']}"
+            line = (f"  compile:    pad_rows "
+                    f"{comp.get('bucket_pad_rows', 0)}")
             if comp.get("cache_disabled"):
                 line += (f"  CACHE-DISABLED "
                          f"x{comp['cache_disabled']}")

@@ -331,14 +331,10 @@ def analyze(report: dict | None = None, *,
             # the wire subtraction above both key on these
             "device_cache": report.get("device_cache"),
             "bytes_hbm_hit": bytes_hbm or None,
-            # cold-start attribution (ISSUE 15): the first dispatch
-            # carries trace+compile on a cold process; its excess over
-            # the steady-state per-dispatch time is what the AOT
-            # program store (COMPILE.md) removes — the `precompile`
-            # advisor rec keys on it
-            "aot": report.get("aot"),
-            "aot_hits": calls.get("aot_hits"),
-            "aot_misses": calls.get("aot_misses"),
+            # cold-start attribution: the first dispatch carries
+            # trace+compile on a cold process (a load from the
+            # persistent cache on a warm one, COMPILE.md); its excess
+            # over the steady-state per-dispatch time is cold_start_s
             "first_dispatch_s": calls.get("first_dispatch_s"),
             "cold_start_s": _cold_start_s(stages, calls, n_disp),
             # serve-session shape (ISSUE 17): mean slot occupancy and
@@ -362,9 +358,8 @@ def _next_pow2(x: float) -> int:
 
 def _cold_start_s(stages: dict, calls: dict, n_disp: int) -> float | None:
     """The first dispatch's excess over the steady-state per-dispatch
-    time — trace + XLA compile on a cold process (the measured cost the
-    AOT program store removes). None when the run can't attribute it
-    (single dispatch, or no first-dispatch sample)."""
+    time — trace + XLA compile on a cold process. None when the run
+    can't attribute it (single dispatch, or no first-dispatch sample)."""
     first = float(calls.get("first_dispatch_s") or 0.0)
     if first <= 0 or n_disp <= 1:
         return None
@@ -427,19 +422,6 @@ def advise(rr: RooflineReport) -> list[dict]:
             "reason": reason,
         })
 
-    # 0) cold start → precompile (ISSUE 15): the first dispatch paid
-    #    trace + XLA compile while every later one ran warm. The AOT
-    #    program store removes it from every FUTURE process (restored
-    #    serialized executables before the first batch lands), so the
-    #    rec fires only when the store is not already armed — armed
-    #    runs warm themselves up on the next start automatically.
-    cold = inp.get("cold_start_s")
-    if cold and cold > _MINOR_FRAC * rr.gap_s and not inp.get("aot"):
-        _rec("precompile", "off", "on", float(cold),
-             f"the first dispatch carried {float(cold):.2f}s of "
-             f"trace+compile (cold start); arm TPUDL_COMPILE_AOT=1 so "
-             f"a fresh process restores precompiled programs from the "
-             f"AOT store before the first batch (COMPILE.md)")
     # 1) dispatch round-trip → dispatch_depth (the async window): depth
     #    d hides all but ~1/d of the blocking round-trip residue, and —
     #    because the D2H copies start at dispatch — the same share of

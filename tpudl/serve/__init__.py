@@ -5,9 +5,7 @@ per-request deadlines, rung-bucketed dynamic batching for ragged
 featurize/UDF payloads, slot-based continuous batch decoding for
 ``TinyCausalLM`` on a fixed-geometry KV cache (one compiled decode-step
 program serves a churning request mix with zero retraces), and a
-multi-model registry that warm-starts every model's programs from the
-persisted store so time-to-first-token is a deserialization, not a
-60-second jit. Overload rides the PR-14 degradation ladder; SLO
+multi-model registry. Overload rides the PR-14 degradation ladder; SLO
 metrics (``serve.*``) publish through ``tpudl.obs``.
 
 Request-scoped telemetry (ISSUE 18): every request carries a

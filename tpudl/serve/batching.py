@@ -8,8 +8,7 @@ flight, pads the row count UP to the PR-15 :class:`BucketLadder` rung
 (``pad_to`` — row-0 repeat, bitwise-honest: pad rows are stripped
 before results fan back out), and dispatches ONE program. The rung set
 is O(log n), so at steady state every dispatch replays an
-already-traced signature — traceck-provably zero retraces — and when
-the AOT store is armed the programs come from disk, not from jit.
+already-traced signature — traceck-provably zero retraces.
 
 ``serve.batches`` counts dispatches; ``serve.batch_occupancy`` gauges
 real rows over rung rows (the saturation SLO: > 0.5 under load);
@@ -32,10 +31,7 @@ class RungBatcher:
     ``fn`` maps ``[N, ...] -> [N, ...]`` (leading dim preserved — the
     UDF/featurize/embed contract); ``buckets`` resolves through
     :func:`tpudl.compile.resolve_ladder` (``None`` = consult
-    ``TPUDL_COMPILE_BUCKETS``, ``True`` = default pow2ish ladder).
-    When ``fn`` is jittable and the AOT store is armed, dispatch
-    routes through ``ProgramStore.call`` so steady state executes
-    precompiled programs."""
+    ``TPUDL_COMPILE_BUCKETS``, ``True`` = default pow2ish ladder)."""
 
     def __init__(self, fn, *, buckets=True):
         from tpudl.compile import resolve_ladder
@@ -50,8 +46,7 @@ class RungBatcher:
         """Dispatch one padded batch for ``payloads`` (a list of
         ``[rows_i, ...]`` arrays, ragged in ``rows_i``) and split the
         result back per request, pad rows stripped."""
-        from tpudl.compile import (aot_enabled, count_pad_rows,
-                                   get_program_store, pad_to)
+        from tpudl.compile import count_pad_rows, pad_to
 
         payloads = [np.asarray(p) for p in payloads]
         if not payloads:
@@ -63,11 +58,7 @@ class RungBatcher:
         rung = self.rung_for(n)
         padded = pad_to(batch, rung)
         count_pad_rows(rung - n)
-        if aot_enabled() and hasattr(self._fn, "lower"):
-            out = get_program_store().call(self._fn, (padded,))
-        else:
-            out = self._fn(padded)
-        out = np.asarray(out)[:n]
+        out = np.asarray(self._fn(padded))[:n]
         _metrics.counter("serve.batches").inc()
         _metrics.gauge("serve.batch_occupancy").set(n / max(rung, 1))
         cuts = np.cumsum(sizes)[:-1]

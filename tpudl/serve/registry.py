@@ -1,20 +1,15 @@
-"""Multi-model serve registry with warm-start from the program store.
+"""Multi-model serve registry (SERVE.md).
 
-Registration is where TTFT is won (SERVE.md): ``add_model`` builds the
-model's :class:`~tpudl.serve.slots.SlotDecoder` and — when the AOT
-store is armed — restores the persisted program table
-(``ensure_restored(block=True)``) and submits every serve-loop
-signature through ``precompile_serve``. A previously-served model's
-first token is then a DESERIALIZATION away, not a 60-second jit (not
-measured on the chip).
+``add_model`` builds the model's :class:`~tpudl.serve.slots.SlotDecoder`;
+registration compiles nothing — each serve program compiles on its
+first request, from the persistent XLA cache when a previous process
+with the same ``JAX_COMPILATION_CACHE_DIR`` compiled it (COMPILE.md).
 
 One instance lock (``serve.registry``) guards the name→entry map;
 the ``serve.models`` gauge publishes outside it.
 """
 
 from __future__ import annotations
-
-import time
 
 from tpudl.obs import metrics as _metrics
 from tpudl.serve.slots import SlotDecoder
@@ -24,22 +19,19 @@ __all__ = ["ModelEntry", "ModelRegistry"]
 
 
 class ModelEntry:
-    """One registered model: its engine plus warm-start forensics.
-    ``tokenizer`` is set for text-serving entries (``add_generator``)
-    so the request path can encode prompts / decode completions with
-    the exact vocab the model was trained against."""
+    """One registered model and its engine. ``tokenizer`` is set for
+    text-serving entries (``add_generator``) so the request path can
+    encode prompts / decode completions with the exact vocab the model
+    was trained against."""
 
-    __slots__ = ("name", "model", "params", "engine",
-                 "warm_signatures", "warm_s", "tokenizer")
+    __slots__ = ("name", "model", "params", "engine", "tokenizer")
 
     def __init__(self, name: str, model, params, engine: SlotDecoder,
-                 warm_signatures: int, warm_s: float, tokenizer=None):
+                 tokenizer=None):
         self.name = name
         self.model = model
         self.params = params
         self.engine = engine
-        self.warm_signatures = warm_signatures
-        self.warm_s = warm_s
         self.tokenizer = tokenizer
 
 
@@ -54,36 +46,16 @@ class ModelRegistry:
                  slots: int | None = None,
                  cache_len: int | None = None,
                  temperature: float = 0.0, prompt_buckets=True,
-                 prompt_rungs=None, mesh=None, tp: bool = False,
-                 warm: bool = True, tokenizer=None) -> ModelEntry:
-        """Build the engine for ``model`` and (``warm=True``, store
-        armed) AOT-warm its serve programs. ``prompt_rungs`` overrides
-        the warmed prefill signature set; default is every ladder rung
-        the fixed cache can admit (an over-approximation costs compile
-        time once, never correctness — a rung missed here compiles on
-        first use like any store miss)."""
+                 mesh=None, tp: bool = False,
+                 tokenizer=None) -> ModelEntry:
+        """Build the engine for ``model`` and file it under ``name``."""
         engine = SlotDecoder(model, params, slots=slots,
                              cache_len=cache_len,
                              temperature=temperature,
                              prompt_buckets=prompt_buckets, mesh=mesh,
                              tp=tp)
-        warm_n, warm_s = 0, 0.0
-        if warm:
-            t0 = time.perf_counter()
-            if prompt_rungs is None:
-                prompt_rungs = (
-                    engine._ladder.rungs_up_to(engine.cache_len - 1)
-                    if engine._ladder else [])
-            if prompt_rungs:
-                warm_n = model.precompile_serve(
-                    params, slots=engine.slots,
-                    cache_len=engine.cache_len,
-                    prompt_rungs=prompt_rungs,
-                    temperature=engine.temperature, mesh=mesh, tp=tp,
-                    block=True)
-            warm_s = time.perf_counter() - t0
-        entry = ModelEntry(str(name), model, params, engine, warm_n,
-                           warm_s, tokenizer=tokenizer)
+        entry = ModelEntry(str(name), model, params, engine,
+                           tokenizer=tokenizer)
         with self._lock:
             self._entries[entry.name] = entry
             count = len(self._entries)
@@ -92,8 +64,7 @@ class ModelRegistry:
 
     def add_generator(self, name: str, generator, *,
                       slots: int | None = None,
-                      cache_len: int | None = None,
-                      warm: bool = True) -> ModelEntry:
+                      cache_len: int | None = None) -> ModelEntry:
         """Register an :class:`~tpudl.ml.lm.LMGenerator`'s signature for
         online serving: the transformer already binds the model, the
         weights, the sampling temperature, the prompt bucket ladder,
@@ -114,7 +85,7 @@ class ModelRegistry:
             prompt_buckets=(generator.promptBuckets
                             if generator.promptBuckets is not None
                             else True),
-            mesh=generator.mesh, tp=bool(generator.tp), warm=warm,
+            mesh=generator.mesh, tp=bool(generator.tp),
             tokenizer=generator.tokenizer)
 
     def get(self, name: str) -> ModelEntry:

@@ -127,13 +127,6 @@ class SlotDecoder:
             return np.asarray(jax.random.PRNGKey(int(rng)))
         return np.asarray(rng)
 
-    def _call(self, fn, args):
-        from tpudl.compile import aot_enabled, get_program_store
-
-        if aot_enabled():
-            return get_program_store().call(fn, args)
-        return fn(*args)
-
     # -- the three verbs ---------------------------------------------------
     def insert(self, request) -> int:
         """Prefill ``request``'s prompt into a free slot; returns the
@@ -163,10 +156,10 @@ class SlotDecoder:
         fill = self.model._slot_prefill_program(
             rung, self.slots, self.cache_len, self.temperature,
             mesh=self.mesh, tp=self.tp)
-        first, self._cache = self._call(fill, (
+        first, self._cache = fill(
             self.params, self._cache, jnp.asarray(padded),
             jnp.asarray(key), jnp.asarray(plen, jnp.int32),
-            jnp.asarray(slot, jnp.int32)))
+            jnp.asarray(slot, jnp.int32))
         first_tok = int(np.asarray(first)[0])
         if trace is not None:
             trace.stamp("first_token")
@@ -193,10 +186,10 @@ class SlotDecoder:
         step_fn = self.model._slot_step_program(
             self.slots, self.cache_len, self.temperature,
             mesh=self.mesh, tp=self.tp)
-        nxt, self._cache = self._call(step_fn, (
+        nxt, self._cache = step_fn(
             self.params, self._cache, jnp.asarray(self._tok),
             jnp.asarray(self._pos), jnp.asarray(self._keys),
-            jnp.asarray(self._steps)))
+            jnp.asarray(self._steps))
         nxt = np.asarray(nxt).copy()  # device views are read-only
         cad = self._trace_cadence
         for s in active:

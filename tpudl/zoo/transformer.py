@@ -81,14 +81,10 @@ class TinyCausalLM:
         # compiled generate() programs keyed by static decode geometry
         # (a fresh jax.jit per call would retrace every time)
         self._gen_jits: dict = {}
-        # cross-process program identity for the AOT store (COMPILE.md):
-        # the generate program's FUNCTION closes over this model object,
-        # whose default repr carries a memory address — the token makes
-        # the fingerprint architecture-determined instead. Weights are
-        # ARGUMENTS (shapes in the signature, values at call time), so
-        # a serialized executable is valid for any params of this
-        # architecture.
-        self.aot_token = (f"TinyCausalLM:v{vocab}:d{dim}:h{heads}:"
+        # the architecture's identity (the default repr carries a
+        # memory address): names the model in jit-cache keys and in
+        # error messages
+        self.arch_token = (f"TinyCausalLM:v{vocab}:d{dim}:h{heads}:"
                           f"l{layers}:m{max_len}:e{experts}:"
                           f"c{capacity_factor}")
 
@@ -204,7 +200,7 @@ class TinyCausalLM:
 
         shardings = self.param_shardings(mesh, model_axis)
         M.require_hbm_fit(params, shardings,
-                          what=f"{self.aot_token} params")
+                          what=f"{self.arch_token} params")
         return jax.tree.map(jax.device_put, params, shardings)
 
     def _tp_hooks(self, mesh, tp):
@@ -680,80 +676,6 @@ class TinyCausalLM:
             fn = self._gen_jits[jit_key] = jax.jit(run)
         return fn
 
-    def precompile_serve(self, params, *, slots: int, cache_len: int,
-                         prompt_rungs, temperature: float = 0.0,
-                         mesh=None, tp: bool = False,
-                         block: bool = True) -> int:
-        """AOT-compile the serve-loop programs (one slot-step program +
-        one prefill program per prompt rung) through the program store,
-        so a fresh serving process's time-to-first-token is a
-        deserialization, not a trace+compile (COMPILE.md; the
-        tpudl.serve registry calls this at model registration).
-        Returns the number of signatures submitted; 0 when the store is
-        unarmed."""
-        from tpudl import compile as _compile
-
-        if not _compile.aot_enabled():
-            return 0
-        _, head_axis = self._tp_hooks(mesh, tp)
-        dh = self.dim // self.heads
-        dtype = jnp.asarray(params["embed"]["table"]).dtype
-        cache_sh = None
-        if head_axis is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            cache_sh = NamedSharding(mesh, P(None, None, head_axis,
-                                             None))
-
-        def _aval(a, sh=None):
-            live = getattr(a, "sharding", None)
-            use = live if hasattr(live, "spec") else sh
-            return jax.ShapeDtypeStruct(jnp.shape(a),
-                                        jnp.asarray(a).dtype,
-                                        sharding=use)
-
-        if head_axis is not None:
-            p_avals = jax.tree.map(_aval, params,
-                                   self.param_shardings(mesh))
-        else:
-            p_avals = jax.tree.map(_aval, params)
-        buf = jax.ShapeDtypeStruct((int(slots), int(cache_len),
-                                    self.heads, dh), dtype,
-                                   sharding=cache_sh)
-        cache_avals = [{"k": buf, "v": buf} for _ in range(self.layers)]
-        key = jax.random.PRNGKey(0)
-        key_dtype = jnp.asarray(key).dtype
-        key_shape = jnp.shape(key)
-        store = _compile.get_program_store()
-        store.ensure_restored(block=True)
-        n = 0
-        step_fn = self._slot_step_program(int(slots), int(cache_len),
-                                          float(temperature), mesh=mesh,
-                                          tp=tp)
-        step_avals = (
-            p_avals, cache_avals,
-            jax.ShapeDtypeStruct((int(slots),), jnp.int32),
-            jax.ShapeDtypeStruct((int(slots),), jnp.int32),
-            jax.ShapeDtypeStruct((int(slots),) + key_shape, key_dtype),
-            jax.ShapeDtypeStruct((int(slots),), jnp.int32),
-        )
-        if store.compile_signature(step_fn, step_avals, block=block):
-            n += 1
-        for rung in sorted({int(r) for r in prompt_rungs}):
-            fill_fn = self._slot_prefill_program(
-                rung, int(slots), int(cache_len), float(temperature),
-                mesh=mesh, tp=tp)
-            fill_avals = (
-                p_avals, cache_avals,
-                jax.ShapeDtypeStruct((1, rung), jnp.int32),
-                jax.ShapeDtypeStruct(key_shape, key_dtype),
-                jax.ShapeDtypeStruct((), jnp.int32),
-                jax.ShapeDtypeStruct((), jnp.int32),
-            )
-            if store.compile_signature(fill_fn, fill_avals, block=block):
-                n += 1
-        return n
-
     def _gen_program(self, b: int, plen: int, max_new: int,
                      temperature: float, *, mesh=None, tp: bool = False):
         """The jitted generate program for one static geometry
@@ -817,7 +739,6 @@ class TinyCausalLM:
 
         # a 2-D TP program and the 1-D program for the same geometry
         # are DIFFERENT executables — the mesh topology joins the key
-        # (the same rail the AOT store applies via sharding tokens)
         topo = (tuple(sorted((str(k), int(v))
                              for k, v in mesh.shape.items()))
                 if tp and mesh is not None else None)
@@ -892,64 +813,7 @@ class TinyCausalLM:
         key = rng if rng is not None else jax.random.PRNGKey(0)
         fn = self._gen_program(b, padded, max_new, float(temperature),
                                mesh=mesh, tp=tp)
-        args = (params, prompt, key, jnp.int32(plen))
-        from tpudl.compile import aot_enabled, get_program_store
-
-        if aot_enabled():
-            # serving hot path: a store hit (precompile_generate, or a
-            # restored executable from the last process) dispatches the
-            # prefill/decode scans with zero trace; a miss records the
-            # geometry so the next process restores it
-            return get_program_store().call(fn, args)
-        return fn(*args)
-
-    def precompile_generate(self, params, batch: int, prompt_len: int,
-                            max_new: int, *, temperature: float = 0.0,
-                            prompt_buckets=None, mesh=None,
-                            tp: bool = False, block: bool = True) -> bool:
-        """AOT-compile the generate program for one declared serving
-        geometry THROUGH the program store (COMPILE.md): no prompt, no
-        trace at serving time — and the serialized executable makes the
-        next process's first request hit a restored program. With
-        ``prompt_buckets`` the declared length snaps to its rung, so
-        one precompile covers every prompt in the bucket. ``tp=True``
-        warms the 2-D tensor-parallel program: the param avals carry
-        their :meth:`param_shardings` (or the live arrays' shardings),
-        so the store keys and restores the model-sharded executable
-        distinctly from the 1-D one. Returns False when the store is
-        unarmed."""
-        from tpudl import compile as _compile
-
-        if not _compile.aot_enabled():
-            return False
-        _, head_axis = self._tp_hooks(mesh, tp)
-        padded = self._gen_bucket(int(prompt_len), int(max_new),
-                                  prompt_buckets)
-        fn = self._gen_program(int(batch), padded, int(max_new),
-                               float(temperature), mesh=mesh, tp=tp)
-        key = jax.random.PRNGKey(0)
-
-        def _aval(a, sh=None):
-            live = getattr(a, "sharding", None)
-            use = live if hasattr(live, "spec") else sh
-            return jax.ShapeDtypeStruct(jnp.shape(a), jnp.asarray(a).dtype,
-                                        sharding=use)
-
-        if head_axis is not None:
-            p_avals = jax.tree.map(_aval, params,
-                                   self.param_shardings(mesh))
-        else:
-            p_avals = jax.tree.map(_aval, params)
-        avals = (
-            p_avals,
-            jax.ShapeDtypeStruct((int(batch), padded), jnp.int32),
-            jax.ShapeDtypeStruct(jnp.shape(key),
-                                 jnp.asarray(key).dtype),
-            jax.ShapeDtypeStruct((), jnp.int32),
-        )
-        store = _compile.get_program_store()
-        store.ensure_restored(block=True)
-        return store.compile_signature(fn, avals, block=block)
+        return fn(params, prompt, key, jnp.int32(plen))
 
     # -- training loss -----------------------------------------------------
     def loss_fn(self, *, mesh=None, use_pallas: bool = False,
